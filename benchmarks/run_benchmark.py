@@ -60,8 +60,7 @@ def run(args) -> dict:
         cmd += ["-o", ov]
     env = dict(os.environ)
     if args.cpu_devices:
-        # tools/train.py routes this through jax.config (env vars can
-        # be overridden by site customization)
+        # tools/train.py routes this through jax.config
         env["PFX_CPU_DEVICES"] = str(args.cpu_devices)
     proc = subprocess.run(cmd, capture_output=True, text=True, env=env,
                           cwd=REPO)

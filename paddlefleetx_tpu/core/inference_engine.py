@@ -44,6 +44,8 @@ class InferenceEngine:
 
     def __init__(self, model_dir: str, mp_degree: int = 1, mesh=None):
         self.model_dir = model_dir
+        from ..utils.env import setup_compilation_cache
+        setup_compilation_cache()
         t_load = time.time()
         meta = load_spec(model_dir)["metadata"]
 

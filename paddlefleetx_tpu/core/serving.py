@@ -1842,7 +1842,9 @@ class GenerationServer:
         :meth:`kv_import_release`), so the registry entry outlives
         request churn. False — caller falls back to plain re-prefill
         — when this server is not paged/sharing, the pool cannot host
-        ``n_pages``, or the prompt is already resident."""
+        ``n_pages`` (free pages, or the one-slot growth reserve that
+        import pins must not eat), or the prompt is already
+        resident."""
         with self._surface_lock:
             if not self.paged or not self._prefix_sharing:
                 return False
@@ -1852,6 +1854,17 @@ class GenerationServer:
                 return False
             if n_pages > self._max_pages or \
                     self._alloc.free_pages < n_pages:
+                return False
+            # import pins live outside every slot, where preemption
+            # cannot reclaim them (_alloc_or_preempt evicts slots,
+            # not imports). Config validation promises that a lone
+            # slot can always grow to max_kv_pages — the pins must
+            # leave that much of the pool alone, or a fast prefill
+            # peer (the async router) fills the pool with imports and
+            # the first admitted slot has no page to grow into.
+            pinned = sum(len(p) for p in self._imports.values())
+            if (self._alloc.num_pages - 1) - pinned - n_pages \
+                    < self._max_pages:
                 return False
             pids = self._alloc.alloc_many(n_pages)
             self._cache = scatter_kv_pages(

@@ -5,7 +5,8 @@ Importing this module compiles ``fast_index_map.cpp`` on first use
 ranks wait on it — the reference's rank-0-compiles-others-spin-wait
 protocol, ``gpt_dataset.py:47-69``) and exposes numpy-typed wrappers.
 Import failure (no compiler, build error) is the signal for callers
-to fall back to the Python builders.
+to fall back to the Python builders. The library is rebuilt whenever
+it was not built from the present source (``_ensure_built``).
 """
 
 from __future__ import annotations
@@ -20,24 +21,47 @@ import numpy as np
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SO = os.path.join(_DIR, "libfast_index_map.so")
 _SRC = os.path.join(_DIR, "fast_index_map.cpp")
+#: sha256 of the source the library was built from (git-ignored)
+_STAMP = _SO + ".srchash"
+
+
+def _src_digest() -> str:
+    import hashlib
+    with open(_SRC, "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()
 
 
 def _ensure_built() -> str:
     # The freshness check must happen under the lock: an unlocked
     # fast path could dlopen a half-written .so while another rank's
     # compiler is still streaming it out.
+    #
+    # Fresh means "built from THIS source": the library carries a
+    # stamp with the source's sha256. mtimes cannot say so — a copied
+    # tree (the chip tool's, a container layer) gives every file the
+    # copy's time, and a stale library then looks newer than the
+    # source both to a getmtime comparison and to make itself.
     lock_path = os.path.join(_DIR, ".build.lock")
     with open(lock_path, "w") as lock:
         fcntl.flock(lock, fcntl.LOCK_EX)  # one builder; others wait here
         try:
-            if not (os.path.exists(_SO) and os.path.getmtime(_SO) >=
-                    os.path.getmtime(_SRC)):
-                proc = subprocess.run(["make", "-C", _DIR],
+            digest = _src_digest()
+            try:
+                with open(_STAMP) as f:
+                    fresh = os.path.exists(_SO) and \
+                        f.read().strip() == digest
+            except OSError:
+                fresh = False
+            if not fresh:
+                proc = subprocess.run(["make", "-B", "-C", _DIR],
                                       capture_output=True, text=True)
                 if proc.returncode != 0:
                     raise ImportError(
                         "fast_index_map compile failed "
                         f"(exit {proc.returncode}):\n{proc.stderr}")
+                with open(_STAMP + ".tmp", "w") as f:
+                    f.write(digest + "\n")
+                os.replace(_STAMP + ".tmp", _STAMP)
         finally:
             fcntl.flock(lock, fcntl.LOCK_UN)
     return _SO

@@ -196,6 +196,8 @@ class _QuantDense(nn.Module):
         s = scale.reshape(n_dim)
         try:
             from ...ops.pallas.quantized_matmul import quantized_matmul
+            from ...ops.ring_attention import require_one_device
+            require_one_device("quantized_matmul")
             y = quantized_matmul(x2, w2, s)
             metrics.inc("quant/matmul")
         except (ImportError, NotImplementedError):
@@ -272,6 +274,8 @@ class _LoRADelta(nn.Module):
         b = lora_b.astype(dtype)
         try:
             from ...ops.lora import grouped_lora_delta
+            from ...ops.ring_attention import require_one_device
+            require_one_device("grouped_lora_delta")
             d = grouped_lora_delta(x2, ids, a, b)
             metrics.inc("lora/grouped")
         except (ImportError, NotImplementedError):
@@ -649,7 +653,9 @@ class MultiHeadAttention(nn.Module):
                 use_flash=cfg.use_flash_attention,
                 kv_cache_layout=kv_cache_layout,
                 page_table=page_table_arg,
-                k_scale=k_scale, v_scale=v_scale)
+                k_scale=k_scale, v_scale=v_scale,
+                heads_axis="act_heads_cp" if use_ulysses
+                else "act_heads")
         if use_ulysses:
             # all-to-all back: seq re-shards over cp, heads gather
             out = with_logical_constraint(

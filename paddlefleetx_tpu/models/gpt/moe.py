@@ -319,18 +319,45 @@ class MoEMLP(nn.Module):
         if counts is not None:
             try:
                 from ...ops.pallas.grouped_matmul import grouped_matmul
-                # groups ordered (e, row): group e*b + i holds batch
-                # row i's slice of expert e's capacity block
-                g_counts = counts.T.reshape(E * bb)
-                y = grouped_matmul(xe.reshape(E * bb, C, h),
-                                   w1.astype(dtype), g_counts)
-                y = y.reshape(E, bb, C, m)
-                gmm = grouped_matmul
+                from ...ops.ring_attention import (
+                    kernel_mesh, shard_kernel,
+                )
+
+                def gmm(x4, w, w_axes):
+                    """Grouped GEMM over the ``[E, b, C, K]`` buffer,
+                    each device on its own (expert, row, channel)
+                    block. Groups ordered (e, row): group e*b + i
+                    holds batch row i's slice of expert e's capacity
+                    block — locally too, since E and b shard whole.
+                    A contraction dim sharded over mp (the second
+                    GEMM) leaves per-device partial sums: psum them."""
+                    k_mesh = nn.logical_to_mesh_axes(w_axes[1:2])[0] \
+                        if kernel_mesh() is not None else None
+
+                    def per_device(x4, w, cnt):
+                        e, b_, c, kd = x4.shape
+                        # counter + fallback live in _expert_ffn
+                        y = grouped_matmul(  # pfxlint: disable=PFX205
+                            x4.reshape(e * b_, c, kd), w,
+                            cnt.T.reshape(e * b_))
+                        if k_mesh:
+                            y = jax.lax.psum(y, k_mesh)
+                        return y.reshape(e, b_, c, w.shape[2])
+                    x_axes = ("act_expert", "act_expert_batch", None)
+                    return shard_kernel(
+                        per_device, (x4, w, counts),
+                        (x_axes + w_axes[1:2], w_axes,
+                         ("act_expert_batch", "act_expert")),
+                        x_axes + w_axes[2:3])
+
+                y = gmm(xe, w1.astype(dtype),
+                        ("expert", "expert_embed", "expert_mlp"))
                 metrics.inc("moe/sort_pallas")
             except (ImportError, NotImplementedError):
                 # kernel rejected the shape — expert compute falls
                 # back to the XLA einsums on the same grouped buffer
                 # (the dispatch stays sort-based; docs/moe.md)
+                gmm = None
                 metrics.inc("moe/fallback/pallas_rejected")
                 metrics.inc("moe/sort")
         if gmm is None:
@@ -355,8 +382,8 @@ class MoEMLP(nn.Module):
             # padding rows here are gelu(b1), not zero — safe because
             # the kernel's skipped-group outputs are never combined
             # (zero gate weight) so their cotangents arrive as zeros
-            y = gmm(y.reshape(E * bb, C, m), w2.astype(dtype),
-                    g_counts).reshape(E, bb, C, h)
+            y = gmm(y, w2.astype(dtype),
+                    ("expert", "expert_mlp", "expert_embed"))
         else:
             y = jnp.einsum("ebcm,emh->ebch", y, w2.astype(dtype))
         y = y + b2.astype(dtype)[:, None, None, :]

@@ -25,6 +25,21 @@ PEAK_FLOPS_BY_KIND = {
     "TPU v6e": 918e12,
 }
 
+# HBM bandwidth per chip in bytes/s, same keys. Source: Google Cloud
+# TPU documentation, per-generation system-architecture pages ("TPU
+# v5e": 16 GB HBM2e at 819 GB/s; v3 900, v4 1200 (1228 in the v4
+# paper), v5p 2765, v6e 1640 GB/s). Roofline shares need both peaks.
+HBM_BYTES_PER_SEC_BY_KIND = {
+    "TPU v3": 900e9,
+    "TPU v4": 1200e9,
+    "TPU v5 lite": 819e9,
+    "TPU v5e": 819e9,
+    "TPU v5": 2765e9,
+    "TPU v5p": 2765e9,
+    "TPU v6 lite": 1640e9,
+    "TPU v6e": 1640e9,
+}
+
 
 def model_flops_per_token(num_layers: int, hidden_size: int,
                           vocab_size: int, seq: int) -> float:
@@ -44,25 +59,32 @@ def causal_attn_flops(b: int, h: int, s: int, d: int) -> float:
     return 4.0 * b * h * s * s * d * 0.5
 
 
-def peak_flops(device=None) -> Optional[float]:
-    """Per-chip bf16 peak for ``device`` (default: the first attached
-    device), or None off-TPU / for an uncalibrated device_kind — MFU
-    is then reported as n/a rather than against a guessed peak."""
+def _tpu_peak(table, device, what):
     if device is None:
         import jax
-        try:
-            device = jax.devices()[0]
-        except Exception:
-            return None
+        device = jax.devices()[0]
     if device.platform != "tpu":
         return None
-    peak = PEAK_FLOPS_BY_KIND.get(device.device_kind)
-    if peak is None:
-        from ..utils.log import logger
-        logger.warning(
-            "unknown TPU device_kind %r; MFU not reported (add it to "
-            "PEAK_FLOPS_BY_KIND)", device.device_kind)
-    return peak
+    if device.device_kind not in table:
+        raise ValueError(
+            f"no {what} on record for TPU device_kind "
+            f"{device.device_kind!r}: add it to observability/flops.py "
+            f"with its source (a guessed peak would mis-scale every "
+            f"utilization reported against it)")
+    return table[device.device_kind]
+
+
+def peak_flops(device=None) -> Optional[float]:
+    """Per-chip bf16 peak for ``device`` (default: the first attached
+    device); None off-TPU, where MFU is reported as n/a. A TPU whose
+    device_kind is not in the table is an error, not a default."""
+    return _tpu_peak(PEAK_FLOPS_BY_KIND, device, "bf16 peak FLOP/s")
+
+
+def peak_hbm_bytes_per_sec(device=None) -> Optional[float]:
+    """Per-chip HBM bandwidth, same contract as :func:`peak_flops`."""
+    return _tpu_peak(HBM_BYTES_PER_SEC_BY_KIND, device,
+                     "HBM bandwidth")
 
 
 def mfu(tokens_per_sec: float, flops_per_token: float,

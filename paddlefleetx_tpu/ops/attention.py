@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ..observability import metrics
+from .ring_attention import MeshIndivisible, kernel_mesh, shard_kernel
 
 NEG_INF = -1e9
 
@@ -191,6 +192,58 @@ def _xla_attention(q, k, v, bias, causal, query_offset, dropout_rate,
     return checkpoint_name(out, "core_attn")
 
 
+def _flash_per_device(q, k, v, bias, causal, query_offset,
+                      dropout_rate, dropout_rng, heads_axis):
+    """Training flash attention with each device of the active mesh
+    running the kernel on its own ``[b/data, s, h/mp, d]`` block
+    (``ring_attention.shard_kernel``; a direct call when no
+    multi-device mesh is active). All three custom-VJP surfaces of
+    ``flash_attention`` (plain, in-kernel dropout, biased) pass
+    through here, so their backward kernels run per device too."""
+    from .pallas import flash_attention as fa
+    b, _, h, _ = q.shape
+    qkv_axes = ("batch", None, heads_axis, None)
+    args, in_axes = [q, k, v], [qkv_axes] * 3
+    if bias is not None:
+        # canonical [b0, h0, q0, skv] form (each leading dim 1 or
+        # full): full dims shard with q's, broadcast dims replicate
+        bias = fa._canon_bias(bias, b, h, q.shape[1], k.shape[1])
+        args.append(bias)
+        in_axes.append(("batch" if bias.shape[0] > 1 else None,
+                        heads_axis if bias.shape[1] > 1 else None,
+                        None, None))
+    dropout = dropout_rate > 0.0 and dropout_rng is not None
+    mesh_axes = ()
+    if dropout:
+        args.append(jax.random.key_data(dropout_rng))
+        in_axes.append((None,))
+        if kernel_mesh() is not None:
+            import flax.linen as nn
+            mesh_axes = [
+                a for axes in nn.logical_to_mesh_axes(qkv_axes)
+                if axes for a in
+                ((axes,) if isinstance(axes, str) else axes)]
+
+    def per_device(q, k, v, *rest):
+        """One device's block; counter + fallback live in the one
+        caller, :func:`dot_product_attention`."""
+        rest = list(rest)
+        rng = None
+        if dropout:
+            rng = jax.random.wrap_key_data(rest.pop())
+            # the kernel seeds its masks from LOCAL block coordinates;
+            # fold the device's mesh position in so shards holding
+            # different batch rows / heads draw different masks
+            for axis in mesh_axes:
+                rng = jax.random.fold_in(rng, jax.lax.axis_index(axis))
+        return fa.flash_attention(  # pfxlint: disable=PFX205
+            q, k, v, causal=causal, query_offset=query_offset,
+            dropout_rate=dropout_rate if dropout else 0.0,
+            dropout_rng=rng, bias=rest.pop() if rest else None)
+
+    return shard_kernel(per_device, args, in_axes, qkv_axes)
+
+
 def dot_product_attention(
         q: jax.Array, k: jax.Array, v: jax.Array,
         bias: Optional[jax.Array] = None,
@@ -204,7 +257,8 @@ def dot_product_attention(
         kv_cache_layout: bool = False,
         page_table: Optional[jax.Array] = None,
         k_scale: Optional[jax.Array] = None,
-        v_scale: Optional[jax.Array] = None) -> jax.Array:
+        v_scale: Optional[jax.Array] = None,
+        heads_axis: str = "act_heads") -> jax.Array:
     """Causal attention; dispatches to the Pallas flash kernel on TPU.
 
     ``bias`` is an additive mask broadcastable to ``[b, h, sq, sk]``
@@ -232,6 +286,13 @@ def dot_product_attention(
     dequant-in-kernel variant (``attention/*_int8`` counters); the
     dense fallback dequantizes the gathered rows up front and is the
     parity oracle (dispatch matrix: docs/quantization.md).
+
+    ``heads_axis`` is the logical axis the heads dim is sharded by
+    ("act_heads", or "act_heads_cp" under Ulysses): under a
+    multi-device mesh the training flash kernel runs per device on
+    its own batch/heads block (:func:`_flash_per_device`); the decode
+    kernels are not wrapped yet, so there a multi-device mesh takes
+    the dense path (``attention/fallback/mesh_sharded``).
     """
     if (k_scale is None) is not (v_scale is None):
         raise ValueError("k_scale and v_scale come together")
@@ -259,21 +320,25 @@ def dot_product_attention(
             and not kv_cache_layout):
         if _kernel_dropout_enabled():
             try:
-                from .pallas import flash_attention as fa
-                out = fa.flash_attention(q, k, v, causal=causal,
-                                         query_offset=query_offset,
-                                         dropout_rate=dropout_rate,
-                                         dropout_rng=dropout_rng,
-                                         bias=bias)
+                out = _flash_per_device(
+                    q, k, v, bias, causal, query_offset, dropout_rate,
+                    dropout_rng, heads_axis)
                 metrics.inc("attention/flash_dropout")
                 return out
+            except MeshIndivisible:
+                metrics.inc("attention/fallback/mesh_sharded")
             except (ImportError, NotImplementedError):
                 metrics.inc("attention/fallback/kernel_rejected")
         else:
             metrics.inc("attention/fallback/dropout_gate_off")
+    if use_flash and kv_cache_layout and kernel_mesh() is not None:
+        # the decode kernels are not shard_map-wrapped: under a
+        # multi-device mesh Mosaic would refuse to partition them at
+        # lowering, past any try/except here — dense by decision
+        metrics.inc("attention/fallback/mesh_sharded")
     # deterministic makes a configured dropout_rate inert, so eval and
     # generation may take the kernel even when training cannot
-    if use_flash and (deterministic or dropout_rate == 0.0):
+    elif use_flash and (deterministic or dropout_rate == 0.0):
         # the decode kernel takes a per-key additive bias (generation's
         # left-pad mask: [b, 1, 1, skv]); the training kernel takes
         # any bias broadcastable to [b, h, sq, skv]
@@ -369,14 +434,16 @@ def dot_product_attention(
             # sequences in either mode
             flash_worthwhile = causal or skv >= DENSE_NONCAUSAL_MAX_SKV
             if not kv_cache_layout and flash_worthwhile:
-                out = fa.flash_attention(q, k, v, causal=causal,
-                                         query_offset=query_offset,
-                                         bias=bias)
+                out = _flash_per_device(q, k, v, bias, causal,
+                                        query_offset, 0.0, None,
+                                        heads_axis)
                 metrics.inc("attention/flash")
                 return out
             metrics.inc("attention/fallback/kv_cache_layout"
                         if kv_cache_layout
                         else "attention/fallback/short_noncausal")
+        except MeshIndivisible:
+            metrics.inc("attention/fallback/mesh_sharded")
         except (ImportError, NotImplementedError):
             metrics.inc("attention/fallback/kernel_rejected")
     elif not use_flash:

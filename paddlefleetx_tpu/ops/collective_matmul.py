@@ -46,15 +46,11 @@ from .ring_attention import _axis_size, _shard_map
 
 
 def _smap(fn, mesh, in_specs, out_specs):
-    """shard_map with replication/vma checking off: the 0.4.x checker
-    has no rewrite rule for ``custom_vjp_call`` in transposed rings,
-    and the specs below are exact by construction."""
-    try:
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-    except TypeError:       # newer jax renamed the knob
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
+    """shard_map with vma checking off: the checker has no rewrite
+    rule for ``custom_vjp_call`` in transposed rings, and the specs
+    below are exact by construction."""
+    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
+                      out_specs=out_specs, check_vma=False)
 
 
 def _ring_visit(shard, axis_name, fold, init):

@@ -214,7 +214,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, sm_scale, causal, block_q,
     else:
         bias_ref = None
         o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
-    # hoisted OUTSIDE the pl.when blocks: 0.4.x interpret mode cannot
+    # hoisted OUTSIDE the pl.when blocks: interpret mode cannot
     # substitute program_id inside a cond closure
     bhi = pl.program_id(0)
     qi, ki = pl.program_id(1), pl.program_id(2)
@@ -261,25 +261,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, sm_scale, causal, block_q,
         lse_ref[0] = (m_scr[:] + jnp.log(l))
 
 
-def _vma(x):
-    """Varying-across-mesh axes of a traced value — pallas out_shapes
-    must carry them for shard_map's vma checker to accept the call
-    (outputs vary exactly where q does). jax 0.4.x has neither
-    ``jax.typeof`` nor the vma concept; there the checker doesn't
-    exist either, so None is correct."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        return None
-    return getattr(typeof(x), "vma", None)
-
-
 def _sds(shape, dtype, ref):
-    """ShapeDtypeStruct carrying ``ref``'s vma when this jax supports
-    the kwarg (0.4.x ShapeDtypeStruct rejects it)."""
-    vma = _vma(ref)
-    if vma is None:
-        return jax.ShapeDtypeStruct(shape, dtype)
-    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+    """ShapeDtypeStruct carrying ``ref``'s varying-across-mesh axes:
+    pallas out_shapes must carry them for shard_map's vma checker to
+    accept the call (outputs vary exactly where ``ref`` does)."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(ref).vma)
 
 
 def _bias_spec(bias, num_heads, block_q, block_kv, qk_of_ids):
@@ -637,12 +623,8 @@ def _flash_backward_fused(q, k, v, g, lse, delta, sm_scale, causal,
         return None
     # the resident tensors' block index never changes within one bh —
     # single-buffer them so the pipeline does not allocate a useless
-    # second copy of the largest VMEM tenants (jax 0.4.x has no
-    # pipeline_mode; there the pipeline still elides the copies, it
-    # just double-allocates the buffers)
-    buffered = getattr(pl, "Buffered", None)
-    mode_kw = {} if buffered is None else {
-        "pipeline_mode": buffered(buffer_count=1)}
+    # second copy of the largest VMEM tenants
+    mode_kw = {"pipeline_mode": pl.Buffered(buffer_count=1)}
     res_spec = pl.BlockSpec((1, sq, d), lambda b, i: (b, 0, 0),
                             **mode_kw)
     row_spec = pl.BlockSpec((1, sq, 1), lambda b, i: (b, 0, 0),
@@ -1260,18 +1242,28 @@ def _flash_decode_call(q, k, v, off, bias, block_kv: int, ragged: bool,
     # kernel instead of tripping the skv % block_kv rejection below
     block_kv = _auto_block(skv, block_kv, 128)
     # all heads ride in one block, so k/v blocks are h-times larger
-    # than a per-head grid's: shrink block_kv until double-buffered
-    # k+v blocks fit comfortably in the ~16M VMEM (a Mosaic
-    # allocation failure would crash instead of falling back)
-    budget = 8 * 1024 * 1024
-    while block_kv > 128 and \
-            4 * h * d * block_kv * k.dtype.itemsize > budget:
+    # than a per-head grid's: shrink block_kv until the kernel's VMEM
+    # footprint fits comfortably in the ~16M scoped limit (a Mosaic
+    # allocation failure would crash instead of falling back). The
+    # single-token kernel holds the double-buffered k+v blocks; the
+    # verify kernel additionally widens the resident K and V blocks
+    # to f32, holds one [h, d, bkv] product at a time and a pair of
+    # [h, bkv] score/prob rows per window position — uncounted, those
+    # put w >= 3 at 18-23M on a v5e (described-chip compile, PR 21).
+    def footprint(bkv):
+        n = 4 * h * d * bkv * k.dtype.itemsize
+        if window > 1:
+            n += 3 * h * d * bkv * 4 + window * 2 * h * bkv * 4
+        return n
+
+    budget = (8 if window == 1 else 12) * 1024 * 1024
+    while block_kv > 128 and footprint(block_kv) > budget:
         block_kv //= 2
     if skv % block_kv or block_kv % 128 or \
-            4 * h * d * block_kv * k.dtype.itemsize > budget:
+            footprint(block_kv) > budget:
         raise NotImplementedError(
             f"cache length {skv} not tileable by {block_kv} "
-            f"within VMEM budget (h={h}, d={d})")
+            f"within VMEM budget (h={h}, d={d}, window={window})")
     if d % 8:
         raise NotImplementedError(f"head_dim {d} unsupported")
     num_kv = skv // block_kv

@@ -26,19 +26,81 @@ from jax.sharding import PartitionSpec as P
 
 from ..observability import metrics
 
-try:                                    # jax >= 0.5 re-exports it
-    _shard_map = jax.shard_map
-except AttributeError:                  # 0.4.x spelling
-    from jax.experimental.shard_map import shard_map as _shard_map
+_shard_map = jax.shard_map
+_axis_size = jax.lax.axis_size
 
 
-def _axis_size(axis_name):
-    try:
-        return jax.lax.axis_size(axis_name)
-    except AttributeError:
-        # 0.4.x: psum of a static 1 is evaluated eagerly to a Python
-        # int — the classic pre-axis_size spelling
-        return jax.lax.psum(1, axis_name)
+class MeshIndivisible(NotImplementedError):
+    """An operand dim does not divide the mesh axes it is sharded by
+    (e.g. the batch-1 abstract-init sample under fsdp=2)."""
+
+
+def kernel_mesh():
+    """The active mesh when it spans more than one device, else None.
+
+    Mosaic kernels cannot be partitioned by GSPMD: a ``pallas_call``
+    whose operands are sharded over a multi-device mesh fails at
+    lowering on real chips (interpret mode lowers to plain HLO and
+    hides it). Call sites ask this at trace time and either wrap the
+    kernel with :func:`shard_kernel` or take their counted XLA path."""
+    from ..parallel.mesh import get_mesh
+    mesh = get_mesh()
+    return mesh if mesh is not None and mesh.devices.size > 1 else None
+
+
+def require_one_device(kernel: str) -> None:
+    """Admission check of the kernels that are NOT wrapped with
+    :func:`shard_kernel` yet (int8 GEMM, grouped LoRA): under a
+    multi-device mesh they raise like any other kernel rejection, so
+    the site takes its counted XLA path instead of reaching Mosaic's
+    lowering error past the site's try/except."""
+    if kernel_mesh() is not None:
+        raise NotImplementedError(
+            f"{kernel} is not shard_map-wrapped: Mosaic cannot "
+            f"partition it over a multi-device mesh")
+
+
+def shard_kernel(fn, args, in_axes, out_axes):
+    """``fn(*args)`` with every device running the (Pallas) call on
+    its own block of the operands.
+
+    ``in_axes`` (one tuple per operand) and ``out_axes`` (the single
+    output's) name each dim by its LOGICAL axis
+    (``parallel/sharding.py`` rule table: "batch" -> dp x fsdp,
+    "act_heads" -> mp, "act_expert" -> the ep plane, ...), so the
+    blocks are exactly what the surrounding GSPMD program already
+    holds per device and nothing is gathered to feed the kernel. With
+    no mesh or a one-device mesh the call is made directly (same HLO
+    as without this helper). Raises :class:`MeshIndivisible` (a
+    ``NotImplementedError``) when a sharded dim does not divide its
+    mesh axes — callers fall back to their XLA path, like any other
+    kernel rejection."""
+    mesh = kernel_mesh()
+    if mesh is None:
+        return fn(*args)
+    import flax.linen as nn
+
+    def spec(axes):
+        return nn.logical_to_mesh_axes(tuple(axes))
+
+    in_specs = tuple(spec(a) for a in in_axes)
+    for x, sp in zip(args, in_specs):
+        for dim, axes in zip(x.shape, sp):
+            if axes is None:
+                continue
+            axes = (axes,) if isinstance(axes, str) else axes
+            n = 1
+            for a in axes:
+                n *= mesh.shape[a]
+            if dim % n:
+                raise MeshIndivisible(
+                    f"operand {x.shape} does not divide mesh axes "
+                    f"{axes} (x{n}) for a per-device kernel call")
+    # vma checking off: the specs are exact by construction, and the
+    # Pallas interpreter (CPU tests) cannot type its scalar-prefetch
+    # slices under the checker
+    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
+                      out_specs=spec(out_axes), check_vma=False)(*args)
 
 
 NEG_INF = -1e30

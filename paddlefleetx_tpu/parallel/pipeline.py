@@ -705,7 +705,7 @@ def pipeline_value_and_grad(
             x_mb, jnp.clip(t, 0, M - 1), 0, keepdims=False)
         # .at[0, 0].set is a two-dim-index scatter; with dim 1 sharded
         # over pp the SPMD partitioner mis-broadcasts the index
-        # concatenation (hlo-verifier RET_CHECK on 0.4.x). A
+        # concatenation (hlo-verifier RET_CHECK). A
         # dynamic_update_slice at a constant origin partitions cleanly
         # and is the same write.
         fstate = jax.lax.dynamic_update_slice(

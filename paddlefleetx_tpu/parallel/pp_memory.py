@@ -106,8 +106,8 @@ def stage_memory_bytes(*, schedule: str, pp: int, vpp: int = 1,
 
 
 def hbm_budget_bytes(device=None) -> Optional[int]:
-    """Per-device HBM budget for depth validation, or ``None`` when
-    unknown (CPU/interpret runs). ``PFX_PP_HBM_BUDGET_BYTES`` pins it
+    """Per-device HBM budget for depth validation, or ``None`` off-TPU
+    (CPU/interpret runs); on a TPU an unknown budget is an error. ``PFX_PP_HBM_BUDGET_BYTES`` pins it
     explicitly (<= 0 disables budget checking); otherwise the
     device's allocator ``bytes_limit`` is used."""
     env = os.environ.get("PFX_PP_HBM_BUDGET_BYTES")
@@ -122,6 +122,16 @@ def hbm_budget_bytes(device=None) -> Optional[int]:
     stats = device_memory_stats(device)
     if stats and stats.get("bytes_limit"):
         return int(stats["bytes_limit"])
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    if device.platform == "tpu":
+        # a TPU that reports no limit must not silently drop the
+        # budget check the schedule choice rests on
+        raise RuntimeError(
+            f"TPU {device.device_kind!r} reports no HBM bytes_limit; "
+            f"set PFX_PP_HBM_BUDGET_BYTES (<= 0 to disable the "
+            f"budget check explicitly)")
     return None
 
 

@@ -51,23 +51,38 @@ def init_dist_env(coordinator: Optional[str] = None,
                     jax.process_index(), jax.process_count())
 
 
-def setup_compilation_cache(cache_dir: Optional[str]) -> None:
-    """Point JAX's persistent compilation cache at ``cache_dir``
-    (``Global.compilation_cache_dir``). TPU-native concern with no
-    reference analogue: XLA compiles of big jitted train steps take
-    minutes, and preempted-and-restarted jobs (see
-    ``Engine.save_on_preemption``) would pay them again on every
-    restart — with the cache on shared storage they are skipped.
+#: the checkout (or install prefix) this package lives in — the
+#: default cache sits beside it at a path that never moves, because
+#: the path is part of the cache key
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def setup_compilation_cache(cache_dir: Optional[str] = None) -> str:
+    """Turn on JAX's persistent compilation cache and return its
+    directory. Every entry point that compiles calls this (Engine,
+    the serving/inference tasks, bench.py, chip_smoke.py): a cold
+    compile of the unrolled 24-layer step is minutes, and both
+    preempted-and-restarted jobs and every chip-tool call start cold.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, that is the cache and
+    this function sets no directory of its own (JAX reads the
+    variable itself) — the cache can be placed from outside. Otherwise
+    ``cache_dir`` (``Global.compilation_cache_dir``), else
+    ``<checkout>/.xla_cache``: a fixed path, never a temp name.
     """
-    if not cache_dir:
-        return
-    cache_dir = os.path.abspath(os.path.expanduser(cache_dir))
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
     # cache every program: the default thresholds skip fast compiles,
     # but a restart replays *all* of them, so small entries pay too
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    cache_dir = os.path.abspath(os.path.expanduser(cache_dir)) \
+        if cache_dir else os.path.join(_CHECKOUT, ".xla_cache")
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
     logger.info("persistent compilation cache at %s", cache_dir)
+    return cache_dir
 
 
 def set_seed(seed: int, data_rank: int = 0) -> jax.Array:

@@ -26,8 +26,6 @@ import os
 from typing import Any, Callable, Dict, List, Sequence, Tuple
 
 import jax
-# on jax 0.4.x the export module exists but is not re-exported as a
-# lazy `jax.export` attribute — the explicit submodule import binds it
 import jax.export
 import numpy as np
 import orbax.checkpoint as ocp

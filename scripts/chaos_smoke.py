@@ -291,6 +291,14 @@ def ptq_leg(work, chaos_out, cfg_path):
         f"skipped it; restored artifact verifies\n")
 
 
+def _interpret_off_tpu(jax):
+    """The in-process drills run their Pallas kernels in interpret
+    mode on the CPU platform only — never where a TPU is attached,
+    which would report success without the chip doing the work."""
+    if jax.default_backend() != "tpu":
+        os.environ.setdefault("PFX_PALLAS_INTERPRET", "1")
+
+
 def fleet_leg(work):
     """In-process fleet drill: rolling-restart a 2-replica tiered
     ASYNC fleet mid-stream — each replica serving from its own worker
@@ -299,9 +307,9 @@ def fleet_leg(work):
     alone, then a warm second wave that must rehydrate from the
     restart-persisted prefix store before it prefills anything."""
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    os.environ.setdefault("PFX_PALLAS_INTERPRET", "1")
     sys.path.insert(0, REPO)
     import jax
+    _interpret_off_tpu(jax)
     import jax.numpy as jnp
 
     from paddlefleetx_tpu.core.fleet import FleetRouter
@@ -481,9 +489,9 @@ def adapters_leg(work):
     and the restarted replicas' adapter-cache re-warm proven from
     ``serving_adapter_load`` events alone."""
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    os.environ.setdefault("PFX_PALLAS_INTERPRET", "1")
     sys.path.insert(0, REPO)
     import jax
+    _interpret_off_tpu(jax)
     import jax.numpy as jnp
     import flax.linen as nn
 

@@ -70,9 +70,14 @@ def main() -> int:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
-    os.environ.setdefault("PFX_PALLAS_INTERPRET", "1")
     import jax
     import jax.numpy as jnp
+    if jax.default_backend() != "tpu":
+        # CPU drive: the Pallas kernels run in interpret mode. Never
+        # where a TPU is attached — there the chip does the work.
+        os.environ.setdefault("PFX_PALLAS_INTERPRET", "1")
+    from paddlefleetx_tpu.utils.env import setup_compilation_cache
+    setup_compilation_cache()
 
     from paddlefleetx_tpu.core.fleet import FleetRouter
     from paddlefleetx_tpu.core.serving import GenerationServer

@@ -163,9 +163,7 @@ def smoke_ernie(batch=32, seq=512):
 
 if __name__ == "__main__":
     from paddlefleetx_tpu.utils.env import setup_compilation_cache
-    setup_compilation_cache(os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        ".xla_cache"))   # the unrolled 24-layer ERNIE compiles slowly
+    setup_compilation_cache()  # the unrolled 24-layer ERNIE compiles slowly
     which = sys.argv[1:] or ["vit", "imagen", "ernie"]
     print("device:", jax.devices()[0].device_kind)
     # successful on-chip family numbers join the committed audit

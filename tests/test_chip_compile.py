@@ -1,0 +1,322 @@
+"""Compiles for a DESCRIBED (not attached) TPU v5e: the chip's own
+compiler run over the main path's kernels at GPT-345M widths, on one
+chip and on the 2x2 mesh.
+
+These are rehearsals (on-chip-measurement guide, section 2): they
+show what interpret mode cannot — a kernel Mosaic refuses (VMEM
+budget, tiling), a kernel GSPMD cannot partition — at no chip time.
+Nothing runs, so a pass here is never a chip run.
+
+Rules this file keeps: the topology is described inside a
+module-scoped fixture (never at import), nothing is ``autouse``, no
+child process is started (the worker that described the topology
+holds libtpu's lock), and the persistent compilation cache is off
+around the compiles (a described-chip entry cannot be read back).
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import (
+    Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding,
+)
+
+# GPT-345M widths: local batch 8, 16 heads of 64, s = S = 1024, bf16
+B, H, D, S = 8, 16, 64, 1024
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu, or its lock is held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    """The Engine's 5-axis mesh at fsdp2 x mp2 over the described
+    chips."""
+    import numpy as np
+
+    from paddlefleetx_tpu.parallel.mesh import MESH_AXES
+    return Mesh(np.asarray(topo.devices).reshape(1, 1, 1, 2, 2),
+                MESH_AXES)
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """Steer the kernels' backend gates onto their TPU branch and keep
+    the persistent compilation cache out of the way."""
+    from jax.experimental.compilation_cache import compilation_cache
+    monkeypatch.delenv("PFX_PALLAS_INTERPRET", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _qkv(sh, b=B, s=S, h=H, d=D):
+    return [_sds((b, s, h, d), BF16, sh)] * 3
+
+
+def _grad_sum(fn, argnums):
+    return jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32)),
+                    argnums=argnums)
+
+
+# -- one chip: training flash -----------------------------------------
+
+def _flash_cases():
+    from paddlefleetx_tpu.ops.pallas import flash_attention as fa
+
+    def dropout(q, k, v):
+        return fa.flash_attention(q, k, v, dropout_rate=0.1,
+                                  dropout_rng=jax.random.key(0))
+
+    def biased(q, k, v, bias):
+        return fa.flash_attention(q, k, v, causal=False, bias=bias)
+
+    def with_lse(q, k, v):
+        out, lse = fa.flash_attention_with_lse(q, k, v)
+        return out.astype(jnp.float32).sum() + lse.sum()
+
+    return {
+        "fwd": (fa.flash_attention, {}),
+        "fwd_bwd": (_grad_sum(fa.flash_attention, (0, 1, 2)), {}),
+        "dropout_fwd_bwd": (_grad_sum(dropout, (0, 1, 2)), {}),
+        "d128_s2048": (_grad_sum(fa.flash_attention, (0, 1, 2)),
+                       dict(b=2, s=2048, h=8, d=128)),
+        "s8192": (_grad_sum(fa.flash_attention, (0, 1, 2)),
+                  dict(b=1, s=8192)),
+        "noncausal_bias": (_grad_sum(biased, (0, 1, 2)),
+                           dict(bias=True)),
+        "with_lse": (jax.grad(with_lse, (0, 1, 2)), {}),
+    }
+
+
+@pytest.mark.parametrize("case", [
+    "fwd", "fwd_bwd", "dropout_fwd_bwd", "d128_s2048", "s8192",
+    "noncausal_bias", "with_lse"])
+def test_flash_training_compiles_on_one_chip(case, one_chip, as_tpu):
+    fn, kw = _flash_cases()[case]
+    kw = dict(kw)
+    has_bias = kw.pop("bias", False)
+    args = _qkv(one_chip, **kw)
+    if has_bias:
+        args.append(_sds((B, 1, 1, S), jnp.float32, one_chip))
+    assert "tpu_custom_call" in _compile(fn, *args).as_text()
+
+
+# -- one chip: decode family ------------------------------------------
+
+def _cache(sh, dtype=BF16):
+    return [_sds((B, H, D, S), dtype, sh)] * 2
+
+
+def _scales(sh, shape=(B, H, 1, S)):
+    return [_sds(shape, jnp.float32, sh)] * 2
+
+
+def test_flash_decode_compiles(one_chip, as_tpu):
+    from paddlefleetx_tpu.ops.pallas import flash_attention as fa
+    q = _sds((B, 1, H, D), BF16, one_chip)
+    off = _sds((), jnp.int32, one_chip)
+    txt = _compile(fa.flash_decode, q, *_cache(one_chip),
+                   off).as_text()
+    assert "tpu_custom_call" in txt
+
+
+@pytest.mark.parametrize("window,int8", [
+    (1, False), (1, True), (2, False), (3, False), (5, False),
+    (8, False), (32, False), (5, True)])
+def test_flash_decode_ragged_compiles(window, int8, one_chip, as_tpu):
+    """Contiguous slot cache: single-token decode and the speculative
+    verify windows. w >= 3 used to ask Mosaic for 18-20 MB of scoped
+    VMEM against a 16 MB limit (the budget counted only the
+    double-buffered K/V blocks, not the window's widened copies)."""
+    from paddlefleetx_tpu.ops.pallas import flash_attention as fa
+    q = _sds((B, window, H, D), BF16, one_chip)
+    off = _sds((B,), jnp.int32, one_chip)
+    if int8:
+        fn = lambda q, k, v, off, ks, vs: fa.flash_decode_ragged(  # noqa: E731
+            q, k, v, off, k_scale=ks, v_scale=vs)
+        args = (q, *_cache(one_chip, jnp.int8), off,
+                *_scales(one_chip))
+    else:
+        fn = fa.flash_decode_ragged
+        args = (q, *_cache(one_chip), off)
+    assert "tpu_custom_call" in _compile(fn, *args).as_text()
+
+
+@pytest.mark.parametrize("window,int8", [
+    (1, False), (5, False), (9, False), (32, False), (1, True),
+    (5, True)])
+def test_flash_decode_paged_compiles(window, int8, one_chip, as_tpu):
+    from paddlefleetx_tpu.ops.pallas import flash_attention as fa
+    page, pages_per_row = 128, S // 128
+    pool = B * pages_per_row + 1
+    q = _sds((B, window, H, D), BF16, one_chip)
+    off = _sds((B,), jnp.int32, one_chip)
+    table = _sds((B, pages_per_row), jnp.int32, one_chip)
+    kv = [_sds((pool, H, D, page), jnp.int8 if int8 else BF16,
+               one_chip)] * 2
+    if int8:
+        fn = lambda q, k, v, off, t, ks, vs: fa.flash_decode_paged(  # noqa: E731
+            q, k, v, off, t, k_scale=ks, v_scale=vs)
+        args = (q, *kv, off, table,
+                *_scales(one_chip, (pool, H, 1, page)))
+    else:
+        fn = fa.flash_decode_paged
+        args = (q, *kv, off, table)
+    assert "tpu_custom_call" in _compile(fn, *args).as_text()
+
+
+# -- one chip: grouped / quantized GEMMs, grouped LoRA ----------------
+
+@pytest.mark.parametrize("backward", [False, True])
+def test_grouped_matmul_compiles(backward, one_chip, as_tpu):
+    from paddlefleetx_tpu.ops.pallas.grouped_matmul import (
+        grouped_matmul,
+    )
+    x = _sds((8, 1024, 1024), BF16, one_chip)
+    w = _sds((8, 1024, 4096), BF16, one_chip)
+    counts = _sds((8,), jnp.int32, one_chip)
+    fn = _grad_sum(grouped_matmul, (0, 1)) if backward \
+        else grouped_matmul
+    assert "tpu_custom_call" in _compile(fn, x, w, counts).as_text()
+
+
+@pytest.mark.parametrize("m", [8, 1024])
+def test_quantized_matmul_compiles(m, one_chip, as_tpu):
+    from paddlefleetx_tpu.ops.pallas.quantized_matmul import (
+        quantized_matmul,
+    )
+    x = _sds((m, 1024), BF16, one_chip)
+    w = _sds((1024, 4096), jnp.int8, one_chip)
+    s = _sds((4096,), jnp.float32, one_chip)
+    assert "tpu_custom_call" in _compile(quantized_matmul, x, w,
+                                         s).as_text()
+
+
+def test_grouped_lora_compiles(one_chip, as_tpu):
+    from paddlefleetx_tpu.ops.lora import grouped_lora_delta
+    x = _sds((64, 1024), BF16, one_chip)
+    ids = _sds((64,), jnp.int32, one_chip)
+    a = _sds((4, 1024, 16), BF16, one_chip)
+    b = _sds((4, 16, 3072), BF16, one_chip)
+    assert "tpu_custom_call" in _compile(grouped_lora_delta, x, ids,
+                                         a, b).as_text()
+
+
+# -- meshes ------------------------------------------------------------
+
+def test_flash_compiles_under_one_device_mesh(topo, as_tpu):
+    """What the Engine builds on one chip: the helper must step aside
+    (direct call, same HLO as with no mesh)."""
+    import numpy as np
+
+    from paddlefleetx_tpu.ops.attention import dot_product_attention
+    from paddlefleetx_tpu.parallel.mesh import MESH_AXES, set_mesh
+    mesh = Mesh(np.asarray(topo.devices[:1]).reshape((1,) * 5),
+                MESH_AXES)
+    set_mesh(mesh)
+    sh = NamedSharding(mesh, P())
+    fn = _grad_sum(functools.partial(dot_product_attention,
+                                     use_flash=True), (0, 1, 2))
+    txt = _compile(fn, *_qkv(sh)).as_text()
+    assert "tpu_custom_call" in txt and "all-gather" not in txt
+
+
+def _mesh_rules():
+    from paddlefleetx_tpu.parallel.mesh import TopologyConfig
+    from paddlefleetx_tpu.parallel.sharding import make_sharding_rules
+    return list(make_sharding_rules(TopologyConfig(
+        mp_degree=2, sharding_degree=2, sharding_stage=3)))
+
+
+def test_flash_compiles_on_2x2_mesh(mesh4, as_tpu):
+    """Batch over fsdp, heads over mp: every chip runs the fwd and bwd
+    kernels on its own block — no all-gather of q/k/v feeds them."""
+    import flax.linen as nn
+
+    from paddlefleetx_tpu.ops.attention import dot_product_attention
+    from paddlefleetx_tpu.parallel.mesh import set_mesh
+    set_mesh(mesh4)
+    sh = NamedSharding(mesh4, P(("dp", "fsdp"), None, "mp", None))
+    fn = _grad_sum(functools.partial(dot_product_attention,
+                                     use_flash=True), (0, 1, 2))
+    with mesh4, nn.logical_axis_rules(_mesh_rules()):
+        txt = _compile(fn, *_qkv(sh)).as_text()
+    assert txt.count("tpu_custom_call") >= 2
+    assert "all-gather" not in txt
+
+
+def test_unwrapped_flash_is_refused_on_2x2_mesh(mesh4, as_tpu):
+    """The failure the helper exists for, pinned: a bare pallas_call
+    under a sharded jit does not lower on real chips."""
+    from paddlefleetx_tpu.ops.pallas import flash_attention as fa
+    sh = NamedSharding(mesh4, P(("dp", "fsdp"), None, "mp", None))
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        _compile(fa.flash_attention, *_qkv(sh))
+
+
+def test_grouped_matmul_compiles_on_2x2_mesh(mesh4, as_tpu):
+    """The MoE expert FFN (sort_pallas) with experts over fsdp and the
+    FFN dim over mp: both grouped GEMMs and their backward lower per
+    device."""
+    import flax.linen as nn
+
+    from paddlefleetx_tpu.models.gpt.config import GPTConfig
+    from paddlefleetx_tpu.models.gpt.moe import MoEMLP
+    from paddlefleetx_tpu.parallel.mesh import set_mesh
+    set_mesh(mesh4)
+    cfg = GPTConfig(vocab_size=50304, hidden_size=1024, num_layers=1,
+                    num_attention_heads=16, ffn_hidden_size=4096,
+                    moe_num_experts=8, moe_top_k=2,
+                    moe_dispatch="sort_pallas", dtype="bfloat16")
+    layer = MoEMLP(cfg)
+    rules = _mesh_rules()
+    x = _sds((B, S, 1024), BF16,
+             NamedSharding(mesh4, P(("dp", "fsdp"), None, None)))
+    with mesh4, nn.logical_axis_rules(rules):
+        abstract = jax.eval_shape(
+            lambda: layer.init(jax.random.key(0),
+                               jnp.zeros((2, 128, 1024), BF16)))
+        shardings = nn.logical_to_mesh_sharding(
+            nn.get_partition_spec(abstract), mesh4, rules)
+        params = jax.tree.map(
+            lambda a, s: _sds(a.shape, a.dtype, s),
+            nn.meta.unbox(abstract), shardings)
+
+        def loss(p, x):
+            y, aux = layer.apply(p, x)
+            return jnp.sum(y.astype(jnp.float32)) + aux
+
+        txt = _compile(jax.grad(loss), params, x).as_text()
+    # fwd: 2 GEMMs; bwd: dw for each + dx of the second (x itself is
+    # not differentiated here)
+    assert txt.count("tpu_custom_call") >= 5

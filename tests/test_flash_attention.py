@@ -1017,3 +1017,108 @@ def test_training_bias_dropout_dispatches_to_kernel(monkeypatch):
     attention.dot_product_attention(q, k, v, bias=bias, causal=True,
                                     use_flash=True)
     assert calls and calls[-1]["bias"] is bias
+
+
+# -- under a multi-device mesh ----------------------------------------
+#
+# Mosaic kernels cannot be partitioned by GSPMD: on real chips a bare
+# pallas_call under a sharded jit fails at lowering (pinned by the
+# described-chip compile in tests/test_chip_compile.py). Interpret
+# mode would partition fine and hide it, so these tests pin the
+# DISPATCH: with the mesh active the training kernel runs per device
+# under shard_map, and the kernels that are not wrapped take their
+# counted XLA path.
+
+def _mesh_2x2():
+    import flax.linen as nn
+    from paddlefleetx_tpu.parallel.mesh import (
+        TopologyConfig, build_mesh, set_mesh,
+    )
+    from paddlefleetx_tpu.parallel.sharding import make_sharding_rules
+    topo = TopologyConfig(mp_degree=2, sharding_degree=2,
+                          sharding_stage=3)
+    mesh = build_mesh(topo, devices=jax.devices()[:4])
+    set_mesh(mesh)
+    return mesh, nn.logical_axis_rules(list(make_sharding_rules(topo)))
+
+
+@pytest.mark.parametrize("bias", [False, True],
+                         ids=["plain", "bias"])
+def test_flash_runs_per_device_under_a_mesh(bias):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from paddlefleetx_tpu.observability import metrics
+    from paddlefleetx_tpu.ops import ring_attention
+    from paddlefleetx_tpu.ops.attention import dot_product_attention
+    q, k, v = _rand(b=4, s=256, h=4)
+    mask = None
+    if bias:
+        keep = np.ones((4, 1, 1, 256), np.float32)
+        keep[:, ..., 200:] = 0.0
+        mask = jnp.asarray((keep - 1.0) * 1e9)
+
+    def loss(q, k, v, flash):
+        out = dot_product_attention(q, k, v, bias=mask, causal=True,
+                                    use_flash=flash)
+        return (out ** 2).sum()
+    ref_l, ref_g = jax.value_and_grad(
+        lambda *a: loss(*a, False), argnums=(0, 1, 2))(q, k, v)
+
+    mesh, rules = _mesh_2x2()
+    sharded = []
+    real = ring_attention._shard_map
+
+    def spy(fn, **kw):
+        sharded.append(kw["in_specs"])
+        return real(fn, **kw)
+    ring_attention._shard_map = spy
+    reg = metrics.get_registry()
+    metrics.set_enabled(True)
+    reg.reset()
+    try:
+        sh = NamedSharding(mesh, P(("dp", "fsdp"), None, "mp", None))
+        args = [jax.device_put(t, sh) for t in (q, k, v)]
+        with mesh, rules:
+            got_l, got_g = jax.jit(jax.value_and_grad(
+                lambda *a: loss(*a, True), argnums=(0, 1, 2)))(*args)
+        assert reg.counter("attention/flash") == 1
+        assert reg.counter("attention/dense") == 0
+    finally:
+        ring_attention._shard_map = real
+        metrics.set_enabled(False)
+    # batch over the data axes, heads over mp — q's own sharding
+    assert sharded and sharded[0][0] == P(("dp", "fsdp"), None, "mp",
+                                          None)
+    np.testing.assert_allclose(float(got_l), float(ref_l), rtol=1e-5)
+    for a, b in zip(got_g, ref_g):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=5e-5, rtol=1e-4)
+
+
+def test_mesh_indivisible_and_decode_take_the_counted_dense_path():
+    """The batch-1 abstract-init sample does not divide fsdp=2, and
+    the decode kernels are not shard_map-wrapped: both go dense under
+    ``attention/fallback/mesh_sharded`` — never to a lowering crash,
+    never counted as a kernel rejection."""
+    from paddlefleetx_tpu.observability import metrics
+    from paddlefleetx_tpu.ops.attention import dot_product_attention
+    mesh, rules = _mesh_2x2()
+    reg = metrics.get_registry()
+    metrics.set_enabled(True)
+    reg.reset()
+    try:
+        q, k, v = _rand(b=1, s=256, h=4)
+        with mesh, rules:
+            out = dot_product_attention(q, k, v, use_flash=True)
+            assert out.shape == q.shape
+            cache = jnp.zeros((4, 4, 64, 256), jnp.float32)
+            dot_product_attention(
+                _rand(b=4, s=1, h=4)[0], cache, cache,
+                query_offset=jnp.array([5, 9, 0, 3], jnp.int32),
+                use_flash=True, kv_cache_layout=True)
+        assert reg.counter("attention/fallback/mesh_sharded") == 2
+        assert reg.counter("attention/dense") == 2
+        assert reg.counter("attention/fallback/kernel_rejected") == 0
+        assert reg.counter("attention/flash_decode_ragged") == 0
+    finally:
+        metrics.set_enabled(False)

@@ -303,6 +303,49 @@ def test_fleet_async_trace_span_ordering(model_and_params, tmp_path):
 # -- prefill/decode disaggregation -------------------------------------
 
 
+def test_kv_import_leaves_one_slot_its_growth(
+        paged512_model_and_params):
+    """Import pins sit outside every slot, where preemption cannot
+    reclaim them: on a pool of two slots' worst case a third import
+    would leave an admitted request fewer pages than one slot's
+    maximum to grow into (PagePoolExhausted under the async router,
+    whose prefill worker runs ahead). The import is refused instead
+    — the caller re-prefills — and every request is still served to
+    its lockstep row."""
+    model, params = paged512_model_and_params
+    gen_cfg = _greedy_cfg()
+    prompts = _long_prompts()
+    ref = _lockstep(model, params, prompts, gen_cfg)
+    kw = dict(page_size=128, prefill_chunk_pages=1,
+              rng=jax.random.PRNGKey(7))
+    src = GenerationServer(model, params, gen_cfg, num_slots=3,
+                           pool_pages=17, **kw)
+    # 8 usable pages, 4 per slot at most: two 2-page imports leave
+    # exactly one slot's maximum; a third would eat into it
+    dst = GenerationServer(model, params, gen_cfg, num_slots=2,
+                           pool_pages=9, **kw)
+    for p in prompts:
+        src.submit(p)
+    took = []
+    for p in prompts:                 # export as each prefill lands
+        for _ in range(50):
+            if src.prompt_ready(p):
+                break
+            src.step()
+        pages, last = src.kv_export(p)
+        took.append(dst.kv_import(p, src.kv_page_data(pages), last,
+                                  len(pages)))
+        src.kv_export_release(pages)
+    assert took == [True, True, False]
+    comps = dst.run(prompts)          # two imported, one re-prefilled
+    assert [c.tokens for c in comps] == ref
+    for p in prompts[:2]:
+        dst.kv_import_release(p)
+    dst._alloc.check()
+    for srv in (src, dst):
+        srv.close()
+
+
 @pytest.mark.parametrize("handoff", ["device", "host"])
 def test_fleet_split_handoff_parity(paged512_model_and_params,
                                     handoff):

@@ -24,6 +24,7 @@ from paddlefleetx_tpu.models.gpt.moe import (
 from paddlefleetx_tpu.parallel import (
     TopologyConfig, build_mesh, make_sharding_rules,
 )
+from paddlefleetx_tpu.parallel.mesh import set_mesh
 
 MOE_CFG = GPTConfig(
     vocab_size=64, hidden_size=16, num_layers=2,
@@ -396,6 +397,10 @@ def test_dispatch_modes_match_einsum(dispatch_golden, monkeypatch,
     x, params, ref_l, ref_g, ref_y = dispatch_golden[top_k]
     topo = TopologyConfig(**EP_TOPOS[ep])
     mesh = build_mesh(topo)
+    # as the Engine does: with the mesh active, sort_pallas runs its
+    # grouped GEMMs per device under shard_map (ops/ring_attention.py
+    # shard_kernel) — what real chips require
+    set_mesh(mesh)
     rules = make_sharding_rules(topo)
     layer = MoEMLP(_parity_cfg(top_k, mode))
 

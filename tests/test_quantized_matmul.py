@@ -364,3 +364,45 @@ def test_quant_config_validation():
         GPTConfig(**{**BASE, "quant_execution": "int4"})
     with pytest.raises(ValueError, match="kv_cache_dtype"):
         GPTConfig(**{**BASE, "kv_cache_dtype": "fp8"})
+
+
+def test_quant_and_lora_sites_take_xla_under_a_multi_device_mesh(
+        fp_model_and_params):
+    """The int8 GEMM and the grouped LoRA GEMM are not shard_map-
+    wrapped: with a multi-device mesh active (what the Engine and a
+    dp8 generation config set) a bare pallas_call would be refused by
+    Mosaic at lowering on real chips, past the site's try/except. The
+    sites see the mesh at trace time and take their counted XLA path
+    instead — same numbers, no crash reachable."""
+    from paddlefleetx_tpu.parallel.mesh import (
+        TopologyConfig, build_mesh, set_mesh,
+    )
+    _, params = fp_model_and_params
+    cfg = GPTConfig(**{**BASE, "quant_execution": "weight_only_int8"})
+    qparams, _ = quantize_param_tree(params)
+    ids = jax.random.randint(jax.random.PRNGKey(1), (2, 8), 0, 96)
+    ref = GPTModel(cfg).apply({"params": qparams}, ids)
+    lcfg = GPTConfig(**{**BASE, "lora_rank": 4, "lora_num_adapters": 3})
+    lmodel = GPTModel(lcfg)
+    lparams = nn.meta.unbox(lmodel.init(jax.random.PRNGKey(0),
+                                        ids)["params"])
+    aids = jnp.asarray([1, 2], jnp.int32)
+    lref = lmodel.apply({"params": lparams}, ids, adapter_ids=aids)
+    reg = metrics.get_registry()
+    metrics.set_enabled(True)
+    reg.reset()
+    try:
+        set_mesh(build_mesh(TopologyConfig(dp_degree=8)))
+        out = GPTModel(cfg).apply({"params": qparams}, ids)
+        lout = lmodel.apply({"params": lparams}, ids, adapter_ids=aids)
+        assert reg.counter("quant/matmul") == 0
+        assert reg.counter("quant/fallback/kernel_rejected") >= 4
+        assert reg.counter("lora/grouped") == 0
+        assert reg.counter("lora/fallback") >= 4
+    finally:
+        metrics.set_enabled(False)
+        reg.reset()
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-2, rtol=2e-2)
+    np.testing.assert_allclose(np.asarray(lout), np.asarray(lref),
+                               atol=1e-4, rtol=1e-4)

@@ -8,27 +8,88 @@ import time
 import pytest
 
 
-def test_compilation_cache_knob(tmp_path):
-    """Global.compilation_cache_dir points jax's persistent cache at
-    shared storage (restart-after-preemption skips recompiles)."""
-    import jax
-    from paddlefleetx_tpu.utils.env import setup_compilation_cache
+_CACHE_KEYS = ("jax_compilation_cache_dir",
+               "jax_persistent_cache_min_compile_time_secs",
+               "jax_persistent_cache_min_entry_size_bytes")
 
-    prev = {
-        k: getattr(jax.config, k) for k in (
-            "jax_compilation_cache_dir",
-            "jax_persistent_cache_min_compile_time_secs",
-            "jax_persistent_cache_min_entry_size_bytes")}
-    try:
-        target = str(tmp_path / "xla-cache")
-        setup_compilation_cache(target)
-        assert jax.config.jax_compilation_cache_dir == target
-        assert jax.config.jax_persistent_cache_min_entry_size_bytes == 0
-        setup_compilation_cache(None)   # absent knob: no-op
-        assert jax.config.jax_compilation_cache_dir == target
-    finally:
-        for k, v in prev.items():
-            jax.config.update(k, v)
+
+@pytest.fixture
+def jax_cache_config(monkeypatch):
+    """Run with JAX_COMPILATION_CACHE_DIR unset and put jax's cache
+    options back afterwards."""
+    import jax
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    prev = {k: getattr(jax.config, k) for k in _CACHE_KEYS}
+    yield jax.config
+    for k, v in prev.items():
+        jax.config.update(k, v)
+
+
+def test_compilation_cache_knob(tmp_path, jax_cache_config):
+    """Global.compilation_cache_dir points jax's persistent cache at
+    shared storage (restart-after-preemption skips recompiles) while
+    JAX_COMPILATION_CACHE_DIR is unset."""
+    from paddlefleetx_tpu.utils.env import setup_compilation_cache
+    target = str(tmp_path / "xla-cache")
+    assert setup_compilation_cache(target) == target
+    assert jax_cache_config.jax_compilation_cache_dir == target
+    assert jax_cache_config.jax_persistent_cache_min_entry_size_bytes \
+        == 0
+
+
+def test_compilation_cache_placed_from_outside(tmp_path, monkeypatch,
+                                               jax_cache_config):
+    """With JAX_COMPILATION_CACHE_DIR set our code sets no directory:
+    whatever jax.config holds (JAX reads the variable itself) stays
+    untouched, even against an explicit YAML key."""
+    from paddlefleetx_tpu.utils.env import setup_compilation_cache
+    jax_cache_config.update("jax_compilation_cache_dir", "/as/jax/read/it")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+    assert setup_compilation_cache() == "/some/dir"
+    assert setup_compilation_cache(str(tmp_path)) == "/some/dir"
+    assert jax_cache_config.jax_compilation_cache_dir == \
+        "/as/jax/read/it"
+    # the thresholds are still ours: every program is cached
+    assert jax_cache_config.jax_persistent_cache_min_compile_time_secs \
+        == 0.0
+
+
+def test_compilation_cache_default_is_a_fixed_checkout_path(
+        tmp_path, monkeypatch, jax_cache_config):
+    """Unset -> <checkout>/.xla_cache, resolved from the package's own
+    location: identical across calls and from another cwd (the path
+    is part of the cache key, so a directory that moves never hits)."""
+    from paddlefleetx_tpu.utils.env import setup_compilation_cache
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    first = setup_compilation_cache()
+    monkeypatch.chdir(tmp_path)
+    assert setup_compilation_cache() == first == \
+        os.path.join(checkout, ".xla_cache")
+    assert jax_cache_config.jax_compilation_cache_dir == first
+
+
+def test_engine_enables_the_cache_without_the_yaml_key(
+        monkeypatch, jax_cache_config):
+    """Engine.__init__ turns the cache on whether or not
+    Global.compilation_cache_dir is set (it used to be a no-op
+    without the key, so every chip-tool call compiled cold)."""
+    from paddlefleetx_tpu.core import engine as engine_mod
+    from paddlefleetx_tpu.utils import env
+    from paddlefleetx_tpu.utils.config import AttrDict
+    seen = []
+    monkeypatch.setattr(env, "setup_compilation_cache",
+                        lambda d=None: seen.append(d))
+
+    class Stop(Exception):
+        pass
+
+    def stop(*a, **k):
+        raise Stop
+    monkeypatch.setattr(engine_mod.TopologyConfig, "from_config", stop)
+    cfg = AttrDict({"Engine": AttrDict({}), "Global": AttrDict({})})
+    with pytest.raises(Stop):
+        engine_mod.Engine(cfg, module=None)
+    assert seen == [None]
 
 
 def test_cached_path(tmp_path, monkeypatch):
@@ -245,3 +306,50 @@ def test_download_waiter_ignores_stale_sentinel(tmp_path, monkeypatch):
     got = download.download("file:///srv/w.bin", str(tmp_path))
     t.join()
     assert open(got, "rb").read() == b"fresh"
+
+
+_LAUNCHER_PARENTS = {
+    # tools/launch.py / pfx-launch: two local ranks of a stub child
+    "launch": (
+        "from paddlefleetx_tpu.tools import launch\n"
+        "rc = launch.launch([sys.executable, '-c', 'print(1)'],\n"
+        "                   nprocs=2)\n"
+        "assert rc == 0, rc\n"),
+    # benchmarks/run_benchmark.py: the train child stubbed out
+    "run_benchmark": (
+        "sys.path.insert(0, os.path.join(repo, 'benchmarks'))\n"
+        "import subprocess\n"
+        "import run_benchmark\n"
+        "class Done:\n"
+        "    returncode = 0\n"
+        "    stdout = 'ips: 1200 tokens/s, loss: 9.5\\n'\n"
+        "    stderr = ''\n"
+        "calls = []\n"
+        "subprocess.run = lambda cmd, **k: calls.append(cmd) or Done()\n"
+        "run_benchmark.run(run_benchmark.get_args(\n"
+        "    ['--config', 'configs/nlp/gpt/pretrain_gpt_345M_single_card"
+        ".yaml']))\n"
+        "assert len(calls) == 1 and 'train.py' in calls[0][1], calls\n"),
+}
+
+
+@pytest.mark.parametrize("launcher", sorted(_LAUNCHER_PARENTS))
+def test_launcher_parent_stays_off_jax(launcher):
+    """A chip belongs to one process: a parent that has touched JAX
+    holds it and the child it starts then fails or hangs. Each
+    launcher's parent path runs in a fresh interpreter with a stub
+    child and must never import jax."""
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = (
+        "import os, sys\n"
+        f"repo = {repo!r}\n"
+        "sys.path.insert(0, repo)\n"
+        + _LAUNCHER_PARENTS[launcher] +
+        "assert 'jax' not in sys.modules, 'parent imported jax'\n"
+        "print('parent-off-jax')\n")
+    proc = subprocess.run([sys.executable, "-c", code], cwd=repo,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert "parent-off-jax" in proc.stdout
