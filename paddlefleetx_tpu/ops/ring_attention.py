@@ -282,7 +282,7 @@ def ring_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array,
     output, with s sharded over ``axis_name`` and the ring running
     inside. Axis defaults come from the mesh convention
     (``parallel/mesh.py``), not re-spelled strings."""
-    from ..parallel.mesh import CP_AXIS, DATA_AXES, MP_AXIS
+    from ..parallel.mesh import CP_AXIS, DATA_AXES, MP_AXIS, PP_AXIS
     axis_name = axis_name or CP_AXIS
     batch_axes = batch_axes or DATA_AXES
     heads_axis = heads_axis or MP_AXIS
@@ -293,5 +293,12 @@ def ring_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array,
     spec = P(batch_axes, axis_name, heads_axis, None)
     fn = partial(ring_attention, axis_name=axis_name, causal=causal,
                  use_flash=use_flash)
+    # under pp > 1 this call sits inside the pipeline's stage vmap,
+    # which names the pp axis (parallel/pipeline.py::_slot_vmap): its
+    # batching rule replays the ring body with pp added to the varying
+    # axes of the batched operands only, which the vma checker cannot
+    # type (JAX's own message names check_vma=False as the way out).
+    # The ring is type-checked wherever pp == 1.
     return _shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                      out_specs=spec)(q, k, v)
+                      out_specs=spec,
+                      check_vma=mesh.shape.get(PP_AXIS, 1) == 1)(q, k, v)

@@ -113,6 +113,22 @@ def _constrain(x, spec: P):
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
+def _slot_vmap(fn: Callable, S: int) -> Callable:
+    """``fn`` over the ``[vpp, S]`` slot grid. The stage dim IS the
+    mesh's ``pp`` axis: naming it (``spmd_axis_name``) lets whatever a
+    stage runs per device — sharding constraints, ``shard_map``-wrapped
+    kernels (``ops/ring_attention.py::shard_kernel``) — see the stage
+    dim sharded over ``pp``. Unnamed, a ``shard_map`` inside the stage
+    takes it as replicated and every pp device computes every stage's
+    kernel. Without a mesh whose ``pp`` axis is ``S`` wide this is the
+    plain double vmap."""
+    mesh = get_mesh()
+    named = mesh is not None and S > 1 \
+        and dict(mesh.shape).get(PP_AXIS, 1) == S
+    return jax.vmap(jax.vmap(
+        fn, spmd_axis_name=PP_AXIS if named else None))
+
+
 def _slot_params(stacked_params: Any, S: int, vpp: int) -> Tuple[Any, int]:
     """``[L, ...]`` stacked params -> ``[vpp, S, L/(S*vpp), ...]``
     sharded over ``pp`` on the physical-stage axis. Virtual stage
@@ -455,7 +471,7 @@ def pipeline_forward(
         h, _ = jax.lax.scan(body, h, (sp, jax.random.split(key, Lc)))
         return h
 
-    slot_stage = jax.vmap(jax.vmap(stage_fn))
+    slot_stage = _slot_vmap(stage_fn, S)
 
     def tick(carry, t):
         """One pipeline clock: every virtual stage computes, then
@@ -620,7 +636,7 @@ def pipeline_value_and_grad(
             return h, jnp.sum(auxs)
         return h
 
-    slot_stage = jax.vmap(jax.vmap(stage_fn))
+    slot_stage = _slot_vmap(stage_fn, S)
 
     # The combined pull (1f1b) extracts dW and dX from one backward;
     # the zb pulls split them — dX on the critical path, dW replayed
@@ -651,12 +667,12 @@ def pipeline_value_and_grad(
         _, pull = jax.vjp(lambda p: stage_fn(p, h, key), sp)
         return pull((g, a_ct))[0]
 
-    slot_backward = jax.vmap(jax.vmap(slot_vjp))
-    slot_backward_aux = jax.vmap(jax.vmap(slot_vjp_aux))
-    slot_backward_dx = jax.vmap(jax.vmap(slot_dx))
-    slot_backward_dx_aux = jax.vmap(jax.vmap(slot_dx_aux))
-    slot_backward_dw = jax.vmap(jax.vmap(slot_dw))
-    slot_backward_dw_aux = jax.vmap(jax.vmap(slot_dw_aux))
+    slot_backward = _slot_vmap(slot_vjp, S)
+    slot_backward_aux = _slot_vmap(slot_vjp_aux, S)
+    slot_backward_dx = _slot_vmap(slot_dx, S)
+    slot_backward_dx_aux = _slot_vmap(slot_dx_aux, S)
+    slot_backward_dw = _slot_vmap(slot_dw, S)
+    slot_backward_dw_aux = _slot_vmap(slot_dw_aux, S)
 
     # zero templates for the loss head's outputs
     y_abs = jax.ShapeDtypeStruct(mb_shape, x.dtype)

@@ -275,6 +275,41 @@ def test_flash_compiles_on_2x2_mesh(mesh4, as_tpu):
     assert "all-gather" not in txt
 
 
+def test_flash_under_the_stage_vmap_runs_one_stage_per_chip(topo,
+                                                           as_tpu):
+    """pp2 x mp2: the pipeline maps its stages with the pp axis named
+    (``parallel/pipeline.py::_slot_vmap``), so each chip's kernel
+    holds its OWN stage's block — no stage dim inside the custom call,
+    nothing gathered over pp. Unnamed, shard_map takes the stage dim
+    as replicated and every chip computes both stages."""
+    import flax.linen as nn
+    import numpy as np
+
+    from paddlefleetx_tpu.ops.attention import dot_product_attention
+    from paddlefleetx_tpu.parallel.mesh import (
+        MESH_AXES, TopologyConfig, set_mesh,
+    )
+    from paddlefleetx_tpu.parallel.pipeline import _slot_vmap
+    from paddlefleetx_tpu.parallel.sharding import make_sharding_rules
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 1, 1, 1, 2),
+                MESH_AXES)
+    set_mesh(mesh)
+    rules = list(make_sharding_rules(TopologyConfig(pp_degree=2,
+                                                    mp_degree=2)))
+    # [vpp, stage, microbatch, s, h, d]
+    sh = NamedSharding(mesh, P(None, "pp", None, None, "mp", None))
+    qkv = [_sds((1, 2, 2, S, H, D), BF16, sh)] * 3
+    fn = _slot_vmap(_grad_sum(functools.partial(
+        dot_product_attention, use_flash=True), (0, 1, 2)), 2)
+    with mesh, nn.logical_axis_rules(rules):
+        txt = _compile(fn, *qkv).as_text()
+    assert txt.count("tpu_custom_call") >= 2
+    assert "all-gather" not in txt
+    per_chip = f"{2 * H // 2},{S},{D}]"       # microbatch x heads/mp
+    assert f"bf16[{per_chip}" in txt
+    assert f"bf16[2,{per_chip}" not in txt    # both stages on a chip
+
+
 def test_unwrapped_flash_is_refused_on_2x2_mesh(mesh4, as_tpu):
     """The failure the helper exists for, pinned: a bare pallas_call
     under a sharded jit does not lower on real chips."""

@@ -106,9 +106,85 @@ def test_multichip_rehearsal_runs_only_the_mesh_phase(tmp_path):
     assert proc.returncode == 0, proc.stderr[-3000:]
     lines = _json_lines(proc.stdout)
     assert [ln.get("phase") for ln in lines] == [
-        "setup", "multichip", "compile_cache", None]
-    mesh = lines[1]
-    assert mesh["max_abs_diff"] <= mesh["tolerance"]
-    assert len(mesh["state_bytes_per_device"]) == 4
-    assert mesh["counters"]["attention/flash"] > 0
+        "setup", "multichip", "multichip_pp", "compile_cache", None]
+    for mesh in lines[1:3]:
+        assert mesh["max_abs_diff"] <= mesh["tolerance"]
+        assert len(mesh["state_bytes_per_device"]) == 4
+        assert mesh["counters"]["attention/flash"] > 0
+    # fsdp2: the batch-1 init trace is the only dense one (2 layers);
+    # pp2 x mp2 shards no batch, so nothing goes dense at all
+    assert lines[1]["counters"]["attention/dense"] == 2
+    assert "attention/dense" not in lines[2]["counters"]
     assert lines[-1]["device"]["count"] == 4
+
+
+def test_the_sizes_are_not_options(tmp_path):
+    """The start-up proof cannot be shrunk from the command line."""
+    proc = _run(["--rehearse", "--train-steps", "1"], tmp_path)
+    assert proc.returncode == 2
+    assert "unrecognized arguments" in proc.stderr
+    assert proc.stdout == ""
+
+
+# -- the serving judge, on a table of logits ---------------------------
+
+class _TableModel:
+    """``apply`` returns the parameters as the logits of one row: the
+    judge's teacher-forced forward, with every logit chosen here."""
+
+    class config:
+        max_position_embeddings = 16
+
+    @staticmethod
+    def apply(variables, ids, deterministic):
+        return variables["params"][None]
+
+
+ULP = 2.0 ** -6              # one bf16 ulp of a logit in [2, 4)
+PROMPT = [1, 2, 3]
+WANT = [4, 5, 6, 7]          # generate()'s row
+
+
+def _judge(served_rows, under_top, exact=False):
+    """Judge ``served_rows`` against WANT rows. The table makes WANT
+    the argmax (logit 3.0) at every position, except that from row
+    0's first divergent token on, row 0's served tokens sit
+    ``under_top[j]`` ulps under a 3.0 top."""
+    import numpy as np
+    sys.path.insert(0, REPO)
+    import chip_smoke
+    table = np.zeros((16, 8), np.float32)
+    for j, tok in enumerate(WANT):
+        table[len(PROMPT) - 1 + j, tok] = 3.0
+    for j, ulps in under_top.items():
+        table[len(PROMPT) - 1 + j, served_rows[0][j]] = 3.0 - ulps * ULP
+    judge = chip_smoke.TieJudge(_TableModel, table, pad=0, exact=exact)
+    return chip_smoke, lambda: judge.compare(
+        [PROMPT] * len(served_rows), served_rows,
+        [WANT] * len(served_rows))
+
+
+def test_judge_admits_one_tie_and_follows_the_served_row():
+    # token 1 leaves WANT at 1 ulp; the rest of the served row is the
+    # teacher-forced argmax (token 2) or within the bound (token 3)
+    _, compare = _judge([[4, 2, 1, 3], WANT], {1: 1, 2: 0, 3: 2})
+    n_exact, ties = compare()
+    assert n_exact == 1
+    assert ties == [{"request": 0, "token": 1,
+                     "ulps_under_top": {"served": 1.0, "lockstep": 0.0},
+                     "rest_tokens": 2, "rest_off_argmax": 1}]
+
+
+@pytest.mark.parametrize("served,under_top,exact,why", [
+    ([[4, 2, 1, 3], WANT], {1: 3, 2: 0, 3: 0}, False, "no tie"),
+    ([[4, 2, 1, 3], WANT], {1: 1, 2: 0, 3: 3}, False,
+     "after the tie at token 1"),
+    ([[4, 2, 6, 7], [4, 2, 6, 7]], {1: 1}, False, "2 rows left"),
+    ([[4, 2, 6, 7], WANT], {1: 0}, True, "served"),
+    ([WANT[:3], WANT], {}, False, "lengths differ"),
+], ids=["wrong-token", "wrong-after-tie", "two-tied-rows",
+        "exact-mode", "short-row"])
+def test_judge_refuses(served, under_top, exact, why):
+    chip_smoke, compare = _judge(served, under_top, exact)
+    with pytest.raises(chip_smoke.SmokeFailure, match=why):
+        compare()

@@ -58,6 +58,32 @@ def test_ring_grads_match_dense():
                                    atol=5e-5, rtol=1e-4)
 
 
+def test_ring_under_the_pipeline_stage_vmap():
+    """pp2 x cp2: the pipeline names the stage dim of its vmap after
+    the pp axis, so the ring's shard_map is batched over a mesh axis —
+    forward and grads still match dense attention stage by stage."""
+    from paddlefleetx_tpu.parallel.pipeline import _slot_vmap
+    topo = TopologyConfig(pp_degree=2, cp_degree=2, dp_degree=2)
+    mesh = build_mesh(topo)
+    set_mesh(mesh)
+    # [vpp, stage, b, s, h, d]
+    q, k, v = (jnp.stack([a, a[::-1]])[None] for a in _qkv(s=16))
+
+    def dense_loss(q, k, v):
+        return jnp.sum(dot_product_attention(q, k, v, causal=True) ** 2)
+
+    def ring_loss(q, k, v):
+        return jnp.sum(
+            ring_attention_sharded(q, k, v, mesh, causal=True) ** 2)
+
+    grads = lambda f: jax.value_and_grad(f, argnums=(0, 1, 2))  # noqa: E731
+    want = jax.vmap(jax.vmap(grads(dense_loss)))(q, k, v)
+    got = jax.jit(_slot_vmap(grads(ring_loss), 2))(q, k, v)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   atol=5e-5, rtol=1e-4)
+
+
 def test_ring_single_block_degenerate():
     """cp group of size 1 == plain attention."""
     q, k, v = _qkv(s=8)
