@@ -11,7 +11,7 @@ mechanical in both directions:
 - **PFX201** — a series name ``inc``'d / ``set_gauge``'d /
   ``timer``'d / ``add_time``'d / ``observe``'d in code — or a SPAN
   name opened via ``start_trace`` / ``start_span`` / ``span_point`` /
-  ``complete_span`` — but absent from every docs file. Anchored at
+  ``complete_span`` / ``annotate`` — but absent from every docs file. Anchored at
   the first code site.
 - **PFX202** — a docs-promised name (in a namespace code actually
   uses) with no code site: stale docs. Anchored at the docs line.
@@ -51,9 +51,11 @@ _REGISTER_ATTRS = {"inc", "set_gauge", "add_time", "timer", "observe"}
 #: docs contract: every span/trace/point name is a docs matrix row;
 #: `_phase` is the serving loop's phase-transition wrapper (its name
 #: argument is positional arg 1, so span attrs scan EVERY positional
-#: arg, not just the first)
+#: arg, not just the first); `annotate` is the host-phase primitive
+#: (observability/trace.py), whose slash-path names are rows of the
+#: "Host phases" table
 _SPAN_ATTRS = {"start_trace", "start_span", "span_point",
-               "complete_span", "_phase"}
+               "complete_span", "_phase", "annotate"}
 _NAME_RE = re.compile(r"^[a-z0-9_]+(/[a-z0-9_]+)+$")
 _PREFIX_RE = re.compile(r"^[a-z0-9_]+(/[a-z0-9_]+)*/$")
 _BACKTICK_RE = re.compile(r"`([^`]+)`")

@@ -43,7 +43,7 @@ from ..observability import timeline as obs_timeline
 from ..observability.memory import device_memory_stats, format_bytes
 from ..observability.recorder import FlightRecorder
 from ..observability.spans import NULL_SPAN, Tracer
-from ..observability.trace import annotate
+from ..observability.trace import annotate, annotate_step
 from ..optims import build_lr_scheduler, build_optimizer
 from ..parallel.mesh import (
     TopologyConfig, build_mesh, set_mesh, DATA_AXES,
@@ -179,9 +179,11 @@ class Engine(BasicEngine):
             events_path = tele.get("events_path") or \
                 os.path.join(self.output_dir, "events.jsonl")
             self._recorder = FlightRecorder(events_path)
-        # span tracing rides the same recorder: engine/fit owns
-        # per-step engine/step spans with compile/h2d/save children
-        # (docs/observability.md); a recorder-less tracer hands out
+        # span tracing rides the same recorder: engine/fit owns the
+        # rare, durable compile and save children
+        # (docs/observability.md); per-step phases are profiler
+        # annotations and in-memory seconds (observability/trace.py),
+        # never recorder lines. A recorder-less tracer hands out
         # NULL_SPAN and costs nothing
         self._tracer = Tracer(self._recorder)
         self._fit_span = NULL_SPAN
@@ -588,16 +590,22 @@ class Engine(BasicEngine):
 
     def _prefetch_iter(self, loader, depth=None):
         """Double-buffered device staging: yields
-        ``(device_batch, h2d_wait_seconds)`` with up to ``depth``
-        batches' host->device transfers in flight ahead of the
-        consumer, so batch N+1's transfer is ISSUED before the
-        consumer ever blocks on step N's result — the transfer rides
-        under the jitted step instead of serializing after it
-        (``jax.device_put`` dispatches asynchronously).
+        ``(device_batch, staged)`` with up to ``depth`` batches'
+        host->device transfers in flight ahead of the consumer, so
+        batch N+1's transfer is ISSUED before the consumer ever blocks
+        on step N's result — the transfer rides under the jitted step
+        instead of serializing after it (``jax.device_put``
+        dispatches asynchronously).
 
-        ``h2d_wait_seconds`` is the host time this iterator spent
-        staging (collation + pretreating + the device-put dispatch)
-        per yielded batch — the step loop's observable input stall.
+        ``staged`` holds the host seconds this iterator spent staging
+        per yielded batch, by phase: ``h2d`` (the whole of it — the
+        step loop's observable input stall) and its children
+        ``h2d/loader_next`` (the loader's ``next``: collation),
+        ``h2d/pretreat`` (``pretreating_batch``) and
+        ``h2d/device_put`` (``_put_batch``: the transfer's dispatch,
+        and the runtime's back-pressure where it sits there). The
+        first yield's record also carries the pipeline fill: it is
+        real input latency the first step pays.
 
         Correctness notes:
 
@@ -618,38 +626,34 @@ class Engine(BasicEngine):
             depth = self.prefetch_depth
         buf = deque()
         it = iter(loader)
+        end = object()
 
-        def stage():
-            try:
-                batch = next(it)
-            except StopIteration:
-                return False
-            with annotate("h2d"):
-                batch = self.module.pretreating_batch(batch)
-                buf.append(self._put_batch(batch))
+        def stage(staged):
+            with annotate("h2d", staged):
+                with annotate("h2d/loader_next", staged):
+                    batch = next(it, end)
+                if batch is end:
+                    return False
+                with annotate("h2d/pretreat", staged):
+                    batch = self.module.pretreating_batch(batch)
+                with annotate("h2d/device_put", staged):
+                    buf.append(self._put_batch(batch))
             return True
 
         if depth <= 0:
             while True:
-                t0 = time.time()
-                if not stage():
+                staged = {}
+                if not stage(staged):
                     return
-                yield buf.popleft(), time.time() - t0
-            return
-        prime = time.time()
+                yield buf.popleft(), staged
+        staged = {}
         for _ in range(depth):
-            if not stage():
+            if not stage(staged):
                 break
-        prime = time.time() - prime
-        first = True
         while buf:
-            t0 = time.time()
-            stage()          # issue batch N+depth before handing out N
-            wait = time.time() - t0
-            # the pipeline fill is the first yield's wait: it is real
-            # input latency the first step pays
-            yield buf.popleft(), (wait + prime if first else wait)
-            first = False
+            stage(staged)    # issue batch N+depth before handing out N
+            yield buf.popleft(), staged
+            staged = {}
 
     # -- loops ----------------------------------------------------------
 
@@ -793,6 +797,10 @@ class Engine(BasicEngine):
                          valid_data_loader=None):
         step_start = time.time()
         window_clean = True
+        # the host's seconds by phase, summed over the steps since the
+        # last step_window record (filled by ``annotate``)
+        window: Dict[str, float] = {}
+        window_steps = 0
         # the training loop's own timeline track — "main" next to the
         # watchdog/loader/server rows in the merged Perfetto view
         tl = obs_timeline.track("main")
@@ -800,7 +808,7 @@ class Engine(BasicEngine):
         # every iteration would sync and kill async dispatch
         step = self._host_step
         with self.mesh, nn.logical_axis_rules(self.rules):
-            for batch, h2d_wait in self._prefetch_iter(
+            for batch, staged in self._prefetch_iter(
                     train_data_loader):
                 if step >= self.max_steps:
                     return
@@ -811,78 +819,87 @@ class Engine(BasicEngine):
                     # at the logging sync / next donation — still
                     # inside this window
                     self._watchdog.arm(tag=f"step {step + 1}")
-                step_span = self._fit_span.start_span(
-                    "engine/step", step=step + 1)
+                for name, seconds in staged.items():
+                    window[name] = window.get(name, 0.0) + seconds
+                self._h2d_waits.append(staged["h2d"])
                 tl_t0 = tl.begin()
-                t_call = time.time()
-                with annotate("train_step"):
-                    self.state, metrics = self._train_step(
-                        self.state, batch)
-                if self._compile_pending:
-                    # the first call traces + compiles before its
-                    # async dispatch returns; charge that host time to
-                    # the compile bucket and sample memory right after
-                    # (the compile-time peak is what OOMs big configs)
-                    self._compile_pending = False
-                    compile_s = time.time() - t_call
-                    self._time_buckets["compile"] += compile_s
-                    step_span.complete_span("engine/compile",
-                                            compile_s)
-                    if self._recorder is not None:
-                        self._recorder.emit(
-                            "compile", step=step,
-                            seconds=round(compile_s, 4),
-                            hbm=self._sample_memory())
-                self._h2d_waits.append(h2d_wait)
-                step_span.complete_span("engine/h2d", h2d_wait)
-                step += 1
-                self._host_step = step
-                if step % self.logging_freq == 0:
-                    metrics = jax.device_get(metrics)
-                    cost = (time.time() - step_start) / self.logging_freq
-                    mem = self._sample_memory()
-                    log_dict = {
-                        "epoch": epoch, "batch": step,
-                        "loss": float(metrics["loss"]),
-                        "lr": float(metrics["lr"]),
-                        "grad_norm": float(metrics["grad_norm"]),
-                        "train_cost": cost,
-                    }
-                    if mem is not None:
-                        log_dict["hbm_bytes_in_use"] = \
-                            mem.get("bytes_in_use")
-                        log_dict["hbm_peak_bytes"] = \
-                            mem.get("peak_bytes_in_use")
-                    self.module.training_step_end(log_dict)
-                    # summary samples: only clean windows (a mid-window
-                    # eval/save resets step_start, which would skew the
-                    # per-step quotient)
-                    if window_clean:
-                        self._step_costs.append(cost)
-                        self._metrics.observe("engine/step_time_ms",
-                                              cost * 1000.0)
-                        # steady-state windows only (the first clean
-                        # window still holds compile, which the
-                        # summary likewise skips via costs[0])
-                        if self._pipeline_bubble_share and \
-                                len(self._step_costs) > 1:
-                            self._time_buckets["pipeline_bubble"] += (
-                                cost * self.logging_freq *
-                                self._pipeline_bubble_share)
-                    if self._recorder is not None:
-                        w = self._h2d_waits[-self.logging_freq:]
-                        self._recorder.emit(
-                            "step_window", step=step,
-                            loss=log_dict["loss"], lr=log_dict["lr"],
-                            grad_norm=log_dict["grad_norm"],
-                            step_time=round(cost, 5),
-                            h2d_wait=round(sum(w) / len(w), 5)
-                            if w else 0.0,
-                            hbm=mem)
-                    window_clean = True
-                    step_start = time.time()
-                tl.add("step", tl_t0)
-                step_span.end()
+                with annotate_step("train", step + 1):
+                    with annotate("train_step", window) as dispatch:
+                        self.state, metrics = self._train_step(
+                            self.state, batch)
+                    if self._compile_pending:
+                        # the first call traces + compiles before its
+                        # async dispatch returns; charge that host time to
+                        # the compile bucket and sample memory right after
+                        # (the compile-time peak is what OOMs big configs)
+                        self._compile_pending = False
+                        compile_s = dispatch.seconds
+                        self._time_buckets["compile"] += compile_s
+                        self._fit_span.complete_span("engine/compile",
+                                                     compile_s)
+                        if self._recorder is not None:
+                            self._recorder.emit(
+                                "compile", step=step,
+                                seconds=round(compile_s, 4),
+                                hbm=self._sample_memory())
+                    step += 1
+                    self._host_step = step
+                    window_steps += 1
+                    if step % self.logging_freq == 0:
+                        with annotate("engine/log_sync"):
+                            metrics = jax.device_get(metrics)
+                        cost = (time.time() - step_start) / self.logging_freq
+                        mem = self._sample_memory()
+                        log_dict = {
+                            "epoch": epoch, "batch": step,
+                            "loss": float(metrics["loss"]),
+                            "lr": float(metrics["lr"]),
+                            "grad_norm": float(metrics["grad_norm"]),
+                            "train_cost": cost,
+                        }
+                        if mem is not None:
+                            log_dict["hbm_bytes_in_use"] = \
+                                mem.get("bytes_in_use")
+                            log_dict["hbm_peak_bytes"] = \
+                                mem.get("peak_bytes_in_use")
+                        self.module.training_step_end(log_dict)
+                        # summary samples: only clean windows (a mid-window
+                        # eval/save resets step_start, which would skew the
+                        # per-step quotient)
+                        if window_clean:
+                            self._step_costs.append(cost)
+                            self._metrics.observe("engine/step_time_ms",
+                                                  cost * 1000.0)
+                            # steady-state windows only (the first clean
+                            # window still holds compile, which the
+                            # summary likewise skips via costs[0])
+                            if self._pipeline_bubble_share and \
+                                    len(self._step_costs) > 1:
+                                self._time_buckets["pipeline_bubble"] += (
+                                    cost * self.logging_freq *
+                                    self._pipeline_bubble_share)
+                        if self._recorder is not None:
+                            # the host's seconds per step by phase,
+                            # as means over the window's steps
+                            per_step = {
+                                k: round(v / window_steps, 5)
+                                for k, v in window.items()}.get
+                            self._recorder.emit(
+                                "step_window", step=step,
+                                loss=log_dict["loss"], lr=log_dict["lr"],
+                                grad_norm=log_dict["grad_norm"],
+                                step_time=round(cost, 5),
+                                h2d_wait=per_step("h2d", 0.0),
+                                loader_next=per_step("h2d/loader_next", 0.0),
+                                pretreat=per_step("h2d/pretreat", 0.0),
+                                device_put=per_step("h2d/device_put", 0.0),
+                                dispatch=per_step("train_step", 0.0),
+                                hbm=mem)
+                        window.clear()
+                        window_steps = 0
+                        window_clean = True
+                        step_start = time.time()
+                    tl.add("step", tl_t0)
                 if self.run_mode == "step" and \
                         step % self.eval_freq == 0 and \
                         valid_data_loader is not None:
@@ -1185,7 +1202,7 @@ class Engine(BasicEngine):
             self._recorder.emit("eval_start", step=self._host_step,
                                 epoch=epoch)
         with annotate("eval"):
-            for i, (batch, _h2d) in enumerate(
+            for i, (batch, _staged) in enumerate(
                     self._prefetch_iter(valid_data_loader)):
                 if max_iters is not None and i >= max_iters:
                     break
@@ -1227,7 +1244,7 @@ class Engine(BasicEngine):
         outs = []
         t0 = time.time()
         with self.mesh, nn.logical_axis_rules(self.rules):
-            for i, (batch, _h2d) in enumerate(
+            for i, (batch, _staged) in enumerate(
                     self._prefetch_iter(test_data_loader)):
                 if i >= self.test_iters:
                     logger.info("The predicting process is complete.")
@@ -1257,12 +1274,11 @@ class Engine(BasicEngine):
             "consumed_samples": step * self.global_batch_size,
             "seed": int(self.configs.Global.get("seed", 1024)),
         }
-        t0 = time.time()
-        with annotate("save"):
+        with annotate("save") as saving:
             path = ckpt.save_checkpoint(self.output_dir, epoch, step,
                                         self.state, meta,
                                         async_save=self.async_save)
-        save_s = time.time() - t0
+        save_s = saving.seconds
         self._time_buckets["save"] += save_s
         self._metrics.add_time("save", save_s)
         self._fit_span.complete_span("engine/save", save_s, step=step)

@@ -99,9 +99,9 @@ spec decode 1 tick != 1 token), the tiered ``serving/spill`` /
 ``serving/spec_accepted`` counters + ``serving/spec_accept_rate``
 gauge, the ``serving/device_ticks`` counter and per-reason
 ``serving/loop_exit/{finished,admission,budget,drain}`` counters of
-the fused loop, a ``serving/decode_tick`` timer (one timing per
-ROUND-TRIP — T ticks when fused), and a tokens/s + TTFT p50/p99
-summary;
+the fused loop, the ``serving/slow_steps`` /
+``serving/slow_step/<phase>`` counters of the slow-step record, and a
+tokens/s + TTFT p50/p99 summary;
 an optional flight recorder mirrors admissions/evictions to an
 ``events.jsonl`` stream CI's failure-diagnostics artifact collects.
 
@@ -119,6 +119,14 @@ reconstructs a request's whole life, submit through evict. With
 ``PFX_METRICS_PORT`` set the server also exposes live ``/metrics``,
 ``/vars``, ``/healthz`` (drain-aware: 503 while draining) and
 ``/trace`` endpoints (``observability/server.py``).
+
+Host phases: every line of ``step()`` / ``prefill_step()`` runs
+inside one ``serving/step/<phase>`` annotation under the root
+``serving/step`` (``observability/trace.py``: a profiler annotation
+on the device trace's clock, and the seconds of the step's
+:class:`StepRecord`). The record feeds ``serving/tick_ms``,
+``serving/host_roundtrip_ms`` and ``summary()``'s decode time, and
+names the phase of a slow step (docs/observability.md, "Host phases").
 """
 
 from __future__ import annotations
@@ -128,6 +136,7 @@ import hashlib
 import json
 import queue
 import signal
+import statistics
 import threading
 import time
 from collections import deque
@@ -151,6 +160,7 @@ from ..observability import server as obs_server
 from ..observability import timeline
 from ..observability.recorder import FlightRecorder
 from ..observability.spans import Tracer
+from ..observability.trace import annotate, unaccounted
 from ..utils.log import logger
 from .adapters import AdapterCache, AdapterCacheFull, insert_adapter
 from .paging import (
@@ -187,6 +197,67 @@ def default_prefill_buckets(max_prompt_len: int) -> Tuple[int, ...]:
         b *= 2
     out.append(max_prompt_len)
     return tuple(out)
+
+
+#: the root annotation of one ``step()`` / ``prefill_step()``; its
+#: children are ``serving/step/<phase>``
+STEP = "serving/step"
+#: a step is SLOW when it took longer than both this many seconds and
+#: ``SLOW_STEP_FACTOR`` x the median of the last ``SLOW_STEP_HISTORY``
+#: decoding steps (judged once ``SLOW_STEP_MIN_HISTORY`` of them are
+#: in — the first steps of a server compile)
+SLOW_STEP_SECONDS = 0.25
+SLOW_STEP_FACTOR = 5.0
+SLOW_STEP_HISTORY = 64
+SLOW_STEP_MIN_HISTORY = 8
+
+
+class StepRecord:
+    """The host's account of one ``step()``: wall time of its start,
+    seconds by phase (``phases``, filled by ``annotate``; the root's
+    duration under ``STEP``), and what the step did."""
+
+    __slots__ = ("start", "phases", "live", "queued", "chunks",
+                 "ticks", "tokens")
+
+    def __init__(self):
+        self.start = time.time()
+        self.phases: Dict[str, float] = {}
+        self.live = 0        # slots the decode launch ticked
+        self.queued = 0      # queue depth once admission had run
+        self.chunks = 0      # prefill chunks dispatched
+        self.ticks = 0       # decode ticks run on the device
+        self.tokens = 0      # tokens committed
+
+    @property
+    def seconds(self) -> float:
+        """The root's duration."""
+        return self.phases.get(STEP, 0.0)
+
+    def tick_seconds(self) -> float:
+        """The decode launch as the host saw it: dispatch, then the
+        wait for its tokens."""
+        return self.phases.get(STEP + "/decode_dispatch", 0.0) + \
+            self.phases.get(STEP + "/decode_harvest", 0.0)
+
+    def phases_ms(self) -> Dict[str, float]:
+        """Milliseconds by phase, short names, ``unaccounted`` (the
+        root's time no phase covers) among them: they sum to the
+        root's duration."""
+        out = {k[len(STEP) + 1:]: round(v * 1e3, 3)
+               for k, v in self.phases.items() if k != STEP}
+        out["unaccounted"] = round(
+            unaccounted(self.phases, STEP) * 1e3, 3)
+        return out
+
+    def as_dict(self) -> dict:
+        """The whole record, as the slow-step log line and event
+        carry it."""
+        return {"start": round(self.start, 3),
+                "dur_ms": round(self.seconds * 1e3, 3),
+                "phases_ms": self.phases_ms(), "live": self.live,
+                "queued": self.queued, "chunks": self.chunks,
+                "ticks": self.ticks, "tokens": self.tokens}
 
 
 @dataclass
@@ -448,6 +519,10 @@ class GenerationServer:
                     "outside the main thread; call drain() explicitly")
         self._decode_tokens = 0
         self._tick_time = 0.0
+        #: the newest finished step's record (always on, in memory)
+        self.last_step: Optional[StepRecord] = None
+        #: durations of the last decoding steps: the slow-step baseline
+        self._recent_steps: deque = deque(maxlen=SLOW_STEP_HISTORY)
         # latency histograms live in a server-local always-on registry
         # (summary percentiles must work with global telemetry off);
         # fixed-memory log buckets replace the old unbounded TTFT list
@@ -1157,62 +1232,76 @@ class GenerationServer:
                        rehydrated=n_host or None,
                        trace=self._trace_id(req))
 
-    def _prefill_pump(self) -> None:
+    def _prefill_pump(self, rec: StepRecord) -> None:
         """Run at most ONE page-aligned prefill chunk per step — the
         oldest still-prefilling slot advances while everyone else's
         decode tick proceeds, so a long admission never freezes
-        tokens/s (the chunked-prefill contract of ROADMAP item 1)."""
-        if not self._prefilling:
-            return
-        slot = self._prefilling[0]
-        req = self._slots[slot]
-        seq = req["prompt"] + req["tokens"]
-        L = len(seq)
-        c0 = req["prefill_pos"]
-        row = np.full((1, self._chunk), self.gen_cfg.pad_token_id,
-                      np.int32)
-        row[0, :len(seq[c0:c0 + self._chunk])] = seq[c0:c0 + self._chunk]
-        self._sync_pt()
-        self._cache, logits = prefill_chunk_paged(
-            self.model, self.params, self._cache, jnp.asarray(row),
-            jnp.asarray([c0], jnp.int32), self._pt_dev[slot:slot + 1],
-            jnp.asarray([int(self._aid_np[slot])], jnp.int32)
-            if self._adapters is not None else None)
-        req["prefill_pos"] = c0 + self._chunk
-        self._prefill_chunk_count += 1
-        metrics.inc("serving/prefill_chunks")
-        self._emit("serving_prefill_chunk", request=req["id"],
-                   slot=slot, start=c0,
-                   tokens=min(self._chunk, L - c0),
-                   trace=self._trace_id(req))
-        if req["prefill_pos"] < L:
-            return
-        self._prefilling.popleft()
-        del req["prefill_pos"]
-        # the chunk-rounded admission allocated pages for the final
-        # chunk's pad tail too; that KV is never read, so hand those
-        # pages straight back to the pool instead of pinning them (and
-        # the registries below) until evict
-        used = -(-L // self._page)
-        if used < req["num_pages"]:
-            for j in range(used, req["num_pages"]):
-                self._release_page(int(self._pt[slot, j]))
-                self._pt[slot, j] = NULL_PAGE
-            req["num_pages"] = used
-            self._pt_dirty = True
-        # the last real token sits at chunk row L - 1 - c0
-        last = np.asarray(logits[0, L - 1 - c0])
-        self._activate(slot, last)
-        # adapter-tinted KV must never enter the shared registries
-        # (_admit_paged's share rule — base-only content addressing)
-        if self._prefix_sharing and not req.get("adapter_id"):
-            keys = page_prefix_keys(seq, self._page)
-            for j, kk in enumerate(keys):
-                self._alloc.register_prefix(kk, int(self._pt[slot, j]))
-            self._alloc.register_prompt(
-                prompt_key(seq),
-                [int(p) for p in self._pt[slot, :req["num_pages"]]],
-                last)
+        tokens/s (the chunked-prefill contract of ROADMAP item 1).
+
+        Phases: ``prefill_pump`` is the host side up to and including
+        the chunk's dispatch, and the slot's activation after a
+        prompt's last chunk; ``prefill_harvest`` between them is the
+        read of that chunk's last logits row, a device sync."""
+        ph = rec.phases
+        with annotate("serving/step/prefill_pump", ph):
+            if not self._prefilling:
+                return
+            slot = self._prefilling[0]
+            req = self._slots[slot]
+            seq = req["prompt"] + req["tokens"]
+            L = len(seq)
+            c0 = req["prefill_pos"]
+            row = np.full((1, self._chunk), self.gen_cfg.pad_token_id,
+                          np.int32)
+            row[0, :len(seq[c0:c0 + self._chunk])] = \
+                seq[c0:c0 + self._chunk]
+            self._sync_pt()
+            self._cache, logits = prefill_chunk_paged(
+                self.model, self.params, self._cache, jnp.asarray(row),
+                jnp.asarray([c0], jnp.int32),
+                self._pt_dev[slot:slot + 1],
+                jnp.asarray([int(self._aid_np[slot])], jnp.int32)
+                if self._adapters is not None else None)
+            req["prefill_pos"] = c0 + self._chunk
+            self._prefill_chunk_count += 1
+            rec.chunks += 1
+            metrics.inc("serving/prefill_chunks")
+            self._emit("serving_prefill_chunk", request=req["id"],
+                       slot=slot, start=c0,
+                       tokens=min(self._chunk, L - c0),
+                       trace=self._trace_id(req))
+            if req["prefill_pos"] < L:
+                return
+            self._prefilling.popleft()
+            del req["prefill_pos"]
+            # the chunk-rounded admission allocated pages for the final
+            # chunk's pad tail too; that KV is never read, so hand
+            # those pages straight back to the pool instead of pinning
+            # them (and the registries below) until evict
+            used = -(-L // self._page)
+            if used < req["num_pages"]:
+                for j in range(used, req["num_pages"]):
+                    self._release_page(int(self._pt[slot, j]))
+                    self._pt[slot, j] = NULL_PAGE
+                req["num_pages"] = used
+                self._pt_dirty = True
+        with annotate("serving/step/prefill_harvest", ph):
+            # the last real token sits at chunk row L - 1 - c0
+            last = np.asarray(logits[0, L - 1 - c0])
+        with annotate("serving/step/prefill_pump", ph):
+            self._activate(slot, last)
+            # adapter-tinted KV must never enter the shared registries
+            # (_admit_paged's share rule — base-only content
+            # addressing)
+            if self._prefix_sharing and not req.get("adapter_id"):
+                keys = page_prefix_keys(seq, self._page)
+                for j, kk in enumerate(keys):
+                    self._alloc.register_prefix(
+                        kk, int(self._pt[slot, j]))
+                self._alloc.register_prompt(
+                    prompt_key(seq),
+                    [int(p) for p in self._pt[slot, :req["num_pages"]]],
+                    last)
 
     def _release_pages(self, slot: int) -> None:
         req = self._slots[slot]
@@ -1766,21 +1855,26 @@ class GenerationServer:
             False (queue head blocked on pool pages, nothing to do)
             to back off instead of spinning, and to keep no-op polls
             off the thread timeline."""
+        rec = StepRecord()
+        with annotate("serving/step", rec.phases):
+            with self._surface_lock:
+                if self._closed:
+                    return False
+                with annotate("serving/step/admit", rec.phases):
+                    q0 = len(self._queue)
+                    if not self._draining:
+                        self._admit()
+                    rec.queued = len(self._queue)
+                if self.paged:
+                    self._prefill_pump(rec)
+                    metrics.get_registry().set_gauge(
+                        "serving/pages_in_use",
+                        self._alloc.pages_in_use)
+                progress = rec.queued != q0 or rec.chunks > 0
+            with annotate("serving/step/ship_spills", rec.phases):
+                self._ship_spills()
         with self._surface_lock:
-            if self._closed:
-                return False
-            q0 = len(self._queue)
-            chunks0 = self._prefill_chunk_count if self.paged else 0
-            if not self._draining:
-                self._admit()
-            progress = len(self._queue) != q0
-            if self.paged:
-                self._prefill_pump()
-                progress = progress or \
-                    self._prefill_chunk_count != chunks0
-                metrics.get_registry().set_gauge(
-                    "serving/pages_in_use", self._alloc.pages_in_use)
-        self._ship_spills()
+            self._account_step(rec)
         return progress
 
     def prompt_ready(self, tokens: Sequence[int]) -> bool:
@@ -2079,163 +2173,229 @@ class GenerationServer:
         spill shipping (the one blocking queue put) happens after the
         lock is released so the writer thread can never be fed from
         inside the critical section."""
+        rec = StepRecord()
+        with annotate("serving/step", rec.phases):
+            with self._surface_lock:
+                if self._closed:
+                    return []
+                if self._loop_ticks > 1:
+                    out = self._step_loop(rec)
+                    with annotate("serving/step/commit", rec.phases):
+                        self._refresh_health()
+                else:
+                    out = self._step_impl(rec)
+            with annotate("serving/step/ship_spills", rec.phases):
+                self._ship_spills()
         with self._surface_lock:
-            if self._closed:
-                return []
-            if self._loop_ticks > 1:
-                out = self._step_loop()
-                self._refresh_health()
-            else:
-                out = self._step_impl()
-        self._ship_spills()
+            self._account_step(rec)
         return out
 
-    def _step_impl(self) -> List[Completion]:
-        step_t0 = time.time()
-        expired = self._expire_deadlines()
-        if self._faults is not None:
-            self._faults.fire("tick", self._ticks + 1)
-        # host yield point: between device launches is the ONLY place
-        # pinned spills move to the host tier (decode never blocks)
-        self._drain_spills()
-        if not self._draining:
-            self._admit()
-        reg = metrics.get_registry()
+    def _account_step(self, rec: StepRecord) -> None:
+        """Feed one finished step's record to what is kept of the
+        series (each interval was clocked once, by ``annotate``) and
+        judge it against the slow-step thresholds. Under the surface
+        lock, like every other write to the server's counters."""
+        self.last_step = rec
+        seconds = rec.seconds
+        if rec.ticks:
+            tick_s = rec.tick_seconds()
+            self._tick_time += tick_s
+            for _ in range(rec.ticks):
+                # a fused launch spreads its wall time over its ticks
+                self._metrics.observe("serving/tick_ms",
+                                      tick_s * 1000.0 / rec.ticks)
+            # one round-trip's full host cost (admit + draft +
+            # dispatch + fetch + replay) — the series the T-sweep
+            # compares against tick_ms to show the amortization win
+            self._metrics.observe("serving/host_roundtrip_ms",
+                                  seconds * 1000.0)
+        recent = self._recent_steps
+        if seconds > SLOW_STEP_SECONDS and \
+                len(recent) >= SLOW_STEP_MIN_HISTORY:
+            median = statistics.median(recent)
+            if seconds > SLOW_STEP_FACTOR * median:
+                self._slow_step(rec, median)
+        if rec.ticks:
+            recent.append(seconds)
+
+    def _slow_step(self, rec: StepRecord, median_s: float) -> None:
+        """Put a slow step on the record: which phase took most of
+        it, and the whole per-phase account."""
+        record = rec.as_dict()
+        phases = record["phases_ms"]
+        worst = max(phases, key=phases.get)
+        metrics.inc("serving/slow_steps")
+        metrics.inc("serving/slow_step/" + worst)
+        logger.warning(
+            "slow step(): %.0f ms against a median of %.0f ms over "
+            "the last %d decoding steps, most of it in %s: %s",
+            record["dur_ms"], median_s * 1e3, len(self._recent_steps),
+            worst, json.dumps(record))
+        self._emit("serving_slow_step", worst=worst,
+                   median_ms=round(median_s * 1e3, 3), **record)
+
+    def _schedule(self, rec: StepRecord
+                  ) -> Tuple[List[Completion], List[int]]:
+        """What every ``step()`` opens with, a phase each: expire
+        deadlines, drain pinned spills, admit what fits, pump one
+        prefill chunk, settle which slots the launch will tick.
+        Returns the expired completions and those slots."""
+        ph = rec.phases
+        with annotate("serving/step/expire", ph):
+            expired = self._expire_deadlines()
+            if self._faults is not None:
+                self._faults.fire("tick", self._ticks + 1)
+        with annotate("serving/step/spill_drain", ph):
+            # host yield point: between device launches is the ONLY
+            # place pinned spills move to the host tier (decode never
+            # blocks); a pending pin capped the previous fused launch
+            # at one tick via _loop_host_flag
+            self._drain_spills()
+        with annotate("serving/step/admit", ph):
+            if not self._draining:
+                self._admit()
+            rec.queued = len(self._queue)
         if self.paged:
-            self._prefill_pump()
-            reg.set_gauge("serving/pages_in_use",
-                          self._alloc.pages_in_use)
-        live = [s for s, r in enumerate(self._slots)
-                if r is not None and (not self.paged or r.get("active"))]
-        if not live:
-            # nothing decodable yet (empty, or every occupant is still
-            # mid-chunked-prefill) — the pump above still made progress
-            reg.set_gauge("serving/slot_occupancy", self.occupancy)
+            self._prefill_pump(rec)
+        with annotate("serving/step/table_sync", ph):
+            if self.paged:
+                metrics.get_registry().set_gauge(
+                    "serving/pages_in_use", self._alloc.pages_in_use)
+            live = [s for s, r in enumerate(self._slots)
+                    if r is not None
+                    and (not self.paged or r.get("active"))]
+            rec.live = len(live)
+            if live:
+                self._sync_aid()
+        return expired, live
+
+    def _idle_step(self, rec: StepRecord, expired: List[Completion]
+                   ) -> List[Completion]:
+        """The end of a step with nothing decodable yet (empty, or
+        every occupant is still mid-chunked-prefill) — the pump still
+        made progress."""
+        with annotate("serving/step/commit", rec.phases):
+            metrics.get_registry().set_gauge(
+                "serving/slot_occupancy", self.occupancy)
             return expired + self._take_dead()
-        self._sync_aid()
+
+    def _step_impl(self, rec: StepRecord) -> List[Completion]:
+        ph = rec.phases
+        expired, live = self._schedule(rec)
+        if not live:
+            return self._idle_step(rec, expired)
         if self._watchdog is not None:
             self._watchdog.arm(tag=f"tick {self._ticks + 1}")
-        t0 = time.time()
-        with reg.timer("serving/decode_tick"):
-            if self.spec:
+        k = self._spec_k if self.spec else 0
+        if self.spec:
+            with annotate("serving/step/draft", ph):
                 # host drafts ride down with the tick; inactive rows
                 # are zeros the verify mask never commits
-                k = self._spec_k
                 drafts = np.zeros((self.num_slots, k), np.int32)
                 for slot in live:
                     req = self._slots[slot]
                     drafts[slot] = self._draft.propose(
                         req["prompt"] + req["tokens"], k)
-                if self.paged:
-                    # growth/COW decisions cover the whole k+1-token
-                    # write window — then one table upload
-                    self._page_maintenance(window=k + 1)
-                    self._sync_pt()
-                    self._cache, self._state, window, counts = \
-                        verify_step(
-                            self.model, self.params, self._cache,
-                            self._state, jnp.asarray(drafts),
-                            self._rng, self.gen_cfg, self._pt_dev_dec,
-                            self._aid_arg())
-                else:
-                    self._cache, self._state, window, counts = \
-                        verify_step(
-                            self.model, self.params, self._cache,
-                            self._state, jnp.asarray(drafts),
-                            self._rng, self.gen_cfg, None,
-                            self._aid_arg())
-                window = np.asarray(window)   # device sync in-timer
+        if self.paged:
+            with annotate("serving/step/page_maintenance", ph):
+                # growth/COW decisions against the PRE-tick lengths,
+                # over the tick's whole write window (k+1 tokens
+                # speculative) — then one table upload
+                self._page_maintenance(window=k + 1)
+            with annotate("serving/step/table_sync", ph):
+                self._sync_pt()
+        with annotate("serving/step/decode_dispatch", ph):
+            pt = self._pt_dev_dec if self.paged else None
+            if self.spec:
+                self._cache, self._state, window, counts = \
+                    verify_step(
+                        self.model, self.params, self._cache,
+                        self._state, jnp.asarray(drafts), self._rng,
+                        self.gen_cfg, pt, self._aid_arg())
+            else:
+                self._cache, self._state, tok = decode_step(
+                    self.model, self.params, self._cache,
+                    self._state, self._rng, self.gen_cfg, pt,
+                    self._aid_arg())
+        with annotate("serving/step/decode_harvest", ph):
+            # the host blocked on the tick
+            if self.spec:
+                window = np.asarray(window)
                 counts = np.asarray(counts)
             else:
-                if self.paged:
-                    # growth/COW decisions against the PRE-tick
-                    # lengths — the tick's write position — then one
-                    # table upload
-                    self._page_maintenance()
-                    self._sync_pt()
-                    self._cache, self._state, tok = decode_step(
-                        self.model, self.params, self._cache,
-                        self._state, self._rng, self.gen_cfg,
-                        self._pt_dev_dec, self._aid_arg())
-                else:
-                    self._cache, self._state, tok = decode_step(
-                        self.model, self.params, self._cache,
-                        self._state, self._rng, self.gen_cfg, None,
-                        self._aid_arg())
-                tok = np.asarray(tok)   # device sync inside the timer
-                window = tok[:, None]
+                window = np.asarray(tok)[:, None]
                 counts = np.ones((self.num_slots,), np.int32)
-        tick_s = time.time() - t0
-        self._tick_time += tick_s
-        self._metrics.observe("serving/tick_ms", tick_s * 1000.0)
-        if self._watchdog is not None:
-            self._watchdog.disarm()
-        self._ticks += 1
-        self._roundtrips += 1
-        metrics.inc("serving/device_ticks")
-        finished = np.asarray(self._state.finished)
-        dec_count = np.asarray(self._state.dec_count)
-        done: List[Completion] = []
-        now = time.time()
-        committed = 0
-        ticked = 0
-        for slot in live:
-            req = self._slots[slot]
-            if req is None or (self.paged and not req.get("active")):
-                # preempted out from under the tick by page
-                # maintenance (pool exhaustion) — nothing committed
-                continue
-            ticked += 1
-            m = int(counts[slot])
-            req["tokens"].extend(int(t) for t in window[slot, :m])
-            if "ttft" not in req:
-                req["ttft"] = now - req["submit_t"]
-                req["first_tok_t"] = now
-                self._metrics.observe("serving/ttft_ms",
-                                      req["ttft"] * 1000.0)
-                req["span"].span_point(
-                    "serving/first_token",
-                    ttft_ms=round(req["ttft"] * 1000.0, 3))
-            if self.paged:
-                req["cur_len"] += m
-                if self.spec:
-                    # rejected-KV rollback: pages wholly past the
-                    # accepted point go straight back to the pool (the
-                    # partial page's stale columns sit past cur_len
-                    # and are overwritten before any masked read)
-                    used = -(-req["cur_len"] // self._page)
-                    if used < req["num_pages"]:
-                        for j in range(used, req["num_pages"]):
-                            self._release_page(int(self._pt[slot, j]))
-                            self._pt[slot, j] = NULL_PAGE
-                        req["num_pages"] = used
-                        self._pt_dirty = True
-            committed += m
-            self._decode_tokens += m
-            if finished[slot]:
-                done.append(self._evict(slot, "eos"))
-            elif dec_count[slot] >= self.gen_cfg.max_dec_len:
-                done.append(self._evict(slot, "length"))
-        metrics.inc("serving/decode_tokens", committed)
-        if self.spec:
-            drafted = self._spec_k * ticked
-            accepted = committed - ticked      # t0s are not drafts
-            self._spec_drafted += drafted
-            self._spec_accepted += accepted
-            metrics.inc("serving/spec_drafted", drafted)
-            metrics.inc("serving/spec_accepted", accepted)
-            reg.set_gauge(
-                "serving/spec_accept_rate",
-                self._spec_accepted / max(self._spec_drafted, 1))
-            self._emit("serving_spec", drafted=drafted,
-                       accepted=accepted, committed=committed)
-        reg.set_gauge("serving/slot_occupancy", self.occupancy)
-        # one round-trip's full host cost (admit + draft + dispatch +
-        # fetch + replay) — the series the T-sweep compares against
-        # tick_ms to show the amortization win
-        self._metrics.observe("serving/host_roundtrip_ms",
-                              (time.time() - step_t0) * 1000.0)
-        return expired + self._take_dead() + done
+        with annotate("serving/step/state_fetch", ph):
+            finished = np.asarray(self._state.finished)
+            dec_count = np.asarray(self._state.dec_count)
+        with annotate("serving/step/commit", ph):
+            if self._watchdog is not None:
+                self._watchdog.disarm()
+            self._ticks += 1
+            self._roundtrips += 1
+            rec.ticks = 1
+            metrics.inc("serving/device_ticks")
+            reg = metrics.get_registry()
+            done: List[Completion] = []
+            now = time.time()
+            committed = 0
+            ticked = 0
+            for slot in live:
+                req = self._slots[slot]
+                if req is None or \
+                        (self.paged and not req.get("active")):
+                    # preempted out from under the tick by page
+                    # maintenance (pool exhaustion) — nothing committed
+                    continue
+                ticked += 1
+                m = int(counts[slot])
+                req["tokens"].extend(int(t) for t in window[slot, :m])
+                if "ttft" not in req:
+                    req["ttft"] = now - req["submit_t"]
+                    req["first_tok_t"] = now
+                    self._metrics.observe("serving/ttft_ms",
+                                          req["ttft"] * 1000.0)
+                    req["span"].span_point(
+                        "serving/first_token",
+                        ttft_ms=round(req["ttft"] * 1000.0, 3))
+                if self.paged:
+                    req["cur_len"] += m
+                    if self.spec:
+                        # rejected-KV rollback: pages wholly past the
+                        # accepted point go straight back to the pool
+                        # (the partial page's stale columns sit past
+                        # cur_len and are overwritten before any
+                        # masked read)
+                        used = -(-req["cur_len"] // self._page)
+                        if used < req["num_pages"]:
+                            for j in range(used, req["num_pages"]):
+                                self._release_page(
+                                    int(self._pt[slot, j]))
+                                self._pt[slot, j] = NULL_PAGE
+                            req["num_pages"] = used
+                            self._pt_dirty = True
+                committed += m
+                self._decode_tokens += m
+                if finished[slot]:
+                    done.append(self._evict(slot, "eos"))
+                elif dec_count[slot] >= self.gen_cfg.max_dec_len:
+                    done.append(self._evict(slot, "length"))
+            rec.tokens = committed
+            metrics.inc("serving/decode_tokens", committed)
+            if self.spec:
+                drafted = self._spec_k * ticked
+                accepted = committed - ticked      # t0s are not drafts
+                self._spec_drafted += drafted
+                self._spec_accepted += accepted
+                metrics.inc("serving/spec_drafted", drafted)
+                metrics.inc("serving/spec_accepted", accepted)
+                reg.set_gauge(
+                    "serving/spec_accept_rate",
+                    self._spec_accepted / max(self._spec_drafted, 1))
+                self._emit("serving_spec", drafted=drafted,
+                           accepted=accepted, committed=committed)
+            reg.set_gauge("serving/slot_occupancy", self.occupancy)
+            return expired + self._take_dead() + done
 
     # -- device-resident decode (device_loop_ticks > 1) ---------------
     #
@@ -2287,7 +2447,7 @@ class GenerationServer:
                 return True
         return False
 
-    def _step_loop(self) -> List[Completion]:
+    def _step_loop(self, rec: StepRecord) -> List[Completion]:
         """The ``device_loop_ticks > 1`` body of :meth:`step`: one
         fused multi-tick launch, then a per-tick replay of the
         returned token buffers so ``serving/decode_tokens``, TTFT/TPOT
@@ -2295,29 +2455,13 @@ class GenerationServer:
         ``serving/tick_ms`` and the per-tick ``serving_spec`` events
         stay tick-accurate. Greedy/seeded output is token-exact vs the
         T=1 path (tests/test_serving.py parity matrix)."""
-        step_t0 = time.time()
-        expired = self._expire_deadlines()
-        if self._faults is not None:
-            self._faults.fire("tick", self._ticks + 1)
-        # host yield point (see step()): pinned spills drain here and
-        # nowhere else — a pending pin capped the previous launch at
-        # one tick via _loop_host_flag
-        self._drain_spills()
-        if not self._draining:
-            self._admit()
-        reg = metrics.get_registry()
-        if self.paged:
-            self._prefill_pump()
-            reg.set_gauge("serving/pages_in_use",
-                          self._alloc.pages_in_use)
-        live = [s for s, r in enumerate(self._slots)
-                if r is not None and (not self.paged or r.get("active"))]
+        ph = rec.phases
+        expired, live = self._schedule(rec)
         if not live:
-            reg.set_gauge("serving/slot_occupancy", self.occupancy)
-            return expired + self._take_dead()
-        self._sync_aid()
+            return self._idle_step(rec, expired)
         T = self._loop_ticks
-        host_flag = self._loop_host_flag(live)
+        with annotate("serving/step/page_maintenance", ph):
+            host_flag = self._loop_host_flag(live)
         # flag up -> the loop exits after one tick, so drafting and
         # page pre-mapping cover one tick's window only (the launch
         # shape stays [slots, T, ...]: loop_ticks is static, the flag
@@ -2326,10 +2470,9 @@ class GenerationServer:
         if self._watchdog is not None:
             self._watchdog.arm(
                 tag=f"ticks {self._ticks + 1}..{self._ticks + T}")
-        t0 = time.time()
-        with reg.timer("serving/decode_tick"):
-            if self.spec:
-                k = self._spec_k
+        k = self._spec_k if self.spec else 0
+        if self.spec:
+            with annotate("serving/step/draft", ph):
                 drafts = np.zeros((self.num_slots, T, k), np.int32)
                 for slot in live:
                     req = self._slots[slot]
@@ -2340,131 +2483,134 @@ class GenerationServer:
                             req["prompt"] + req["tokens"],
                             k * eff_ticks),
                         np.int32).reshape(eff_ticks, k)
-                if self.paged:
-                    self._page_maintenance(window=eff_ticks * (k + 1))
-                    self._sync_pt()
+        if self.paged:
+            with annotate("serving/step/page_maintenance", ph):
+                self._page_maintenance(window=eff_ticks * (k + 1))
+            with annotate("serving/step/table_sync", ph):
+                self._sync_pt()
+        with annotate("serving/step/decode_dispatch", ph):
+            pt = self._pt_dev_dec if self.paged else None
+            if self.spec:
                 (self._cache, self._state, window_buf, counts_buf,
                  ticks_run, exit_code) = verify_loop(
                     self.model, self.params, self._cache, self._state,
                     jnp.asarray(drafts), self._rng, self.gen_cfg,
-                    jnp.int32(host_flag),
-                    self._pt_dev_dec if self.paged else None,
-                    self._aid_arg(), loop_ticks=T)
-                window_np = np.asarray(window_buf)
-                counts_np = np.asarray(counts_buf)
-                n_ticks = int(ticks_run)
+                    jnp.int32(host_flag), pt, self._aid_arg(),
+                    loop_ticks=T)
             else:
-                if self.paged:
-                    self._page_maintenance(window=eff_ticks)
-                    self._sync_pt()
                 (self._cache, self._state, tokens_buf, ticks_run,
                  exit_code) = decode_loop(
                     self.model, self.params, self._cache, self._state,
-                    self._rng, self.gen_cfg, jnp.int32(host_flag),
-                    self._pt_dev_dec if self.paged else None,
+                    self._rng, self.gen_cfg, jnp.int32(host_flag), pt,
                     self._aid_arg(), loop_ticks=T)
-                # device sync inside the timer, like the T=1 path
+        with annotate("serving/step/decode_harvest", ph):
+            # the host blocked on the launch
+            n_ticks = int(ticks_run)
+            if self.spec:
+                window_np = np.asarray(window_buf)
+                counts_np = np.asarray(counts_buf)
+            else:
                 window_np = np.asarray(tokens_buf)[:, :, None]
-                n_ticks = int(ticks_run)
                 counts_np = np.zeros((self.num_slots, T), np.int32)
                 counts_np[:, :n_ticks] = 1
             exit_code = int(exit_code)
-        loop_s = time.time() - t0
-        self._tick_time += loop_s
-        per_tick_s = loop_s / n_ticks
-        for _ in range(n_ticks):
-            self._metrics.observe("serving/tick_ms",
-                                  per_tick_s * 1000.0)
-        if self._watchdog is not None:
-            self._watchdog.disarm()
-        self._ticks += n_ticks
-        self._roundtrips += 1
-        metrics.inc("serving/device_ticks", n_ticks)
-        metrics.inc(
-            "serving/loop_exit/finished"
-            if exit_code == LOOP_EXIT_FINISHED
-            else "serving/loop_exit/budget"
-            if exit_code == LOOP_EXIT_BUDGET
-            else ("serving/loop_exit/drain" if self._draining
-                  else "serving/loop_exit/admission"))
-        finished = np.asarray(self._state.finished)
-        dec_count = np.asarray(self._state.dec_count)
-        done: List[Completion] = []
-        committed = 0
-        for j in range(n_ticks):
-            # the loop is one opaque device program; per-tick
-            # timestamps interpolate its wall time so TTFT/TPOT stay
-            # comparable with the T=1 histograms
-            t_j = t0 + (j + 1) * per_tick_s
-            tick_committed = 0
-            ticked = 0
+            t_end = time.time()
+        with annotate("serving/step/state_fetch", ph):
+            finished = np.asarray(self._state.finished)
+            dec_count = np.asarray(self._state.dec_count)
+        with annotate("serving/step/commit", ph):
+            if self._watchdog is not None:
+                self._watchdog.disarm()
+            self._ticks += n_ticks
+            self._roundtrips += 1
+            rec.ticks = n_ticks
+            metrics.inc("serving/device_ticks", n_ticks)
+            metrics.inc(
+                "serving/loop_exit/finished"
+                if exit_code == LOOP_EXIT_FINISHED
+                else "serving/loop_exit/budget"
+                if exit_code == LOOP_EXIT_BUDGET
+                else ("serving/loop_exit/drain" if self._draining
+                      else "serving/loop_exit/admission"))
+            reg = metrics.get_registry()
+            per_tick_s = rec.tick_seconds() / n_ticks
+            done: List[Completion] = []
+            committed = 0
+            for j in range(n_ticks):
+                # the loop is one opaque device program; per-tick
+                # timestamps interpolate its wall time so TTFT/TPOT
+                # stay comparable with the T=1 histograms
+                t_j = t_end - (n_ticks - 1 - j) * per_tick_s
+                tick_committed = 0
+                ticked = 0
+                for slot in live:
+                    req = self._slots[slot]
+                    if req is None or \
+                            (self.paged and not req.get("active")):
+                        # preempted out from under the launch by page
+                        # pre-mapping (pool exhaustion) — nothing
+                        # committed
+                        continue
+                    ticked += 1
+                    m = int(counts_np[slot, j])
+                    req["tokens"].extend(
+                        int(t) for t in window_np[slot, j, :m])
+                    if "ttft" not in req:
+                        req["ttft"] = t_j - req["submit_t"]
+                        req["first_tok_t"] = t_j
+                        self._metrics.observe("serving/ttft_ms",
+                                              req["ttft"] * 1000.0)
+                        req["span"].span_point(
+                            "serving/first_token",
+                            ttft_ms=round(req["ttft"] * 1000.0, 3))
+                    tick_committed += m
+                committed += tick_committed
+                self._decode_tokens += tick_committed
+                if self.spec and ticked:
+                    drafted = self._spec_k * ticked
+                    accepted = tick_committed - ticked
+                    self._spec_drafted += drafted
+                    self._spec_accepted += accepted
+                    metrics.inc("serving/spec_drafted", drafted)
+                    metrics.inc("serving/spec_accepted", accepted)
+                    self._emit("serving_spec", drafted=drafted,
+                               accepted=accepted,
+                               committed=tick_committed)
+            rec.tokens = committed
+            metrics.inc("serving/decode_tokens", committed)
+            if self.spec:
+                reg.set_gauge(
+                    "serving/spec_accept_rate",
+                    self._spec_accepted / max(self._spec_drafted, 1))
+            if self.paged:
+                # advance each slot past its committed tokens and hand
+                # pages wholly past that point back to the pool — both
+                # the pre-mapped-but-unused tail of an early exit and
+                # spec's rejected-KV rollback
+                for slot in live:
+                    req = self._slots[slot]
+                    if req is None or not req.get("active"):
+                        continue
+                    req["cur_len"] += int(
+                        counts_np[slot, :n_ticks].sum())
+                    used = -(-req["cur_len"] // self._page)
+                    if used < req["num_pages"]:
+                        for j in range(used, req["num_pages"]):
+                            self._release_page(int(self._pt[slot, j]))
+                            self._pt[slot, j] = NULL_PAGE
+                        req["num_pages"] = used
+                        self._pt_dirty = True
             for slot in live:
                 req = self._slots[slot]
                 if req is None or \
                         (self.paged and not req.get("active")):
-                    # preempted out from under the launch by page
-                    # pre-mapping (pool exhaustion) — nothing committed
                     continue
-                ticked += 1
-                m = int(counts_np[slot, j])
-                req["tokens"].extend(
-                    int(t) for t in window_np[slot, j, :m])
-                if "ttft" not in req:
-                    req["ttft"] = t_j - req["submit_t"]
-                    req["first_tok_t"] = t_j
-                    self._metrics.observe("serving/ttft_ms",
-                                          req["ttft"] * 1000.0)
-                    req["span"].span_point(
-                        "serving/first_token",
-                        ttft_ms=round(req["ttft"] * 1000.0, 3))
-                tick_committed += m
-            committed += tick_committed
-            self._decode_tokens += tick_committed
-            if self.spec and ticked:
-                drafted = self._spec_k * ticked
-                accepted = tick_committed - ticked
-                self._spec_drafted += drafted
-                self._spec_accepted += accepted
-                metrics.inc("serving/spec_drafted", drafted)
-                metrics.inc("serving/spec_accepted", accepted)
-                self._emit("serving_spec", drafted=drafted,
-                           accepted=accepted,
-                           committed=tick_committed)
-        metrics.inc("serving/decode_tokens", committed)
-        if self.spec:
-            reg.set_gauge(
-                "serving/spec_accept_rate",
-                self._spec_accepted / max(self._spec_drafted, 1))
-        if self.paged:
-            # advance each slot past its committed tokens and hand
-            # pages wholly past that point back to the pool — both the
-            # pre-mapped-but-unused tail of an early exit and spec's
-            # rejected-KV rollback
-            for slot in live:
-                req = self._slots[slot]
-                if req is None or not req.get("active"):
-                    continue
-                req["cur_len"] += int(counts_np[slot, :n_ticks].sum())
-                used = -(-req["cur_len"] // self._page)
-                if used < req["num_pages"]:
-                    for j in range(used, req["num_pages"]):
-                        self._release_page(int(self._pt[slot, j]))
-                        self._pt[slot, j] = NULL_PAGE
-                    req["num_pages"] = used
-                    self._pt_dirty = True
-        for slot in live:
-            req = self._slots[slot]
-            if req is None or (self.paged and not req.get("active")):
-                continue
-            if finished[slot]:
-                done.append(self._evict(slot, "eos"))
-            elif dec_count[slot] >= self.gen_cfg.max_dec_len:
-                done.append(self._evict(slot, "length"))
-        reg.set_gauge("serving/slot_occupancy", self.occupancy)
-        self._metrics.observe("serving/host_roundtrip_ms",
-                              (time.time() - step_t0) * 1000.0)
-        self._refresh_health()
-        return expired + self._take_dead() + done
+                if finished[slot]:
+                    done.append(self._evict(slot, "eos"))
+                elif dec_count[slot] >= self.gen_cfg.max_dec_len:
+                    done.append(self._evict(slot, "length"))
+            reg.set_gauge("serving/slot_occupancy", self.occupancy)
+            return expired + self._take_dead() + done
 
     def drain(self, max_ticks: Optional[int] = None
               ) -> List[Completion]:

@@ -22,12 +22,15 @@ from .recorder import FlightRecorder, read_events, read_tail
 from .server import MetricsServer
 from .spans import NULL_SPAN, Span, Tracer
 from .timeline import ThreadTimeline, get_timeline
-from .trace import annotate
+
+# ``observability.trace`` (host phases as profiler annotations) imports
+# jax and is imported where it is used, not here: a launcher's parent
+# takes ``timeline`` from this package and must stay off jax.
 
 __all__ = [
     "FlightRecorder", "LogHistogram", "MetricsRegistry",
     "MetricsServer", "NULL_SPAN", "PEAK_FLOPS_BY_KIND", "Span",
-    "ThreadTimeline", "Tracer", "annotate", "causal_attn_flops",
+    "ThreadTimeline", "Tracer", "causal_attn_flops",
     "device_memory_stats", "export", "format_bytes", "get_registry",
     "get_timeline", "metrics", "model_flops_per_token", "peak_flops",
     "read_events", "read_tail", "server", "timeline",

@@ -1,23 +1,68 @@
-"""Labeled trace phases: the XProf trace and the summary share names.
+"""Host phases on the clock the device trace is on.
 
-``annotate(name)`` wraps a host-side phase in
-``jax.profiler.TraceAnnotation`` so the trace viewer shows the same
-buckets the goodput accounting reports (``h2d``, ``train_step``,
-``eval``, ``save``, ``mp_collective_probe``). Degrades to a no-op
-context when the profiler machinery is unavailable — annotation must
-never be the thing that kills a run.
+``annotate(name, record)`` is the one call every host phase uses. It
+opens a ``jax.profiler.TraceAnnotation`` (a TraceMe: recorded only
+while a profiler session runs, on the session's clock and on the
+calling thread's line, so the ``.xplane.pb`` shows the phase beside
+the device's op line) and adds the elapsed ``perf_counter`` seconds
+to the caller's per-step ``record`` under the same name — one
+interval, clocked once, feeding the trace and the always-on
+accounting alike. With no session running it costs about a
+microsecond.
+
+Names are slash-paths whose parent is their prefix
+(``serving/step/admit`` lies inside ``serving/step``, ``h2d/pretreat``
+inside ``h2d``), so nesting can be rebuilt from names and intervals
+alone. The vocabulary is the "Host phases" table of
+``docs/observability.md``.
 """
 
 from __future__ import annotations
 
-from contextlib import nullcontext
+import time
+from typing import MutableMapping, Optional
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 
-def annotate(name: str):
+class annotate:
     """Context manager labeling the enclosed host block ``name`` in
-    the profiler timeline (microseconds of overhead; safe per step)."""
-    try:
-        import jax
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:
-        return nullcontext()
+    the profiler timeline; on exit the block's seconds are in
+    ``.seconds`` and added to ``record[name]`` (a phase entered twice
+    in one step sums)."""
+
+    __slots__ = ("name", "seconds", "_record", "_ann", "_t0")
+
+    def __init__(self, name: str,
+                 record: Optional[MutableMapping[str, float]] = None):
+        self.name = name
+        self.seconds = 0.0
+        self._record = record
+        self._ann = TraceAnnotation(name)
+
+    def __enter__(self) -> "annotate":
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.seconds = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        if self._record is not None:
+            self._record[self.name] = \
+                self._record.get(self.name, 0.0) + self.seconds
+
+
+def annotate_step(name: str, step_num: int) -> StepTraceAnnotation:
+    """One training step for the profiler: the device plane's step
+    line then carries ``step_num`` for what was launched inside."""
+    return StepTraceAnnotation(name, step_num=step_num)
+
+
+def unaccounted(record: MutableMapping[str, float], root: str) -> float:
+    """Seconds of ``record[root]`` that none of its direct children
+    (``root/<phase>``) covers."""
+    depth = root.count("/") + 1
+    return record.get(root, 0.0) - sum(
+        v for k, v in record.items()
+        if k.startswith(root + "/") and k.count("/") == depth)

@@ -604,14 +604,16 @@ def test_prefetch_iter_stages_ahead_and_preserves_order():
     """The double-buffer contract: batch N+depth's device put is
     ISSUED before batch N is handed to the consumer (so the transfer
     overlaps step N's compute), loader order is preserved, and every
-    yield carries a non-negative h2d wait sample."""
+    yield carries the staging seconds by phase (``h2d`` and its
+    children, which cannot outlast it)."""
     events = []
     fake = _FakePrefetchHost(events, depth=2)
     got = []
-    for batch, wait in Engine._prefetch_iter(fake, [0, 1, 2, 3]):
+    for batch, staged in Engine._prefetch_iter(fake, [0, 1, 2, 3]):
         events.append(("yield", batch))
         got.append(batch)
-        assert wait >= 0.0
+        assert staged["h2d"] >= sum(
+            v for k, v in staged.items() if k != "h2d") >= 0.0
     assert got == [0, 1, 2, 3]
     assert [b for e, b in events if e == "put"] == [0, 1, 2, 3]
     assert events.index(("put", 2)) < events.index(("yield", 0))
@@ -666,3 +668,91 @@ def test_fit_with_prefetch_disabled_matches_defaults(tmp_path):
         engine.fit(epoch=1, train_data_loader=loader)
         losses[depth] = seen
     assert losses[2] == pytest.approx(losses[0], rel=1e-6)
+
+
+def test_fit_keeps_host_phases_per_step_in_memory(tmp_path):
+    """A fit of a few steps leaves the window means of the host's
+    phases (loader, pretreat, device put, dispatch) on ``step_window``
+    beside ``h2d_wait``, and NO record that recurs every step: the
+    per-step ``engine/step`` / ``engine/h2d`` spans are gone from
+    ``events.jsonl``, ``engine/compile`` hangs off ``engine/fit``."""
+    import json
+
+    from paddlefleetx_tpu.observability import metrics as obs_metrics
+    try:
+        cfg, engine, loader = _build(
+            tmp_path, **{"Telemetry": {"enable": True}})
+        engine.fit(epoch=1, train_data_loader=loader)
+    finally:
+        obs_metrics.set_enabled(False)
+        obs_metrics.get_registry().reset()
+    with open(tmp_path / "out" / "events.jsonl") as f:
+        events = [json.loads(line) for line in f]
+    windows = [e for e in events if e["event"] == "step_window"]
+    assert len(windows) == 2                # 10 steps, logging_freq 5
+    for w in windows:
+        for key in ("loader_next", "pretreat", "device_put",
+                    "dispatch"):
+            assert w[key] >= 0.0, key
+        # the children cannot outlast the phase they lie in
+        assert w["loader_next"] + w["pretreat"] + w["device_put"] \
+            <= w["h2d_wait"] + 1e-4
+        assert w["dispatch"] <= w["step_time"] + 1e-4
+    # the first window's dispatch holds the compile
+    assert windows[0]["dispatch"] > windows[1]["dispatch"]
+    spans = [e for e in events if e["event"].startswith("span")]
+    names = [e["name"] for e in spans]
+    assert "engine/step" not in names and "engine/h2d" not in names
+    fit_begin = next(e for e in spans if e["name"] == "engine/fit")
+    (compile_span,) = [e for e in spans
+                       if e["name"] == "engine/compile"]
+    assert compile_span["parent"] == fit_begin["span"]
+    # nothing recurs per step: 10 steps, and no event seen 10 times
+    counts = {}
+    for e in events:
+        key = (e["event"], e.get("name"))
+        counts[key] = counts.get(key, 0) + 1
+    assert max(counts.values()) < cfg.Engine.max_steps, counts
+
+
+def test_fit_under_a_profiler_session_annotates_each_step(tmp_path):
+    """Under a profiler session the Engine's main-thread line holds
+    one ``train`` step annotation per step with ``train_step`` inside
+    it, ``h2d`` with its three children inside, and ``engine/log_sync``
+    on the logging steps."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+    cfg, engine, loader = _build(tmp_path, **{"Engine.max_steps": 5})
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.enable_hlo_proto = False
+    trace_dir = str(tmp_path / "trace")
+    jax.profiler.start_trace(trace_dir, profiler_options=opts)
+    try:
+        engine.fit(epoch=1, train_data_loader=loader)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    evs = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            got = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                   for e in line.events]
+            if any(n == "train_step" for n, _, _ in got):
+                evs = got
+    by = {}
+    for n, s, e in evs:
+        by.setdefault(n, []).append((s, e))
+    assert len(by["train"]) == len(by["train_step"]) == 5
+    assert len(by["engine/log_sync"]) == 1          # logging_freq 5
+
+    def inside(child, parent):
+        return all(any(ps <= s and e <= pe for ps, pe in by[parent])
+                   for s, e in by[child])
+    assert inside("train_step", "train")
+    assert inside("engine/log_sync", "train")
+    for child in ("h2d/loader_next", "h2d/pretreat", "h2d/device_put"):
+        assert len(by[child]) >= 5 and inside(child, "h2d"), child

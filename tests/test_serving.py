@@ -165,7 +165,7 @@ def test_serving_smoke_interpret_kernel(model_and_params, tmp_path):
         assert reg.counter("serving/admitted") == 3
         assert reg.counter("serving/evicted") == 3
         assert reg.gauge("serving/slot_occupancy") == 0
-        assert reg.counter("serving/decode_tick/calls") == \
+        assert reg.counter("serving/device_ticks") == \
             srv.summary()["decode_ticks"]
         kinds = [json.loads(l)["event"] for l in
                  events.read_text().splitlines()]
@@ -2301,3 +2301,139 @@ def test_tiered_requires_paged_prefix_sharing(model_and_params):
                          page_size=128, pool_pages=8,
                          prefill_chunk_pages=1, prefix_sharing=False,
                          host_pool_bytes=1 << 20)
+
+
+# -- host phases and the slow-step record ------------------------------
+
+
+def _phase_server(paged512_model_and_params, **kw):
+    model, params = paged512_model_and_params
+    return GenerationServer(model, params, _greedy_cfg(max_dec=12),
+                            num_slots=2, page_size=128,
+                            prefill_chunk_pages=1, **kw)
+
+
+@pytest.mark.parametrize("loop_ticks", [1, 4])
+def test_step_record_phases_sum_to_the_root(paged512_model_and_params,
+                                            loop_ticks):
+    """Every ``step()`` leaves one record whose per-phase seconds,
+    with ``unaccounted``, are the root's duration — phases never
+    overlap, so what they leave uncovered is small and not negative —
+    and the record, not a second clock, feeds the kept series."""
+    srv = _phase_server(paged512_model_and_params,
+                        device_loop_ticks=loop_ticks)
+    rng = np.random.default_rng(1)
+    for n in (5, 200, 9):
+        srv.submit(rng.integers(0, EOS, n).tolist())
+    records = []
+    try:
+        while srv.pending or srv.occupancy:
+            srv.step()
+            records.append(srv.last_step)
+        summ = srv.summary()
+    finally:
+        srv.close()
+    assert len({id(r) for r in records}) == len(records)
+    for rec in records:
+        d = rec.as_dict()
+        ms = d["phases_ms"]
+        assert sum(ms.values()) == pytest.approx(d["dur_ms"], abs=0.02)
+        assert -0.01 <= ms["unaccounted"] < 0.25 * d["dur_ms"] + 0.5
+        assert all(v >= 0.0 for k, v in ms.items()
+                   if k != "unaccounted")
+        assert (rec.ticks > 0) == ("decode_harvest" in ms)
+        assert rec.live <= 2 and rec.chunks in (0, 1)
+    assert sum(r.chunks for r in records) == summ["prefill_chunks"]
+    assert sum(r.ticks for r in records) == summ["decode_ticks"]
+    assert sum(r.tokens for r in records) == summ["decode_tokens"]
+    decoding = [r for r in records if r.ticks]
+    assert len(decoding) == summ["host_roundtrips"]
+    # one interval, clocked once: the series hold what the records do
+    assert srv._metrics.histogram("serving/tick_ms").count == \
+        summ["decode_ticks"]
+    h = srv._metrics.histogram("serving/host_roundtrip_ms")
+    assert h.count == len(decoding)
+    assert h.sum == pytest.approx(
+        sum(r.seconds for r in decoding) * 1e3, rel=1e-6)
+    assert summ["decode_time_sec"] == pytest.approx(
+        sum(r.tick_seconds() for r in decoding), abs=1e-4)
+
+
+def test_slow_step_names_its_phase(paged512_model_and_params, tmp_path,
+                                   monkeypatch, caplog):
+    """A ``step()`` slowed by one stubbed phase raises
+    ``serving/slow_steps`` and ``serving/slow_step/<that phase>`` and
+    leaves the whole per-phase record in the log and the event
+    stream; the steps around it raise neither."""
+    import logging
+    import time as _time
+    events = tmp_path / "events.jsonl"
+    metrics.set_enabled(True)
+    reg = metrics.get_registry()
+    reg.reset()
+    srv = _phase_server(paged512_model_and_params,
+                        events_path=str(events))
+    try:
+        srv.submit([5, 9, 2])
+        srv.submit([7, 1])
+        for _ in range(9):                  # the history to judge by
+            srv.step()
+        assert srv.last_step.ticks == 1
+        assert reg.counter("serving/slow_steps") == 0
+        plain = srv._page_maintenance
+
+        def stalled(*a, **kw):
+            _time.sleep(0.4)
+            return plain(*a, **kw)
+        monkeypatch.setattr(srv, "_page_maintenance", stalled)
+        from paddlefleetx_tpu.utils.log import logger as pfx_logger
+        monkeypatch.setattr(pfx_logger, "propagate", True)
+        with caplog.at_level(logging.WARNING):
+            srv.step()
+        monkeypatch.setattr(srv, "_page_maintenance", plain)
+        slow = srv.last_step
+        assert slow.seconds > 0.4
+        assert reg.counter("serving/slow_steps") == 1
+        assert reg.counter("serving/slow_step/page_maintenance") == 1
+        srv.step()                          # and a normal one again
+        assert reg.counter("serving/slow_steps") == 1
+        snap = reg.snapshot()["counters"]
+        assert [k for k in snap if k.startswith("serving/slow_step/")] \
+            == ["serving/slow_step/page_maintenance"]
+    finally:
+        srv.close()
+        metrics.set_enabled(False)
+        reg.reset()
+    (ev,) = [e for e in read_events(str(events))
+             if e["event"] == "serving_slow_step"]
+    assert ev["worst"] == "page_maintenance"
+    assert ev["phases_ms"]["page_maintenance"] >= 400.0
+    assert ev["dur_ms"] > 5 * ev["median_ms"]
+    assert sum(ev["phases_ms"].values()) == \
+        pytest.approx(ev["dur_ms"], abs=0.02)
+    for key in ("start", "live", "queued", "chunks", "ticks", "tokens"):
+        assert key in ev, key
+    (line,) = [r.getMessage() for r in caplog.records
+               if "slow step()" in r.getMessage()]
+    assert "page_maintenance" in line and '"phases_ms"' in line
+
+
+def test_a_normal_run_has_no_slow_step(paged512_model_and_params):
+    """Compilation in a server's first steps is not a slow step (too
+    little history to judge by), and nothing after it is."""
+    metrics.set_enabled(True)
+    reg = metrics.get_registry()
+    reg.reset()
+    srv = _phase_server(paged512_model_and_params)
+    rng = np.random.default_rng(2)
+    try:
+        srv.run([rng.integers(0, EOS, n).tolist()
+                 for n in (5, 140, 9, 30)])
+        assert srv.summary()["decode_ticks"] > 8
+        assert reg.counter("serving/slow_steps") == 0
+        assert not [k for k in reg.snapshot()["counters"]
+                    if k.startswith("serving/slow_step")]
+    finally:
+        srv.close()
+        metrics.set_enabled(False)
+        reg.reset()

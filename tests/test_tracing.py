@@ -532,3 +532,157 @@ def test_start_from_env_gating(monkeypatch, tmp_path):
     finally:
         obs_server.stop()
     assert obs_server.get_server() is None
+
+
+# -- host phases: observability/trace.py --------------------------------
+
+
+def test_annotate_hands_its_seconds_to_the_record():
+    """One interval, clocked once: the block's seconds land in
+    ``.seconds`` and are ADDED to the record under the phase's name,
+    so a phase entered twice in one step sums; ``unaccounted`` is the
+    root's time its direct children leave uncovered."""
+    import time
+
+    from paddlefleetx_tpu.observability.trace import (
+        annotate, unaccounted)
+    rec = {}
+    with annotate("root", rec) as root:
+        with annotate("root/a", rec) as a:
+            time.sleep(0.01)
+        with annotate("root/a", rec) as again:
+            with annotate("root/a/inner", rec):
+                pass
+        with annotate("root/b", rec):
+            pass
+        time.sleep(0.005)                       # in no phase
+    assert rec["root"] == root.seconds
+    assert rec["root/a"] == pytest.approx(a.seconds + again.seconds)
+    assert a.seconds >= 0.01
+    left = unaccounted(rec, "root")
+    # grandchildren are their parent's business, not the root's
+    assert left == pytest.approx(
+        rec["root"] - rec["root/a"] - rec["root/b"])
+    assert 0.005 <= left < rec["root"]
+    # no record: the annotation alone, nothing raised
+    with annotate("root/c") as c:
+        pass
+    assert c.seconds >= 0.0 and "root/c" not in rec
+
+
+def test_annotate_cost_with_no_profiler_session_stays_in_budget():
+    """With no profiler session the primitive is an inactive TraceMe
+    and two ``perf_counter`` reads: pinned under the budget
+    ``tests/test_bench_harness.py`` pins for disabled telemetry (1% of
+    a 10 ms host step, per call) — the server makes some fifteen such
+    calls per ``step()``, the Engine six per step."""
+    import timeit
+
+    from paddlefleetx_tpu.observability.trace import annotate
+    assert not metrics.get_registry().enabled
+    rec = {}
+
+    def one():
+        with annotate("serving/step/admit", rec):
+            pass
+    n = 10_000
+    per_call = min(timeit.timeit(one, number=n) for _ in range(5)) / n
+    assert per_call < 0.01 * 0.010, per_call
+    assert rec["serving/step/admit"] > 0.0
+
+
+def _main_thread_annotations(trace_dir, marker):
+    """``[(name, start_ns, end_ns)]`` of the host line that holds
+    ``marker``: the thread that drove the program."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                   for e in line.events]
+            if any(n == marker for n, _, _ in evs):
+                return evs
+    raise AssertionError(f"no line of {path} holds {marker!r}")
+
+
+def _traced(trace_dir):
+    """A profiler session as the benchmark starts one: python tracer
+    off, no HLO dump."""
+    import contextlib
+
+    import jax
+
+    @contextlib.contextmanager
+    def session():
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.enable_hlo_proto = False
+        jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+        try:
+            yield
+        finally:
+            jax.profiler.stop_trace()
+    return session()
+
+
+def test_server_phases_land_on_the_profilers_clock(tmp_path):
+    """A paged server run under a profiler session yields an
+    ``.xplane.pb`` whose main-thread line holds ``serving/step`` with
+    every phase the run exercised, each child inside its parent — the
+    names and intervals alone rebuild the nesting."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddlefleetx_tpu.core.serving import GenerationServer
+    from paddlefleetx_tpu.models.gpt import GPTConfig, GPTForPretraining
+    from paddlefleetx_tpu.models.gpt.generation import GenerationConfig
+    cfg = GPTConfig(vocab_size=96, hidden_size=32, num_layers=2,
+                    num_attention_heads=4, max_position_embeddings=512,
+                    hidden_dropout_prob=0.0,
+                    attention_probs_dropout_prob=0.0)
+    model = GPTForPretraining(cfg)
+    params = model.init({"params": jax.random.key(0)},
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    gen_cfg = GenerationConfig(max_dec_len=4,
+                               decode_strategy="greedy_search",
+                               eos_token_id=95, pad_token_id=95)
+    srv = GenerationServer(model, params, gen_cfg, num_slots=2,
+                           page_size=128, prefill_chunk_pages=1)
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, 95, n).tolist() for n in (5, 200, 9)]
+    try:
+        with _traced(tmp_path):
+            with jax.profiler.TraceAnnotation("test/marker"):
+                pass
+            srv.run(prompts)
+    finally:
+        srv.close()
+    evs = _main_thread_annotations(str(tmp_path), "test/marker")
+    roots = sorted((s, e) for n, s, e in evs if n == "serving/step")
+    assert roots, sorted({n for n, _, _ in evs})
+    phases = {}
+    for name, s, e in evs:
+        if name.startswith("serving/step/"):
+            phases.setdefault(name[len("serving/step/"):], []).append(
+                (s, e))
+    # what a paged, non-speculative, untiered run exercises
+    assert set(phases) == {
+        "expire", "spill_drain", "admit", "prefill_pump",
+        "prefill_harvest", "page_maintenance", "table_sync",
+        "decode_dispatch", "decode_harvest", "state_fetch", "commit",
+        "ship_spills"}
+    for name, spans in phases.items():
+        for s, e in spans:
+            assert any(rs <= s and e <= re_ for rs, re_ in roots), name
+    # every decoding step harvested once, after its dispatch
+    assert len(phases["decode_harvest"]) == \
+        len(phases["decode_dispatch"]) == \
+        srv.summary()["host_roundtrips"]
+    # phases of one step do not overlap: the line is flat under a root
+    flat = sorted(x for spans in phases.values() for x in spans)
+    assert all(a[1] <= b[0] for a, b in zip(flat, flat[1:]))
