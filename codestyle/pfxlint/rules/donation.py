@@ -18,7 +18,9 @@ the donated positions map to the argument expressions; a donated
 ``name`` / ``self.attr`` argument read later in the SAME function
 body — with no rebind in between — is flagged. A rebind on the
 statement that makes the call (tuple targets included) counts as at
-the call line.
+the call line. A donor imported by name from another in-tree module
+(``from ..generation import decode_step``) is followed to its
+definition.
 
 Known-unsound: reads that lexically precede the call but execute
 after it on a loop back-edge are missed (the analysis is
@@ -247,6 +249,13 @@ def check(ctx) -> List[Finding]:
                 spec = donors.get((sk, tok))
                 if spec is not None:
                     break
+            if spec is None:
+                # a donor imported from another in-tree module
+                # (``from ..generation import decode_step``)
+                mod = cg.modules.get(fn.modname)
+                gmod, _, gname = (cg.resolve_dotted(mod, tok)
+                                  if mod else tok).rpartition(".")
+                spec = donors.get((f"{gmod}|", gname))
             if spec is None:
                 continue
             nums, kwnames = spec

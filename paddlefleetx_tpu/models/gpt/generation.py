@@ -598,7 +598,8 @@ def _scatter_slot_rows(cache, rows, slot_ids):
     return jax.tree_util.tree_map_with_path(put, cache, rows)
 
 
-@partial(jax.jit, static_argnames=("model",))
+@partial(jax.jit, static_argnames=("model",),
+         donate_argnames=("cache",))
 def prefill_into_slots(model, params, cache, state: SlotState,
                        slot_ids: jax.Array, input_ids: jax.Array,
                        true_lengths: jax.Array,
@@ -712,7 +713,8 @@ def _decode_tick_impl(model, params, cache, state: SlotState,
     return cache, new_state, token
 
 
-@partial(jax.jit, static_argnames=("model", "gen_cfg"))
+@partial(jax.jit, static_argnames=("model", "gen_cfg"),
+         donate_argnames=("cache",))
 def decode_step(model, params, cache, state: SlotState,
                 rng: jax.Array, gen_cfg: GenerationConfig,
                 page_table=None, adapter_ids=None):
@@ -861,7 +863,8 @@ def _verify_tick_impl(model, params, cache, state: SlotState,
     return cache, new_state, window, counts
 
 
-@partial(jax.jit, static_argnames=("model", "gen_cfg"))
+@partial(jax.jit, static_argnames=("model", "gen_cfg"),
+         donate_argnames=("cache",))
 def verify_step(model, params, cache, state: SlotState,
                 drafts: jax.Array, rng: jax.Array,
                 gen_cfg: GenerationConfig, page_table=None,
@@ -972,7 +975,8 @@ def _loop_exit_reason(state: SlotState, gen_cfg: GenerationConfig,
                             LOOP_EXIT_BUDGET))).astype(jnp.int32)
 
 
-@partial(jax.jit, static_argnames=("model", "gen_cfg", "loop_ticks"))
+@partial(jax.jit, static_argnames=("model", "gen_cfg", "loop_ticks"),
+         donate_argnames=("cache",))
 def decode_loop(model, params, cache, state: SlotState,
                 rng: jax.Array, gen_cfg: GenerationConfig,
                 host_flag: jax.Array, page_table=None,
@@ -1022,7 +1026,8 @@ def decode_loop(model, params, cache, state: SlotState,
             _loop_exit_reason(state, gen_cfg, host_flag))
 
 
-@partial(jax.jit, static_argnames=("model", "gen_cfg", "loop_ticks"))
+@partial(jax.jit, static_argnames=("model", "gen_cfg", "loop_ticks"),
+         donate_argnames=("cache",))
 def verify_loop(model, params, cache, state: SlotState,
                 drafts: jax.Array, rng: jax.Array,
                 gen_cfg: GenerationConfig, host_flag: jax.Array,
@@ -1112,7 +1117,8 @@ def init_page_pool(model, params, num_slots: int):
     return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
 
 
-@partial(jax.jit, static_argnames=("model",))
+@partial(jax.jit, static_argnames=("model",),
+         donate_argnames=("cache",))
 def prefill_chunk_paged(model, params, cache, input_chunk: jax.Array,
                         chunk_start: jax.Array, page_table: jax.Array,
                         adapter_ids=None):
@@ -1146,7 +1152,7 @@ def prefill_chunk_paged(model, params, cache, input_chunk: jax.Array,
             logits.astype(jnp.float32))
 
 
-@jax.jit
+@partial(jax.jit, donate_argnames=("cache",))
 def copy_kv_pages(cache, src: jax.Array, dst: jax.Array):
     """Device-side copy of physical pages ``src -> dst`` (both
     ``[k]`` int32) in every KV pool leaf — the copy half of a
@@ -1226,7 +1232,7 @@ def stack_kv_pages(page_trees):
     return jax.tree_util.tree_map_with_path(cat, *page_trees)
 
 
-@jax.jit
+@partial(jax.jit, donate_argnames=("cache",))
 def scatter_kv_pages(cache, page_data, pids: jax.Array):
     """Write gathered page contents into pages ``pids`` of THIS pool —
     the import half of a cross-server KV handoff, and the rehydrate

@@ -35,7 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
-from ...ops.attention import dot_product_attention
+from ...ops.attention import dot_product_attention, kv_cache_write
 from ...parallel.sharding import with_logical_constraint
 from .config import GPTConfig
 
@@ -301,6 +301,16 @@ def _quantize_kv(t):
     return q, sc
 
 
+def _window_positions(lengths, window: int, capacity: int):
+    """``[b, W]`` cache positions of a decode write: row ``i``'s ``W``
+    new tokens sit at ``lengths[i] .. lengths[i] + W - 1``, clipped
+    into the cache."""
+    return jnp.clip(
+        jnp.asarray(lengths, jnp.int32)[:, None]
+        + jnp.arange(window, dtype=jnp.int32)[None, :], 0,
+        capacity - 1)
+
+
 def _remat_policy(granularity: str):
     """Map reference recompute granularities onto checkpoint policies.
 
@@ -420,11 +430,13 @@ class MultiHeadAttention(nn.Module):
             # contiguous cache, just cut into kv_page_size columns.
             # Two write modes:
             #   - ragged decode (cache_lengths): one token per row at
-            #     that row's position — look up the physical page of
-            #     position//page_size and scatter the column at
-            #     position%page_size. Inactive slots' page-table rows
-            #     are all NULL_PAGE, so their dead writes land in the
-            #     reserved garbage page.
+            #     that row's position (a window of them in a verify
+            #     tick) — look up the physical page of
+            #     position//page_size and write the column at
+            #     position%page_size in place (kv_cache_write).
+            #     Inactive slots' page-table rows are all NULL_PAGE,
+            #     so their dead writes land in the reserved garbage
+            #     page.
             #   - chunked prefill (chunk_start): the chunk is
             #     page-aligned and spans whole pages, so the fresh
             #     chunk KV drops straight into its physical pages with
@@ -459,35 +471,25 @@ class MultiHeadAttention(nn.Module):
                           (cache_ks, ks), (cache_vs, vs)]
             pt = jnp.asarray(page_table, jnp.int32)
             if cache_lengths is not None:
-                base = jnp.clip(
-                    jnp.asarray(cache_lengths, jnp.int32), 0,
-                    cfg.cache_capacity - 1)
-                if x.shape[1] == 1:
-                    pid = jnp.take_along_axis(
-                        pt, (base // page)[:, None], axis=1)[:, 0]
-                    for var, t in writes:
-                        var.value = var.value.at[pid, :, :,
-                                                 base % page].set(
-                            t.transpose(0, 2, 3, 1)[..., 0])
-                else:
-                    # speculative verify window: row i's W tokens land
-                    # at positions lengths[i] .. lengths[i] + W - 1,
-                    # each resolved through the page table (the server
-                    # pre-maps/COWs every page the window touches —
-                    # _page_maintenance(window)). Positions clipped at
-                    # capacity land in the last column, which is never
-                    # read before eviction (commit clamp). Advanced
-                    # indexing on dims 0 and 3 puts the index dims
-                    # first, so the value IS k/v's native [b, W, h, d].
-                    wpos = jnp.clip(
-                        jnp.asarray(cache_lengths, jnp.int32)[:, None]
-                        + jnp.arange(x.shape[1], dtype=jnp.int32)[
-                            None, :], 0, cfg.cache_capacity - 1)
-                    pid = jnp.take_along_axis(pt, wpos // page, axis=1)
-                    for var, t in writes:
-                        var.value = var.value.at[
-                            pid, :, :, wpos % page].set(t)
-                query_offset = base                     # [b]
+                # row i's W tokens (1 per decode tick, k+1 in a
+                # speculative verify window) land at positions
+                # lengths[i] .. lengths[i] + W - 1, each resolved
+                # through the page table (the server pre-maps/COWs
+                # every page the window touches —
+                # _page_maintenance(window)). Positions clipped at
+                # capacity land in the last column, which is never
+                # read before eviction (commit clamp). The value is
+                # k/v's native [b, W, h, d]; kv_cache_write rewrites
+                # only the pages written and leaves the pool in the
+                # layout flash_decode_paged reads.
+                wpos = _window_positions(cache_lengths, x.shape[1],
+                                         cfg.cache_capacity)
+                pid = jnp.take_along_axis(pt, wpos // page, axis=1)
+                for var, t in writes:
+                    var.value = kv_cache_write(
+                        var.value, pid, wpos % page, t,
+                        use_flash=cfg.use_flash_attention)
+                query_offset = wpos[:, 0]               # [b]
             elif chunk_start is not None:
                 c = x.shape[1]
                 if c % page:
@@ -568,30 +570,23 @@ class MultiHeadAttention(nn.Module):
                 # per-row-offset fallback). cache_index is left
                 # untouched: the slot lengths live with the server's
                 # SlotState, not in the cache collection.
-                rows = jnp.arange(x.shape[0])
-                base = jnp.clip(
-                    jnp.asarray(cache_lengths, jnp.int32), 0,
-                    capacity - 1)
-                if x.shape[1] == 1:
-                    for var, t in writes:
-                        var.value = var.value.at[
-                            rows, :, :, base].set(
-                            t.transpose(0, 2, 3, 1)[..., 0])
-                else:
-                    # speculative verify window (see the paged branch
-                    # above): scatter row i's W columns at
-                    # lengths[i] .. lengths[i] + W - 1; rejected
-                    # columns are overwritten by the next window
-                    # before any read (the next tick's window starts
-                    # at the accepted length)
-                    wpos = jnp.clip(
-                        jnp.asarray(cache_lengths, jnp.int32)[:, None]
-                        + jnp.arange(x.shape[1], dtype=jnp.int32)[
-                            None, :], 0, capacity - 1)
-                    for var, t in writes:
-                        var.value = var.value.at[
-                            rows[:, None], :, :, wpos].set(t)
-                query_offset = base                     # [b]
+                # A verify window (x.shape[1] > 1) writes row i's W
+                # columns at lengths[i] .. lengths[i] + W - 1; rejected
+                # columns are overwritten by the next window before
+                # any read (the next tick's window starts at the
+                # accepted length). Same write as the paged branch:
+                # the slot is the "page", its capacity the page size.
+                wpos = _window_positions(cache_lengths, x.shape[1],
+                                         capacity)
+                rows = jnp.broadcast_to(
+                    jnp.arange(x.shape[0], dtype=jnp.int32)[:, None],
+                    wpos.shape)
+                for var, t in writes:
+                    var.value = kv_cache_write(
+                        var.value, rows, wpos, t,
+                        use_flash=cfg.use_flash_attention,
+                        paged=False)
+                query_offset = wpos[:, 0]               # [b]
             else:
                 idx = cache_index.value
                 for var, t in writes:

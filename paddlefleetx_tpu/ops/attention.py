@@ -153,6 +153,43 @@ def _gather_kv_pages(pool, page_table):
     return g.transpose(0, 2, 3, 1, 4).reshape(b, h, d, m * p)
 
 
+def kv_cache_write(leaf, rows, cols, new, use_flash: bool = True,
+                   paged: bool = True):
+    """Write fresh key/value columns into a decode cache leaf:
+    ``leaf.at[rows, :, :, cols].set(new)`` for ``leaf [N, h, d, M]``
+    (the paged pool, ``N`` physical pages of ``M`` columns, or the
+    contiguous slot cache, ``N`` slots of ``M = capacity``; ``d`` is 1
+    for an int8 cache's fp32 scale leaves), ``rows`` / ``cols``
+    ``[b, W]`` and ``new [b, W, h, d]`` (``W`` = 1 for a decode tick,
+    the window of a speculative verify tick).
+
+    The Pallas kernel (``ops/pallas/kv_write.py``) rewrites only the
+    128-column blocks it writes and leaves the leaf in the layout the
+    decode kernels read, so under a jit that donates the cache the
+    write is in place (``attention/kv_write_paged`` /
+    ``attention/kv_write_ragged``, counted at trace time like the
+    attention dispatch). The XLA scatter is the fallback and the
+    parity oracle: where the kernel refuses
+    (``attention/fallback/kernel_rejected``: off the TPU, an untiled
+    minor dim) and, by decision, under a multi-device mesh
+    (``attention/fallback/mesh_sharded``: the kernel is not
+    ``shard_map``-wrapped) or with ``use_flash=False``. The chip's
+    compiler brackets that scatter with two copies of the whole leaf
+    (PERF.md, PR 24)."""
+    if use_flash and kernel_mesh() is not None:
+        metrics.inc("attention/fallback/mesh_sharded")
+    elif use_flash:
+        try:
+            from .pallas.kv_write import kv_write
+            out = kv_write(leaf, rows, cols, new)
+            metrics.inc("attention/kv_write_paged" if paged
+                        else "attention/kv_write_ragged")
+            return out
+        except (ImportError, NotImplementedError):
+            metrics.inc("attention/fallback/kernel_rejected")
+    return leaf.at[rows, :, :, cols].set(new)
+
+
 def _xla_attention(q, k, v, bias, causal, query_offset, dropout_rate,
                    dropout_rng, deterministic, softmax_in_fp32,
                    kv_cache_layout=False):

@@ -15,6 +15,8 @@ around the compiles (a described-chip entry cannot be read back).
 """
 
 import functools
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -193,6 +195,155 @@ def test_flash_decode_paged_compiles(window, int8, one_chip, as_tpu):
         fn = fa.flash_decode_paged
         args = (q, *kv, off, table)
     assert "tpu_custom_call" in _compile(fn, *args).as_text()
+
+
+# -- one chip: the serving cell's cache programs, in place -------------
+#
+# gpt345m.serve-chat: 64 slots over a 513-page pool of 128-token pages,
+# [513, 16, 64, 128] a leaf. One decoder layer at those widths through
+# the REAL jits (``decode_step``, ``verify_step``,
+# ``prefill_chunk_paged``): the compiled program may hold no ``copy`` /
+# ``transpose`` that produces a pool-shaped array, and the donated pool
+# must come back aliased.
+
+SLOTS, POOL, PAGE = 64, 513, 128
+
+
+def _pool_copies(compiled):
+    """Instructions that copy a whole pool leaf: a ``copy`` /
+    ``transpose`` (or a fusion named after one) whose result has a
+    leaf's element count — values ``513 x 16 x 64 x 128`` or int8-KV
+    scales ``513 x 16 x 1 x 128`` — in whatever order of dims (the
+    scatter's own layout is ``[513,128,16,64]``)."""
+    sizes = {POOL * H * D * PAGE, POOL * H * PAGE}
+    n = 0
+    for name, dims, op in re.findall(
+            r"%(\S+) = \w+\[([\d,]+)\]\S* (copy|transpose|fusion)\(",
+            compiled.as_text()):
+        if math.prod(map(int, dims.split(","))) in sizes and (
+                op != "fusion" or "copy" in name or "transpose" in name):
+            n += 1
+    return n
+
+
+def _kv_write_calls(compiled):
+    """Instructions named after the write kernel (``name="kv_write"``
+    on its ``pallas_call``), which keeps them out of the
+    ``*self_attn* custom-call`` lines ``paged_decode_roofline``
+    reads."""
+    return len(re.findall(r"%kv_write[.\d]* = \S+ custom-call",
+                          compiled.as_text()))
+
+
+def _serving_program(sh, kv_dtype="bf16"):
+    """(model, params, pool, slot state, rng key, page table,
+    generation config) of the cell at one layer, as shapes on ``sh``."""
+    import flax.linen as nn
+
+    from paddlefleetx_tpu.models.gpt import GPTConfig, GPTForPretraining
+    from paddlefleetx_tpu.models.gpt import generation as g
+    cfg = GPTConfig(
+        vocab_size=50304, hidden_size=H * D, num_layers=1,
+        num_attention_heads=H, max_position_embeddings=S,
+        hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0,
+        scan_layers=False, use_flash_attention=True, dtype="bfloat16",
+        kv_page_size=PAGE, kv_pool_pages=POOL, kv_cache_dtype=kv_dtype)
+    model = GPTForPretraining(cfg)
+
+    def on_chip(tree, dtype=None):
+        return jax.tree.map(
+            lambda a: _sds(a.shape, dtype or a.dtype, sh), tree)
+    params = on_chip(nn.meta.unbox(jax.eval_shape(
+        model.init, {"params": jax.random.key(0)},
+        jnp.zeros((1, 8), jnp.int32))["params"]), BF16)
+    pool = on_chip(jax.eval_shape(
+        lambda p: g.init_page_pool(model, p, SLOTS), params))
+    state = on_chip(jax.eval_shape(
+        lambda: g.init_slot_state(SLOTS, cfg.vocab_size)))
+    rng = on_chip(jax.eval_shape(lambda: jax.random.key(1)))
+    table = _sds((SLOTS, cfg.max_kv_pages), jnp.int32, sh)
+    gen_cfg = g.GenerationConfig(
+        max_dec_len=128, decode_strategy="greedy_search",
+        eos_token_id=50303, pad_token_id=50303)
+    return model, params, pool, state, rng, table, gen_cfg
+
+
+def _pool_bytes(pool):
+    return sum(a.size * a.dtype.itemsize
+               for a in jax.tree.leaves(pool) if a.ndim == 4)
+
+
+def _decode_tick(sh, window, kv_dtype):
+    from paddlefleetx_tpu.models.gpt import generation as g
+    model, params, pool, state, rng, table, gen_cfg = \
+        _serving_program(sh, kv_dtype)
+    if window == 1:
+        lowered = g.decode_step.lower(
+            model, params, pool, state, rng, gen_cfg, page_table=table)
+    else:
+        drafts = _sds((SLOTS, window - 1), jnp.int32, sh)
+        lowered = g.verify_step.lower(
+            model, params, pool, state, drafts, rng, gen_cfg,
+            page_table=table)
+    return lowered.compile(), _pool_bytes(pool)
+
+
+@pytest.mark.parametrize("window,kv_dtype", [
+    (1, "bf16"), (5, "bf16"), (1, "int8"), (5, "int8"), (32, "bf16")])
+def test_decode_tick_updates_the_pool_in_place(window, kv_dtype,
+                                               one_chip, as_tpu):
+    """The KV write kernel, then ``flash_decode_paged``, the pool
+    donated: no pool-shaped copy, every pool byte aliased."""
+    compiled, pool_bytes = _decode_tick(one_chip, window, kv_dtype)
+    assert _kv_write_calls(compiled) == (4 if kv_dtype == "int8" else 2)
+    assert _pool_copies(compiled) == 0
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+
+
+def test_decode_tick_with_the_scatter_copies_the_pool(one_chip, as_tpu,
+                                                      monkeypatch):
+    """The same program with the write as the XLA scatter it was: the
+    chip's compiler brackets every leaf's scatter with two copies of
+    the whole leaf (to the scatter's layout and back to the decode
+    kernel's), donated or not. This is the case the test above must
+    be able to see."""
+    from paddlefleetx_tpu.ops.pallas import kv_write as kw
+
+    def refuse(*a, **k):
+        raise NotImplementedError("the scatter")
+    monkeypatch.setattr(kw, "kv_write", refuse)
+    # decode_step's trace cache does not see the patch: drop the trace
+    # the in-place case left, and this one's before the patch is undone
+    jax.clear_caches()
+    try:
+        compiled, _ = _decode_tick(one_chip, 1, "bf16")
+    finally:
+        jax.clear_caches()
+    assert _kv_write_calls(compiled) == 0
+    assert _pool_copies(compiled) == 4          # K and V, in and out
+
+
+@pytest.mark.parametrize("donated", [True, False])
+def test_prefill_chunk_scatters_pages_in_place(donated, one_chip,
+                                               as_tpu):
+    """The chunk's whole-page scatter (index on dim 0 only) keeps the
+    pool's layout: in place when the pool is donated, one copy a leaf
+    when it is not (the jit without its ``donate_argnames``)."""
+    from paddlefleetx_tpu.models.gpt import generation as g
+    model, params, pool, _, _, _, _ = _serving_program(one_chip)
+    chunk = _sds((1, 2 * PAGE), jnp.int32, one_chip)
+    start = _sds((1,), jnp.int32, one_chip)
+    table = _sds((1, model.config.max_kv_pages), jnp.int32, one_chip)
+    fn = g.prefill_chunk_paged if donated else jax.jit(
+        g.prefill_chunk_paged.__wrapped__, static_argnames=("model",))
+    compiled = fn.lower(model, params, pool, chunk, start,
+                        table).compile()
+    if donated:
+        assert _pool_copies(compiled) == 0
+        assert compiled.memory_analysis().alias_size_in_bytes >= \
+            _pool_bytes(pool)
+    else:
+        assert _pool_copies(compiled) == 2
 
 
 # -- one chip: grouped / quantized GEMMs, grouped LoRA ----------------

@@ -13,6 +13,8 @@ trip.
 import os
 import sys
 
+import pytest
+
 REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
@@ -543,6 +545,35 @@ def test_pfx104_partial_decorator_form():
         "    return state, out\n")
     assert _codes({"paddlefleetx_tpu/a.py": src},
                   {"PFX104"}) == ["PFX104"]
+
+
+@pytest.mark.parametrize("rebinds,want", [(False, ["PFX104"]),
+                                          (True, [])])
+def test_pfx104_follows_a_donor_imported_from_another_module(rebinds,
+                                                             want):
+    """``decode_step`` donates its cache by NAME in generation.py; the
+    server calls it positionally from serving.py."""
+    donor = MOD + (
+        "import jax\n"
+        "from functools import partial\n"
+        '@partial(jax.jit, static_argnames=("model",),\n'
+        '         donate_argnames=("cache",))\n'
+        "def decode_step(model, params, cache, state):\n"
+        '    """Tick."""\n'
+        "    return cache, state\n")
+    target = "self._cache" if rebinds else "_"
+    caller = MOD + (
+        "from .generation import decode_step\n"
+        "class Server:\n"
+        '    """S."""\n'
+        "    def step(self):\n"
+        '        """One tick."""\n'
+        f"        {target}, st = decode_step(self.model, self.params,\n"
+        "                                   self._cache, self._state)\n"
+        "        return self._cache, st\n")
+    assert _codes({"paddlefleetx_tpu/generation.py": donor,
+                   "paddlefleetx_tpu/serving.py": caller},
+                  {"PFX104"}) == want
 
 
 # -- jit dataflow: PFX105 tracer escape --------------------------------

@@ -62,6 +62,15 @@ def _greedy_cfg(max_dec=8):
                             eos_token_id=EOS, pad_token_id=PAD)
 
 
+def _snapshot(cache):
+    """A copy of the cache tree a test may hand to a cache jit and
+    still hold the original: ``decode_step`` / ``verify_step`` /
+    ``decode_loop`` ... DONATE their cache argument (the in-place KV
+    write, docs/inference.md), so an array passed to one is deleted —
+    on the CPU too."""
+    return jax.tree.map(jnp.copy, cache)
+
+
 def _lockstep(model, params, prompts, gen_cfg):
     """Reference rows from the lockstep path, truncated at EOS
     (inclusive) — exactly what a Completion.tokens should hold."""
@@ -778,7 +787,7 @@ def test_spec_greedy_chain_stops_at_first_mismatch(model_and_params):
     # sequential oracle: four plain ticks from a snapshot
     cache, state = srv._cache, srv._state
     seq = []
-    c, s = cache, state
+    c, s = _snapshot(cache), state
     for _ in range(4):
         c, s, tok = decode_step(model_u, params_u, c, s,
                                 srv._rng, gen_cfg)
@@ -820,7 +829,7 @@ def test_spec_sampling_accept_rule(model_and_params):
     model_u, params_u = srv.model, srv.params
     cache, state = srv._cache, srv._state
     seq = []
-    c, s = cache, state
+    c, s = _snapshot(cache), state
     for _ in range(3):
         c, s, tok = decode_step(model_u, params_u, c, s,
                                 srv._rng, gen_cfg)
@@ -828,7 +837,7 @@ def test_spec_sampling_accept_rule(model_and_params):
     seq = np.stack(seq, 1)                    # [slots, 3]
     # (a) true continuation -> all accepted (p(draft) ~ 1)
     _, s_ok, window, counts = verify_step(
-        model_u, params_u, cache, state,
+        model_u, params_u, _snapshot(cache), state,
         jnp.asarray(seq[:, 1:], jnp.int32), srv._rng, gen_cfg)
     assert np.asarray(counts).tolist() == [3, 3]
     np.testing.assert_array_equal(np.asarray(window), seq)
@@ -865,8 +874,9 @@ def test_spec_rejected_token_excluded_from_next_draw(model_and_params):
     cache, state = srv._cache, srv._state
     k = 2
     zeros = jnp.zeros((2, k), jnp.int32)
-    _, _, window, _ = verify_step(srv.model, srv.params, cache, state,
-                                  zeros, srv._rng, gen_cfg)
+    _, _, window, _ = verify_step(srv.model, srv.params,
+                                  _snapshot(cache), state, zeros,
+                                  srv._rng, gen_cfg)
     t0 = np.asarray(window)[:, 0]             # the point-mass tokens
     state_rej = state._replace(
         rejected=jnp.asarray(t0, jnp.int32))
@@ -1579,8 +1589,8 @@ def test_decode_loop_t1_matches_decode_step(model_and_params):
     srv._admit()
     model_u, params_u = srv.model, srv.params
     cache, state = srv._cache, srv._state
-    c1, s1, tok = decode_step(model_u, params_u, cache, state,
-                              srv._rng, gen_cfg)
+    c1, s1, tok = decode_step(model_u, params_u, _snapshot(cache),
+                              state, srv._rng, gen_cfg)
     c2, s2, buf, ticks, reason = decode_loop(
         model_u, params_u, cache, state, srv._rng, gen_cfg,
         jnp.int32(0), loop_ticks=1)
@@ -1614,8 +1624,8 @@ def test_decode_loop_host_flag_exits_after_one_tick(model_and_params):
         srv.submit(p)
     srv._admit()
     cache, state = srv._cache, srv._state
-    _, _, tok = decode_step(srv.model, srv.params, cache, state,
-                            srv._rng, gen_cfg)
+    _, _, tok = decode_step(srv.model, srv.params, _snapshot(cache),
+                            state, srv._rng, gen_cfg)
     _, _, buf, ticks, reason = decode_loop(
         srv.model, srv.params, cache, state, srv._rng, gen_cfg,
         jnp.int32(1), loop_ticks=8)
