@@ -469,8 +469,18 @@ class Engine(BasicEngine):
             step = state["step"]
             rng = jax.random.fold_in(root_rng, step)
 
-            def loss_for(p, mb):
-                return module.loss_fn(p, mb, rng, train=True)
+            # optional in the module's contract: ``loss_and_stats``
+            # returns ``(loss, {name: scalar})``, the step's own
+            # statistics (an expert layer's routing), which leave the
+            # jitted step beside the loss and are fetched at the
+            # logging sync; ``reduce_step_stats`` merges micro-batches'
+            las = getattr(module, "loss_and_stats", None)
+            stats = {}
+
+            def loss_for(p, mb, key=rng):
+                if las is not None:
+                    return las(p, mb, key, train=True)
+                return module.loss_fn(p, mb, key, train=True), {}
 
             if acc == 1:
                 # modules may fuse loss+grad into one pass (GPT's 1F1B
@@ -480,8 +490,8 @@ class Engine(BasicEngine):
                 if lag is not None:
                     loss, grads = lag(params, batch, rng)
                 else:
-                    loss, grads = jax.value_and_grad(loss_for)(
-                        params, batch)
+                    (loss, stats), grads = jax.value_and_grad(
+                        loss_for, has_aux=True)(params, batch)
             else:
                 micro = jax.tree.map(
                     lambda x: x.reshape(acc, x.shape[0] // acc,
@@ -504,22 +514,23 @@ class Engine(BasicEngine):
                     # step-level rng would repeat masks across the
                     # accumulation scan)
                     mb_rng = jax.random.fold_in(rng, mb_idx)
-                    loss, grads = jax.value_and_grad(
-                        lambda p, m: module.loss_fn(p, m, mb_rng,
-                                                    train=True))(params, mb)
+                    (loss, mb_stats), grads = jax.value_and_grad(
+                        loss_for, has_aux=True)(params, mb, mb_rng)
                     grad_sum = jax.tree.map(jnp.add, grad_sum, grads)
-                    return (loss_sum + loss, grad_sum), None
+                    return (loss_sum + loss, grad_sum), mb_stats
 
-                (loss, grads), _ = jax.lax.scan(
+                (loss, grads), stats = jax.lax.scan(
                     body, (jnp.zeros((), jnp.float32), zero),
                     (jnp.arange(acc), micro))
+                if stats:
+                    stats = module.reduce_step_stats(stats)
                 loss = loss / acc
                 grads = jax.tree.map(lambda g: g / acc, grads)
 
             updates, new_opt = tx.update(grads, opt_state, params)
             new_params = optax.apply_updates(params, updates)
             metrics = {"loss": loss, "lr": schedule(step),
-                       "grad_norm": optax.global_norm(grads)}
+                       "grad_norm": optax.global_norm(grads), **stats}
             new_state = {"params": new_params, "opt_state": new_opt,
                          "step": step + 1}
             return new_state, metrics
@@ -894,7 +905,10 @@ class Engine(BasicEngine):
                                 pretreat=per_step("h2d/pretreat", 0.0),
                                 device_put=per_step("h2d/device_put", 0.0),
                                 dispatch=per_step("train_step", 0.0),
-                                hbm=mem)
+                                hbm=mem,
+                                # the module's own step statistics
+                                **{k: float(v) for k, v in metrics.items()
+                                   if k not in log_dict})
                         window.clear()
                         window_steps = 0
                         window_clean = True

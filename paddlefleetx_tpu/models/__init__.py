@@ -21,7 +21,7 @@ def build_module(config):
     # populate the registry lazily to avoid heavy imports at package load
     import importlib
     for mod in ("gpt.modules", "ernie.modules", "vit.modules",
-                "imagen.modules"):
+                "imagen.modules", "deepseek_v3.modules"):
         try:
             importlib.import_module(f".{mod}", __package__)
         except ModuleNotFoundError as e:

@@ -37,6 +37,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ...ops.attention import dot_product_attention, kv_cache_write
 from ...parallel.sharding import with_logical_constraint
+from ..language_utils import chunked_nll_sums, masked_nll_sums
 from .config import GPTConfig
 
 Dtype = Any
@@ -928,23 +929,6 @@ def tied_logits(x: jax.Array, word_emb: jax.Array) -> jax.Array:
     return with_logical_constraint(logits, ("batch", "seq", "act_vocab"))
 
 
-def masked_nll_sums(logits: jax.Array, labels: jax.Array,
-                    loss_mask: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """Fp32 masked token NLL: ``(sum of nll over unmasked, mask sum)``.
-
-    The shared core of the pretraining criterion and the offline-eval
-    scorer; with vocab-sharded logits GSPMD turns the log-sum-exp and
-    gather into the psum-based sharded softmax the reference's
-    ``ParallelCrossEntropy`` (``hybrid_model.py:799``) hand-writes.
-    """
-    logits = logits.astype(jnp.float32)
-    logz = jax.scipy.special.logsumexp(logits, axis=-1)
-    label_logits = jnp.take_along_axis(
-        logits, labels[..., None], axis=-1)[..., 0]
-    mask = loss_mask.astype(jnp.float32).reshape(logz.shape)
-    return jnp.sum((logz - label_logits) * mask), jnp.sum(mask)
-
-
 def _pipeline_parts(cfg: GPTConfig, input_ids, position_ids,
                     deterministic: bool, rng):
     """Shared setup for the pipelined loss paths: embedding output,
@@ -1139,11 +1123,6 @@ def chunked_lm_loss(model: "GPTForPretraining", params, input_ids,
     one extra head matmul per chunk buys O(s/chunks) logits memory.
     """
     cfg = model.config
-    b, s = input_ids.shape
-    if s % chunks:
-        raise ValueError(
-            f"loss_chunks ({chunks}) must divide the sequence length "
-            f"({s})")
     moe_aux = jnp.zeros((), jnp.float32)
     if cfg.moe_num_experts and include_moe_aux:
         h, mods = GPTModel(cfg).apply(
@@ -1155,19 +1134,7 @@ def chunked_lm_loss(model: "GPTForPretraining", params, input_ids,
                                 position_ids, None, False,
                                 deterministic, rngs=rngs)
     word_emb = _word_embedding(params["gpt"]["embeddings"])
-
-    csz = s // chunks
-    hc = h.reshape(b, chunks, csz, h.shape[-1]).swapaxes(0, 1)
-    lc = labels.reshape(b, chunks, csz).swapaxes(0, 1)
-    mc = loss_mask.reshape(b, chunks, csz).swapaxes(0, 1)
-
-    @jax.checkpoint
-    def body(carry, xs):
-        hh, ll, mm = xs
-        nll, msum = masked_nll_sums(tied_logits(hh, word_emb), ll, mm)
-        return (carry[0] + nll, carry[1] + msum), None
-
-    (nll, msum), _ = jax.lax.scan(
-        body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
-        (hc, lc, mc))
+    nll, msum = chunked_nll_sums(
+        h, lambda hh: tied_logits(hh, word_emb), labels, loss_mask,
+        chunks)
     return nll / jnp.maximum(msum, 1.0) + moe_aux

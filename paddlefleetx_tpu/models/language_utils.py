@@ -10,6 +10,58 @@ Parity: reference ``ppfleetx/models/language_model/utils.py:39-150``:
 
 from __future__ import annotations
 
+from typing import Callable, Tuple
+
+import jax
+import jax.numpy as jnp
+
+
+def masked_nll_sums(logits: jax.Array, labels: jax.Array,
+                    loss_mask: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """Fp32 masked token NLL: ``(sum of nll over unmasked, mask sum)``.
+
+    The shared core of the pretraining criterion and the offline-eval
+    scorer; with vocab-sharded logits GSPMD turns the log-sum-exp and
+    gather into the psum-based sharded softmax the reference's
+    ``ParallelCrossEntropy`` (``hybrid_model.py:799``) hand-writes.
+    """
+    logits = logits.astype(jnp.float32)
+    logz = jax.scipy.special.logsumexp(logits, axis=-1)
+    label_logits = jnp.take_along_axis(
+        logits, labels[..., None], axis=-1)[..., 0]
+    mask = loss_mask.astype(jnp.float32).reshape(logz.shape)
+    return jnp.sum((logz - label_logits) * mask), jnp.sum(mask)
+
+
+def chunked_nll_sums(h: jax.Array, logits_fn: Callable, labels: jax.Array,
+                     loss_mask: jax.Array,
+                     chunks: int) -> Tuple[jax.Array, jax.Array]:
+    """``masked_nll_sums(logits_fn(h), ...)`` over ``chunks`` sequence
+    chunks inside a rematerialized scan: the ``[b, s, V]`` logits never
+    exist beyond ``[b, s/chunks, V]``, and the backward recomputes each
+    chunk's logits instead of saving them. The per-token NLL sums are
+    exact, not chunk-mean-of-means. ``h`` is ``[b, s, hidden]``."""
+    b, s = labels.shape
+    if s % chunks:
+        raise ValueError(
+            f"loss_chunks ({chunks}) must divide the sequence length "
+            f"({s})")
+    csz = s // chunks
+    hc = h.reshape(b, chunks, csz, h.shape[-1]).swapaxes(0, 1)
+    lc = labels.reshape(b, chunks, csz).swapaxes(0, 1)
+    mc = loss_mask.reshape(b, chunks, csz).swapaxes(0, 1)
+
+    @jax.checkpoint
+    def body(carry, xs):
+        hh, ll, mm = xs
+        nll, msum = masked_nll_sums(logits_fn(hh), ll, mm)
+        return (carry[0] + nll, carry[1] + msum), None
+
+    sums, _ = jax.lax.scan(
+        body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
+        (hc, lc, mc))
+    return sums
+
 
 def process_model_configs(config) -> None:
     """Derive/validate model-section defaults in place — ffn=4h,

@@ -192,9 +192,8 @@ def kv_cache_write(leaf, rows, cols, new, use_flash: bool = True,
 
 def _xla_attention(q, k, v, bias, causal, query_offset, dropout_rate,
                    dropout_rng, deterministic, softmax_in_fp32,
-                   kv_cache_layout=False):
-    head_dim = q.shape[-1]
-    scale = head_dim ** -0.5
+                   kv_cache_layout=False, sm_scale=None):
+    scale = q.shape[-1] ** -0.5 if sm_scale is None else sm_scale
     k_eq = "bhdk" if kv_cache_layout else "bkhd"
     scores = jnp.einsum(f"bqhd,{k_eq}->bhqk", q * scale, k)
     if softmax_in_fp32:
@@ -230,7 +229,8 @@ def _xla_attention(q, k, v, bias, causal, query_offset, dropout_rate,
 
 
 def _flash_per_device(q, k, v, bias, causal, query_offset,
-                      dropout_rate, dropout_rng, heads_axis):
+                      dropout_rate, dropout_rng, heads_axis,
+                      sm_scale=None):
     """Training flash attention with each device of the active mesh
     running the kernel on its own ``[b/data, s, h/mp, d]`` block
     (``ring_attention.shard_kernel``; a direct call when no
@@ -276,7 +276,8 @@ def _flash_per_device(q, k, v, bias, causal, query_offset,
         return fa.flash_attention(  # pfxlint: disable=PFX205
             q, k, v, causal=causal, query_offset=query_offset,
             dropout_rate=dropout_rate if dropout else 0.0,
-            dropout_rng=rng, bias=rest.pop() if rest else None)
+            dropout_rng=rng, bias=rest.pop() if rest else None,
+            sm_scale=sm_scale)
 
     return shard_kernel(per_device, args, in_axes, qkv_axes)
 
@@ -295,8 +296,15 @@ def dot_product_attention(
         page_table: Optional[jax.Array] = None,
         k_scale: Optional[jax.Array] = None,
         v_scale: Optional[jax.Array] = None,
-        heads_axis: str = "act_heads") -> jax.Array:
+        heads_axis: str = "act_heads",
+        sm_scale: Optional[float] = None) -> jax.Array:
     """Causal attention; dispatches to the Pallas flash kernel on TPU.
+
+    ``v`` may be narrower than ``q``/``k`` (latent attention scores at
+    192 and mixes values of 128); the output has v's width, the
+    training flash kernel then counts as ``attention/flash_mla``.
+    ``sm_scale`` defaults to ``q.shape[-1] ** -0.5``; the cached-decode
+    kernels take neither (one width, their own scale).
 
     ``bias`` is an additive mask broadcastable to ``[b, h, sq, sk]``
     (the reference's ``attn_mask`` convention, additive -1e4 style).
@@ -359,7 +367,7 @@ def dot_product_attention(
             try:
                 out = _flash_per_device(
                     q, k, v, bias, causal, query_offset, dropout_rate,
-                    dropout_rng, heads_axis)
+                    dropout_rng, heads_axis, sm_scale)
                 metrics.inc("attention/flash_dropout")
                 return out
             except MeshIndivisible:
@@ -473,8 +481,10 @@ def dot_product_attention(
             if not kv_cache_layout and flash_worthwhile:
                 out = _flash_per_device(q, k, v, bias, causal,
                                         query_offset, 0.0, None,
-                                        heads_axis)
-                metrics.inc("attention/flash")
+                                        heads_axis, sm_scale)
+                metrics.inc("attention/flash_mla"
+                            if v.shape[-1] != q.shape[-1]
+                            else "attention/flash")
                 return out
             metrics.inc("attention/fallback/kv_cache_layout"
                         if kv_cache_layout
@@ -504,4 +514,5 @@ def dot_product_attention(
         v = (v.astype(jnp.float32) * v_scale).astype(q.dtype)
     return _xla_attention(q, k, v, bias, causal, query_offset, dropout_rate,
                           dropout_rng, deterministic, softmax_in_fp32,
-                          kv_cache_layout=kv_cache_layout)
+                          kv_cache_layout=kv_cache_layout,
+                          sm_scale=sm_scale)

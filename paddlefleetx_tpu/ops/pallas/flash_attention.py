@@ -292,27 +292,29 @@ def _bias_spec(bias, num_heads, block_q, block_kv, qk_of_ids):
 def _flash_forward(q, k, v, sm_scale, causal, query_offset, block_q,
                    block_kv, dropout_rate=0.0, seed=None, bias=None,
                    num_heads=None):
+    # q and k share the score width d; v (and so the output) may be
+    # narrower (latent attention: 192 against 128)
     bh, sq, d = q.shape
-    skv = k.shape[1]
+    skv, dv = k.shape[1], v.shape[2]
     num_q, num_kv = sq // block_q, skv // block_kv
     out_shape = [
-        _sds((bh, sq, d), q.dtype, q),
+        _sds((bh, sq, dv), q.dtype, q),
         _sds((bh, sq, 1), jnp.float32, q),
     ]
     scratch = [
         pltpu.VMEM((block_q, 1), jnp.float32),
         pltpu.VMEM((block_q, 1), jnp.float32),
-        pltpu.VMEM((block_q, d), jnp.float32),
+        pltpu.VMEM((block_q, dv), jnp.float32),
     ]
     # ONE spec set for both paths (the dropout path lifts the index
     # maps for the prefetched scalar, _lift_spec)
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
         pl.BlockSpec((1, block_kv, d), lambda b, qi, ki: (b, ki, 0)),
-        pl.BlockSpec((1, block_kv, d), lambda b, qi, ki: (b, ki, 0)),
+        pl.BlockSpec((1, block_kv, dv), lambda b, qi, ki: (b, ki, 0)),
     ]
     out_specs = [
-        pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
+        pl.BlockSpec((1, block_q, dv), lambda b, qi, ki: (b, qi, 0)),
         pl.BlockSpec((1, block_q, 1), lambda b, qi, ki: (b, qi, 0)),
     ]
     operands = [q, k, v]
@@ -536,6 +538,13 @@ FUSED_BWD_RESIDENT_BUDGET = 6 * 1024 * 1024
 #: compete with the resident tensors for VMEM
 FUSED_BWD_BLOCK_Q = 512
 FUSED_BWD_BLOCK_KV = 512
+#: scoped VMEM asked for where q/k are wider than one lane multiple
+#: and narrower than two (the v5e holds 128 MiB; the default scope is 16)
+FUSED_BWD_WIDE_VMEM_LIMIT = 32 * 1024 * 1024
+
+
+def _lanes(width: int) -> int:
+    return -(-width // 128) * 128
 
 
 def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -594,13 +603,13 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_ref[0, pl.ds(qi * block_q, block_q), :] = cur + dq_blk
         return dk_acc, dv_acc
 
-    zeros = jnp.zeros((k.shape[0], k.shape[1]), jnp.float32)
     # first possibly-live qi block: its end must reach the kv block
     qi_start = ((ki * block_kv - query_offset) // block_q) if causal \
         else 0
     qi_start = jnp.maximum(qi_start, 0) if causal else 0
-    dk_acc, dv_acc = jax.lax.fori_loop(qi_start, num_q, _body,
-                                       (zeros, zeros))
+    dk_acc, dv_acc = jax.lax.fori_loop(
+        qi_start, num_q, _body,
+        (jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32)))
     dk_ref[0] = dk_acc.astype(dk_ref.dtype)
     dv_ref[0] = dv_acc.astype(dv_ref.dtype)
 
@@ -611,7 +620,7 @@ def _flash_backward_fused(q, k, v, g, lse, delta, sm_scale, causal,
     the shape doesn't fit the resident-VMEM budget (caller falls back
     to the split kernel pair)."""
     bh, sq, d = q.shape
-    skv = k.shape[1]
+    skv, d_v = k.shape[1], v.shape[2]
     bq, bkv = FUSED_BWD_BLOCK_Q, FUSED_BWD_BLOCK_KV
     if sq % bq or skv % bkv:
         return None
@@ -619,30 +628,44 @@ def _flash_backward_fused(q, k, v, g, lse, delta, sm_scale, causal,
     # dq at fp32, lse+delta at fp32 — fp32 inputs must not sneak past
     # a bf16-sized estimate into a Mosaic allocation failure
     itemsize = jnp.dtype(q.dtype).itemsize
-    if sq * (d * (2 * itemsize + 4) + 8) > FUSED_BWD_RESIDENT_BUDGET:
+    resident = sq * (d * (itemsize + 4) + d_v * itemsize + 8)
+    params = {}
+    if d % 128 and d > 128:
+        # a width between lane multiples (192) sits in VMEM padded to
+        # the next one; the chip's compiler counted 16.55 MB for
+        # s=4096 at 192/128 against its default 16 MB of scoped VMEM,
+        # so this case reckons with padded lanes and asks for more
+        resident = sq * (_lanes(d) * (itemsize + 4) + d_v * itemsize + 8)
+        params["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=FUSED_BWD_WIDE_VMEM_LIMIT)
+        if resident > FUSED_BWD_WIDE_VMEM_LIMIT // 4:
+            return None
+    elif resident > FUSED_BWD_RESIDENT_BUDGET:
         return None
     # the resident tensors' block index never changes within one bh —
     # single-buffer them so the pipeline does not allocate a useless
     # second copy of the largest VMEM tenants
     mode_kw = {"pipeline_mode": pl.Buffered(buffer_count=1)}
-    res_spec = pl.BlockSpec((1, sq, d), lambda b, i: (b, 0, 0),
+
+    def res_spec(width):
+        return pl.BlockSpec((1, sq, width), lambda b, i: (b, 0, 0),
                             **mode_kw)
-    row_spec = pl.BlockSpec((1, sq, 1), lambda b, i: (b, 0, 0),
-                            **mode_kw)
-    kv_spec = pl.BlockSpec((1, bkv, d), lambda b, i: (b, i, 0))
+
+    def kv_spec(width):
+        return pl.BlockSpec((1, bkv, width), lambda b, i: (b, i, 0))
     dq32, dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_fused_kernel, sm_scale=sm_scale, causal=causal,
             block_q=bq, block_kv=bkv, num_q=sq // bq,
             query_offset=query_offset),
         grid=(bh, skv // bkv),
-        in_specs=[res_spec, kv_spec, kv_spec, res_spec, row_spec,
-                  row_spec],
-        out_specs=[res_spec, kv_spec, kv_spec],
+        in_specs=[res_spec(d), kv_spec(d), kv_spec(d_v), res_spec(d_v),
+                  res_spec(1), res_spec(1)],
+        out_specs=[res_spec(d), kv_spec(d), kv_spec(d_v)],
         out_shape=[_sds((bh, sq, d), jnp.float32, q),
                    _sds((bh, skv, d), k.dtype, q),
-                   _sds((bh, skv, d), v.dtype, q)],
-        interpret=_interpret(),
+                   _sds((bh, skv, d_v), v.dtype, q)],
+        interpret=_interpret(), **params,
     )(q, k, v, g, lse, delta)
     return (dq32 * sm_scale).astype(q.dtype), dk, dv
 
@@ -670,7 +693,7 @@ def _flash_backward(res, g, sm_scale, causal, query_offset, block_q,
                     bias=None, num_heads=None):
     q, k, v, out, lse = res
     bh, sq, d = q.shape
-    skv = k.shape[1]
+    skv, d_v = k.shape[1], v.shape[2]
     num_q, num_kv = sq // block_q, skv // block_kv
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1, keepdims=True)             # [bh, sq, 1]
@@ -719,20 +742,23 @@ def _flash_backward(res, g, sm_scale, causal, query_offset, block_q,
         )(q, k, v, g, lse, delta, *bias_ops)
 
     if num_q == 1:
-        q_spec = pl.BlockSpec((1, block_q, d), lambda b, i: (b, 0, 0))
-        r_spec = pl.BlockSpec((1, block_q, 1), lambda b, i: (b, 0, 0))
-        kv_spec = pl.BlockSpec((1, block_kv, d),
-                               lambda b, i: (b, i, 0))
+        def q_spec(width):
+            return pl.BlockSpec((1, block_q, width),
+                                lambda b, i: (b, 0, 0))
+
+        def kv_spec(width):
+            return pl.BlockSpec((1, block_kv, width),
+                                lambda b, i: (b, i, 0))
         dq, dk, dv = _call(
             _bwd_combined_kernel,
             grid=(bh, num_kv),
-            in_specs=[q_spec, kv_spec, kv_spec, q_spec, r_spec,
-                      r_spec],
-            out_specs=[q_spec, kv_spec, kv_spec],
+            in_specs=[q_spec(d), kv_spec(d), kv_spec(d_v), q_spec(d_v),
+                      q_spec(1), q_spec(1)],
+            out_specs=[q_spec(d), kv_spec(d), kv_spec(d_v)],
             qk_of_ids=lambda b, i: (0, i),
             out_shape=[_sds((bh, sq, d), q.dtype, q),
                        _sds((bh, skv, d), k.dtype, q),
-                       _sds((bh, skv, d), v.dtype, q)],
+                       _sds((bh, skv, d_v), v.dtype, q)],
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
             sm_scale=sm_scale, causal=causal, block_q=block_q,
             block_kv=block_kv, num_kv=num_kv,
@@ -749,32 +775,41 @@ def _flash_backward(res, g, sm_scale, causal, query_offset, block_q,
         if fused is not None:
             return fused
 
-    q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, j, 0))
-    r_spec = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, j, 0))
-    kv_spec = pl.BlockSpec((1, block_kv, d), lambda b, i, j: (b, i, 0))
+    def q_spec(width):
+        return pl.BlockSpec((1, block_q, width),
+                            lambda b, i, j: (b, j, 0))
+
+    def kv_spec(width):
+        return pl.BlockSpec((1, block_kv, width),
+                            lambda b, i, j: (b, i, 0))
     dk, dv = _call(
         _bwd_dkv_kernel,
         grid=(bh, num_kv, num_q),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, r_spec, r_spec],
-        out_specs=[kv_spec, kv_spec],
+        in_specs=[q_spec(d), kv_spec(d), kv_spec(d_v), q_spec(d_v),
+                  q_spec(1), q_spec(1)],
+        out_specs=[kv_spec(d), kv_spec(d_v)],
         qk_of_ids=lambda b, i, j: (j, i),
         out_shape=[_sds((bh, skv, d), k.dtype, q),
-                   _sds((bh, skv, d), v.dtype, q)],
+                   _sds((bh, skv, d_v), v.dtype, q)],
         scratch_shapes=[pltpu.VMEM((block_kv, d), jnp.float32),
-                        pltpu.VMEM((block_kv, d), jnp.float32)],
+                        pltpu.VMEM((block_kv, d_v), jnp.float32)],
         sm_scale=sm_scale, causal=causal, block_q=block_q,
         block_kv=block_kv, num_q=num_q, num_kv=num_kv,
         query_offset=query_offset)
 
-    q_spec2 = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-    r_spec2 = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
-    kv_spec2 = pl.BlockSpec((1, block_kv, d), lambda b, i, j: (b, j, 0))
+    def q_spec2(width):
+        return pl.BlockSpec((1, block_q, width),
+                            lambda b, i, j: (b, i, 0))
+
+    def kv_spec2(width):
+        return pl.BlockSpec((1, block_kv, width),
+                            lambda b, i, j: (b, j, 0))
     dq = _call(
         _bwd_dq_kernel,
         grid=(bh, num_q, num_kv),
-        in_specs=[q_spec2, kv_spec2, kv_spec2, q_spec2, r_spec2,
-                  r_spec2],
-        out_specs=q_spec2,
+        in_specs=[q_spec2(d), kv_spec2(d), kv_spec2(d_v), q_spec2(d_v),
+                  q_spec2(1), q_spec2(1)],
+        out_specs=q_spec2(d),
         qk_of_ids=lambda b, i, j: (i, j),
         out_shape=_sds((bh, sq, d), q.dtype, q),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
@@ -920,11 +955,13 @@ _flash_lse_biased.defvjp(_flash_lse_biased_fwd, _flash_lse_biased_bwd)
 
 
 def check_shapes(sq, skv, d, block_q: int = None,
-                 block_kv: int = None):
+                 block_kv: int = None, d_v: int = None):
     """(block_q, block_kv) after clamping, or NotImplementedError —
     shared by the public wrappers and by callers (ring attention) that
     must decide statically whether the kernel can take their shapes.
-    ``None`` blocks auto-pick the largest aligned divisor <= 1024."""
+    ``None`` blocks auto-pick the largest aligned divisor <= 1024.
+    ``d`` is the width q and k are scored at, ``d_v`` the width of v
+    and of the output where it differs (latent attention)."""
     block_q = _auto_block(sq, DEFAULT_BLOCK_Q, 8) if block_q is None \
         else min(block_q, sq)
     block_kv = _auto_block(skv, DEFAULT_BLOCK_KV, 128) \
@@ -941,7 +978,12 @@ def check_shapes(sq, skv, d, block_q: int = None,
         raise NotImplementedError(
             f"blocks ({block_q}, {block_kv}) not tile-aligned")
     if d % 128 and d not in (64,):
-        raise NotImplementedError(f"head_dim {d} unsupported")
+        # a narrower v leaves q/k a whole number of 64-lane halves
+        # (192 = 128 + 64); one width for all three keeps the old rule
+        if d_v in (None, d) or d % 64:
+            raise NotImplementedError(f"head_dim {d} unsupported")
+    if d_v not in (None, d) and d_v % 128:
+        raise NotImplementedError(f"v head_dim {d_v} unsupported")
     return block_q, block_kv
 
 
@@ -953,10 +995,14 @@ def _to_bh(x):
 def flash_attention(q, k, v, causal: bool = True, query_offset=0,
                     block_q: int = None, block_kv: int = None,
                     dropout_rate: float = 0.0, dropout_rng=None,
-                    bias=None):
+                    bias=None, sm_scale: float = None):
     """``[b, s, h, d]`` attention; raises NotImplementedError when the
     shape/backend can't take the kernel (caller falls back to the XLA
     path in ``ops.attention``).
+
+    ``v`` may be ``[b, s, h, d_v]`` with ``d_v != d`` (the output then
+    has v's width); ``sm_scale`` defaults to ``d ** -0.5`` of the q/k
+    width.
 
     ``bias`` is an additive score bias broadcastable to
     ``[b, h, sq, skv]`` (each leading dim 1 or full — ERNIE padding
@@ -975,8 +1021,10 @@ def flash_attention(q, k, v, causal: bool = True, query_offset=0,
     if not isinstance(query_offset, int) or query_offset != 0:
         raise NotImplementedError("cached decode uses the XLA path")
     b, sq, h, d = q.shape
+    d_v = v.shape[-1]
     block_q, block_kv = check_shapes(sq, k.shape[1], d, block_q,
-                                     block_kv)
+                                     block_kv, d_v=d_v)
+    sm_scale = d ** -0.5 if sm_scale is None else sm_scale
     if dropout_rate > 0.0 and dropout_rng is None:
         raise NotImplementedError(
             "flash dropout needs a dropout_rng")
@@ -992,22 +1040,22 @@ def flash_attention(q, k, v, causal: bool = True, query_offset=0,
         else:
             seed = jnp.zeros((1,), jnp.int32)   # ignored
         out, _ = _flash_lse_biased(
-            _to_bh(q), _to_bh(k), _to_bh(v), bias, seed, d ** -0.5,
+            _to_bh(q), _to_bh(k), _to_bh(v), bias, seed, sm_scale,
             causal, block_q, block_kv, float(dropout_rate), h)
-        return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
+        return out.reshape(b, h, sq, d_v).transpose(0, 2, 1, 3)
     if dropout_rate > 0.0:
         seed = jax.random.randint(dropout_rng, (1,), 0, 2 ** 31 - 1,
                                   dtype=jnp.int32)
         out, _ = _flash_lse_dropout(
-            _to_bh(q), _to_bh(k), _to_bh(v), seed, d ** -0.5, causal,
+            _to_bh(q), _to_bh(k), _to_bh(v), seed, sm_scale, causal,
             block_q, block_kv, float(dropout_rate))
-        return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
+        return out.reshape(b, h, sq, d_v).transpose(0, 2, 1, 3)
     # lse discarded: its cotangent is then symbolically zero and the
     # backward's delta adjustment is a no-op — one custom_vjp serves
     # both the plain and the with-lse surface
-    out, _ = _flash_lse(_to_bh(q), _to_bh(k), _to_bh(v), d ** -0.5,
+    out, _ = _flash_lse(_to_bh(q), _to_bh(k), _to_bh(v), sm_scale,
                         causal, block_q, block_kv)
-    return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
+    return out.reshape(b, h, sq, d_v).transpose(0, 2, 1, 3)
 
 
 def flash_attention_with_lse(q, k, v, causal: bool = True,

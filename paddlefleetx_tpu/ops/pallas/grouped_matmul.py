@@ -225,3 +225,180 @@ def grouped_matmul(x: jax.Array, w: jax.Array, counts: jax.Array,
     _check_shapes(x, w, counts)
     return _grouped_matmul(x, w, counts.astype(jnp.int32), block_n,
                            block_k)
+
+
+# -- the ragged form: groups of uneven, data-dependent size, no capacity --
+#
+# The dropless expert layer (``models/deepseek_v3/moe.py``) sorts its
+# rows by expert into ONE ``[M, K]`` buffer whose groups start on
+# ``block_m`` boundaries. A row tile then belongs to exactly one group,
+# the tile -> group table and the count of occupied tiles come by
+# scalar prefetch, and the grid's row axis is DYNAMIC: it runs over the
+# occupied tiles only, so time follows the rows that were routed here
+# and not the static bound ``M`` (which has to hold the worst routing).
+# Rows past the occupied tiles are never read and never written: what
+# the output holds there is unspecified, and callers mask it.
+
+
+def ragged_layout(group_sizes: jax.Array, block_m: int, num_tiles: int):
+    """Where the groups of a ragged buffer lie: ``(tile_group [T],
+    tiles_used, row_start [G], group_rows [G])``, the last the rows each
+    group occupies with its padding (what ``jax.lax.ragged_dot`` takes
+    as ``group_sizes`` on the same buffer).
+
+    Group ``g`` takes ``max(1, ceil(size_g / block_m))`` whole tiles
+    (an empty group keeps one tile of zero rows, so its ``dw`` block is
+    written as zeros and never left unset) starting at row
+    ``row_start[g]``. ``num_tiles`` is the static length ``T`` of the
+    table; ``sum(sizes) / block_m + G`` tiles always suffice."""
+    sizes = group_sizes.astype(jnp.int32)
+    tiles = jnp.maximum(1, -(-sizes // block_m))
+    tile_end = jnp.cumsum(tiles)
+    tile_group = jnp.searchsorted(
+        tile_end, jnp.arange(num_tiles, dtype=jnp.int32), side="right")
+    tile_group = jnp.minimum(tile_group, sizes.shape[0] - 1)
+    return (tile_group.astype(jnp.int32), tile_end[-1],
+            (tile_end - tiles) * block_m, tiles * block_m)
+
+
+def _ragged_kernel(tg_ref, x_ref, w_ref, o_ref, *, transpose_rhs):
+    """One row tile against its group's weight block, whole contraction
+    in one step (no accumulator carried across the grid)."""
+    del tg_ref
+    o_ref[...] = _dot(x_ref[...], w_ref[0],
+                      trans_b=transpose_rhs).astype(o_ref.dtype)
+
+
+def _ragged_dw_kernel(tg_ref, x_ref, dy_ref, dw_ref):
+    """dw[g] += x_tile^T @ dy_tile over the tiles of group ``g``, which
+    are consecutive on the innermost grid axis: the output block stays
+    in VMEM while the group lasts and is zeroed on its first tile."""
+    t = pl.program_id(2)
+    first = jnp.logical_or(
+        t == 0, tg_ref[t] != tg_ref[jnp.maximum(t - 1, 0)])
+
+    @pl.when(first)
+    def _init():
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    dw_ref[0] += _dot(x_ref[...], dy_ref[...], trans_a=True)
+
+
+def _ragged_forward(x, w, tile_group, tiles_used, block_m, block_n,
+                    transpose_rhs):
+    """``out[rows of g] = x[rows of g] @ w[g]`` (``w[g]^T`` with
+    ``transpose_rhs``) over the occupied tiles."""
+    m_rows, k_dim = x.shape
+    n_dim = w.shape[1] if transpose_rhs else w.shape[2]
+    bn = _block(n_dim, block_n)
+    if transpose_rhs:
+        w_spec = pl.BlockSpec((1, bn, k_dim),
+                              lambda ni, t, tg: (tg[t], ni, 0))
+    else:
+        w_spec = pl.BlockSpec((1, k_dim, bn),
+                              lambda ni, t, tg: (tg[t], 0, ni))
+    # rows innermost: a group's weight block is fetched once per
+    # column tile and stays while its row tiles pass
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(n_dim // bn, tiles_used),
+        in_specs=[pl.BlockSpec((block_m, k_dim),
+                               lambda ni, t, tg: (t, 0)), w_spec],
+        out_specs=pl.BlockSpec((block_m, bn),
+                               lambda ni, t, tg: (t, ni)),
+    )
+    return pl.pallas_call(
+        functools.partial(_ragged_kernel, transpose_rhs=transpose_rhs),
+        grid_spec=grid_spec,
+        out_shape=_sds((m_rows, n_dim), x.dtype, x),
+        interpret=_interpret(), name="moe_gmm",
+    )(tile_group, x, w)
+
+
+def _ragged_dw(x, dy, tile_group, tiles_used, num_groups, block_m,
+               block_n, block_k):
+    """fp32 ``[G, K, N]`` cotangent of the weights."""
+    k_dim, n_dim = x.shape[1], dy.shape[1]
+    bk, bn = _block(k_dim, block_k), _block(n_dim, block_n)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(k_dim // bk, n_dim // bn, tiles_used),
+        in_specs=[
+            pl.BlockSpec((block_m, bk), lambda ki, ni, t, tg: (t, ki)),
+            pl.BlockSpec((block_m, bn), lambda ki, ni, t, tg: (t, ni)),
+        ],
+        out_specs=pl.BlockSpec((1, bk, bn),
+                               lambda ki, ni, t, tg: (tg[t], ki, ni)),
+    )
+    return pl.pallas_call(
+        _ragged_dw_kernel, grid_spec=grid_spec,
+        out_shape=_sds((num_groups, k_dim, n_dim), jnp.float32, x),
+        interpret=_interpret(), name="moe_gmm_dw",
+    )(tile_group, x, dy)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _ragged_matmul(x, w, tile_group, tiles_used, block_m, block_n,
+                   block_k):
+    return _ragged_forward(x, w, tile_group, tiles_used, block_m,
+                           block_n, False)
+
+
+def _ragged_matmul_fwd(x, w, tile_group, tiles_used, block_m, block_n,
+                       block_k):
+    out = _ragged_forward(x, w, tile_group, tiles_used, block_m,
+                          block_n, False)
+    return out, (x, w, tile_group, tiles_used)
+
+
+def _ragged_matmul_bwd(block_m, block_n, block_k, res, g):
+    x, w, tile_group, tiles_used = res
+    # dx rows of g = dy rows of g @ w[g]^T: the same kernel, the weight
+    # block read transposed in place (no transposed copy of w)
+    dx = _ragged_forward(g, w, tile_group, tiles_used, block_m,
+                         block_n, True)
+    dw = _ragged_dw(x, g, tile_group, tiles_used, w.shape[0], block_m,
+                    block_n, block_k)
+    return (dx, dw.astype(w.dtype),
+            np.zeros(tile_group.shape, jax.dtypes.float0),
+            np.zeros((), jax.dtypes.float0))
+
+
+_ragged_matmul.defvjp(_ragged_matmul_fwd, _ragged_matmul_bwd)
+
+
+def ragged_matmul(x: jax.Array, w: jax.Array, tile_group: jax.Array,
+                  tiles_used: jax.Array, block_m: int = 128,
+                  block_n: int = 512, block_k: int = 1024) -> jax.Array:
+    """Grouped matmul over groups of uneven size, with no capacity.
+
+    Args:
+      x: ``[M, K]`` rows sorted by group, each group starting on a
+        ``block_m`` boundary (:func:`ragged_layout`); rows of a group's
+        last tile past its size MUST be zero.
+      w: ``[G, K, N]`` per-group weights.
+      tile_group: int32 ``[M / block_m]`` group of each row tile.
+      tiles_used: int32 scalar, the occupied tiles (the dynamic extent
+        of the grid's row axis).
+
+    Returns ``[M, N]`` in ``x.dtype``; rows past ``tiles_used *
+    block_m`` are unspecified. The custom VJP gives ``dx`` by the same
+    kernel on ``w^T`` and ``dw`` (fp32 accumulation per group over its
+    rows) by ``moe_gmm_dw``; ``dy`` rows past the occupied tiles are
+    never read. A shape the kernel cannot take raises
+    ``NotImplementedError`` (counted by the caller as
+    ``moe/fallback/pallas_rejected``)."""
+    if x.ndim != 2 or w.ndim != 3 or x.shape[1] != w.shape[1]:
+        raise NotImplementedError(
+            f"ragged_matmul wants x[M,K] w[G,K,N], got {x.shape} / "
+            f"{w.shape}")
+    if x.shape[0] % block_m or block_m % 8 or \
+            tile_group.shape != (x.shape[0] // block_m,):
+        raise NotImplementedError(
+            f"ragged_matmul: {x.shape[0]} rows in tiles of {block_m} "
+            f"with a table of {tile_group.shape}")
+    if jax.default_backend() != "tpu" and not _interpret():
+        raise NotImplementedError("ragged_matmul targets TPU")
+    return _ragged_matmul(x, w, tile_group.astype(jnp.int32),
+                          jnp.asarray(tiles_used, jnp.int32), block_m,
+                          block_n, block_k)

@@ -361,6 +361,42 @@ def test_grouped_matmul_compiles(backward, one_chip, as_tpu):
     assert "tpu_custom_call" in _compile(fn, x, w, counts).as_text()
 
 
+@pytest.mark.parametrize("split_backward", [False, True])
+def test_flash_at_latent_attention_widths_compiles(split_backward,
+                                                   one_chip, as_tpu,
+                                                   monkeypatch):
+    """q/k of 192 against v of 128 at the Kanana cell's b4 x s4096 x 32
+    heads, forward and backward: the fused backward with its larger
+    VMEM scope, and the split pair it falls back to."""
+    from paddlefleetx_tpu.ops.pallas import flash_attention as fa
+    if split_backward:
+        monkeypatch.setattr(fa, "FUSED_BWD_WIDE_VMEM_LIMIT", 0)
+    q = _sds((4, 4096, 32, 192), BF16, one_chip)
+    v = _sds((4, 4096, 32, 128), BF16, one_chip)
+    text = _compile(_grad_sum(fa.flash_attention, (0, 1, 2)),
+                    q, q, v).as_text()
+    assert text.count("tpu_custom_call") == (3 if split_backward else 2)
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1536), (768, 2048)])
+def test_ragged_matmul_compiles(k, n, one_chip, as_tpu):
+    """The dropless expert layer's grouped products at the Kanana
+    cell's worst-case buffer (16,384 x 6 picks + 16 tiles), forward,
+    dx and dw, the row axis of the grid dynamic."""
+    from paddlefleetx_tpu.ops.pallas.grouped_matmul import ragged_matmul
+    rows = 16384 * 6 + 16 * 128
+    x = _sds((rows, k), BF16, one_chip)
+    w = _sds((16, k, n), BF16, one_chip)
+    table = _sds((rows // 128,), jnp.int32, one_chip)
+    used = _sds((), jnp.int32, one_chip)
+    def loss_and_grads(*a):
+        return jax.value_and_grad(
+            lambda *a: jnp.sum(ragged_matmul(*a).astype(jnp.float32)),
+            (0, 1))(*a)
+    text = _compile(loss_and_grads, x, w, table, used).as_text()
+    assert text.count("tpu_custom_call") == 3
+
+
 @pytest.mark.parametrize("m", [8, 1024])
 def test_quantized_matmul_compiles(m, one_chip, as_tpu):
     from paddlefleetx_tpu.ops.pallas.quantized_matmul import (

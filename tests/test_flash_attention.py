@@ -12,7 +12,9 @@ import numpy as np
 import pytest
 
 from paddlefleetx_tpu.ops.attention import _xla_attention
-from paddlefleetx_tpu.ops.pallas.flash_attention import flash_attention
+from paddlefleetx_tpu.ops.pallas.flash_attention import (
+    check_shapes, flash_attention,
+)
 
 
 def _rand(b=1, s=256, h=2, d=64, seed=0):
@@ -48,6 +50,43 @@ def test_grads_match_xla():
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-3, rtol=1e-3)
+
+
+@pytest.mark.parametrize("s,blocks", [(256, None), (1024, 256)])
+def test_latent_attention_widths_match_xla(s, blocks):
+    """q/k scored at 192, values of 128 (MLA), the scale an argument:
+    forward and backward against the XLA path, through the combined
+    (one q block) and the fused / split (several) backward."""
+    from paddlefleetx_tpu.ops.attention import _xla_attention
+    ks = jax.random.split(jax.random.key(3), 4)
+    q = jax.random.normal(ks[0], (1, s, 2, 192), jnp.float32)
+    k = jax.random.normal(ks[1], (1, s, 2, 192), jnp.float32)
+    v = jax.random.normal(ks[2], (1, s, 2, 128), jnp.float32)
+    g = jax.random.normal(ks[3], (1, s, 2, 128), jnp.float32)
+    scale = 0.05
+
+    def flash(q, k, v):
+        out = flash_attention(q, k, v, block_q=blocks, block_kv=blocks,
+                              sm_scale=scale)
+        assert out.shape == v.shape
+        return jnp.sum(out * g)
+
+    def dense(q, k, v):
+        return jnp.sum(_xla_attention(q, k, v, None, True, 0, 0.0, None,
+                                      True, True, sm_scale=scale) * g)
+    got = jax.value_and_grad(flash, (0, 1, 2))(q, k, v)
+    want = jax.value_and_grad(dense, (0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    for a, b in zip(got[1], want[1]):
+        np.testing.assert_allclose(a, b, atol=2e-5)
+
+
+def test_widths_the_kernel_cannot_take_are_refused():
+    for d, d_v in [(192, 192), (96, 64), (192, 64), (160, 128)]:
+        with pytest.raises(NotImplementedError):
+            check_shapes(256, 256, d, d_v=d_v)
+    assert check_shapes(256, 256, 192, d_v=128) == (256, 256)
+    assert check_shapes(256, 256, 128, d_v=128) == (256, 256)
 
 
 @pytest.mark.parametrize("causal", [True, False])

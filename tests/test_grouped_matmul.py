@@ -14,7 +14,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddlefleetx_tpu.ops.pallas.grouped_matmul import grouped_matmul
+from paddlefleetx_tpu.ops.pallas.grouped_matmul import (
+    grouped_matmul, ragged_layout, ragged_matmul,
+)
 
 
 def _case(g=6, gw=3, c=8, k=16, n=24, seed=0, fill=0.6):
@@ -134,3 +136,66 @@ def test_shape_rejection_is_notimplemented():
         grouped_matmul(x, jnp.swapaxes(w, 1, 2), counts)  # K mismatch
     with pytest.raises(NotImplementedError):
         grouped_matmul(x, w, counts.astype(jnp.float32))  # counts dtype
+
+
+# -- the ragged form (no capacity): forward, dx, dw against einsums ----
+
+def _ragged_case(sizes, k=32, n=48, block_m=8, seed=0):
+    sizes = np.asarray(sizes, np.int32)
+    tiles = int(sizes.sum()) // block_m + 1 + len(sizes)
+    table, used, start, rows = ragged_layout(jnp.asarray(sizes), block_m,
+                                             tiles)
+    rng = np.random.default_rng(seed)
+    x = np.zeros((tiles * block_m, k), np.float32)
+    group_of_row = np.full(tiles * block_m, -1)
+    for g, size in enumerate(sizes):
+        lo = int(start[g])
+        x[lo:lo + size] = rng.normal(size=(size, k))
+        group_of_row[lo:lo + size] = g
+    w = rng.normal(size=(len(sizes), k, n)).astype(np.float32)
+    dy = rng.normal(size=(tiles * block_m, n)).astype(np.float32)
+    return (jnp.asarray(x), jnp.asarray(w), table, used, rows,
+            group_of_row, jnp.asarray(dy), block_m)
+
+
+@pytest.mark.parametrize("sizes", [
+    [5, 0, 17, 8],          # uneven, one empty, none tile-aligned
+    [0, 0, 0, 0],           # nothing routed here
+    [30, 0, 0, 0],          # every row on one expert
+    [8, 16, 8, 24]])        # all tile-aligned
+def test_ragged_forward_dx_dw_match_einsums(sizes):
+    x, w, table, used, rows, group_of_row, dy, bm = _ragged_case(sizes)
+    live = jnp.asarray(group_of_row >= 0)[:, None]
+    assert int(used) == sum(max(1, -(-s // bm)) for s in sizes)
+    assert [int(r) for r in rows] == [bm * max(1, -(-s // bm))
+                                      for s in sizes]
+
+    def kernel(x, w):
+        out = ragged_matmul(x, w, table, used, block_m=bm, block_n=16,
+                            block_k=16)
+        return jnp.where(live, out, 0)   # rows past `used` are unspecified
+
+    def dense(x, w):
+        out = jnp.einsum("mk,mkn->mn", x, w[np.maximum(group_of_row, 0)])
+        return jnp.where(live, out, 0)
+    got, vjp = jax.vjp(kernel, x, w)
+    want, vjp_ref = jax.vjp(dense, x, w)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    (dx, dw), (dx_ref, dw_ref) = vjp(dy), vjp_ref(dy)
+    np.testing.assert_allclose(jnp.where(live, dx, 0), dx_ref, atol=1e-5)
+    np.testing.assert_allclose(dw, dw_ref, atol=1e-5)
+    # XLA's ragged_dot on the same buffer: the counted stand-in
+    np.testing.assert_allclose(
+        jnp.where(live, jax.lax.ragged_dot(x, w, rows), 0), want,
+        atol=1e-5)
+
+
+def test_ragged_matmul_refuses_what_it_cannot_take(monkeypatch):
+    x, w, table, used, *_ = _ragged_case([5, 3])
+    with pytest.raises(NotImplementedError):
+        ragged_matmul(x[:-1], w, table, used, block_m=8)
+    with pytest.raises(NotImplementedError):
+        ragged_matmul(x, w[:, :-1], table, used, block_m=8)
+    monkeypatch.delenv("PFX_PALLAS_INTERPRET")
+    with pytest.raises(NotImplementedError):
+        ragged_matmul(x, w, table, used, block_m=8)
