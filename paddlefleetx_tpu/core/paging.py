@@ -28,7 +28,10 @@ sharing happen:
 
 Page 0 is reserved as the null page: empty ``page_table`` entries point
 at it, so an inactive slot's dead decode writes land in a dedicated
-garbage page instead of corrupting live data.
+garbage page instead of corrupting live data. A nulled row (first
+entry ``NULL_PAGE``) is skipped by the decode kernel and yields zeros
+(``ops/pallas/flash_attention.py::flash_decode_paged``), which is why
+an ACTIVE slot's first page may never be page 0.
 
 PR 16 adds a second tier: constructed with ``host_pages > 0`` the
 allocator also tracks a bounded pinned-host-DRAM pool occupying the id

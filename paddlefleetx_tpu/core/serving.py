@@ -2269,6 +2269,28 @@ class GenerationServer:
                 self._sync_aid()
         return expired, live
 
+    def _count_decode_walk(self, live: List[int], window: int,
+                           ahead=None) -> None:
+        """One tick of the paged decode kernel, as the host knows it
+        without a device read: the slots it walks of those it was
+        launched for, and the pages (its blocks, at the cells' page
+        size) it walks of the table's capacity. Of ``live`` only the
+        slots still active count (page maintenance may have preempted
+        one: its row went down nulled); ``ahead [slots]`` are tokens a
+        fused launch committed in its earlier ticks."""
+        lengths = [
+            req["cur_len"] + (int(ahead[s]) if ahead is not None else 0)
+            for s in live
+            if (req := self._slots[s]) is not None and req.get("active")]
+        last = self._max_pages - 1
+        metrics.inc("serving/decode_rows_live", len(lengths))
+        metrics.inc("serving/decode_rows_slots", self.num_slots)
+        metrics.inc("serving/decode_blocks_live", sum(
+            min((n + window - 1) // self._page, last) + 1
+            for n in lengths))
+        metrics.inc("serving/decode_blocks_capacity",
+                    self.num_slots * self._max_pages)
+
     def _idle_step(self, rec: StepRecord, expired: List[Completion]
                    ) -> List[Completion]:
         """The end of a step with nothing decodable yet (empty, or
@@ -2335,6 +2357,8 @@ class GenerationServer:
             self._roundtrips += 1
             rec.ticks = 1
             metrics.inc("serving/device_ticks")
+            if self.paged:
+                self._count_decode_walk(live, k + 1)
             reg = metrics.get_registry()
             done: List[Completion] = []
             now = time.time()
@@ -2541,6 +2565,9 @@ class GenerationServer:
                 # timestamps interpolate its wall time so TTFT/TPOT
                 # stay comparable with the T=1 histograms
                 t_j = t_end - (n_ticks - 1 - j) * per_tick_s
+                if self.paged:
+                    self._count_decode_walk(
+                        live, k + 1, counts_np[:, :j].sum(axis=1))
                 tick_committed = 0
                 ticked = 0
                 for slot in live:

@@ -1082,6 +1082,30 @@ def flash_attention_with_lse(q, k, v, causal: bool = True,
 # -- cached decode -----------------------------------------------------
 
 
+def _decode_block(q, k, v, live, sm_scale, m_prev, l_prev, acc_prev,
+                  bias=None):
+    """One key block of the cached-decode online softmax for ONE query
+    token, every head in one vectorized pass (a per-head loop would
+    issue ~6x num_heads small VPU ops and dominate the call): ``q
+    [h, d, 1]`` against the fp32 ``k`` / ``v`` ``[h, d, bkv]`` of the
+    resident block, ``live [1, bkv]`` the keys this query may see.
+    Takes and returns the running ``m [h, 1]``, ``l [h, 1]``, ``acc
+    [h, d]``, all fp32. Shared by the contiguous kernels and the paged
+    one, so a row's number does not depend on which of them walked
+    its blocks."""
+    s = jnp.sum(q * k, axis=1) * sm_scale          # [h, bkv] f32
+    if bias is not None:
+        s = s + bias                               # [1, bkv] broadcasts
+    s = jnp.where(live, s, NEG_INF)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)                         # [h, bkv]
+    l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+    # output: broadcast p over d, reduce over the key lanes
+    acc_new = acc_prev * alpha + jnp.sum(p[:, None, :] * v, axis=2)
+    return m_new, l_new, acc_new
+
+
 def _decode_kernel(off_ref, q_ref, k_ref, v_ref, *refs, sm_scale,
                    block_kv, num_kv, has_bias, ragged=False,
                    quantized=False):
@@ -1098,15 +1122,20 @@ def _decode_kernel(off_ref, q_ref, k_ref, v_ref, *refs, sm_scale,
 
     The live length is DYNAMIC (the decode loop's cache index), so it
     arrives as a prefetched scalar: blocks wholly past the last valid
-    position are skipped — short prefixes only pay for the cache they
-    have actually filled — and the straddling block is masked. With
-    ``has_bias`` a per-key additive bias tile rides along (the
-    generation loop's left-pad mask).
+    position move no bytes and do no math (the index map re-references
+    the resident block, the body is ``pl.when``-ed off) — short
+    prefixes only STREAM the cache they have actually filled — and the
+    straddling block is masked. They are still grid steps: the grid is
+    ``(b, num_kv)`` whatever is filled, at ~0.4 us a step on a v5e
+    (ledger, PR 25), so this kernel's TIME has a floor set by the
+    capacity. Only the paged kernel (:func:`_paged_kernel`) walks the
+    live pairs alone. With ``has_bias`` a per-key additive bias tile
+    rides along (the generation loop's left-pad mask).
 
     ``ragged``: the prefetched offsets are PER ROW (``[b]``, the
     continuous-batching slot lengths) instead of one shared scalar —
     each batch row masks and block-skips against its OWN last valid
-    position, so a short slot never pays a long slot's cache walk.
+    position, so a short slot never streams a long slot's cache.
 
     ``quantized``: the cache tiles are int8 and two extra operands
     carry the per-(row, head, position) fp32 scales (``[h, 1, bkv]``
@@ -1144,21 +1173,9 @@ def _decode_kernel(off_ref, q_ref, k_ref, v_ref, *refs, sm_scale,
         if quantized:
             k = k * ks_ref[0]                      # [h, 1, bkv] bcast
             v = v * vs_ref[0]
-        # every head in one vectorized pass — a per-head loop would
-        # issue ~6x num_heads small VPU ops and dominate the call
-        s = jnp.sum(q * k, axis=1) * sm_scale      # [h, bkv] f32
-        if has_bias:
-            s = s + bias_ref[0]                    # [1, bkv] broadcasts
-        s = jnp.where(live, s, NEG_INF)
-        m_prev = m_scr[:]                          # [h, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                     # [h, bkv]
-        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        # output: broadcast p over d, reduce over the key lanes
-        acc_scr[:] = acc_scr[:] * alpha + jnp.sum(p[:, None, :] * v,
-                                                  axis=2)
-        m_scr[:] = m_new
+        m_scr[:], l_scr[:], acc_scr[:] = _decode_block(
+            q, k, v, live, sm_scale, m_scr[:], l_scr[:], acc_scr[:],
+            bias_ref[0] if has_bias else None)     # [1, bkv] bias
 
     @pl.when(ki == num_kv - 1)
     def _finish():
@@ -1219,18 +1236,9 @@ def _verify_kernel(off_ref, q_ref, k_ref, v_ref, *refs, sm_scale,
         for j in range(window):
             live = k_pos <= offset + j             # [1, bkv]
             qj = q_ref[0, :, :, j].astype(jnp.float32)   # [h, d]
-            s = jnp.sum(qj[:, :, None] * k, axis=1) * sm_scale
-            s = jnp.where(live, s, NEG_INF)        # [h, bkv]
-            m_prev = m_scr[j]                      # [h, 1]
-            m_new = jnp.maximum(m_prev,
-                                jnp.max(s, axis=1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
-            l_scr[j] = l_scr[j] * alpha + jnp.sum(p, axis=1,
-                                                  keepdims=True)
-            acc_scr[j] = acc_scr[j] * alpha + jnp.sum(p[:, None, :] * v,
-                                                      axis=2)
-            m_scr[j] = m_new
+            m_scr[j], l_scr[j], acc_scr[j] = _decode_block(
+                qj[:, :, None], k, v, live, sm_scale, m_scr[j],
+                l_scr[j], acc_scr[j])
 
     @pl.when(ki == num_kv - 1)
     def _finish():
@@ -1322,11 +1330,12 @@ def _flash_decode_call(q, k, v, off, bias, block_kv: int, ragged: bool,
 
     # clamp the kv block index once past the live length: skipped
     # iterations re-reference the already-resident block, so the
-    # HBM->VMEM copy is elided and a short prefix pays only for the
+    # HBM->VMEM copy is elided and a short prefix streams only the
     # cache it has actually filled (the compute skip alone would
-    # still stream the full capacity). Ragged, each ROW clamps
-    # against its own length — the per-slot cost model of the
-    # continuous-batching server. A verify window's LAST query
+    # still stream the full capacity; the skipped iterations remain
+    # grid steps). Ragged, each ROW clamps against its own length —
+    # the per-slot byte model of the continuous-batching server. A
+    # verify window's LAST query
     # (position off + window - 1) sets the walk bound; earlier
     # queries just mask the tail blocks out.
     def kv_block(bi, ki, off):
@@ -1433,8 +1442,12 @@ def flash_decode_ragged(q, k, v, query_offsets, bias=None,
     Same kernel body and layout contract as :func:`flash_decode`; the
     offsets prefetch as a ``[b]`` scalar operand so both the in-kernel
     masking and the block-skip index maps read the PER-ROW length —
-    a freshly admitted slot walks only its own short cache while a
-    long-running neighbour streams its full one.
+    a freshly admitted slot streams only its own short cache while a
+    long-running neighbour streams its full one. That holds for the
+    bytes, not for the grid: every row still takes ``S // block_kv``
+    steps (see :func:`_decode_kernel`); the paged kernel
+    (:func:`flash_decode_paged`) is the one whose grid follows the
+    live slots and blocks.
 
     ``sq > 1`` is the speculative VERIFY window (no bias): query ``j``
     of row ``i`` sits at position ``query_offsets[i] + j`` and masks
@@ -1459,23 +1472,161 @@ def flash_decode_ragged(q, k, v, query_offsets, bias=None,
                               v_scale=v_scale)
 
 
-def _paged_decode_kernel(off_ref, pt_ref, *refs, **kw):
-    """:func:`_decode_kernel` behind TWO prefetched scalars: the
-    per-row offsets AND the page table. The table is consumed entirely
-    by the BlockSpec index maps (physical-page redirection happens in
-    the grid, before the kernel body runs); the body itself masks and
-    block-skips against LOGICAL positions exactly as the ragged kernel
-    does, so it needs only the offsets."""
-    del pt_ref
-    _decode_kernel(off_ref, *refs, **kw)
+# -- paged decode: a walk over the live (slot, block) pairs -------------
+
+#: the reserved page of ``core/paging.py``: a slot whose page-table row
+#: starts with it is not decoding (``core/serving.py::_sync_pt`` nulls
+#: every non-ACTIVE slot's row; an active slot's first page is never
+#: this one)
+NULL_PAGE = 0
+#: slots per lane group of the rows-on-lanes operands (``q`` and the
+#: output here, the fresh values of ``kv_write.py``)
+LANES = 128
+#: what Mosaic scopes for a kernel unless told otherwise, and the most
+#: the cache kernels count for themselves before they refuse a shape
+#: (a wide verify window's ``q`` / output here and fresh values there)
+VMEM_DEFAULT = 16 * 1024 * 1024
+VMEM_MOST = 48 * 1024 * 1024
+#: of the chip's 1 MiB of SMEM, what the paged kernel's prefetched
+#: scalars (offsets, page table, the walk's two lists) may take
+SMEM_MOST = 768 * 1024
 
 
-def _paged_verify_kernel(off_ref, pt_ref, *refs, **kw):
-    """:func:`_verify_kernel` behind the paged kernel's two prefetched
-    scalars — same delegation as :func:`_paged_decode_kernel`: the
-    page table lives entirely in the index maps."""
-    del pt_ref
-    _verify_kernel(off_ref, *refs, **kw)
+def _paged_walk(offs, pt, window: int, block_kv: int, num_kv: int):
+    """The grid of one paged decode call: ``(rows [T], blocks [T],
+    steps)`` with ``T = b * num_kv``, of which the first ``steps``
+    entries are the (slot, logical block) pairs the kernel visits —
+    slots ascending, each slot's blocks ascending.
+
+    A live slot (its page-table row does not start with
+    :data:`NULL_PAGE`) takes blocks ``0 .. (off + W - 1) // block_kv``;
+    a dead one takes none, except that the FIRST slot of every group of
+    :data:`LANES` always takes one step, dead or not: the step that
+    zeroes the group's output block, so no output block is left
+    unvisited and ``steps >= 1``. A handful of integer ops on ``[b]``
+    and ``[T]``; every layer of a tick builds the same walk from the
+    same operands, and XLA keeps one (tests/test_chip_compile.py)."""
+    b = offs.shape[0]
+    last = jnp.minimum((offs + (window - 1)) // block_kv, num_kv - 1)
+    n = jnp.where(pt[:, 0] != NULL_PAGE, jnp.maximum(last + 1, 0), 0)
+    n = jnp.maximum(
+        n, (jnp.arange(b, dtype=jnp.int32) % LANES == 0).astype(
+            jnp.int32))
+    end = jnp.cumsum(n, dtype=jnp.int32)
+    t = jnp.arange(b * num_kv, dtype=jnp.int32)
+    # step t belongs to the first slot whose steps end past t: one
+    # [T, b] compare, counted for the slot and summed for the step its
+    # blocks began at (no search loop, no gather: nothing XLA would
+    # leave un-merged across the layers)
+    before = end[None, :] <= t[:, None]
+    rows = jnp.minimum(jnp.sum(before, axis=1, dtype=jnp.int32), b - 1)
+    began = jnp.sum(jnp.where(before, n[None, :], 0), axis=1)
+    blocks = jnp.clip(t - began, 0, num_kv - 1)
+    return rows, blocks, end[-1]
+
+
+def _paged_kernel(off_ref, pt_ref, row_ref, blk_ref, q_ref, k_ref,
+                  v_ref, *refs, sm_scale, block_kv, num_kv, window,
+                  quantized):
+    """One grid step = one live (slot, block) pair of
+    :func:`_paged_walk`: the math of :func:`_decode_kernel` (``window``
+    1) and :func:`_verify_kernel` per pair, with the slot taken from
+    the walk instead of a grid axis. A slot's blocks come in ascending
+    order on consecutive steps, so its fp32 ``m / l / acc`` state
+    lives in scratch from its block 0 to its last block exactly as it
+    did under the ``(slot, block)`` grid.
+
+    ``q`` and the output are rows-on-lanes (``[W, h, d, 128]`` blocks,
+    slot ``i`` in lane ``i % 128`` of group ``i // 128``, resident
+    while the walk stays inside the group): at a slot's first block a
+    max-reduce over its one unmasked lane lifts its ``[h, d]`` query
+    column(s) out (exact, the sign of a zero included) and scratch
+    keeps them copied onto every lane, so a block's product reads
+    whole vregs and broadcasts nothing (my chip runs, PR 26: 0.90 us a
+    step against 0.98 with ``[h, d, 1]`` columns, a full server's call
+    503 us against 561); at its last block the finished column goes
+    into its lane of the output block. The group's first step zeroes
+    the block, so a slot the walk never reaches reads zeros, not what
+    the buffer held.
+    """
+    refs = list(refs)
+    if quantized:
+        ks_ref, vs_ref = refs[0], refs[1]
+        refs = refs[2:]
+    o_ref, q_scr, m_scr, l_scr, acc_scr = refs
+    t = pl.program_id(0)
+    row, kb = row_ref[t], blk_ref[t]
+    offset = off_ref[row]
+    last = jnp.minimum((offset + (window - 1)) // block_kv, num_kv - 1)
+    alive = jnp.logical_and(pt_ref[row, 0] != NULL_PAGE, last >= 0)
+    mine = jax.lax.broadcasted_iota(
+        jnp.int32, q_ref.shape[1:], 2) == row % LANES    # [h, d, 128]
+
+    @pl.when(jnp.logical_and(row % LANES == 0, kb == 0))
+    def _zero():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(jnp.logical_and(alive, kb == 0))
+    def _first():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+        # loops, not unrolled windows: unrolled, Mosaic stacks every
+        # position's [h, d, 128] fp32 temporaries (kv_write, PR 24)
+        def lift(j, _):
+            col = jnp.max(
+                jnp.where(mine, q_ref[j].astype(jnp.float32), -jnp.inf),
+                axis=2, keepdims=True)                   # [h, d, 1]
+            q_scr[j] = jnp.broadcast_to(col, q_scr.shape[1:])
+            return _
+        jax.lax.fori_loop(0, window, lift, 0)
+
+    @pl.when(alive)
+    def _block():
+        k_pos = kb * block_kv + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_kv), 1)
+        k = k_ref[0].astype(jnp.float32)           # [h, d, bkv]
+        v = v_ref[0].astype(jnp.float32)
+        if quantized:
+            k = k * ks_ref[0]                      # [h, 1, bkv] bcast
+            v = v * vs_ref[0]
+        # a block participates when ANY window query can see it (the
+        # walk's bound); per-query liveness is the mask
+        for j in range(window):
+            # the column on every lane: one copy per 128 keys of the
+            # block, whole vregs side by side
+            q = jnp.concatenate([q_scr[j]] * (block_kv // LANES), axis=2)
+            m_scr[j], l_scr[j], acc_scr[j] = _decode_block(
+                q, k, v, k_pos <= offset + j, sm_scale,
+                m_scr[j], l_scr[j], acc_scr[j])
+
+    @pl.when(jnp.logical_and(alive, kb == last))
+    def _finish():
+        def put(j, _):
+            o = acc_scr[j] / jnp.maximum(l_scr[j], 1e-30)    # [h, d]
+            o_ref[j] = jnp.where(mine, o[..., None].astype(o_ref.dtype),
+                                 o_ref[j])
+            return _
+        jax.lax.fori_loop(0, window, put, 0)
+
+
+def _paged_vmem_bytes(window, h, d, block_kv, q_item, kv_item,
+                      quantized) -> int:
+    """What one grid step of the paged kernel holds in VMEM: the
+    ``q`` block (one buffer) and the output block (two), the K and V
+    blocks double-buffered (with their scale blocks), the lifted
+    query columns (fp32, on all 128 lanes), and the block math's
+    widened K and V, one ``[h, d, bkv]`` product and a score / prob
+    pair per window position."""
+    dd = max(d, 8)
+    n = 3 * window * h * dd * LANES * q_item
+    n += 4 * h * dd * block_kv * kv_item
+    if quantized:
+        n += 4 * h * 8 * block_kv * 4
+    n += window * h * dd * LANES * 4
+    n += 3 * h * dd * block_kv * 4 + window * 2 * h * block_kv * 4
+    return n
 
 
 def flash_decode_paged(q, k, v, query_offsets, page_table, bias=None,
@@ -1488,32 +1639,38 @@ def flash_decode_paged(q, k, v, query_offsets, page_table, bias=None,
     ``page_table [b, max_pages]`` (int32 physical page ids;
     ``core/paging.py``).
 
-    Same kernel body, grid walk, and per-row block clamping as
-    :func:`flash_decode_ragged` — the ONLY difference is the KV
-    BlockSpec index map, which redirects logical block ``kb`` to block
-    ``kb % blocks_per_page`` of physical page
-    ``page_table[i, kb // blocks_per_page]``. Both scalars prefetch
-    (``PrefetchScalarGridSpec(num_scalar_prefetch=2)``) so the
-    redirection is resolved before each block's HBM->VMEM copy issues,
-    and the clamp keeps a short row from streaming pages it never
-    wrote. Block size is the largest 128-aligned divisor of the page
-    size that fits the VMEM budget, so a block never straddles two
-    (physically unrelated) pages.
+    The launch is shaped by the live work of the tick, not by the
+    server's capacity. The grid is ONE axis of dynamic extent over the
+    live (slot, block) pairs (:func:`_paged_walk`, built from the
+    offsets and the table by a few XLA integer ops and handed over by
+    scalar prefetch with them): a slot whose row starts with
+    :data:`NULL_PAGE` is dead and takes no step, a live slot takes its
+    blocks up to ``(query_offsets[i] + W - 1) // block_kv`` and none
+    past it — so time, and not only the bytes streamed, follows the
+    cache that is filled. The K/V index map redirects logical block
+    ``kb`` of row ``i`` to block ``kb % blocks_per_page`` of physical
+    page ``page_table[i, kb // blocks_per_page]``; block size is the
+    largest 128-aligned divisor of the page size that fits the VMEM
+    budget, so a block never straddles two (physically unrelated)
+    pages. ``q`` goes in and the output comes out rows-on-lanes
+    (``[W, h, d, b]`` padded to 128 slots a group, one VMEM-resident
+    block per group: :func:`_paged_kernel`) — a ``[b, h, d, W]``
+    operand pads its minor dim of ``W`` to 128 lanes in HBM. A dead
+    row's output is zeros.
 
-    ``sq > 1`` is the speculative VERIFY window: the within-window
-    causal mask of :func:`flash_decode_ragged` over the paged pool
-    (:func:`_paged_verify_kernel`).
+    ``sq > 1`` is the speculative VERIFY window: query ``j`` of row
+    ``i`` sits at ``query_offsets[i] + j`` and sees keys up to there
+    (the within-window causal mask of :func:`flash_decode_ragged`).
 
     Inference-only; no bias operand (serving decode carries none —
-    per-slot validity lives in the offsets). Raises
+    per-slot validity lives in the offsets and the table). Raises
     NotImplementedError where the caller must fall back to the XLA
     gather path (``ops/attention.py::_gather_kv_pages``).
 
     ``k_scale``/``v_scale`` (``[num_pages, h, 1, page_size]`` fp32
-    scale POOLS, page-parallel with the int8 K/V pools) switch both
-    the single-token and the verify-window kernel to their int8-KV
-    dequant-in-kernel variants; the scale blocks redirect through the
-    same page-table index map as their K/V tiles.
+    scale POOLS, page-parallel with the int8 K/V pools) switch the
+    kernel to its int8-KV dequant-in-kernel variant; the scale blocks
+    redirect through the same page-table index map as their K/V tiles.
     """
     if jax.default_backend() != "tpu" and not _interpret():
         raise NotImplementedError("flash kernel targets TPU")
@@ -1521,8 +1678,7 @@ def flash_decode_paged(q, k, v, query_offsets, page_table, bias=None,
         raise NotImplementedError(
             "flash_decode_paged takes no bias (per-slot validity is "
             "the offsets')")
-    b, sq, h, d = q.shape
-    window = sq
+    b, window, h, d = q.shape
     if window < 1:
         raise NotImplementedError("empty decode window")
     if d % 8:
@@ -1540,80 +1696,98 @@ def flash_decode_paged(q, k, v, query_offsets, page_table, bias=None,
     if pt.ndim != 2 or pt.shape[0] != b:
         raise NotImplementedError(
             f"page_table must be [b={b}, max_pages], got {pt.shape}")
-    max_pages = pt.shape[1]
     # block the PAGE, not the logical capacity: a kv block must stay
     # inside one physical page for the redirection to be a pure index
     # remap
     block_kv = _auto_block(page, block_kv, 128)
-    budget = 8 * 1024 * 1024
+
+    def vmem(bkv):
+        return _paged_vmem_bytes(window, h, d, bkv, q.dtype.itemsize,
+                                 k.dtype.itemsize, quantized)
+
     while block_kv > 128 and page % (block_kv // 2) == 0 and \
-            4 * h * d * block_kv * k.dtype.itemsize > budget:
+            vmem(block_kv) > VMEM_DEFAULT // 2:
         block_kv //= 2
-    if page % block_kv or block_kv % 128 or \
-            4 * h * d * block_kv * k.dtype.itemsize > budget:
+    if page % block_kv or block_kv % 128 or vmem(block_kv) > VMEM_MOST:
         raise NotImplementedError(
             f"page size {page} not tileable by {block_kv} within "
-            f"VMEM budget (h={h}, d={d})")
+            f"VMEM budget (h={h}, d={d}, window={window})")
+    smem = 4 * (b + pt.size + 2 * b * pt.shape[1] * (page // block_kv))
+    if smem > SMEM_MOST:
+        raise NotImplementedError(
+            f"{b} slots x {pt.shape[1]} pages: the walk's {smem} bytes "
+            f"of prefetched scalars do not fit SMEM")
+    operands = (q, k, v, offs, pt)
+    if quantized:
+        operands += (k_scale, v_scale)
+    return _flash_decode_paged_call(
+        *operands, block_kv=block_kv,
+        vmem_limit=max(vmem(block_kv) * 5 // 4, VMEM_DEFAULT),
+        interpret=_interpret())
+
+
+@functools.partial(
+    jax.jit, static_argnames=("block_kv", "vmem_limit", "interpret"),
+    inline=True)
+def _flash_decode_paged_call(q, k, v, offs, pt, *scales, block_kv,
+                             vmem_limit, interpret):
+    """The walk and the ``pallas_call``, jitted so that a model's
+    layers trace ONE kernel per shape (the 24-layer tick lowers in 1.1
+    s where 24 traces took 1.9 s), and ``inline`` with no ``name=`` on
+    the call: a Mosaic call is named after the innermost scope, and
+    the chip's op line has to stay ``self_attn.<n> custom-call``,
+    which the benchmark's roofline share reads (a plain inner jit
+    would make it ``_flash_decode_paged_call.<n>``)."""
+    b, window, h, d = q.shape
+    page = k.shape[3]
     bpp = page // block_kv                     # blocks per page
-    num_kv = max_pages * bpp                   # logical capacity walk
+    num_kv = pt.shape[1] * bpp                 # a row's capacity walk
+    rows, blocks, steps = _paged_walk(offs, pt, window, block_kv,
+                                      num_kv)
 
-    qp = q.transpose(0, 2, 3, 1)               # [b, h, d, W]
+    def kv_block(t, off, pt, rows, blocks):
+        kb = blocks[t]
+        return (pt[rows[t], kb // bpp], 0, 0, kb % bpp)
 
-    def kv_block(bi, ki, off, pt):
-        # clamp to the row's live block (same dead-block elision as
-        # the ragged kernel; a verify window's last query sets the
-        # bound), then redirect through the page table
-        kb = jnp.minimum(ki, (off[bi] + (window - 1)) // block_kv)
-        return (pt[bi, kb // bpp], 0, 0, kb % bpp)
+    def lane_group(t, off, pt, rows, blocks):
+        return (0, 0, 0, rows[t] // LANES)
 
+    # [b, W, h, d] -> [W, h, d, b]: the slots on the lanes, whole
+    # groups of 128
+    qp = jnp.pad(q.transpose(1, 2, 3, 0),
+                 ((0, 0),) * 3 + ((0, -b % LANES),))
     in_specs = [
-        pl.BlockSpec((1, h, d, window),
-                     lambda bi, ki, off, pt: (bi, 0, 0, 0)),
+        # resident while the walk stays in its group of 128 slots: one
+        # buffer (a wide window's second would not fit)
+        pl.BlockSpec((window, h, d, LANES), lane_group,
+                     pipeline_mode=pl.Buffered(1)),
         pl.BlockSpec((1, h, d, block_kv), kv_block),
         pl.BlockSpec((1, h, d, block_kv), kv_block),
     ]
-    operands = [qp, k, v]
-    if quantized:
-        # scale pools redirect through the SAME page-table index map
-        # as their K/V tiles (d axis collapsed to 1)
-        for _ in range(2):
-            in_specs.append(pl.BlockSpec((1, h, 1, block_kv),
-                                         kv_block))
-        operands += [k_scale, v_scale]
-    if window == 1:
-        kernel = functools.partial(_paged_decode_kernel,
-                                   sm_scale=d ** -0.5,
-                                   block_kv=block_kv, num_kv=num_kv,
-                                   has_bias=False, ragged=True,
-                                   quantized=quantized)
-        scratch = [
-            pltpu.VMEM((h, 1), jnp.float32),
-            pltpu.VMEM((h, 1), jnp.float32),
-            pltpu.VMEM((h, d), jnp.float32),
-        ]
-    else:
-        kernel = functools.partial(_paged_verify_kernel,
-                                   sm_scale=d ** -0.5,
-                                   block_kv=block_kv, num_kv=num_kv,
-                                   window=window, ragged=True,
-                                   quantized=quantized)
-        scratch = [
-            pltpu.VMEM((window, h, 1), jnp.float32),
-            pltpu.VMEM((window, h, 1), jnp.float32),
-            pltpu.VMEM((window, h, d), jnp.float32),
-        ]
+    # scale pools redirect through the SAME page-table index map as
+    # their K/V tiles (d axis collapsed to 1)
+    in_specs += [pl.BlockSpec((1, h, 1, block_kv), kv_block)
+                 for _ in scales]
     out = pl.pallas_call(
-        kernel,
+        functools.partial(_paged_kernel, sm_scale=d ** -0.5,
+                          block_kv=block_kv, num_kv=num_kv,
+                          window=window, quantized=bool(scales)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, num_kv),
+            num_scalar_prefetch=4,
+            grid=(steps,),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (1, h, d, window),
-                lambda bi, ki, off, pt: (bi, 0, 0, 0)),
-            scratch_shapes=scratch,
+            out_specs=pl.BlockSpec((window, h, d, LANES), lane_group),
+            scratch_shapes=[
+                pltpu.VMEM((window, h, d, LANES), jnp.float32),
+                pltpu.VMEM((window, h, 1), jnp.float32),
+                pltpu.VMEM((window, h, 1), jnp.float32),
+                pltpu.VMEM((window, h, d), jnp.float32),
+            ],
         ),
-        out_shape=_sds((b, h, d, window), q.dtype, q),
-        interpret=_interpret(),
-    )(offs, pt, *operands)
-    return out.transpose(0, 3, 1, 2)
+        out_shape=_sds(qp.shape, q.dtype, q),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit),
+        interpret=interpret,
+    )(offs, pt, rows, blocks, qp, k, v, *scales)
+    # [W, h, d, b] -> [b, W, h, d]
+    return out[..., :b].transpose(3, 0, 1, 2)

@@ -33,14 +33,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _interpret, _sds
-
-#: lanes of one written block; a leaf's minor dim must tile by it
-LANES = 128
-#: what Mosaic scopes for a kernel unless told otherwise, and the most
-#: this kernel asks for (the fresh values of a wide verify window)
-VMEM_DEFAULT = 16 * 1024 * 1024
-VMEM_MOST = 48 * 1024 * 1024
+# LANES: the lanes of one written block (a leaf's minor dim must tile
+# by it) and the slots per group of the rows-on-lanes fresh values
+from .flash_attention import (
+    LANES, VMEM_DEFAULT, VMEM_MOST, _interpret, _sds,
+)
 
 
 def _kv_write_kernel(rows_ref, cols_ref, new_ref, leaf_ref, out_ref, *,

@@ -174,16 +174,22 @@ def test_flash_decode_ragged_compiles(window, int8, one_chip, as_tpu):
     assert "tpu_custom_call" in _compile(fn, *args).as_text()
 
 
+@pytest.mark.parametrize("slots,pages", [(64, 8), (256, 64)])
 @pytest.mark.parametrize("window,int8", [
     (1, False), (5, False), (9, False), (32, False), (1, True),
     (5, True)])
-def test_flash_decode_paged_compiles(window, int8, one_chip, as_tpu):
+def test_flash_decode_paged_compiles(window, int8, slots, pages,
+                                     one_chip, as_tpu):
+    """The walk over live (slot, block) pairs at the serving cell's
+    shape (64 slots, 8 pages of 128) and at 256 slots x 64 pages,
+    where the walk's two lists, the table and the offsets are 192 KB
+    of the 1 MiB of SMEM: a dynamic grid extent, four prefetched
+    scalars, the rows-on-lanes ``q`` / output blocks."""
     from paddlefleetx_tpu.ops.pallas import flash_attention as fa
-    page, pages_per_row = 128, S // 128
-    pool = B * pages_per_row + 1
-    q = _sds((B, window, H, D), BF16, one_chip)
-    off = _sds((B,), jnp.int32, one_chip)
-    table = _sds((B, pages_per_row), jnp.int32, one_chip)
+    page, pool = 128, 513
+    q = _sds((slots, window, H, D), BF16, one_chip)
+    off = _sds((slots,), jnp.int32, one_chip)
+    table = _sds((slots, pages), jnp.int32, one_chip)
     kv = [_sds((pool, H, D, page), jnp.int8 if int8 else BF16,
                one_chip)] * 2
     if int8:
@@ -235,15 +241,16 @@ def _kv_write_calls(compiled):
                           compiled.as_text()))
 
 
-def _serving_program(sh, kv_dtype="bf16"):
+def _serving_program(sh, kv_dtype="bf16", layers=1):
     """(model, params, pool, slot state, rng key, page table,
-    generation config) of the cell at one layer, as shapes on ``sh``."""
+    generation config) of the cell at ``layers`` layers, as shapes on
+    ``sh``."""
     import flax.linen as nn
 
     from paddlefleetx_tpu.models.gpt import GPTConfig, GPTForPretraining
     from paddlefleetx_tpu.models.gpt import generation as g
     cfg = GPTConfig(
-        vocab_size=50304, hidden_size=H * D, num_layers=1,
+        vocab_size=50304, hidden_size=H * D, num_layers=layers,
         num_attention_heads=H, max_position_embeddings=S,
         hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0,
         scan_layers=False, use_flash_attention=True, dtype="bfloat16",
@@ -273,10 +280,10 @@ def _pool_bytes(pool):
                for a in jax.tree.leaves(pool) if a.ndim == 4)
 
 
-def _decode_tick(sh, window, kv_dtype):
+def _decode_tick(sh, window, kv_dtype, layers=1):
     from paddlefleetx_tpu.models.gpt import generation as g
     model, params, pool, state, rng, table, gen_cfg = \
-        _serving_program(sh, kv_dtype)
+        _serving_program(sh, kv_dtype, layers)
     if window == 1:
         lowered = g.decode_step.lower(
             model, params, pool, state, rng, gen_cfg, page_table=table)
@@ -298,6 +305,40 @@ def test_decode_tick_updates_the_pool_in_place(window, kv_dtype,
     assert _kv_write_calls(compiled) == (4 if kv_dtype == "int8" else 2)
     assert _pool_copies(compiled) == 0
     assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+
+
+def _paged_decode_calls(compiled):
+    """The HLO text of every paged decode kernel in the program: the
+    unnamed Mosaic call the chip's op line shows as ``self_attn.<n>
+    custom-call`` (what ``paged_decode_roofline`` matches)."""
+    return re.findall(r"%self_attn[.\d]* = [^\n]* custom-call\([^\n]*",
+                      compiled.as_text())
+
+
+@pytest.mark.parametrize("window,kv_dtype", [
+    (1, "bf16"), (5, "bf16"), (1, "int8")])
+def test_decode_tick_walks_once_and_pads_no_lanes(window, kv_dtype,
+                                                  one_chip, as_tpu):
+    """Two layers of the cell's tick. The paged kernel is launched
+    once a layer under the name the benchmark reads; the program holds
+    no ``[64, 16, 64, W]`` array and the kernel touches none whose
+    minor dim is 1 (``q`` and the output went in and out that way: ``W``
+    padded to 128 lanes in HBM, 16.8 MB for 128 KB, and two copies a
+    layer to make and unmake it); and
+    the walk over live (slot, block) pairs is built once a tick, not
+    once a layer: the second layer adds no cumulative sum."""
+    def scans(compiled):
+        return len(re.findall(r" reduce-window\(", compiled.as_text()))
+    one, _ = _decode_tick(one_chip, window, kv_dtype, layers=1)
+    two, _ = _decode_tick(one_chip, window, kv_dtype, layers=2)
+    assert len(_paged_decode_calls(one)) == 1
+    calls = _paged_decode_calls(two)
+    assert len(calls) == 2
+    assert f"[{SLOTS},{H},{D},{window}]" not in two.as_text()
+    for text in calls:                   # operands and result alike
+        assert not re.search(r"\[[\d,]+,1\]", text), text[:400]
+    assert scans(one) >= 1
+    assert scans(two) == scans(one)
 
 
 def test_decode_tick_with_the_scatter_copies_the_pool(one_chip, as_tpu,

@@ -577,6 +577,138 @@ def test_flash_decode_paged_identity_table_matches_ragged():
                                atol=2e-6, rtol=2e-6)
 
 
+# -- the paged kernel's walk over live (slot, block) pairs --------------
+#
+# Each case is (slots, {live row: offset}, pages some rows share). A row
+# not named is dead: its page-table row is all NULL_PAGE, as
+# ``GenerationServer._sync_pt`` leaves every slot that is not decoding.
+
+_WALK_PAGES, _WALK_PAGE = 3, 128
+_WALK_CASES = {
+    "none_live": (4, {}, {}),
+    "last_row_only": (4, {3: 200}, {}),
+    "all_live_at_capacity": (4, {i: _WALK_PAGES * _WALK_PAGE - 1
+                                 for i in range(4)}, {}),
+    # on a page's last position, on the next page's first
+    "page_edges": (4, {0: 127, 1: 128, 2: 255, 3: 256}, {}),
+    # (row, logical page) -> the (row, logical page) whose physical
+    # page it maps too: prefix sharing / COW before the split
+    "shared_pages": (4, {0: 300, 1: 140, 2: 130, 3: 5},
+                     {(2, 0): (0, 0), (1, 1): (0, 1)}),
+    "nulled_between": (6, {0: 10, 2: 257, 5: 129}, {}),
+    "over_128_slots": (131, {0: 3, 5: 260, 127: 128, 128: 127,
+                             130: 381}, {}),
+    # row 128 opens the second lane group and is dead: its one step
+    # zeroes that group's output block and computes nothing
+    "lane_group_head_dead": (131, {3: 130, 129: 255}, {}),
+}
+
+
+def _walk_inputs(case, window, int8, seed=41):
+    b, offs_of, shared = _WALK_CASES[case]
+    h, d, page, mp = 2, 64, _WALK_PAGE, _WALK_PAGES
+    # a verify window's last query still has to fit the table
+    offs_of = {i: min(o, mp * page - window)
+               for i, o in offs_of.items()}
+    rng = np.random.default_rng(seed)
+    pool = 1 + mp * max(len(offs_of), 1)
+    pt = np.zeros((b, mp), np.int32)           # NULL_PAGE everywhere
+    ids = iter(rng.permutation(np.arange(1, pool)))
+    for i in offs_of:
+        pt[i] = [next(ids) for _ in range(mp)]
+    for (i, j), (i2, j2) in shared.items():
+        pt[i, j] = pt[i2, j2]
+    offs = rng.integers(0, mp * page, size=b).astype(np.int32)
+    for i, o in offs_of.items():
+        offs[i] = o
+    q = jnp.asarray(rng.normal(size=(b, window, h, d)), jnp.float32)
+    if int8:
+        k = jnp.asarray(rng.integers(-127, 128, (pool, h, d, page)),
+                        jnp.int8)
+        v = jnp.asarray(rng.integers(-127, 128, (pool, h, d, page)),
+                        jnp.int8)
+        ks = jnp.asarray(rng.uniform(0.002, 0.02, (pool, h, 1, page)),
+                         jnp.float32)
+        vs = jnp.asarray(rng.uniform(0.002, 0.02, (pool, h, 1, page)),
+                         jnp.float32)
+    else:
+        k = jnp.asarray(rng.normal(size=(pool, h, d, page)),
+                        jnp.float32)
+        v = jnp.asarray(rng.normal(size=(pool, h, d, page)),
+                        jnp.float32)
+        ks = vs = None
+    return q, k, v, ks, vs, jnp.asarray(offs), jnp.asarray(pt), \
+        sorted(offs_of)
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16kv", "int8kv"])
+@pytest.mark.parametrize("window", [1, 2, 5])
+@pytest.mark.parametrize("case", list(_WALK_CASES))
+def test_flash_decode_paged_walks_live_pairs_only(case, window, int8):
+    """The grid over live (slot, block) pairs: every live row reads
+    what the XLA oracle reads on the gathered view, every dead row
+    reads zeros, the walk holds exactly the live pairs (and one
+    zeroing step for a dead head of a lane group), and nothing outside
+    the live pairs is ever read — poison in every page past a live
+    length and in NULL_PAGE changes no output."""
+    from paddlefleetx_tpu.ops.attention import _gather_kv_pages
+    from paddlefleetx_tpu.ops.pallas import flash_attention as fa
+    q, k, v, ks, vs, offs, pt, live = _walk_inputs(case, window, int8)
+    b, page, mp = q.shape[0], _WALK_PAGE, _WALK_PAGES
+    dead = np.setdiff1d(np.arange(b), live)
+
+    def run(k, v, ks, vs):
+        return np.asarray(fa.flash_decode_paged(
+            q, k, v, offs, pt, k_scale=ks, v_scale=vs))
+    got = run(k, v, ks, vs)
+    assert np.isfinite(got).all()
+    assert not got[dead].any()                  # zeros, written
+    if live:
+        kf, vf = (k, v) if not int8 else (
+            k.astype(jnp.float32) * ks, v.astype(jnp.float32) * vs)
+        rows = jnp.asarray(live)
+        ref = _xla_attention(
+            q[rows], _gather_kv_pages(kf, pt[rows]),
+            _gather_kv_pages(vf, pt[rows]), None, True, offs[rows],
+            0.0, None, True, True, kv_cache_layout=True)
+        np.testing.assert_allclose(got[live], np.asarray(ref),
+                                   atol=1e-5, rtol=1e-5)
+    # the walk: a live row's blocks 0 .. (off + W - 1) // page in
+    # order, rows ascending; a dead row none, unless it heads a group
+    want = []
+    for i in range(b):
+        n = (int(offs[i]) + window - 1) // page + 1 if i in live else 0
+        want += [(i, kb) for kb in range(max(n, i % fa.LANES == 0))]
+    rows_, blocks_, steps = fa._paged_walk(offs, pt, window, page, mp)
+    assert int(steps) == len(want)
+    assert list(zip(np.asarray(rows_)[:len(want)].tolist(),
+                    np.asarray(blocks_)[:len(want)].tolist())) == want
+    # poison whatever no live pair reaches, NULL_PAGE included
+    reached = np.zeros(k.shape[0], bool)
+    for i in live:
+        for j in range((int(offs[i]) + window - 1) // page + 1):
+            reached[int(pt[i, j])] = True
+    assert not reached[fa.NULL_PAGE]
+    bad = jnp.asarray(~reached)[:, None, None, None]
+    if int8:
+        got2 = run(jnp.where(bad, jnp.int8(127), k),
+                   jnp.where(bad, jnp.int8(-127), v),
+                   jnp.where(bad, 1e3, ks), jnp.where(bad, 1e3, vs))
+    else:
+        got2 = run(jnp.where(bad, 1e3, k), jnp.where(bad, -1e3, v),
+                   None, None)
+    np.testing.assert_array_equal(got2, got)
+
+
+def test_null_page_is_the_allocators():
+    """The kernel reads a slot's deadness off the page the allocator
+    reserves; the two modules cannot import each other, so the number
+    is pinned here."""
+    from paddlefleetx_tpu.core import paging
+    from paddlefleetx_tpu.ops.pallas import flash_attention as fa
+    assert fa.NULL_PAGE == paging.NULL_PAGE
+
+
 def test_flash_decode_paged_rejects_bad_shapes():
     from paddlefleetx_tpu.ops.pallas.flash_attention import (
         flash_decode_paged,
@@ -599,6 +731,10 @@ def test_flash_decode_paged_rejects_bad_shapes():
         flash_decode_paged(q, k[:, :2], v[:, :2], offs, pt)
     with pytest.raises(NotImplementedError):  # page not 128-tileable
         flash_decode_paged(q, k[..., :64], v[..., :64], offs, pt)
+    with pytest.raises(NotImplementedError):  # the walk outgrows SMEM
+        flash_decode_paged(jnp.zeros((1024, 1) + q.shape[2:]), k, v,
+                           jnp.zeros((1024,), jnp.int32),
+                           jnp.zeros((1024, 64), jnp.int32))
 
 
 def test_paged_decode_dispatch_and_counter():
