@@ -39,17 +39,13 @@ fixed-size pages reached through a slot->page table
   tokens/s for running slots.
 
 Hierarchical KV cache (``host_pool_bytes``, docs/inference.md): a
-bounded pinned-host spill tier under the HBM pool. A registered
-prefix/prompt page's last reference is pinned instead of freed, and at
-the next step-entry yield point its KV is gathered on device and
-staged to host memory by a background writer thread while the
-registries keep pointing at it across the tier move
-(``PageAllocator.spill``); a later registry hit scatters the host copy
-back into a fresh HBM page (``serving/rehydrate``) instead of
-re-prefilling. Decode ticks never block on the swap, COW splits only
-ever touch HBM pages, and ``export_prefix_store`` /
-``import_prefix_store`` carry the tier across rolling restarts
-(``core/checkpoint.py`` manifest path + ``FleetRouter``).
+bounded pinned-host spill tier under the HBM pool, one
+``core/host_tier.py::HostSpillTier`` the server calls at its yield
+points. A registered prefix/prompt page's last reference spills to
+host memory instead of freeing, a later registry hit scatters it back
+into a fresh HBM page instead of re-prefilling, decode ticks never
+block on the swap, and ``export_prefix_store`` /
+``import_prefix_store`` carry the tier across rolling restarts.
 
 Speculative decoding (``GenerationConfig.spec_method``/``spec_tokens``):
 decode at small batch is latency-bound on the per-step collectives, so
@@ -74,8 +70,9 @@ the returned per-tick token buffers so committed tokens, traces, and
 histograms stay tick-accurate, paying one dispatch/fetch/schedule
 round-trip per up-to-T ticks instead of per tick — the host-overhead
 kill for latency-bound small-batch decode (docs/inference.md
-"Device-resident decode"). T=1 (the default) is byte-identical to the
-pre-loop server; any T commits the same tokens.
+"Device-resident decode"). T=1 (the default) launches the one-tick
+``decode_step``/``verify_step`` programs from the same step body; any
+T commits the same tokens.
 
 Graceful degradation (docs/robustness.md): per-request deadlines/TTL
 (``submit(deadline_s=...)`` or a server-wide ``request_ttl_s``) evict
@@ -132,9 +129,7 @@ names the phase of a slow step (docs/observability.md, "Host phases").
 from __future__ import annotations
 
 import dataclasses as _dc
-import hashlib
 import json
-import queue
 import signal
 import statistics
 import threading
@@ -152,17 +147,16 @@ from ..models.gpt.generation import (
     _unrolled_twin, activate_slot, copy_kv_pages, decode_loop,
     decode_step, gather_kv_pages, init_page_pool, init_slot_cache,
     init_slot_state, prefill_chunk_paged, prefill_into_slots,
-    scatter_kv_pages, split_kv_pages, stack_kv_pages, verify_loop,
-    verify_step,
+    scatter_kv_pages, verify_loop, verify_step,
 )
 from ..observability import metrics
 from ..observability import server as obs_server
-from ..observability import timeline
 from ..observability.recorder import FlightRecorder
 from ..observability.spans import Tracer
 from ..observability.trace import annotate, unaccounted
 from ..utils.log import logger
 from .adapters import AdapterCache, AdapterCacheFull, insert_adapter
+from .host_tier import HostSpillTier, RehydrateMiss, model_fingerprint
 from .paging import (
     NULL_PAGE, PageAllocator, PagePoolExhausted, page_prefix_keys,
     pool_pages_for_bytes, prompt_key,
@@ -176,14 +170,6 @@ class RequestShed(RuntimeError):
     server is draining, or an ``admit_fail`` fault fired. The caller
     should back off and retry elsewhere — everything already admitted
     is unaffected."""
-
-
-class _RehydrateMiss(Exception):
-    """A host page's staged bytes are gone because its spill stage
-    failed on the writer thread; the page has been evicted (reaped)
-    and admission must unwind whatever it already mapped and retry
-    the request — it re-prefills cold on the next pass. Internal to
-    the admission loop, never escapes :meth:`GenerationServer.step`."""
 
 
 def default_prefill_buckets(max_prompt_len: int) -> Tuple[int, ...]:
@@ -318,13 +304,11 @@ class GenerationServer:
             raise ValueError(
                 f"device_loop_ticks must be >= 1, got "
                 f"{device_loop_ticks}")
-        # device-resident decode: T > 1 routes step() through ONE
-        # jitted decode_loop/verify_loop launch of up to T ticks per
-        # host round-trip (docs/inference.md "Device-resident decode");
-        # T = 1 keeps the original one-tick step() path byte-for-byte
+        # device-resident decode: with T > 1 a step()'s launch is ONE
+        # jitted decode_loop/verify_loop of up to T ticks per host
+        # round-trip (docs/inference.md "Device-resident decode")
         self._loop_ticks = int(device_loop_ticks)
         self._roundtrips = 0
-        self._tiered = False
         model, params = _unrolled_twin(model, params)
         cfg = model.config
         # paged mode: explicit kwargs win, else the config's own
@@ -382,40 +366,8 @@ class GenerationServer:
                     raise ValueError(
                         f"host_pool_bytes ({host_pool_bytes}) smaller "
                         f"than one KV page")
-            self._tiered = host_pages > 0
             self._alloc = PageAllocator(cfg.kv_pool_pages, self._page,
                                         host_pages=host_pages)
-            if self._tiered:
-                self._host_pool_bytes = int(host_pool_bytes)
-                # pages whose LAST reference is held back as a spill
-                # pin until the next yield-point drain (insertion
-                # order = spill order)
-                self._spill_pin: Dict[int, None] = {}
-                # host id -> (residency generation, device_get'd page
-                # tree); shared with the spill writer thread, every
-                # access under _spill_lock. The generation tag keeps a
-                # recycled host id's stale bytes (an old spill still
-                # in the writer queue when the LRU evicted and reused
-                # the id) from ever rehydrating as the new page's KV.
-                self._host_data: Dict[int, Tuple[int, object]] = {}
-                # (hpid, gen) pairs whose device_get failed on the
-                # writer; the main loop evicts them at the next yield
-                # point (_reap_failed_spills). Under _spill_lock.
-                self._spill_failed: List[Tuple[int, int]] = []
-                # a Condition, not a bare Lock: the rehydrate slow
-                # path and prefix-store export WAIT on it for the
-                # writer's publishes instead of joining the queue, so
-                # the wait works from under the surface lock (the
-                # writer never takes that lock)
-                self._spill_lock = threading.Condition()
-                #: writer items shipped but not yet published/failed;
-                #: guarded by _spill_lock, notified on every change
-                self._spill_outstanding = 0
-                self._spill_q: queue.Queue = queue.Queue()
-                self._spill_writer_thread = threading.Thread(
-                    target=self._spill_writer, name="kv-spill-writer",
-                    daemon=True)
-                self._spill_writer_thread.start()
             self._pt = np.full((num_slots, self._max_pages), NULL_PAGE,
                                np.int32)
             self._pt_dev = jnp.asarray(self._pt)
@@ -445,7 +397,6 @@ class GenerationServer:
                 return p.astype(compute_dtype)
             params = jax.tree_util.tree_map_with_path(_cast, params)
         self.model, self.params = model, params
-        self._model_fp: Optional[str] = None
         self.gen_cfg = gen_cfg
         self.num_slots = num_slots
         # speculative decoding: the host draft source proposes, the
@@ -535,17 +486,13 @@ class GenerationServer:
         # serializes on this re-entrant lock, so a fleet worker
         # thread can drive step()/prefill_step() while the router
         # thread calls submit()/kv_*()/summary() concurrently.
-        # Blocking primitives never run under it: _drain_spills only
-        # COLLECTS writer items into _spill_outbox, and the public
-        # wrappers ship them to the spill queue after releasing the
-        # lock (_ship_spills); writer waits go through the
-        # _spill_lock condition, which the writer thread can always
-        # take.
+        # Blocking primitives never run under it: the spill tier only
+        # COLLECTS writer items under it, and the public wrappers
+        # ship them to its writer after releasing the lock
+        # (HostSpillTier.ship); writer waits go through the tier's
+        # own condition, which the writer thread can always take.
         self._surface_lock = threading.RLock()
         self._closed = False
-        #: batched writer items _drain_spills collected this entry —
-        #: surface-lock state, drained by _ship_spills
-        self._spill_outbox: List[tuple] = []
         # /healthz is answered on the metrics server's per-request
         # threads while the main loop mutates queue/slot state, so the
         # payload is an immutable snapshot the main loop republishes
@@ -564,11 +511,17 @@ class GenerationServer:
             else FaultInjector.from_env(recorder=self._recorder)
         self._watchdog = StepWatchdog.from_env(name="decode_tick",
                                                recorder=self._recorder)
-        if self._tiered:
-            # computed eagerly: the fingerprint's jax.device_get must
-            # never run under the surface lock, so the locked
-            # prefix-store paths read the cached value
-            self._model_fingerprint()
+        #: the hierarchical KV cache's host spill tier
+        #: (core/host_tier.py); None unless host_pool_bytes is set
+        self._tier: Optional[HostSpillTier] = None
+        if self.paged and self._alloc.host_pages:
+            # the fingerprint is computed here, eagerly: its
+            # jax.device_get must never run under the surface lock
+            self._tier = HostSpillTier(
+                self._alloc, int(host_pool_bytes), cfg.kv_cache_dtype,
+                model_fingerprint(cfg, self.params),
+                self._read_pages, self._write_pages, self._emit,
+                self._metrics)
         self._emit("serving_start", slots=num_slots,
                    buckets=list(buckets),
                    max_dec_len=gen_cfg.max_dec_len,
@@ -704,8 +657,7 @@ class GenerationServer:
                 return True
             if self.paged and self._prefilling:
                 return True
-            if self._tiered and (self._spill_pin or
-                                 self._spill_outbox):
+            if self._tier is not None and self._tier.work_pending():
                 return True
             if self._dead:
                 return True
@@ -919,11 +871,8 @@ class GenerationServer:
                        row=lease.row)
         if lease.tree is not None:
             # cast-on-insert: the bank leaves already carry the
-            # server's compute dtype. The unlocked params read in
-            # _model_fingerprint cannot race this write: the
-            # fingerprint is computed eagerly at __init__, before any
-            # request (or router thread) exists.
-            self.params = insert_adapter(  # pfxlint: disable=PFX301
+            # server's compute dtype
+            self.params = insert_adapter(
                 self.params, lease.tree, lease.row)
             self._emit("serving_adapter_load", adapter=aid,
                        row=lease.row, request=req["id"])
@@ -1130,15 +1079,13 @@ class GenerationServer:
                     # every spilled page of the hit comes back in ONE
                     # stacked scatter; each fresh id's refcount-1
                     # reference belongs to this request
-                    promoted = dict(zip(
-                        host_ids, self._rehydrate_many(host_ids)))
-                except _RehydrateMiss:
+                    promoted = self._rehydrate(host_ids)
+                except RehydrateMiss:
                     # a failed spill surfaced mid-batch: nothing was
                     # mapped yet (the batch allocates only once every
                     # page's bytes arrived) and the reap dropped the
                     # dead page's registrations, so the retry
                     # re-prefills cold on the next pass
-                    self._drop_evicted_host_data()
                     self._release_adapter(slot, req)
                     self._queue.appendleft(req)
                     continue
@@ -1201,13 +1148,11 @@ class GenerationServer:
             host_ids = [p for p in shared_pids
                         if self._alloc.is_host(p)]
             try:
-                promoted = dict(zip(
-                    host_ids, self._rehydrate_many(host_ids)))
-            except _RehydrateMiss:
+                promoted = self._rehydrate(host_ids)
+            except RehydrateMiss:
                 # same unwind as the prompt-hit path: the dead prefix
                 # page's registration is gone, so the retry shares
                 # fewer pages and prefills the rest
-                self._drop_evicted_host_data()
                 self._release_adapter(slot, req)
                 self._queue.appendleft(req)
                 continue
@@ -1278,13 +1223,7 @@ class GenerationServer:
             # chunk's pad tail too; that KV is never read, so hand
             # those pages straight back to the pool instead of pinning
             # them (and the registries below) until evict
-            used = -(-L // self._page)
-            if used < req["num_pages"]:
-                for j in range(used, req["num_pages"]):
-                    self._release_page(int(self._pt[slot, j]))
-                    self._pt[slot, j] = NULL_PAGE
-                req["num_pages"] = used
-                self._pt_dirty = True
+            self._trim_pages(slot, req, -(-L // self._page))
         with annotate("serving/step/prefill_harvest", ph):
             # the last real token sits at chunk row L - 1 - c0
             last = np.asarray(logits[0, L - 1 - c0])
@@ -1303,6 +1242,16 @@ class GenerationServer:
                     [int(p) for p in self._pt[slot, :req["num_pages"]]],
                     last)
 
+    def _trim_pages(self, slot: int, req: dict, used: int) -> None:
+        """Hand the slot's pages past its first ``used`` back to the
+        pool."""
+        if used < req["num_pages"]:
+            for j in range(used, req["num_pages"]):
+                self._release_page(int(self._pt[slot, j]))
+                self._pt[slot, j] = NULL_PAGE
+            req["num_pages"] = used
+            self._pt_dirty = True
+
     def _release_pages(self, slot: int) -> None:
         req = self._slots[slot]
         for j in range(req.get("num_pages", 0)):
@@ -1313,318 +1262,33 @@ class GenerationServer:
         self._pt_dirty = True
         req["num_pages"] = 0
 
-    # -- hierarchical KV cache: HBM -> pinned-host spill tier ---------
-    #
-    # With host_pool_bytes set, a REGISTERED page's last reference is
-    # never dropped outright: _release_page keeps it as a spill pin,
-    # and _drain_spills — called only at the host yield point (step
-    # entry, between device launches) — gathers the page's KV on
-    # device, moves its registrations onto a host-tier id
-    # (PageAllocator.spill) and frees the HBM page. The blocking
-    # device->host copy happens on a background writer thread
-    # (_spill_writer), so decode ticks never wait on a spill. A later
-    # registry hit rehydrates: fresh HBM page, scatter the staged
-    # bytes, move the registrations back (promote) — the same
-    # export-pin -> gather -> remap -> scatter contract as the fleet
-    # KV handoff, pointed at this server's own host tier. COW safety
-    # is structural: host ids never appear in any page table, so a
-    # divergent write can only target an HBM page and the host copy is
-    # never mutated. Thread discipline mirrors _health_lock: the
-    # writer touches ONLY the spill queue and the _spill_lock-guarded
-    # _host_data dict; allocator, cache, and telemetry stay with the
-    # main loop.
-
-    def _spill_writer(self) -> None:
-        """Background spill writer: stage each batched writer item —
-        ONE stacked :func:`gather_kv_pages` tree covering every page
-        of a yield's drain — to host memory with a single
-        ``jax.device_get`` (the device sync the decode tick must
-        never pay), split it back into per-page trees, and publish
-        each under the spill condition, tagged with its host id's
-        residency generation. The outstanding count drops and the
-        condition notifies on EVERY path, success or failure: the
-        rehydrate slow path and prefix-store export wait for
-        ``outstanding == 0`` instead of joining the queue, and a
-        writer that died mid-item must never strand them. A failed
-        stage records every page of the batch instead (the main loop
-        evicts those host pages at the next yield point, so the loss
-        surfaces as a cold re-prefill, never a hang or wrong KV).
-        ``None`` is the shutdown sentinel (:meth:`close`)."""
-        tl = timeline.track("kv-spill-writer")
-        while True:
-            t0 = tl.begin()
-            item = self._spill_q.get()
-            tl.add("idle", t0)
-            if item is None:
-                return
-            entries, data = item
-            t0 = tl.begin()
-            try:
-                host = jax.device_get(data)
-                pages = split_kv_pages(host, len(entries))
-            except Exception:
-                logger.exception(
-                    "kv-spill-writer: staging %d host pages failed; "
-                    "their KV is lost and the pages will be evicted",
-                    len(entries))
-                with self._spill_lock:
-                    self._spill_failed.extend(entries)
-                    self._spill_outstanding -= 1
-                    self._spill_lock.notify_all()
-                tl.add("spill_device_get", t0)
-                continue
-            with self._spill_lock:
-                for (hpid, gen), page in zip(entries, pages):
-                    cur = self._host_data.get(hpid)
-                    if cur is None or cur[0] <= gen:
-                        # never let a stale residency's late publish
-                        # clobber a recycled id's fresher bytes
-                        self._host_data[hpid] = (gen, page)
-                self._spill_outstanding -= 1
-                self._spill_lock.notify_all()
-            tl.add("spill_device_get", t0)
-
     def _release_page(self, pid: int) -> None:
-        """Release one reference to a slot-mapped page. In tiered mode
-        a registered page's LAST reference becomes a spill pin instead
-        of freeing — the page stays whole until :meth:`_drain_spills`
-        moves it to the host tier at the next yield point."""
-        if self._tiered and pid not in self._spill_pin and \
-                self._alloc.refcount(pid) == 1 and \
-                self._alloc.page_registered(pid):
-            self._spill_pin[pid] = None
-            return
-        self._alloc.release(pid)
-        if self._tiered:
-            self._drop_evicted_host_data()
+        """Release one reference to a slot-mapped page (a tiered
+        server keeps a registered page's last one as a spill pin)."""
+        if self._tier is not None:
+            self._tier.release(pid)
+        else:
+            self._alloc.release(pid)
 
-    def _drop_evicted_host_data(self) -> None:
-        """Forget the staged bytes of host pages the allocator evicted
-        (LRU pressure, orphan sweep, failed spill) — before their ids
-        are reused. Generation-checked: if an evicted id was already
-        recycled AND the writer already published the new residency's
-        bytes, those bytes are live and must survive this drain."""
-        evicted = self._alloc.pop_host_evicted()
-        if not evicted:
-            return
-        with self._spill_lock:
-            for hpid in evicted:
-                entry = self._host_data.get(hpid)
-                if entry is not None and \
-                        entry[0] != self._alloc.host_generation(hpid):
-                    del self._host_data[hpid]
+    # the spill tier's (and the KV handoff's) two ways to the device
+    # pages: ONE stacked dispatch each; the surface lock is re-entrant
 
-    def _reap_failed_spills(self) -> None:
-        """Evict host pages whose spill stage failed on the writer
-        thread (their bytes never reached host memory): drop the
-        registrations pointing at them so no lookup can hand out a
-        page that cannot rehydrate. Main loop only — the writer
-        records failures, it never touches the allocator."""
-        with self._spill_lock:
-            failed, self._spill_failed = self._spill_failed, []
-        for hpid, gen in failed:
-            # gen guard: the failed residency may already be gone and
-            # the id recycled — never evict the successor
-            if self._alloc.host_generation(hpid) == gen:
-                self._alloc.evict_host(hpid)
-                metrics.inc("serving/spill_failed")
-        if failed:
-            self._drop_evicted_host_data()
-
-    def _pop_host_bytes(self, hpid: int, gen: int):
-        """Pop the staged bytes of the CURRENT residency of ``hpid``,
-        or None when they are not published yet. An entry tagged with
-        an older generation is a recycled id's stale spill whose
-        publish raced the eviction drain — discard it (its residency
-        is dead) and report a miss; the writer queue is FIFO, so after
-        ``_spill_q.join()`` the live generation's bytes are the ones
-        in place."""
-        with self._spill_lock:
-            entry = self._host_data.get(hpid)
-            if entry is None:
-                return None
-            del self._host_data[hpid]
-            if entry[0] != gen:
-                return None
-            return entry[1]
-
-    def _drain_spills(self) -> None:
-        """Collect every pinned spill into ONE batched writer item:
-        per page, move its registrations to a host id and free the
-        HBM page; then gather ALL spilled pages' KV in a single
-        stacked dispatch (async — the blocking copy runs on the
-        writer thread) and append the item to the spill outbox. Runs
-        under the surface lock at the step-entry yield point only;
-        the public wrappers ship the outbox to the writer queue AFTER
-        releasing the lock (:meth:`_ship_spills`), so the queue put
-        never runs under a lock. The event-timeline contract is
-        unchanged: every ``serving_spill`` pairs with the
-        ``serving_yield`` that opened the drain. Freeing the page ids
-        before the gather is safe — nothing allocates between, and
-        later decode writes build NEW functional cache arrays while
-        the dispatched gather keeps referencing these buffers."""
-        if not self._tiered:
-            return
-        self._reap_failed_spills()
-        if not self._spill_pin:
-            return
-        self._emit("serving_yield", ticks=self._ticks,
-                   roundtrips=self._roundtrips,
-                   pending_spills=len(self._spill_pin))
-        spilled: List[int] = []
-        entries: List[Tuple[int, int]] = []
-        while self._spill_pin:
-            pid = next(iter(self._spill_pin))   # FIFO: oldest pin first
-            del self._spill_pin[pid]
-            if self._alloc.refcount(pid) > 1:
-                # re-shared while pinned: drop the pin, stay in HBM
-                self._alloc.release(pid)
-                continue
-            hpid = self._alloc.spill(pid)
-            if hpid is None:
-                # registrations died while pinned (a co-member freed);
-                # the release can cascade host evictions of its own —
-                # drain them now, not at some later call, so staged
-                # bytes never outlive their residency
-                self._alloc.release(pid)
-                self._drop_evicted_host_data()
-                continue
-            gen = self._alloc.host_generation(hpid)
-            self._drop_evicted_host_data()
-            spilled.append(pid)
-            entries.append((hpid, gen))
-            metrics.inc("serving/spill")
-            self._emit("serving_spill", page=pid, host_page=hpid,
-                       ticks=self._ticks, roundtrips=self._roundtrips)
-        if spilled:
-            data = gather_kv_pages(self._cache,
-                                   jnp.asarray(spilled, jnp.int32))
-            self._spill_outbox.append((entries, data))
-        metrics.get_registry().set_gauge(
-            "serving/host_pages", self._alloc.host_pages_resident)
-
-    def _ship_spills(self) -> None:
-        """Hand the writer items :meth:`_drain_spills` collected to
-        the spill queue. Called by the public wrappers AFTER the
-        surface lock is released — the outstanding-count bump and the
-        queue puts are the only cross-thread edges, and neither runs
-        under it."""
+    def _read_pages(self, pids: Sequence[int]):
         with self._surface_lock:
-            items, self._spill_outbox = self._spill_outbox, []
-        if not items:
-            return
-        with self._spill_lock:
-            self._spill_outstanding += len(items)
-        for item in items:
-            self._spill_q.put(item)
+            return gather_kv_pages(
+                self._cache, jnp.asarray(list(pids), jnp.int32))
 
-    #: upper bound on waiting for the writer to publish a page's
-    #: bytes at rehydrate/export time — generous next to a single
-    #: device_get, only ever reached if the writer thread died
-    _SPILL_WAIT_S = 30.0
+    def _write_pages(self, stacked, pids: Sequence[int]) -> None:
+        with self._surface_lock:
+            self._cache = scatter_kv_pages(
+                self._cache, stacked, jnp.asarray(pids, jnp.int32))
 
-    def _outbox_page(self, hpid: int, gen: int):
-        """A page's device tree from a writer item still sitting in
-        the spill outbox — a spill collected THIS step entry whose
-        ship happens only after the surface lock releases. Rehydrating
-        straight from the pending gather skips the host round trip;
-        the item stays queued untouched (its eventual publish of this
-        residency is discarded by the generation guards once the
-        promote recycles the id)."""
-        for entries, data in self._spill_outbox:
-            for i, (h, g) in enumerate(entries):
-                if h == hpid and g == gen:
-                    return split_kv_pages(data, len(entries))[i]
-        return None
-
-    def _await_host_bytes(self, hpid: int, gen: int):
-        """Wait (admission time only, never between decode ticks) for
-        the writer to publish the CURRENT residency of ``hpid`` and
-        pop it. None once the bytes are known gone: the residency's
-        failure was recorded, a fresher residency owns the id, the
-        writer went idle with nothing published, or the wait timed
-        out. Waits on the spill condition — the writer publishes
-        under it and never takes the surface lock, so waiting here
-        from under the surface lock cannot deadlock."""
-        deadline = time.monotonic() + self._SPILL_WAIT_S
-        with self._spill_lock:
-            while True:
-                entry = self._host_data.get(hpid)
-                if entry is not None:
-                    if entry[0] == gen:
-                        del self._host_data[hpid]
-                        return entry[1]
-                    if entry[0] < gen:
-                        # a recycled id's stale spill raced the
-                        # eviction drain: discard, keep waiting
-                        del self._host_data[hpid]
-                    else:
-                        return None   # this residency is dead
-                elif (hpid, gen) in self._spill_failed:
-                    return None
-                elif self._spill_outstanding == 0:
-                    return None
-                if time.monotonic() >= deadline:
-                    return None
-                self._spill_lock.wait(timeout=0.05)
-
-    def _rehydrate_many(self, hpids: Sequence[int]) -> List[int]:
-        """Bring N host-resident pages back into HBM with ONE stacked
-        scatter: pop (or await) every page's staged bytes, allocate N
-        fresh page ids, scatter the stacked tree in a single
-        dispatch, and move each page's registrations back. Every
-        fresh page's refcount-1 reference belongs to the admitting
-        request; the callers check ``free_pages`` first, so the
-        allocs always succeed. Raises :class:`_RehydrateMiss` — with
-        every already-popped page's bytes restored, those residencies
-        stay live — when any page's stage failed; the caller unwinds
-        and retries cold."""
-        if not hpids:
-            return []
-        t0 = time.time()
-        popped: List[Tuple[int, int, object]] = []
-        miss: Optional[int] = None
-        for hpid in hpids:
-            gen = self._alloc.host_generation(hpid)
-            data = self._pop_host_bytes(hpid, gen)
-            if data is None:
-                data = self._outbox_page(hpid, gen)
-            if data is None:
-                data = self._await_host_bytes(hpid, gen)
-            if data is None:
-                miss = hpid
-                break
-            popped.append((hpid, gen, data))
-        if miss is not None:
-            with self._spill_lock:
-                for hpid, gen, data in popped:
-                    self._host_data[hpid] = (gen, data)
-            # the one legitimate way here: the spill's device_get
-            # failed on the writer after this page was looked up but
-            # before the failure was reaped. Reap now (evicts the
-            # page, drops its registrations) and let admission unwind
-            # — the prompt re-prefills cold. Anything else is an
-            # invariant bug and must fail loudly.
-            self._reap_failed_spills()
-            if self._alloc.is_host(miss):
-                raise RuntimeError(
-                    f"host page {miss} resident but its bytes are "
-                    f"gone")
-            raise _RehydrateMiss(miss)
-        pids = self._alloc.alloc_many(len(popped))
-        stacked = stack_kv_pages([d for _, _, d in popped])
-        self._cache = scatter_kv_pages(
-            self._cache, stacked, jnp.asarray(pids, jnp.int32))
-        for (hpid, _, _), pid in zip(popped, pids):
-            self._alloc.promote(hpid, pid)
-            self._emit("serving_rehydrate", host_page=hpid, page=pid,
-                       ticks=self._ticks)
-        metrics.inc("serving/rehydrate", len(pids))
-        self._metrics.observe("serving/rehydrate_ms",
-                              (time.time() - t0) * 1000.0)
-        metrics.get_registry().set_gauge(
-            "serving/host_pages", self._alloc.host_pages_resident)
-        return pids
+    def _rehydrate(self, host_ids: List[int]) -> Dict[int, int]:
+        """Host id -> the fresh HBM page its KV came back into."""
+        if not host_ids:
+            return {}
+        return dict(zip(host_ids,
+                        self._tier.rehydrate(host_ids, self._ticks)))
 
     def _alloc_or_preempt(self, needy_slot: int) -> int:
         """A free page, preempting the youngest OTHER occupied slot
@@ -1633,14 +1297,9 @@ class GenerationServer:
         always grow to its maximum length, so this terminates."""
         pid = self._alloc.try_alloc()
         while pid is None:
-            if self._tiered and self._spill_pin:
-                # a pinned to-be-spilled page is idle KV: reclaiming
-                # it costs one lost spill, never a preemption (and
-                # keeps the pin set from deadlocking the pool)
-                held = next(iter(self._spill_pin))
-                del self._spill_pin[held]
-                self._alloc.release(held)
-                self._drop_evicted_host_data()
+            if self._tier is not None and self._tier.reclaim_pin():
+                # a pinned to-be-spilled page went back to the pool:
+                # one lost spill, never a preemption
                 pid = self._alloc.try_alloc()
                 continue
             victims = [s for s, r in enumerate(self._slots)
@@ -1699,7 +1358,7 @@ class GenerationServer:
         shared pages copy-on-write (device page copy + host refcount
         handoff) at the first divergent write. Pages mapped for window
         positions past a verify tick's accepted point are returned to
-        the pool by the post-tick rollback in :meth:`step`."""
+        the pool by the post-launch rollback in :meth:`_run_step`."""
         for slot in range(self.num_slots):
             req = self._slots[slot]
             if req is None or not req.get("active"):
@@ -1872,7 +1531,8 @@ class GenerationServer:
                         self._alloc.pages_in_use)
                 progress = rec.queued != q0 or rec.chunks > 0
             with annotate("serving/step/ship_spills", rec.phases):
-                self._ship_spills()
+                if self._tier is not None:
+                    self._tier.ship()
         with self._surface_lock:
             self._account_step(rec)
         return progress
@@ -1921,9 +1581,7 @@ class GenerationServer:
         whatever the page count. Hand it to a peer's
         :meth:`kv_import` directly (same devices, the d2d path) or
         via ``jax.device_get`` (host-staged, foreign mesh)."""
-        with self._surface_lock:
-            return gather_kv_pages(self._cache,
-                                   jnp.asarray(list(pages), jnp.int32))
+        return self._read_pages(pages)
 
     def kv_import(self, tokens: Sequence[int], page_data,
                   last_logits, n_pages: int) -> bool:
@@ -1961,8 +1619,7 @@ class GenerationServer:
                     < self._max_pages:
                 return False
             pids = self._alloc.alloc_many(n_pages)
-            self._cache = scatter_kv_pages(
-                self._cache, page_data, jnp.asarray(pids, jnp.int32))
+            self._write_pages(page_data, pids)
             for j, kk in enumerate(page_prefix_keys(seq, self._page)):
                 self._alloc.register_prefix(kk, pids[j])
             self._alloc.register_prompt(
@@ -1983,178 +1640,35 @@ class GenerationServer:
             for pid in pids or ():
                 self._release_page(pid)
 
-    # -- restart-persistent prefix store ------------------------------
-    #
-    # A drained tiered server's shareable KV is (by construction) all
-    # host-resident: every registered page released to its last
-    # reference spilled. export_prefix_store snapshots that tier —
-    # staged bytes + the registry entries that reach them — as a
-    # plain dict; core/checkpoint.py's save/load_prefix_store round it
-    # through a committed-last manifest directory, and
-    # FleetRouter.restart_replica hands it to the restarted replica's
-    # import_prefix_store so it serves its first request warm.
-
-    def _model_fingerprint(self) -> str:
-        """Identity of the model this server serves: a digest over
-        the config plus every parameter leaf's path, shape, dtype and
-        fp32 sum — cheap (one scalar reduction per leaf, one host
-        transfer), deterministic, and different whenever the weights
-        are. Stamped into every exported prefix store and checked on
-        import, so KV persisted under one deploy can never warm-start
-        a model with different weights. Computed once and cached."""
-        if self._model_fp is None:
-            h = hashlib.sha256()
-            cfg = self.model.config
-            cfg_d = _dc.asdict(cfg) if _dc.is_dataclass(cfg) \
-                else vars(cfg)
-            h.update(json.dumps({k: str(v) for k, v in cfg_d.items()},
-                                sort_keys=True).encode())
-            leaves = jax.tree_util.tree_flatten_with_path(
-                self.params)[0]
-            sums = jax.device_get(
-                [jnp.sum(jnp.asarray(leaf, jnp.float32))
-                 for _, leaf in leaves])
-            for (path, leaf), s in zip(leaves, sums):
-                h.update(jax.tree_util.keystr(path).encode())
-                h.update(str((tuple(leaf.shape),
-                              str(leaf.dtype))).encode())
-                h.update(np.float32(s).tobytes())
-            self._model_fp = h.hexdigest()[:16]
-        return self._model_fp
-
-    def _await_spill_writer(self) -> None:
-        """Wait (bounded) for the writer to finish every shipped item
-        — the prefix-store export's quiesce point, replacing the old
-        queue join. Runs at an UNLOCKED position: the writer never
-        needs the surface lock, but waiting under it would still
-        stall a concurrently ticking fleet worker for the whole
-        device_get."""
-        deadline = time.monotonic() + self._SPILL_WAIT_S
-        with self._spill_lock:
-            while self._spill_outstanding > 0 and \
-                    time.monotonic() < deadline:
-                self._spill_lock.wait(timeout=0.05)
+    # -- restart-persistent prefix store (core/host_tier.py) ----------
 
     def export_prefix_store(self) -> Optional[dict]:
-        """Snapshot the host tier for a restart warm start: drain any
-        pending spill pins first (a just-drained server's shareable
-        pages are still pinned), ship the batch and wait out the
-        writer, and return page bytes (flat numpy leaf lists in cache
-        tree order) plus the host-resident registry entries. None on
-        non-tiered servers."""
+        """Snapshot the host tier for a restart warm start: collect
+        any pending spill pins first (a just-drained server's
+        shareable pages are still pinned), ship the batch and wait
+        out the writer — both with the surface lock released — and
+        return page bytes (flat numpy leaf lists in cache tree order)
+        plus the host-resident registry entries. None on non-tiered
+        servers."""
+        if self._tier is None:
+            return None
         with self._surface_lock:
-            if not self.paged or not self._tiered:
-                return None
-            self._drain_spills()
-        self._ship_spills()
-        self._await_spill_writer()
+            self._tier.collect(self._ticks, self._roundtrips)
+        self._tier.ship()
+        self._tier.await_writer()
         with self._surface_lock:
-            return self._export_prefix_store_impl()
-
-    def _export_prefix_store_impl(self) -> dict:
-        # the writer quiesce flushed every publish AND every failure
-        # record — reap now so dead pages drop out of the snapshot
-        self._reap_failed_spills()
-        prefixes, prompts = self._alloc.host_snapshot()
-        needed = set(prefixes.values())
-        for pages, _ in prompts.values():
-            needed.update(pages)
-        with self._spill_lock:
-            data = {h: self._host_data[h][1] for h in needed
-                    if h in self._host_data and self._host_data[h][0]
-                    == self._alloc.host_generation(h)}
-        cfg = self.model.config
-        store = {
-            "page_size": self._page,
-            "kv_cache_dtype": cfg.kv_cache_dtype,
-            # cached at construction (tiered servers fingerprint
-            # eagerly) — the device_get inside _model_fingerprint
-            # must not run under the surface lock
-            "model_fingerprint": self._model_fp,
-            "pages": {h: jax.tree_util.tree_leaves(t)
-                      for h, t in data.items()},
-            "prefixes": {k: h for k, h in prefixes.items()
-                         if h in data},
-            "prompts": {k: (pages, payload)
-                        for k, (pages, payload) in prompts.items()
-                        if all(p in data for p in pages)},
-        }
-        self._emit("serving_prefix_store_export",
-                   pages=len(store["pages"]),
-                   prefixes=len(store["prefixes"]),
-                   prompts=len(store["prompts"]))
-        return store
+            return self._tier.export_store()
 
     def import_prefix_store(self, store: Optional[dict]) -> int:
-        """Adopt an exported prefix store on a fresh server (the
-        restart warm start): fill free host slots with the saved pages
-        and re-register their content keys, so the next admission of
-        a covered prompt rehydrates instead of re-prefilling. A
-        geometry mismatch (page size, KV dtype) imports nothing — the
-        bytes would be garbage — and so does a model-identity
-        mismatch: KV computed by DIFFERENT weights under identical
-        geometry scatters cleanly but serves silently wrong
-        attention, the one failure mode a disk round-trip across
-        deploys invites. Returns the pages adopted."""
+        """Adopt an exported prefix store on a fresh server, so the
+        next admission of a covered prompt rehydrates instead of
+        re-prefilling. A store of another geometry or another model
+        (fingerprint) imports nothing. Returns the pages adopted."""
+        if self._tier is None:
+            return 0
         with self._surface_lock:
-            return self._import_prefix_store_impl(store)
-
-    def _import_prefix_store_impl(self, store: Optional[dict]) -> int:
-        if not store or not self.paged or not self._tiered:
-            return 0
-        cfg = self.model.config
-        if store.get("page_size") != self._page or \
-                store.get("kv_cache_dtype") != cfg.kv_cache_dtype:
-            logger.warning(
-                "prefix store geometry mismatch (page %s dtype %s vs "
-                "page %d dtype %s): starting cold",
-                store.get("page_size"), store.get("kv_cache_dtype"),
-                self._page, cfg.kv_cache_dtype)
-            return 0
-        fp = self._model_fp
-        if store.get("model_fingerprint") != fp:
-            logger.warning(
-                "prefix store model fingerprint mismatch (%s vs %s): "
-                "its KV was computed by different weights — starting "
-                "cold", store.get("model_fingerprint"), fp)
-            return 0
-        treedef = jax.tree_util.tree_structure(self._cache)
-        remap: Dict[int, int] = {}
-
-        def _adopt(old: int) -> Optional[int]:
-            if old in remap:
-                return remap[old]
-            leaves = store["pages"].get(old)
-            if leaves is None:
-                return None
-            hpid = self._alloc.host_import()
-            if hpid is None:   # tier full: import what fits, stop
-                return None
-            gen = self._alloc.host_generation(hpid)
-            with self._spill_lock:
-                self._host_data[hpid] = (
-                    gen, jax.tree_util.tree_unflatten(treedef, leaves))
-            remap[old] = hpid
-            return hpid
-
-        for key, old in store.get("prefixes", {}).items():
-            hpid = _adopt(old)
-            if hpid is not None:
-                self._alloc.register_prefix(key, hpid)
-        for key, (pages, payload) in store.get("prompts", {}).items():
-            new_pages = [_adopt(p) for p in pages]
-            if all(p is not None for p in new_pages):
-                self._alloc.register_prompt(key, new_pages, payload)
-        # a page adopted for a prompt entry that then failed to fully
-        # remap may be unreachable — evict such orphans right away
-        self._alloc.sweep_host_orphans()
-        self._drop_evicted_host_data()
-        adopted = self._alloc.host_pages_resident
-        metrics.get_registry().set_gauge("serving/host_pages", adopted)
-        self._emit("serving_prefix_store_import", pages=adopted,
-                   prefixes=len(store.get("prefixes", {})),
-                   prompts=len(store.get("prompts", {})))
-        return adopted
+            return self._tier.import_store(
+                store, jax.tree_util.tree_structure(self._cache))
 
     # -- the serving loop ---------------------------------------------
 
@@ -2166,7 +1680,7 @@ class GenerationServer:
         partials). While draining, admission is skipped.
 
         With ``device_loop_ticks > 1`` one call runs up to that many
-        ticks in a single fused device program (:meth:`_step_loop`) —
+        ticks in a single fused device program (:meth:`_launch`) —
         same committed tokens, T× fewer host round-trips.
 
         Thread-safe: the whole tick runs under the surface lock;
@@ -2178,14 +1692,10 @@ class GenerationServer:
             with self._surface_lock:
                 if self._closed:
                     return []
-                if self._loop_ticks > 1:
-                    out = self._step_loop(rec)
-                    with annotate("serving/step/commit", rec.phases):
-                        self._refresh_health()
-                else:
-                    out = self._step_impl(rec)
+                out = self._run_step(rec)
             with annotate("serving/step/ship_spills", rec.phases):
-                self._ship_spills()
+                if self._tier is not None:
+                    self._tier.ship()
         with self._surface_lock:
             self._account_step(rec)
         return out
@@ -2250,7 +1760,8 @@ class GenerationServer:
             # place pinned spills move to the host tier (decode never
             # blocks); a pending pin capped the previous fused launch
             # at one tick via _loop_host_flag
-            self._drain_spills()
+            if self._tier is not None:
+                self._tier.collect(self._ticks, self._roundtrips)
         with annotate("serving/step/admit", ph):
             if not self._draining:
                 self._admit()
@@ -2269,169 +1780,30 @@ class GenerationServer:
                 self._sync_aid()
         return expired, live
 
-    def _count_decode_walk(self, live: List[int], window: int,
-                           ahead=None) -> None:
+    def _count_decode_walk(self, live: List[int], window: int) -> None:
         """One tick of the paged decode kernel, as the host knows it
-        without a device read: the slots it walks of those it was
-        launched for, and the pages (its blocks, at the cells' page
-        size) it walks of the table's capacity. Of ``live`` only the
-        slots still active count (page maintenance may have preempted
-        one: its row went down nulled); ``ahead [slots]`` are tokens a
-        fused launch committed in its earlier ticks."""
-        lengths = [
-            req["cur_len"] + (int(ahead[s]) if ahead is not None else 0)
-            for s in live
-            if (req := self._slots[s]) is not None and req.get("active")]
+        without a device read: the slots it walks (``live``, at their
+        lengths before the tick) of those it was launched for, and
+        the pages (its blocks, at the cells' page size) it walks of
+        the table's capacity."""
         last = self._max_pages - 1
-        metrics.inc("serving/decode_rows_live", len(lengths))
+        metrics.inc("serving/decode_rows_live", len(live))
         metrics.inc("serving/decode_rows_slots", self.num_slots)
         metrics.inc("serving/decode_blocks_live", sum(
-            min((n + window - 1) // self._page, last) + 1
-            for n in lengths))
+            min((self._slots[s]["cur_len"] + window - 1) // self._page,
+                last) + 1
+            for s in live))
         metrics.inc("serving/decode_blocks_capacity",
                     self.num_slots * self._max_pages)
 
-    def _idle_step(self, rec: StepRecord, expired: List[Completion]
-                   ) -> List[Completion]:
-        """The end of a step with nothing decodable yet (empty, or
-        every occupant is still mid-chunked-prefill) — the pump still
-        made progress."""
-        with annotate("serving/step/commit", rec.phases):
-            metrics.get_registry().set_gauge(
-                "serving/slot_occupancy", self.occupancy)
-            return expired + self._take_dead()
-
-    def _step_impl(self, rec: StepRecord) -> List[Completion]:
-        ph = rec.phases
-        expired, live = self._schedule(rec)
-        if not live:
-            return self._idle_step(rec, expired)
-        if self._watchdog is not None:
-            self._watchdog.arm(tag=f"tick {self._ticks + 1}")
-        k = self._spec_k if self.spec else 0
-        if self.spec:
-            with annotate("serving/step/draft", ph):
-                # host drafts ride down with the tick; inactive rows
-                # are zeros the verify mask never commits
-                drafts = np.zeros((self.num_slots, k), np.int32)
-                for slot in live:
-                    req = self._slots[slot]
-                    drafts[slot] = self._draft.propose(
-                        req["prompt"] + req["tokens"], k)
-        if self.paged:
-            with annotate("serving/step/page_maintenance", ph):
-                # growth/COW decisions against the PRE-tick lengths,
-                # over the tick's whole write window (k+1 tokens
-                # speculative) — then one table upload
-                self._page_maintenance(window=k + 1)
-            with annotate("serving/step/table_sync", ph):
-                self._sync_pt()
-        with annotate("serving/step/decode_dispatch", ph):
-            pt = self._pt_dev_dec if self.paged else None
-            if self.spec:
-                self._cache, self._state, window, counts = \
-                    verify_step(
-                        self.model, self.params, self._cache,
-                        self._state, jnp.asarray(drafts), self._rng,
-                        self.gen_cfg, pt, self._aid_arg())
-            else:
-                self._cache, self._state, tok = decode_step(
-                    self.model, self.params, self._cache,
-                    self._state, self._rng, self.gen_cfg, pt,
-                    self._aid_arg())
-        with annotate("serving/step/decode_harvest", ph):
-            # the host blocked on the tick
-            if self.spec:
-                window = np.asarray(window)
-                counts = np.asarray(counts)
-            else:
-                window = np.asarray(tok)[:, None]
-                counts = np.ones((self.num_slots,), np.int32)
-        with annotate("serving/step/state_fetch", ph):
-            finished = np.asarray(self._state.finished)
-            dec_count = np.asarray(self._state.dec_count)
-        with annotate("serving/step/commit", ph):
-            if self._watchdog is not None:
-                self._watchdog.disarm()
-            self._ticks += 1
-            self._roundtrips += 1
-            rec.ticks = 1
-            metrics.inc("serving/device_ticks")
-            if self.paged:
-                self._count_decode_walk(live, k + 1)
-            reg = metrics.get_registry()
-            done: List[Completion] = []
-            now = time.time()
-            committed = 0
-            ticked = 0
-            for slot in live:
-                req = self._slots[slot]
-                if req is None or \
-                        (self.paged and not req.get("active")):
-                    # preempted out from under the tick by page
-                    # maintenance (pool exhaustion) — nothing committed
-                    continue
-                ticked += 1
-                m = int(counts[slot])
-                req["tokens"].extend(int(t) for t in window[slot, :m])
-                if "ttft" not in req:
-                    req["ttft"] = now - req["submit_t"]
-                    req["first_tok_t"] = now
-                    self._metrics.observe("serving/ttft_ms",
-                                          req["ttft"] * 1000.0)
-                    req["span"].span_point(
-                        "serving/first_token",
-                        ttft_ms=round(req["ttft"] * 1000.0, 3))
-                if self.paged:
-                    req["cur_len"] += m
-                    if self.spec:
-                        # rejected-KV rollback: pages wholly past the
-                        # accepted point go straight back to the pool
-                        # (the partial page's stale columns sit past
-                        # cur_len and are overwritten before any
-                        # masked read)
-                        used = -(-req["cur_len"] // self._page)
-                        if used < req["num_pages"]:
-                            for j in range(used, req["num_pages"]):
-                                self._release_page(
-                                    int(self._pt[slot, j]))
-                                self._pt[slot, j] = NULL_PAGE
-                            req["num_pages"] = used
-                            self._pt_dirty = True
-                committed += m
-                self._decode_tokens += m
-                if finished[slot]:
-                    done.append(self._evict(slot, "eos"))
-                elif dec_count[slot] >= self.gen_cfg.max_dec_len:
-                    done.append(self._evict(slot, "length"))
-            rec.tokens = committed
-            metrics.inc("serving/decode_tokens", committed)
-            if self.spec:
-                drafted = self._spec_k * ticked
-                accepted = committed - ticked      # t0s are not drafts
-                self._spec_drafted += drafted
-                self._spec_accepted += accepted
-                metrics.inc("serving/spec_drafted", drafted)
-                metrics.inc("serving/spec_accepted", accepted)
-                reg.set_gauge(
-                    "serving/spec_accept_rate",
-                    self._spec_accepted / max(self._spec_drafted, 1))
-                self._emit("serving_spec", drafted=drafted,
-                           accepted=accepted, committed=committed)
-            reg.set_gauge("serving/slot_occupancy", self.occupancy)
-            return expired + self._take_dead() + done
-
-    # -- device-resident decode (device_loop_ticks > 1) ---------------
+    # -- the decoding step --------------------------------------------
     #
-    # One step() call launches ONE fused decode_loop/verify_loop of up
-    # to T ticks; the host amortizes admission, drafting, deadline/TTL
-    # checks, page maintenance, and telemetry over the ticks it gets
-    # back. The loop exits early (ticks_run < T) when a slot finishes
-    # or runs out of budget — eviction can't wait — or when the host
-    # flagged pending scheduling work at launch, in which case exactly
-    # one tick runs and the host resumes control, so drain(max_ticks)
-    # and chunked prefill keep their one-unit-of-progress-per-step
-    # contracts.
+    # One step() makes ONE device launch: a one-tick program, or with
+    # device_loop_ticks > 1 a fused loop (module docstring,
+    # "Device-resident decode"); when the host flags pending
+    # scheduling work the loop runs exactly one tick, so
+    # drain(max_ticks) and chunked prefill keep their
+    # one-unit-of-progress-per-step contracts.
 
     def _loop_host_flag(self, live: List[int]) -> bool:
         """Should the fused loop hand control back after ONE tick?
@@ -2444,14 +1816,12 @@ class GenerationServer:
         page pool can't cover the full T-tick write window for every
         live slot without preempting (better one short loop than an
         avoidable preemption)."""
-        if self._draining:
-            return True
-        if self._queue:
+        if self._draining or self._queue:
             return True
         if self.paged:
             if self._prefilling:
                 return True
-            if self._tiered and self._spill_pin:
+            if self._tier is not None and self._tier.pinned:
                 # pinned spills drain at step entry — exit after one
                 # tick so the writer gets its work this round-trip
                 return True
@@ -2467,78 +1837,121 @@ class GenerationServer:
                     if j >= req["num_pages"] or self._alloc.refcount(
                             int(self._pt[slot, j])) > 1:
                         need += 1   # fresh map, or a COW split's copy
-            if need > self._alloc.free_pages:
-                return True
+            return need > self._alloc.free_pages
         return False
 
-    def _step_loop(self, rec: StepRecord) -> List[Completion]:
-        """The ``device_loop_ticks > 1`` body of :meth:`step`: one
-        fused multi-tick launch, then a per-tick replay of the
-        returned token buffers so ``serving/decode_tokens``, TTFT/TPOT
-        timestamps (interpolated across the loop's wall time),
-        ``serving/tick_ms`` and the per-tick ``serving_spec`` events
-        stay tick-accurate. Greedy/seeded output is token-exact vs the
-        T=1 path (tests/test_serving.py parity matrix)."""
+    def _launch(self, drafts, host_flag: bool):
+        """The step's one device launch, and the only place that
+        knows which of the four tick programs runs. Hands back device
+        arrays of the tokens (``slots x ticks x (k+1)`` of them,
+        whatever their shape) and, speculative only, the counts
+        committed per tick; the ticks run; the loop's exit code
+        (None from a one-tick program, which has none)."""
+        T = self._loop_ticks
+        pt = self._pt_dev_dec if self.paged else None
+        if T == 1:
+            if self.spec:
+                self._cache, self._state, window, counts = \
+                    verify_step(
+                        self.model, self.params, self._cache,
+                        self._state, jnp.asarray(drafts[:, 0]),
+                        self._rng, self.gen_cfg, pt, self._aid_arg())
+                return window, counts, 1, None
+            self._cache, self._state, tok = decode_step(
+                self.model, self.params, self._cache,
+                self._state, self._rng, self.gen_cfg, pt,
+                self._aid_arg())
+            return tok, None, 1, None
+        if self.spec:
+            (self._cache, self._state, window, counts,
+             ticks_run, exit_code) = verify_loop(
+                self.model, self.params, self._cache, self._state,
+                jnp.asarray(drafts), self._rng, self.gen_cfg,
+                jnp.int32(host_flag), pt, self._aid_arg(),
+                loop_ticks=T)
+            return window, counts, ticks_run, exit_code
+        (self._cache, self._state, tokens, ticks_run,
+         exit_code) = decode_loop(
+            self.model, self.params, self._cache, self._state,
+            self._rng, self.gen_cfg, jnp.int32(host_flag), pt,
+            self._aid_arg(), loop_ticks=T)
+        return tokens, None, ticks_run, exit_code
+
+    def _run_step(self, rec: StepRecord) -> List[Completion]:
+        """The body of :meth:`step`: schedule, draft, map pages,
+        launch, then replay what came back one tick at a time so
+        ``serving/decode_tokens``, the TTFT stamps (a fused launch's
+        are interpolated across its wall time), ``serving/tick_ms``
+        and the per-tick ``serving_spec`` events stay tick-accurate.
+        Greedy/seeded output is token-exact whatever
+        ``device_loop_ticks`` is (tests/test_serving.py parity
+        matrix)."""
         ph = rec.phases
         expired, live = self._schedule(rec)
         if not live:
-            return self._idle_step(rec, expired)
+            # nothing decodable yet (empty, or every occupant is still
+            # mid-chunked-prefill) — the pump still made progress
+            with annotate("serving/step/commit", ph):
+                metrics.get_registry().set_gauge(
+                    "serving/slot_occupancy", self.occupancy)
+                self._refresh_health()
+                return expired + self._take_dead()
         T = self._loop_ticks
-        with annotate("serving/step/page_maintenance", ph):
-            host_flag = self._loop_host_flag(live)
+        k = self._spec_k if self.spec else 0
+        if self._watchdog is not None:
+            self._watchdog.arm(
+                tag=f"ticks {self._ticks + 1}..{self._ticks + T}")
+        host_flag = False
+        if T > 1:
+            with annotate("serving/step/page_maintenance", ph):
+                host_flag = self._loop_host_flag(live)
         # flag up -> the loop exits after one tick, so drafting and
         # page pre-mapping cover one tick's window only (the launch
         # shape stays [slots, T, ...]: loop_ticks is static, the flag
         # is traced, nothing recompiles)
         eff_ticks = 1 if host_flag else T
-        if self._watchdog is not None:
-            self._watchdog.arm(
-                tag=f"ticks {self._ticks + 1}..{self._ticks + T}")
-        k = self._spec_k if self.spec else 0
+        if self.paged:
+            with annotate("serving/step/page_maintenance", ph):
+                # growth/COW decisions against the PRE-launch lengths,
+                # over the launch's whole write window. A slot
+                # preempted out from under the launch (pool
+                # exhaustion) goes down nulled: nothing is drafted
+                # for it and nothing of it is committed
+                self._page_maintenance(window=eff_ticks * (k + 1))
+                live = [s for s in live
+                        if (req := self._slots[s]) is not None
+                        and req.get("active")]
+        drafts = None
         if self.spec:
             with annotate("serving/step/draft", ph):
+                # host drafts ride down with the launch, k per tick,
+                # all proposed from the pre-launch history (tick j
+                # verifies chunk j); inactive rows are zeros the
+                # verify mask never commits
                 drafts = np.zeros((self.num_slots, T, k), np.int32)
                 for slot in live:
                     req = self._slots[slot]
-                    # k·T drafts per round-trip, all proposed from the
-                    # pre-loop history; tick j verifies chunk j
                     drafts[slot, :eff_ticks] = np.asarray(
                         self._draft.propose(
                             req["prompt"] + req["tokens"],
                             k * eff_ticks),
                         np.int32).reshape(eff_ticks, k)
         if self.paged:
-            with annotate("serving/step/page_maintenance", ph):
-                self._page_maintenance(window=eff_ticks * (k + 1))
             with annotate("serving/step/table_sync", ph):
                 self._sync_pt()
         with annotate("serving/step/decode_dispatch", ph):
-            pt = self._pt_dev_dec if self.paged else None
-            if self.spec:
-                (self._cache, self._state, window_buf, counts_buf,
-                 ticks_run, exit_code) = verify_loop(
-                    self.model, self.params, self._cache, self._state,
-                    jnp.asarray(drafts), self._rng, self.gen_cfg,
-                    jnp.int32(host_flag), pt, self._aid_arg(),
-                    loop_ticks=T)
-            else:
-                (self._cache, self._state, tokens_buf, ticks_run,
-                 exit_code) = decode_loop(
-                    self.model, self.params, self._cache, self._state,
-                    self._rng, self.gen_cfg, jnp.int32(host_flag), pt,
-                    self._aid_arg(), loop_ticks=T)
+            tokens, counts, ticks_run, exit_code = self._launch(
+                drafts, host_flag)
         with annotate("serving/step/decode_harvest", ph):
             # the host blocked on the launch
             n_ticks = int(ticks_run)
+            window = np.asarray(tokens).reshape(
+                self.num_slots, T, k + 1)
             if self.spec:
-                window_np = np.asarray(window_buf)
-                counts_np = np.asarray(counts_buf)
+                counts = np.asarray(counts).reshape(self.num_slots, T)
             else:
-                window_np = np.asarray(tokens_buf)[:, :, None]
-                counts_np = np.zeros((self.num_slots, T), np.int32)
-                counts_np[:, :n_ticks] = 1
-            exit_code = int(exit_code)
-            t_end = time.time()
+                counts = np.zeros((self.num_slots, T), np.int32)
+                counts[:, :n_ticks] = 1
         with annotate("serving/step/state_fetch", ph):
             finished = np.asarray(self._state.finished)
             dec_count = np.asarray(self._state.dec_count)
@@ -2549,39 +1962,32 @@ class GenerationServer:
             self._roundtrips += 1
             rec.ticks = n_ticks
             metrics.inc("serving/device_ticks", n_ticks)
-            metrics.inc(
-                "serving/loop_exit/finished"
-                if exit_code == LOOP_EXIT_FINISHED
-                else "serving/loop_exit/budget"
-                if exit_code == LOOP_EXIT_BUDGET
-                else ("serving/loop_exit/drain" if self._draining
-                      else "serving/loop_exit/admission"))
+            if exit_code is not None:
+                exit_code = int(exit_code)
+                metrics.inc(
+                    "serving/loop_exit/finished"
+                    if exit_code == LOOP_EXIT_FINISHED
+                    else "serving/loop_exit/budget"
+                    if exit_code == LOOP_EXIT_BUDGET
+                    else ("serving/loop_exit/drain" if self._draining
+                          else "serving/loop_exit/admission"))
             reg = metrics.get_registry()
+            # a launch is one opaque device program; the stamps of a
+            # fused one's earlier ticks interpolate its wall time so
+            # TTFT/TPOT stay comparable across device_loop_ticks
+            now = time.time()
             per_tick_s = rec.tick_seconds() / n_ticks
-            done: List[Completion] = []
             committed = 0
             for j in range(n_ticks):
-                # the loop is one opaque device program; per-tick
-                # timestamps interpolate its wall time so TTFT/TPOT
-                # stay comparable with the T=1 histograms
-                t_j = t_end - (n_ticks - 1 - j) * per_tick_s
+                t_j = now - (n_ticks - 1 - j) * per_tick_s
                 if self.paged:
-                    self._count_decode_walk(
-                        live, k + 1, counts_np[:, :j].sum(axis=1))
+                    self._count_decode_walk(live, k + 1)
                 tick_committed = 0
-                ticked = 0
                 for slot in live:
                     req = self._slots[slot]
-                    if req is None or \
-                            (self.paged and not req.get("active")):
-                        # preempted out from under the launch by page
-                        # pre-mapping (pool exhaustion) — nothing
-                        # committed
-                        continue
-                    ticked += 1
-                    m = int(counts_np[slot, j])
+                    m = int(counts[slot, j])
                     req["tokens"].extend(
-                        int(t) for t in window_np[slot, j, :m])
+                        int(t) for t in window[slot, j, :m])
                     if "ttft" not in req:
                         req["ttft"] = t_j - req["submit_t"]
                         req["first_tok_t"] = t_j
@@ -2590,12 +1996,15 @@ class GenerationServer:
                         req["span"].span_point(
                             "serving/first_token",
                             ttft_ms=round(req["ttft"] * 1000.0, 3))
+                    if self.paged:
+                        req["cur_len"] += m
                     tick_committed += m
                 committed += tick_committed
                 self._decode_tokens += tick_committed
-                if self.spec and ticked:
-                    drafted = self._spec_k * ticked
-                    accepted = tick_committed - ticked
+                if self.spec:
+                    drafted = k * len(live)
+                    # each slot's t0 is sampled, not drafted
+                    accepted = tick_committed - len(live)
                     self._spec_drafted += drafted
                     self._spec_accepted += accepted
                     metrics.inc("serving/spec_drafted", drafted)
@@ -2609,34 +2018,23 @@ class GenerationServer:
                 reg.set_gauge(
                     "serving/spec_accept_rate",
                     self._spec_accepted / max(self._spec_drafted, 1))
-            if self.paged:
-                # advance each slot past its committed tokens and hand
-                # pages wholly past that point back to the pool — both
-                # the pre-mapped-but-unused tail of an early exit and
-                # spec's rejected-KV rollback
-                for slot in live:
-                    req = self._slots[slot]
-                    if req is None or not req.get("active"):
-                        continue
-                    req["cur_len"] += int(
-                        counts_np[slot, :n_ticks].sum())
-                    used = -(-req["cur_len"] // self._page)
-                    if used < req["num_pages"]:
-                        for j in range(used, req["num_pages"]):
-                            self._release_page(int(self._pt[slot, j]))
-                            self._pt[slot, j] = NULL_PAGE
-                        req["num_pages"] = used
-                        self._pt_dirty = True
+            done: List[Completion] = []
             for slot in live:
-                req = self._slots[slot]
-                if req is None or \
-                        (self.paged and not req.get("active")):
-                    continue
+                if self.paged:
+                    # pages wholly past the committed point go
+                    # straight back to the pool: the pre-mapped tail
+                    # of an early exit, and spec's rejected KV (the
+                    # partial page's stale columns sit past cur_len
+                    # and are overwritten before any masked read)
+                    req = self._slots[slot]
+                    self._trim_pages(
+                        slot, req, -(-req["cur_len"] // self._page))
                 if finished[slot]:
                     done.append(self._evict(slot, "eos"))
                 elif dec_count[slot] >= self.gen_cfg.max_dec_len:
                     done.append(self._evict(slot, "length"))
             reg.set_gauge("serving/slot_occupancy", self.occupancy)
+            self._refresh_health()
             return expired + self._take_dead() + done
 
     def drain(self, max_ticks: Optional[int] = None
@@ -2651,7 +2049,8 @@ class GenerationServer:
         lost."""
         with self._surface_lock:
             out = self._drain_impl(max_ticks)
-        self._ship_spills()
+        if self._tier is not None:
+            self._tier.ship()
         return out
 
     def _drain_impl(self, max_ticks: Optional[int]
@@ -2705,15 +2104,10 @@ class GenerationServer:
         [] instead of touching torn-down state. Idempotent."""
         with self._surface_lock:
             self._closed = True
-        # last outboxed spills still reach the writer before the
-        # sentinel below shuts it down
-        self._ship_spills()
         if self._watchdog is not None:
             self._watchdog.stop()
-        if self._tiered and self._spill_writer_thread is not None:
-            self._spill_q.put(None)
-            self._spill_writer_thread.join(timeout=10.0)
-            self._spill_writer_thread = None
+        if self._tier is not None:
+            self._tier.close()
         if self._sigterm_installed:
             signal.signal(signal.SIGTERM, self._prev_sigterm)
             self._sigterm_installed = False
@@ -2798,11 +2192,8 @@ class GenerationServer:
                 mcfg.num_layers, mcfg.num_attention_heads,
                 mcfg.head_dim, self._page, self._alloc.num_pages,
                 mcfg.kv_cache_dtype)
-            if self._tiered:
-                s["tiered"] = True
-                s["host_pool_bytes"] = self._host_pool_bytes
-                s["host_pages_cap"] = self._alloc.host_pages
-                s["host_pages"] = self._alloc.host_pages_resident
+            if self._tier is not None:
+                s.update(self._tier.summary())
             s.update(self._alloc.stats)
         if self._adapters is not None:
             s["adapter_rows"] = self._adapters.capacity

@@ -513,7 +513,7 @@ def test_randomized_admit_evict_preempt_trace():
 def test_randomized_tiered_trace_spill_rehydrate_cow():
     """The same transition mix over a TWO-tier allocator: evictions of
     registered last-ref pages spill instead of freeing (what
-    ``core/serving.py::_drain_spills`` does), registry hits that land
+    ``core/host_tier.py::HostSpillTier.collect`` does), registry hits that land
     on host ids rehydrate through ``try_alloc`` + ``promote`` (what
     ``_rehydrate`` does), and COW stays device-only structurally —
     the ledger never references a host id. ``check()``'s cross-tier

@@ -933,27 +933,31 @@ def test_serving_health_lock_mutation_trips_gate():
 
 
 def test_serving_spill_lock_mutation_trips_gate():
-    """Same pin for the hierarchical KV cache: the spill writer
-    thread publishes staged host bytes into ``_host_data`` under
-    ``_spill_lock`` while the main loop pops them on rehydrate —
-    dropping the writer-side guard must re-race them (PFX301)."""
-    srv = open(os.path.join(REPO, "paddlefleetx_tpu", "core",
-                            "serving.py"), encoding="utf-8").read()
-    obs = open(os.path.join(REPO, "paddlefleetx_tpu",
-                            "observability", "server.py"),
-               encoding="utf-8").read()
-    sources = {"paddlefleetx_tpu/core/serving.py": srv,
-               "paddlefleetx_tpu/observability/server.py": obs}
-    guarded = ("            with self._spill_lock:\n"
+    """Same pin for the hierarchical KV cache (core/host_tier.py):
+    the spill writer thread publishes staged host bytes into
+    ``_host_data`` under the tier's ``_lock`` while the server's loop
+    pops them on rehydrate — dropping the writer-side guard must
+    re-race them (PFX301)."""
+    def read(*parts):
+        return open(os.path.join(REPO, "paddlefleetx_tpu", *parts),
+                    encoding="utf-8").read()
+    tier = read("core", "host_tier.py")
+    sources = {"paddlefleetx_tpu/core/host_tier.py": tier,
+               "paddlefleetx_tpu/core/serving.py":
+                   read("core", "serving.py"),
+               "paddlefleetx_tpu/observability/server.py":
+                   read("observability", "server.py")}
+    assert run_rules(_ctx(sources), select={"PFX301"}) == []
+    guarded = ("            with self._lock:\n"
                "                for (hpid, gen), page in "
                "zip(entries, pages):\n")
-    assert guarded in srv, "spill writer lost its _spill_lock guard?"
-    mutated = srv.replace(
+    assert guarded in tier, "spill writer lost its _lock guard?"
+    mutated = tier.replace(
         guarded,
         "            if True:\n"
         "                for (hpid, gen), page in "
         "zip(entries, pages):\n")
-    sources["paddlefleetx_tpu/core/serving.py"] = mutated
+    sources["paddlefleetx_tpu/core/host_tier.py"] = mutated
     keys = {f.key for f in run_rules(_ctx(sources),
                                      select={"PFX301"})}
     assert any("_host_data" in k for k in keys), keys
@@ -975,14 +979,7 @@ def test_fleet_snapshot_lock_mutation_trips_gate():
     sources = {"paddlefleetx_tpu/core/fleet.py": flt,
                "paddlefleetx_tpu/core/serving.py": srv,
                "paddlefleetx_tpu/observability/server.py": obs}
-    # the adapter-insert params write carries a documented inline
-    # suppression in the real tree (docs/lora.md: its unlocked
-    # reader runs at __init__, before threads); run_rules reports raw
-    # findings, so mask that one key here
-    known = {"paddlefleetx_tpu.core.serving:GenerationServer.params"}
-    base = [f for f in run_rules(_ctx(sources), select={"PFX301"})
-            if f.key not in known]
-    assert base == []
+    assert run_rules(_ctx(sources), select={"PFX301"}) == []
     mutated = flt.replace("with self._health_lock:", "if True:")
     assert mutated != flt, "fleet.py lost its _health_lock guards?"
     sources["paddlefleetx_tpu/core/fleet.py"] = mutated
@@ -1040,23 +1037,21 @@ def test_cli_github_format_emits_error_annotations(tmp_path, capsys):
 
 
 def test_real_tree_suppression_counts_pinned():
-    """Exactly two documented inline PFX301 suppressions: the
-    `enabled` fast-path flag in observability/metrics.py and the
-    adapter-insert params write in core/serving.py (its unlocked
-    reader, _model_fingerprint, runs eagerly at __init__ before any
-    thread exists); growth here means a new unjustified disable crept
-    in."""
+    """Exactly one documented inline PFX301 suppression: the
+    `enabled` fast-path flag in observability/metrics.py (the server's
+    adapter-insert params write needs none any more: its one unlocked
+    reader, the model fingerprint, reads the params inside __init__);
+    growth here means a new unjustified disable crept in."""
     res = run_lint(REPO)
     counts = res.suppression_counts()
-    assert counts.get("PFX301") == 2, counts
+    assert counts.get("PFX301") == 1, counts
     # and every suppressed thread finding lives where documented
     where = {f.path for f in res.suppressed if f.code == "PFX301"}
-    assert where == {"paddlefleetx_tpu/observability/metrics.py",
-                     "paddlefleetx_tpu/core/serving.py"}
+    assert where == {"paddlefleetx_tpu/observability/metrics.py"}
 
 
 def test_cli_stats_prints_per_rule_suppressions(capsys):
     from codestyle.pfxlint.__main__ import main
     assert main(["--root", REPO, "--stats"]) == 0
     err = capsys.readouterr().err
-    assert "pfxlint: suppressed[PFX301]=2" in err
+    assert "pfxlint: suppressed[PFX301]=1" in err

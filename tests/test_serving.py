@@ -1553,8 +1553,8 @@ def test_device_loop_mid_loop_eos_parity(model_and_params):
 
 
 def test_device_loop_t1_step_path_unchanged(model_and_params):
-    """device_loop_ticks=1 must not even route through _step_loop —
-    the T=1 server IS today's tick-per-step path, byte-identical."""
+    """device_loop_ticks=1 is one host round-trip per device tick,
+    token-exact with the lockstep path."""
     model, params = model_and_params
     gen_cfg = _greedy_cfg()
     srv = GenerationServer(model, params, gen_cfg, num_slots=2,
@@ -2124,7 +2124,7 @@ def test_tiered_stale_host_generation_never_rehydrated(
     in the writer queue, the OLD residency's bytes may publish under
     the reused id. Generation tags must keep them from ever serving a
     rehydrate (`_pop_host_bytes`) and keep an eviction drain from
-    clobbering the NEW residency's bytes (`_drop_evicted_host_data`)."""
+    clobbering the NEW residency's bytes (`_drop_evicted`)."""
     model, params = paged512_model_and_params
     gen_cfg = _greedy_cfg(max_dec=4)
     srv = GenerationServer(model, params, gen_cfg, num_slots=2,
@@ -2134,36 +2134,36 @@ def test_tiered_stale_host_generation_never_rehydrated(
     for w in _conv_trace(seed=3, users=2, turns=1):
         srv.run(w)
     with srv._surface_lock:
-        srv._drain_spills()
-    srv._ship_spills()
-    srv._await_spill_writer()
+        srv._tier.collect(srv._ticks, srv._roundtrips)
+    srv._tier.ship()
+    srv._tier.await_writer()
     assert srv._alloc.host_pages_resident > 0
     hpid = next(iter(srv._alloc._hosted))
     gen = srv._alloc.host_generation(hpid)
-    live = srv._pop_host_bytes(hpid, gen)
+    live = srv._tier._pop_host_bytes(hpid, gen)
     assert live is not None
     # a dead residency's bytes: discarded on pop, never returned
-    with srv._spill_lock:
-        srv._host_data[hpid] = (gen - 1, "stale")
-    assert srv._pop_host_bytes(hpid, gen) is None
-    with srv._spill_lock:
-        assert hpid not in srv._host_data
+    with srv._tier._lock:
+        srv._tier._host_data[hpid] = (gen - 1, "stale")
+    assert srv._tier._pop_host_bytes(hpid, gen) is None
+    with srv._tier._lock:
+        assert hpid not in srv._tier._host_data
     # the live residency's bytes survive a drain of the id's EARLIER
     # eviction (the recycled-id case)...
-    with srv._spill_lock:
-        srv._host_data[hpid] = (gen, live)
+    with srv._tier._lock:
+        srv._tier._host_data[hpid] = (gen, live)
     srv._alloc._host_evicted.append(hpid)
-    srv._drop_evicted_host_data()
-    with srv._spill_lock:
-        assert srv._host_data[hpid][0] == gen
+    srv._tier._drop_evicted()
+    with srv._tier._lock:
+        assert srv._tier._host_data[hpid][0] == gen
     # ...while a dead generation's bytes are dropped by the same drain
-    with srv._spill_lock:
-        srv._host_data[hpid] = (gen - 1, "stale")
+    with srv._tier._lock:
+        srv._tier._host_data[hpid] = (gen - 1, "stale")
     srv._alloc._host_evicted.append(hpid)
-    srv._drop_evicted_host_data()
-    with srv._spill_lock:
-        assert hpid not in srv._host_data
-        srv._host_data[hpid] = (gen, live)   # restore for close()
+    srv._tier._drop_evicted()
+    with srv._tier._lock:
+        assert hpid not in srv._tier._host_data
+        srv._tier._host_data[hpid] = (gen, live)   # restore for close()
     srv._alloc.check()
     srv.close()
 
@@ -2204,7 +2204,7 @@ def test_tiered_spill_writer_failure_never_hangs_or_corrupts(
     # (the device-side gather is live before the writer's failing
     # device_get ever runs) — those bytes are real, and the parity
     # assert above proves nothing fake was served from a failed stage
-    assert srv._spill_writer_thread.is_alive()  # writer survived
+    assert srv._tier._writer_thread.is_alive()  # writer survived
     srv._alloc.check()
     srv.close()
 
@@ -2241,11 +2241,11 @@ def test_spill_rehydrate_batched_single_dispatch(
 
     monkeypatch.setattr(serving_mod, "gather_kv_pages", gather)
     monkeypatch.setattr(serving_mod, "scatter_kv_pages", scatter)
-    assert len(srv._spill_pin) >= 2
+    assert srv._tier.pinned >= 2
     with srv._surface_lock:
-        srv._drain_spills()
-    srv._ship_spills()
-    srv._await_spill_writer()
+        srv._tier.collect(srv._ticks, srv._roundtrips)
+    srv._tier.ship()
+    srv._tier.await_writer()
     assert srv._alloc.stats["spills"] >= 2
     assert calls["gather"] == 1          # N pages, ONE stacked gather
     # the same prompt re-admits as a registry hit: every host page
@@ -2321,6 +2321,118 @@ def _phase_server(paged512_model_and_params, **kw):
     return GenerationServer(model, params, _greedy_cfg(max_dec=12),
                             num_slots=2, page_size=128,
                             prefill_chunk_pages=1, **kw)
+
+
+#: what a paged, untiered decoding step with no prefill chunk leaves
+#: in its record, in order, whatever ``device_loop_ticks`` is
+#: (speculative servers add ``draft``)
+_DECODING_PHASES = ("expire", "spill_drain", "admit", "prefill_pump",
+                    "table_sync", "page_maintenance", "draft",
+                    "decode_dispatch", "decode_harvest", "state_fetch",
+                    "commit", "ship_spills")
+
+
+@pytest.mark.parametrize("spec", [False, True], ids=["plain", "spec"])
+@pytest.mark.parametrize("loop_ticks", [1, 4])
+def test_one_step_body_launches_the_modes_own_program(
+        paged512_model_and_params, monkeypatch, loop_ticks, spec):
+    """``step()`` has one body: each decoding step makes exactly ONE
+    launch — ``decode_step`` / ``verify_step`` at T = 1 and never a
+    loop, ``decode_loop`` / ``verify_loop`` at T = 4 and never a
+    one-tick program — and leaves the same phases in the same order in
+    both modes."""
+    import paddlefleetx_tpu.core.serving as serving_mod
+    calls = dict.fromkeys(
+        ("decode_step", "verify_step", "decode_loop", "verify_loop"), 0)
+    for name in calls:
+        def counted(*a, _real=getattr(serving_mod, name), _name=name,
+                    **kw):
+            calls[_name] += 1
+            return _real(*a, **kw)
+        monkeypatch.setattr(serving_mod, name, counted)
+    mine = ("verify" if spec else "decode") + \
+        ("_step" if loop_ticks == 1 else "_loop")
+    gen_cfg = _greedy_cfg(max_dec=12)
+    if spec:
+        gen_cfg = _spec_cfg(gen_cfg, 2)
+    model, params = paged512_model_and_params
+    srv = GenerationServer(model, params, gen_cfg, num_slots=2,
+                           page_size=128, prefill_chunk_pages=1,
+                           device_loop_ticks=loop_ticks)
+    srv.submit([5, 9, 2])
+    srv.submit([7, 1])
+    orders = set()
+    try:
+        while srv.pending or srv.occupancy:
+            before = dict(calls)
+            srv.step()
+            launched = {n: calls[n] - before[n] for n in calls
+                        if calls[n] != before[n]}
+            rec = srv.last_step
+            assert launched == ({mine: 1} if rec.ticks else {})
+            if rec.ticks and not rec.chunks:
+                orders.add(tuple(rec.phases_ms()))
+        assert srv.summary()["decode_ticks"] >= 4
+    finally:
+        srv.close()
+    assert orders == {tuple(p for p in _DECODING_PHASES
+                            if spec or p != "draft")
+                      + ("unaccounted",)}
+
+
+def test_health_snapshot_follows_a_t1_servers_steps(
+        paged512_model_and_params):
+    """``/healthz`` is at most one step stale whatever
+    ``device_loop_ticks`` is: a T = 1 server's ``ticks`` and
+    ``occupancy`` advance across ``step()`` calls with no ``submit()``
+    between them."""
+    srv = _phase_server(paged512_model_and_params)
+    try:
+        srv.submit([5, 9, 2])
+        assert srv.health_snapshot()["ticks"] == 0
+        seen = []
+        while srv.pending or srv.occupancy:
+            srv.step()
+            snap = srv.health_snapshot()
+            assert snap["ticks"] == srv.summary()["decode_ticks"]
+            assert snap["occupancy"] == srv.occupancy
+            seen.append(snap["ticks"])
+        assert seen[-1] > seen[0] and seen[-1] >= 8
+        assert srv.health_snapshot()["pending"] == 0
+    finally:
+        srv.close()
+
+
+def test_pinned_spill_page_goes_back_before_anyone_is_preempted(
+        paged512_model_and_params):
+    """Pool pressure on a tiered server reclaims a pinned
+    to-be-spilled page (idle KV: one lost spill) before it preempts a
+    running request — here with no one to preempt at all, where the
+    untiered server could only raise."""
+    from paddlefleetx_tpu.core.paging import (
+        NULL_PAGE, PagePoolExhausted,
+    )
+    model, params = paged512_model_and_params
+    srv = GenerationServer(model, params, _greedy_cfg(max_dec=4),
+                           num_slots=2, page_size=128, pool_pages=7,
+                           prefill_chunk_pages=1, prefix_sharing=True,
+                           host_pool_bytes=1 << 20)
+    try:
+        srv.run([np.random.default_rng(23).integers(
+            0, EOS, 260).tolist()])
+        pinned = srv._tier.pinned
+        assert pinned >= 2
+        while srv._alloc.try_alloc() is not None:
+            pass
+        with srv._surface_lock:
+            for left in range(pinned - 1, -1, -1):
+                assert srv._alloc_or_preempt(0) != NULL_PAGE
+                assert srv._tier.pinned == left
+            with pytest.raises(PagePoolExhausted):
+                srv._alloc_or_preempt(0)
+        assert srv.summary()["preempted"] == 0
+    finally:
+        srv.close()
 
 
 @pytest.mark.parametrize("loop_ticks", [1, 4])
