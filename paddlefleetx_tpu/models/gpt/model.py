@@ -486,10 +486,11 @@ class MultiHeadAttention(nn.Module):
                 wpos = _window_positions(cache_lengths, x.shape[1],
                                          cfg.cache_capacity)
                 pid = jnp.take_along_axis(pt, wpos // page, axis=1)
-                for var, t in writes:
-                    var.value = kv_cache_write(
-                        var.value, pid, wpos % page, t,
-                        use_flash=cfg.use_flash_attention)
+                written = kv_cache_write(
+                    [(var.value, t) for var, t in writes], pid,
+                    wpos % page, use_flash=cfg.use_flash_attention)
+                for (var, _), leaf in zip(writes, written):
+                    var.value = leaf
                 query_offset = wpos[:, 0]               # [b]
             elif chunk_start is not None:
                 c = x.shape[1]
@@ -582,11 +583,11 @@ class MultiHeadAttention(nn.Module):
                 rows = jnp.broadcast_to(
                     jnp.arange(x.shape[0], dtype=jnp.int32)[:, None],
                     wpos.shape)
-                for var, t in writes:
-                    var.value = kv_cache_write(
-                        var.value, rows, wpos, t,
-                        use_flash=cfg.use_flash_attention,
-                        paged=False)
+                written = kv_cache_write(
+                    [(var.value, t) for var, t in writes], rows, wpos,
+                    use_flash=cfg.use_flash_attention, paged=False)
+                for (var, _), leaf in zip(writes, written):
+                    var.value = leaf
                 query_offset = wpos[:, 0]               # [b]
             else:
                 idx = cache_index.value

@@ -153,41 +153,67 @@ def _gather_kv_pages(pool, page_table):
     return g.transpose(0, 2, 3, 1, 4).reshape(b, h, d, m * p)
 
 
-def kv_cache_write(leaf, rows, cols, new, use_flash: bool = True,
+def kv_cache_write(writes, rows, cols, use_flash: bool = True,
                    paged: bool = True):
-    """Write fresh key/value columns into a decode cache leaf:
-    ``leaf.at[rows, :, :, cols].set(new)`` for ``leaf [N, h, d, M]``
-    (the paged pool, ``N`` physical pages of ``M`` columns, or the
-    contiguous slot cache, ``N`` slots of ``M = capacity``; ``d`` is 1
-    for an int8 cache's fp32 scale leaves), ``rows`` / ``cols``
-    ``[b, W]`` and ``new [b, W, h, d]`` (``W`` = 1 for a decode tick,
-    the window of a speculative verify tick).
+    """Write fresh key/value columns into the decode cache leaves of
+    one layer: ``leaf.at[rows, :, :, cols].set(new)`` for every
+    ``(leaf, new)`` of ``writes``, the leaves returned in that order.
+    ``leaf [N, h, d, M]`` is the paged pool (``N`` physical pages of
+    ``M`` columns) or, with ``paged=False``, the contiguous slot cache
+    (``N`` slots of ``M = capacity``); ``d`` is 1 for an int8 cache's
+    fp32 scale leaves; ``rows`` / ``cols`` are ``[b, W]`` and ``new
+    [b, W, h, d]`` (``W`` = 1 for a decode tick, the window of a
+    speculative verify tick).
 
-    The Pallas kernel (``ops/pallas/kv_write.py``) rewrites only the
-    128-column blocks it writes and leaves the leaf in the layout the
-    decode kernels read, so under a jit that donates the cache the
-    write is in place (``attention/kv_write_paged`` /
-    ``attention/kv_write_ragged``, counted at trace time like the
-    attention dispatch). The XLA scatter is the fallback and the
-    parity oracle: where the kernel refuses
+    The Pallas kernel (``ops/pallas/kv_write.py``) takes the leaves of
+    one shape and dtype in ONE launch (a layer's K and V; the two
+    scale leaves of an int8 cache in a second), rewrites only the
+    128-column blocks the live rows write and leaves each leaf in the
+    layout the decode kernels read, so under a jit that donates the
+    cache the write is in place. Its grid follows the rows that write:
+    of the paged pool, those with a position off ``NULL_PAGE`` (a free
+    slot costs no step and the reserved page is never written); of the
+    contiguous cache, every row. ``attention/kv_write_paged`` /
+    ``attention/kv_write_ragged`` count the leaves it wrote, at trace
+    time like the attention dispatch. The XLA scatter is the fallback
+    and the parity oracle: where the kernel refuses
     (``attention/fallback/kernel_rejected``: off the TPU, an untiled
     minor dim) and, by decision, under a multi-device mesh
     (``attention/fallback/mesh_sharded``: the kernel is not
     ``shard_map``-wrapped) or with ``use_flash=False``. The chip's
     compiler brackets that scatter with two copies of the whole leaf
     (PERF.md, PR 24)."""
+    writes = list(writes)
+    # one launch per shape: K and V values, then an int8 cache's scales
+    groups = {}
+    for at, (leaf, _) in enumerate(writes):
+        groups.setdefault((leaf.shape, leaf.dtype), []).append(at)
+    out = [None] * len(writes)
+    for group in groups.values():
+        leaves, news = zip(*(writes[at] for at in group))
+        for at, leaf in zip(group, _kv_write_leaves(
+                leaves, news, rows, cols, use_flash, paged)):
+            out[at] = leaf
+    return out
+
+
+def _kv_write_leaves(leaves, news, rows, cols, use_flash, paged):
+    """:func:`kv_cache_write` for leaves of one shape and dtype: one
+    kernel launch, or a scatter a leaf."""
     if use_flash and kernel_mesh() is not None:
-        metrics.inc("attention/fallback/mesh_sharded")
+        metrics.inc("attention/fallback/mesh_sharded", len(leaves))
     elif use_flash:
         try:
             from .pallas.kv_write import kv_write
-            out = kv_write(leaf, rows, cols, new)
+            out = kv_write(leaves, rows, cols, news, paged=paged)
             metrics.inc("attention/kv_write_paged" if paged
-                        else "attention/kv_write_ragged")
+                        else "attention/kv_write_ragged", len(leaves))
             return out
         except (ImportError, NotImplementedError):
-            metrics.inc("attention/fallback/kernel_rejected")
-    return leaf.at[rows, :, :, cols].set(new)
+            metrics.inc("attention/fallback/kernel_rejected",
+                        len(leaves))
+    return [leaf.at[rows, :, :, cols].set(new)
+            for leaf, new in zip(leaves, news)]
 
 
 def _xla_attention(q, k, v, bias, causal, query_offset, dropout_rate,

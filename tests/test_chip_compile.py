@@ -237,7 +237,7 @@ def _kv_write_calls(compiled):
     on its ``pallas_call``), which keeps them out of the
     ``*self_attn* custom-call`` lines ``paged_decode_roofline``
     reads."""
-    return len(re.findall(r"%kv_write[.\d]* = \S+ custom-call",
+    return len(re.findall(r"%kv_write[.\d]* = [^\n]*\) custom-call\(",
                           compiled.as_text()))
 
 
@@ -300,11 +300,35 @@ def _decode_tick(sh, window, kv_dtype, layers=1):
 def test_decode_tick_updates_the_pool_in_place(window, kv_dtype,
                                                one_chip, as_tpu):
     """The KV write kernel, then ``flash_decode_paged``, the pool
-    donated: no pool-shaped copy, every pool byte aliased."""
+    donated: one write call a layer for K and V (one more for an int8
+    cache's two scale pools), no pool-shaped copy, every pool byte
+    aliased."""
     compiled, pool_bytes = _decode_tick(one_chip, window, kv_dtype)
-    assert _kv_write_calls(compiled) == (4 if kv_dtype == "int8" else 2)
+    assert _kv_write_calls(compiled) == (2 if kv_dtype == "int8" else 1)
     assert _pool_copies(compiled) == 0
     assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+
+
+@pytest.mark.parametrize("window,kv_dtype,paged", [
+    (1, "bf16", True), (5, "bf16", True), (32, "bf16", True),
+    (5, "int8", True), (1, "bf16", False), (5, "int8", False)])
+def test_kv_write_compiles(window, kv_dtype, paged, one_chip, as_tpu):
+    """The write kernel alone at the cell's widths: a grid whose first
+    axis is the count of live rows (dynamic), three prefetched scalars,
+    K and V in one call with both leaves aliased; the paged pool and
+    the contiguous slot cache ``[64, 16, 64, 1024]``."""
+    from paddlefleetx_tpu.ops.pallas import kv_write as kw
+    dtype, d = {"bf16": (BF16, D), "int8": (jnp.int8, D)}[kv_dtype]
+    shape = (POOL, H, d, PAGE) if paged else (SLOTS, H, d, S)
+    leaves = [_sds(shape, dtype, one_chip)] * 2
+    news = [_sds((SLOTS, window, H, d), dtype, one_chip)] * 2
+    idx = _sds((SLOTS, window), jnp.int32, one_chip)
+    compiled = jax.jit(
+        functools.partial(kw.kv_write, paged=paged),
+        donate_argnums=0).lower(leaves, idx, idx, news).compile()
+    assert _kv_write_calls(compiled) == 1
+    assert compiled.memory_analysis().alias_size_in_bytes >= \
+        2 * math.prod(shape) * jnp.dtype(dtype).itemsize
 
 
 def _paged_decode_calls(compiled):
@@ -325,8 +349,9 @@ def test_decode_tick_walks_once_and_pads_no_lanes(window, kv_dtype,
     minor dim is 1 (``q`` and the output went in and out that way: ``W``
     padded to 128 lanes in HBM, 16.8 MB for 128 KB, and two copies a
     layer to make and unmake it); and
-    the walk over live (slot, block) pairs is built once a tick, not
-    once a layer: the second layer adds no cumulative sum."""
+    the walk over live (slot, block) pairs and the write kernel's
+    over the live rows are each built once a tick, not once a layer:
+    the second layer adds no cumulative sum."""
     def scans(compiled):
         return len(re.findall(r" reduce-window\(", compiled.as_text()))
     one, _ = _decode_tick(one_chip, window, kv_dtype, layers=1)
@@ -337,8 +362,9 @@ def test_decode_tick_walks_once_and_pads_no_lanes(window, kv_dtype,
     assert f"[{SLOTS},{H},{D},{window}]" not in two.as_text()
     for text in calls:                   # operands and result alike
         assert not re.search(r"\[[\d,]+,1\]", text), text[:400]
-    assert scans(one) >= 1
+    assert scans(one) >= 2
     assert scans(two) == scans(one)
+    assert _kv_write_calls(two) == 2 * _kv_write_calls(one)
 
 
 def test_decode_tick_with_the_scatter_copies_the_pool(one_chip, as_tpu,

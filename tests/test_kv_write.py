@@ -62,41 +62,104 @@ CASES = {
         [(jnp.bfloat16, D)], 3, [126, 40, 41, 7],
         np.array([[1, 2, 3], [0, 0, 0], [0, 0, 0], [10, 11, 0]])),
     "fp32_values": ([(jnp.float32, D)], 2, [127, 0, 382, 200], TABLE),
+    # row 3 is live and 254..258 runs off its two mapped pages: the
+    # columns on page 11 are written, those on NULL_PAGE are not
+    "window_runs_onto_null_page": ([(jnp.bfloat16, D)], 5,
+                                   [0, 60, 123, 254], TABLE),
 }
+
+
+def _write_and_check(leaves, rows, cols, window, b, pages, rng):
+    """K and V of every ``(dtype, d)`` through ONE donated call: off
+    ``NULL_PAGE`` the scatter's bits, ``NULL_PAGE`` as it was."""
+    write = jax.jit(kw.kv_write, donate_argnums=0)
+    for dtype, d in leaves:
+        pools = [_leaf(rng, (pages, H, d, PAGE), dtype) for _ in "kv"]
+        news = [_leaf(rng, (b, window, H, d), dtype) for _ in "kv"]
+        want = [pool.at[rows, :, :, cols].set(new)
+                for pool, new in zip(pools, news)]
+        null = [_bits(pool[NULL_PAGE]) for pool in pools]
+        got = write(pools, jnp.asarray(rows), jnp.asarray(cols), news)
+        assert len(got) == 2
+        for g, w, was in zip(got, want, null):
+            assert g.dtype == w.dtype and g.shape == w.shape
+            np.testing.assert_array_equal(_bits(g)[NULL_PAGE + 1:],
+                                          _bits(w)[NULL_PAGE + 1:])
+            np.testing.assert_array_equal(_bits(g)[NULL_PAGE], was)
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_kv_write_is_the_scatter_bit_for_bit(case):
-    """Every page but NULL_PAGE (written by several rows, read by
-    none) holds exactly what ``leaf.at[rows, :, :, cols].set(new)``
-    leaves there, the leaf donated as the cache jits donate it."""
+    """Every page but NULL_PAGE holds exactly what
+    ``leaf.at[rows, :, :, cols].set(new)`` leaves there, K and V in
+    one call, the leaves donated as the cache jits donate them;
+    NULL_PAGE (the scatter writes the free rows' garbage there) is not
+    written at all."""
     leaves, window, lengths, table = CASES[case]
     rng = np.random.default_rng(sorted(CASES).index(case))
     wpos = np.asarray(lengths)[:, None] + np.arange(window)[None, :]
     rows = np.take_along_axis(table, wpos // PAGE, axis=1)
-    cols = wpos % PAGE
-    write = jax.jit(kw.kv_write, donate_argnums=0)
-    for dtype, d in leaves:
-        pool = _leaf(rng, (12, H, d, PAGE), dtype)
-        new = _leaf(rng, (4, window, H, d), dtype)
-        want = pool.at[rows, :, :, cols].set(new)
-        got = write(pool, jnp.asarray(rows), jnp.asarray(cols), new)
-        assert got.dtype == want.dtype and got.shape == want.shape
-        np.testing.assert_array_equal(_bits(got)[NULL_PAGE + 1:],
-                                      _bits(want)[NULL_PAGE + 1:])
+    _write_and_check(leaves, rows, wpos % PAGE, window, 4, 12, rng)
 
 
-def test_kv_write_takes_the_contiguous_slot_cache():
-    """``[b, h, d, capacity]``: the slot is the major index, the
-    128-column block of its position the "page"."""
+#: which of 130 rows (two lane groups of the fresh values) are live
+MIXES = {
+    "none_live": [],
+    "one_live": [5],
+    "first_and_last_of_a_lane_group": [0, 127, 128],
+    "past_one_lane_block": [3, 129],
+    "all_live": list(range(130)),
+}
+KINDS = {
+    "bf16": [(jnp.bfloat16, D)],
+    "fp32": [(jnp.float32, D)],
+    "int8_with_scale_pools": [(jnp.int8, D), (jnp.float32, 1)],
+}
+
+
+@pytest.mark.parametrize("kind", sorted(KINDS))
+@pytest.mark.parametrize("window", [1, 5])
+@pytest.mark.parametrize("mix", sorted(MIXES))
+def test_kv_write_walks_the_live_rows_only(mix, window, kind):
+    """130 slots of two pages each, some free (their table rows all
+    NULL_PAGE): the live rows' columns are the scatter's, wherever
+    they sit in the walk and in their lane group, and a free row
+    writes nothing, NULL_PAGE included."""
+    b = 130
+    live = np.zeros(b, bool)
+    live[MIXES[mix]] = True
+    table = np.where(live[:, None],
+                     1 + 2 * np.arange(b)[:, None] + np.arange(2), 0)
+    lengths = np.arange(b) * 37 % 250         # some windows straddle
+    wpos = lengths[:, None] + np.arange(window)[None, :]
+    rows = np.take_along_axis(table, wpos // PAGE, axis=1)
+    order, n = kw._live_rows(jnp.asarray(rows), True)
+    assert int(n) == live.sum()
+    assert np.asarray(order)[:int(n)].tolist() == MIXES[mix]
+    rng = np.random.default_rng(len(mix) + window)
+    _write_and_check(KINDS[kind], rows, wpos % PAGE, window, b,
+                     1 + 2 * b, rng)
+
+
+@pytest.mark.parametrize("window", [1, 5])
+def test_kv_write_takes_the_contiguous_slot_cache(window):
+    """``[b, h, d, capacity]`` with ``paged=False``: the slot is the
+    major index, the 128-column block of its position the "page", and
+    index 0 is slot 0, not a null page: every row is written."""
     rng = np.random.default_rng(11)
-    cache = _leaf(rng, (4, H, D, 3 * PAGE), jnp.bfloat16)
-    new = _leaf(rng, (4, 5, H, D), jnp.bfloat16)
-    cols = np.asarray([0, 126, 255, 379])[:, None] + np.arange(5)
+    caches = [_leaf(rng, (4, H, D, 3 * PAGE), jnp.bfloat16)
+              for _ in "kv"]
+    news = [_leaf(rng, (4, window, H, D), jnp.bfloat16) for _ in "kv"]
+    cols = np.asarray([0, 126, 255, 379])[:, None] + np.arange(window)
     rows = np.broadcast_to(np.arange(4)[:, None], cols.shape)
-    want = cache.at[rows, :, :, cols].set(new)
-    got = kw.kv_write(cache, jnp.asarray(rows), jnp.asarray(cols), new)
-    np.testing.assert_array_equal(_bits(got), _bits(want))
+    order, n = kw._live_rows(jnp.asarray(rows), False)
+    assert int(n) == 4 and np.asarray(order).tolist() == [0, 1, 2, 3]
+    got = kw.kv_write(caches, jnp.asarray(rows), jnp.asarray(cols),
+                      news, paged=False)
+    for g, cache, new in zip(got, caches, news):
+        want = cache.at[rows, :, :, cols].set(new)
+        assert (_bits(want[0]) != _bits(cache[0])).any()
+        np.testing.assert_array_equal(_bits(g), _bits(want))
 
 
 def test_kv_write_rows_past_one_lane_block():
@@ -108,7 +171,8 @@ def test_kv_write_rows_past_one_lane_block():
     rows = np.broadcast_to(np.arange(1, 131)[:, None], (130, 2))
     cols = (np.arange(130) * 7 % 127)[:, None] + np.arange(2)
     want = pool.at[rows, :, :, cols].set(new)
-    got = kw.kv_write(pool, jnp.asarray(rows), jnp.asarray(cols), new)
+    got, = kw.kv_write([pool], jnp.asarray(rows), jnp.asarray(cols),
+                       [new])
     np.testing.assert_array_equal(_bits(got), _bits(want))
 
 
@@ -121,14 +185,27 @@ def test_kv_write_rows_past_one_lane_block():
 def test_kv_write_refuses_what_it_cannot_tile(leaf, new, why):
     idx = jnp.zeros(new[:2], jnp.int32)
     with pytest.raises(NotImplementedError, match=why):
-        kw.kv_write(jnp.zeros(leaf, jnp.bfloat16), idx, idx,
-                    jnp.zeros(new, jnp.bfloat16))
+        kw.kv_write([jnp.zeros(leaf, jnp.bfloat16)], idx, idx,
+                    [jnp.zeros(new, jnp.bfloat16)])
+
+
+def test_kv_write_refuses_leaves_of_two_shapes():
+    """One call walks one block shape: values and an int8 cache's
+    scale pools go in two calls (``kv_cache_write`` groups them)."""
+    idx = jnp.zeros((4, 1), jnp.int32)
+    with pytest.raises(NotImplementedError, match="one shape"):
+        kw.kv_write([jnp.zeros((12, H, D, PAGE), jnp.int8),
+                     jnp.zeros((12, H, 1, PAGE), jnp.float32)],
+                    idx, idx, [jnp.zeros((4, 1, H, D), jnp.int8),
+                               jnp.zeros((4, 1, H, 1), jnp.float32)])
 
 
 def test_dispatch_falls_back_to_the_scatter_and_counts(monkeypatch):
     """Off the TPU (no interpret mode) the kernel refuses: the write
     is today's scatter, counted as a kernel rejection; with
-    ``use_flash=False`` it is the scatter by decision, uncounted."""
+    ``use_flash=False`` it is the scatter by decision, uncounted. The
+    counters count leaves; int8 values and their scales are grouped
+    into a call each."""
     rng = np.random.default_rng(3)
     pool = _leaf(rng, (12, H, D, PAGE), jnp.bfloat16)
     new = _leaf(rng, (4, 1, H, D), jnp.bfloat16)
@@ -139,19 +216,43 @@ def test_dispatch_falls_back_to_the_scatter_and_counts(monkeypatch):
     reg = metrics.get_registry()
     reg.reset()
     try:
-        got = attention.kv_cache_write(pool, rows, cols, new)
+        got, = attention.kv_cache_write([(pool, new)], rows, cols)
         assert reg.counter("attention/kv_write_paged") == 1
         np.testing.assert_array_equal(_bits(got), _bits(want))
-        attention.kv_cache_write(pool, rows, cols, new, paged=False)
+        attention.kv_cache_write([(pool, new)], rows, cols,
+                                 paged=False)
         assert reg.counter("attention/kv_write_ragged") == 1
+        # K, V, K's scales, V's scales: two calls, each leaf where it
+        # was handed over
+        scale = jnp.asarray(rng.normal(size=(12, H, 1, PAGE)),
+                            jnp.float32)
+        s_new = jnp.asarray(rng.normal(size=(4, 1, H, 1)), jnp.float32)
+        calls = []
+        real = kw.kv_write
+
+        def counted(leaves, *a, **k):
+            calls.append(len(leaves))
+            return real(leaves, *a, **k)
+        monkeypatch.setattr(kw, "kv_write", counted)
+        got = attention.kv_cache_write(
+            [(pool, new), (pool + 1, new), (scale, s_new),
+             (scale + 1, s_new)], rows, cols)
+        assert calls == [2, 2]
+        assert reg.counter("attention/kv_write_paged") == 5
+        for g, (leaf, fresh) in zip(got, [
+                (pool, new), (pool + 1, new), (scale, s_new),
+                (scale + 1, s_new)]):
+            np.testing.assert_array_equal(
+                _bits(g), _bits(leaf.at[rows, :, :, cols].set(fresh)))
+        monkeypatch.setattr(kw, "kv_write", real)
         monkeypatch.delenv("PFX_PALLAS_INTERPRET")
-        got = attention.kv_cache_write(pool, rows, cols, new)
+        got, = attention.kv_cache_write([(pool, new)], rows, cols)
         assert reg.counter("attention/fallback/kernel_rejected") == 1
         np.testing.assert_array_equal(_bits(got), _bits(want))
-        got = attention.kv_cache_write(pool, rows, cols, new,
-                                       use_flash=False)
+        got, = attention.kv_cache_write([(pool, new)], rows, cols,
+                                        use_flash=False)
         np.testing.assert_array_equal(_bits(got), _bits(want))
-        assert reg.counter("attention/kv_write_paged") == 1
+        assert reg.counter("attention/kv_write_paged") == 5
         assert reg.counter("attention/fallback/kernel_rejected") == 1
     finally:
         metrics.set_enabled(False)
@@ -187,7 +288,8 @@ def test_model_cache_write_matches_the_scatter(params, monkeypatch,
     """A decode tick / verify window through the model with the
     kernel and with the scatter (same attention kernels either way):
     the same logits and, outside NULL_PAGE, the same cache bits —
-    values and, for int8 KV, the scale leaves."""
+    values and, for int8 KV, the scale leaves; the free row 1 writes
+    nothing through the kernel."""
     cfg = dataclasses.replace(
         CFG, kv_cache_dtype=kv_dtype,
         **(dict(kv_page_size=PAGE, kv_pool_pages=8) if paged else {}))
@@ -228,10 +330,15 @@ def test_model_cache_write_matches_the_scatter(params, monkeypatch,
     np.testing.assert_array_equal(np.asarray(logits),
                                   np.asarray(ref_logits))
     first = NULL_PAGE + 1 if paged else 0
-    for a, b in zip(jax.tree.leaves(out["cache"]),
-                    jax.tree.leaves(ref["cache"])):
-        if a.ndim == 4:
-            a, b = a[first:], b[first:]
+    for a, b, was in zip(jax.tree.leaves(out["cache"]),
+                         jax.tree.leaves(ref["cache"]),
+                         jax.tree.leaves(cache)):
+        if a.ndim >= 4:          # [layers,] pages or slots, h, d, M
+            if paged:            # the kernel leaves NULL_PAGE alone
+                np.testing.assert_array_equal(
+                    _bits(a[..., NULL_PAGE, :, :, :]),
+                    _bits(was[..., NULL_PAGE, :, :, :]))
+            a, b = (x[..., first:, :, :, :] for x in (a, b))
         np.testing.assert_array_equal(_bits(a), _bits(b))
 
 
