@@ -501,9 +501,11 @@ class PageAllocator:
 
 # -- pool sizing -------------------------------------------------------
 
-def kv_page_bytes(num_heads: int, head_dim: int, page_size: int,
+def kv_page_bytes(num_kv_heads: int, head_dim: int, page_size: int,
                   kv_cache_dtype: str = "bf16") -> int:
-    """Device bytes ONE K or V page costs per layer.
+    """Device bytes ONE K or V page costs per layer; ``num_kv_heads``
+    is the heads the POOL holds (a config's ``num_kv_heads``: the query
+    heads of a multi-head model, fewer under grouped-query attention).
 
     ``bf16``: 2 bytes per element. ``int8``: 1 byte per element plus
     one fp32 scale per (head, position) — the ``cached_*_scale`` pool
@@ -511,9 +513,9 @@ def kv_page_bytes(num_heads: int, head_dim: int, page_size: int,
     per head-token instead of ``2 * head_dim``: a 1.88x density win at
     head_dim 64 (docs/quantization.md)."""
     if kv_cache_dtype == "int8":
-        per_token = num_heads * (head_dim + 4)
+        per_token = num_kv_heads * (head_dim + 4)
     elif kv_cache_dtype == "bf16":
-        per_token = num_heads * head_dim * 2
+        per_token = num_kv_heads * head_dim * 2
     else:
         raise ValueError(
             f"unknown kv_cache_dtype {kv_cache_dtype!r} "
@@ -521,18 +523,18 @@ def kv_page_bytes(num_heads: int, head_dim: int, page_size: int,
     return per_token * page_size
 
 
-def pool_bytes(num_layers: int, num_heads: int, head_dim: int,
+def pool_bytes(num_layers: int, num_kv_heads: int, head_dim: int,
                page_size: int, num_pages: int,
                kv_cache_dtype: str = "bf16") -> int:
-    """Total device bytes of a ``num_pages`` KV pool (K and V, all
-    layers) — the figure the serving summary reports and the A/B
-    bench divides slot counts by."""
+    """Total device bytes of a ``num_pages`` KV pool (K and V, the
+    ``num_layers`` layers of one page class) — the figure the serving
+    summary reports and the A/B bench divides slot counts by."""
     return 2 * num_layers * num_pages * kv_page_bytes(
-        num_heads, head_dim, page_size, kv_cache_dtype)
+        num_kv_heads, head_dim, page_size, kv_cache_dtype)
 
 
 def pool_pages_for_bytes(budget_bytes: int, num_layers: int,
-                         num_heads: int, head_dim: int,
+                         num_kv_heads: int, head_dim: int,
                          page_size: int,
                          kv_cache_dtype: str = "bf16") -> int:
     """Largest pool (in pages) fitting ``budget_bytes`` of HBM —
@@ -540,5 +542,5 @@ def pool_pages_for_bytes(budget_bytes: int, num_layers: int,
     while switching ``kv_cache_dtype`` (int8 admits ~1.9x the pages,
     hence ~1.9x the resident slots on the same memory)."""
     per_page = 2 * num_layers * kv_page_bytes(
-        num_heads, head_dim, page_size, kv_cache_dtype)
+        num_kv_heads, head_dim, page_size, kv_cache_dtype)
     return int(budget_bytes) // max(per_page, 1)

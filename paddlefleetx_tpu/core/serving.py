@@ -327,13 +327,18 @@ class GenerationServer:
                 pool_pages = cfg.kv_pool_pages or (
                     num_slots * (cfg.cache_capacity
                                  // max(page_size, 1)) + 1)
-            cfg = _dc.replace(cfg, kv_page_size=page_size,
-                              kv_pool_pages=int(pool_pages))
-            model = type(model)(cfg)
             if prefill_chunk_pages < 1:
                 raise ValueError(
                     f"prefill_chunk_pages must be >= 1, got "
                     f"{prefill_chunk_pages}")
+            cfg = _dc.replace(cfg, kv_page_size=page_size,
+                              kv_pool_pages=int(pool_pages))
+            if hasattr(cfg, "window_class"):
+                # a model with sliding-window layers brings a second
+                # page class: a ring of pages a slot (below)
+                cfg = cfg.window_class(num_slots,
+                                       page_size * prefill_chunk_pages)
+            model = type(model)(cfg)
             if cfg.max_kv_pages % prefill_chunk_pages:
                 raise ValueError(
                     f"prefill_chunk_pages ({prefill_chunk_pages}) must "
@@ -347,7 +352,22 @@ class GenerationServer:
                     f"prefill chunk ({self._chunk} tokens) exceeds "
                     f"max_position_embeddings "
                     f"{cfg.max_position_embeddings}")
-            self._prefix_sharing = bool(prefix_sharing)
+            # the window class (models/smallthinker): each slot owns a
+            # static ring of ``_ring`` pages on every window layer,
+            # whose ids ride behind the global columns of the device
+            # table (_sync_pt). The ring is the slot's alone, so a
+            # prefix hit on the global class could not serve the window
+            # layers: a model with window layers shares no prefix, and
+            # each admission it would have looked up is counted
+            # (serving/prefix_refused_window)
+            self._ring = getattr(cfg, "window_ring_pages", 0)
+            self._window_layers = getattr(cfg, "window_layers", 0)
+            self._ring_cols = (
+                1 + np.arange(num_slots, dtype=np.int32)[:, None]
+                * self._ring + np.arange(self._ring, dtype=np.int32))
+            self._prefix_refused = bool(prefix_sharing and self._ring)
+            self._prefix_sharing = bool(prefix_sharing) and not self._ring
+            self._moe_published = np.zeros((4,), np.int64)
             # hierarchical KV cache (docs/inference.md): a bounded
             # pinned-host spill tier sized dtype-aware from a BYTE
             # budget, so int8 KV doubles its page capacity for free
@@ -360,7 +380,7 @@ class GenerationServer:
                         "pages")
                 host_pages = pool_pages_for_bytes(
                     int(host_pool_bytes), cfg.num_layers,
-                    cfg.num_attention_heads, cfg.head_dim, self._page,
+                    cfg.num_kv_heads, cfg.head_dim, self._page,
                     cfg.kv_cache_dtype)
                 if host_pages < 1:
                     raise ValueError(
@@ -988,13 +1008,19 @@ class GenerationServer:
     def _sync_pt(self) -> None:
         if not self._pt_dirty:
             return
-        self._pt_dev = jnp.asarray(self._pt)
         act = np.zeros((self.num_slots, 1), bool)
         for s, r in enumerate(self._slots):
             if r is not None and r.get("active"):
                 act[s, 0] = True
-        self._pt_dev_dec = jnp.asarray(
-            np.where(act, self._pt, NULL_PAGE).astype(np.int32))
+        dec = np.where(act, self._pt, NULL_PAGE).astype(np.int32)
+        if self._ring:
+            # the slots' ring pages behind the global columns
+            self._pt_dev = jnp.asarray(
+                np.concatenate([self._pt, self._ring_cols], axis=1))
+            dec = np.concatenate([dec, self._ring_cols], axis=1)
+        else:
+            self._pt_dev = jnp.asarray(self._pt)
+        self._pt_dev_dec = jnp.asarray(dec)
         self._pt_dirty = False
 
     def _place(self, req: dict, slot: int, num_pages: int) -> None:
@@ -1128,6 +1154,8 @@ class GenerationServer:
                 # recompute locally with the rest of the prompt
                 cpp = self._chunk // self._page
                 del shared_pids[len(shared_pids) - len(shared_pids) % cpp:]
+            if self._prefix_refused:
+                metrics.inc("serving/prefix_refused_window")
             start = len(shared_pids) * self._page
             n_chunks = -(-(L - start) // self._chunk)
             total_pages = (start + n_chunks * self._chunk) // self._page
@@ -1208,6 +1236,13 @@ class GenerationServer:
                 jnp.asarray([int(self._aid_np[slot])], jnp.int32)
                 if self._adapters is not None else None)
             req["prefill_pos"] = c0 + self._chunk
+            if self._ring:
+                # the chunk's pages past the ring's first lap each went
+                # over a page that had fallen behind the window
+                metrics.inc(
+                    "serving/window_pages_reused", self._window_layers
+                    * sum(j >= self._ring for j in range(
+                        c0 // self._page, (c0 + self._chunk) // self._page)))
             self._prefill_chunk_count += 1
             rec.chunks += 1
             metrics.inc("serving/prefill_chunks")
@@ -1795,6 +1830,38 @@ class GenerationServer:
             for s in live))
         metrics.inc("serving/decode_blocks_capacity",
                     self.num_slots * self._max_pages)
+        if self._ring:
+            self._count_page_classes(live, window)
+
+    def _count_page_classes(self, live: List[int], window: int) -> None:
+        """The same tick by page class (a model with window layers):
+        pages held by the slots it walks, global layers whole
+        sequences and window layers no more than their ring; the
+        blocks the walk visits against what it would without windows;
+        ring pages written over for the first time this tick."""
+        glob = self.model.config.num_layers - self._window_layers
+        reach = self.model.config.sliding_window_size
+        pages = held = whole = walked = reused = 0
+        for s in live:
+            req = self._slots[s]
+            cur, n = req["cur_len"], req["num_pages"]
+            pages += n
+            held += min(n, self._ring)
+            last = min((cur + window - 1) // self._page,
+                       self._max_pages - 1)
+            whole += last + 1
+            walked += last + 1 - max((cur + 1 - reach) // self._page, 0)
+            reused += cur % self._page == 0 and cur // self._page \
+                >= self._ring
+        metrics.inc("serving/pages_global_held", glob * pages)
+        metrics.inc("serving/pages_window_held",
+                    self._window_layers * held)
+        metrics.inc("serving/window_pages_reused",
+                    self._window_layers * reused)
+        metrics.inc("serving/kv_blocks_whole",
+                    (glob + self._window_layers) * whole)
+        metrics.inc("serving/kv_blocks_walked",
+                    glob * whole + self._window_layers * walked)
 
     # -- the decoding step --------------------------------------------
     #
@@ -2189,9 +2256,31 @@ class GenerationServer:
             # BYTES admit ~1.9x the pages under int8 + fp32 scales
             s["kv_cache_dtype"] = mcfg.kv_cache_dtype
             s["pool_bytes"] = pool_bytes(
-                mcfg.num_layers, mcfg.num_attention_heads,
+                mcfg.num_layers - self._window_layers, mcfg.num_kv_heads,
                 mcfg.head_dim, self._page, self._alloc.num_pages,
                 mcfg.kv_cache_dtype)
+            if self._ring:
+                s["window_ring_pages"] = self._ring
+                s["window_pool_bytes"] = pool_bytes(
+                    self._window_layers, mcfg.num_kv_heads,
+                    mcfg.head_dim, self._page, mcfg.window_pool_pages,
+                    mcfg.kv_cache_dtype)
+                s["prefix_refused_window"] = self._prefix_refused
+            if "moe_stats" in self._cache:
+                # what the jitted ticks counted on the device, read
+                # here, outside any tick: picks dispatched and distinct
+                # experts touched (a layer, summed over layers), of the
+                # decode ticks and of the prefill chunks
+                total = np.asarray(self._cache["moe_stats"], np.int64)
+                grown = [int(n) for n in total - self._moe_published]
+                self._moe_published = total
+                metrics.inc("moe/decode_picks", grown[0])
+                metrics.inc("moe/experts_touched", grown[1])
+                metrics.inc("moe/prefill_picks", grown[2])
+                metrics.inc("moe/prefill_touched", grown[3])
+                (s["moe_decode_picks"], s["moe_experts_touched"],
+                 s["moe_prefill_picks"], s["moe_prefill_touched"]) = (
+                     int(n) for n in total)
             if self._tier is not None:
                 s.update(self._tier.summary())
             s.update(self._alloc.stats)

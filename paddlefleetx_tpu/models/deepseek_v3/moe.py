@@ -11,14 +11,17 @@ for the experts ``H = [lo, hi)`` it holds: what the absent experts
 would add is another chip's to compute. With ``H`` everything it is the
 whole layer. No capacity: every pick of a held expert is computed.
 
-One lowering:
+One lowering (:func:`routed_experts`; the router's rule and the gate's
+activation are its arguments, so the softmax-over-picked ReLU layer of
+``models/smallthinker`` on the serving path takes it too):
 
   1. a stable sort of the ``N x k`` picks by expert, picks of experts
      not held behind the held ones;
   2. the held picks' rows gathered into ONE static ``[M, h]`` buffer,
-     ``M = N k + G block`` rows, each group starting on a row-tile
-     boundary (``ops/pallas/grouped_matmul.py::ragged_layout``). ``M``
-     holds the worst routing (every pick held), so no routing is ever
+     ``M = N k + G block`` rows (``block`` by :func:`block_rows`), each
+     group starting on a row-tile boundary
+     (``ops/pallas/grouped_matmul.py::ragged_layout``). ``M`` holds the
+     worst routing (every pick held), so no routing is ever
      cut; the group sizes and the count of occupied tiles are data;
   3. gate|up as one ragged grouped product, ``silu(g) * u``, down as a
      second: the kernels' grids run over the occupied tiles only;
@@ -50,10 +53,21 @@ from ...observability import metrics
 from ...ops.pallas.grouped_matmul import ragged_layout, ragged_matmul
 from .config import DeepSeekV3Config
 
-#: rows of one tile of the grouped product: small enough that the
-#: padding of 16 groups to whole tiles stays a few percent of ~12 k
+#: rows of one tile of the grouped product at most: small enough that
+#: the padding of 16 groups to whole tiles stays a few percent of ~12 k
 #: routed rows, a full MXU pass of 128 all the same
 BLOCK_M = 128
+
+
+def block_rows(picks: int, groups: int) -> int:
+    """Rows of one tile for ``picks`` rows over ``groups`` experts: the
+    power of two at or above the mean group, between a bf16 sublane
+    tile (16) and :data:`BLOCK_M`. A training step's groups hold
+    thousands of rows and take 128; a decode tick's hold a handful, and
+    a tile of 128 would be nine tenths padding that the products read
+    and write all the same."""
+    mean = max(1, -(-picks // groups))
+    return min(BLOCK_M, max(16, 1 << (mean - 1).bit_length()))
 
 
 def route(u32, w_gate, bias, top_k: int, scaling: float):
@@ -173,12 +187,34 @@ def grouped_product(x, w, plan):
     """Rows of group ``g`` times ``w[g]`` over the planned buffer."""
     try:
         out = ragged_matmul(x, w, plan["tile_group"], plan["tiles_used"],
-                            block_m=BLOCK_M)
+                            block_m=x.shape[0] // plan["tile_group"].shape[0])
         metrics.inc("moe/dropless")
         return out
     except NotImplementedError:
         metrics.inc("moe/fallback/pallas_rejected")
         return jax.lax.ragged_dot(x, w, plan["group_rows"])
+
+
+def routed_experts(x, idx, weights, w_gate_up, w_down, lo: int, hi: int,
+                   activation=jax.nn.silu):
+    """Steps 1-4 of the lowering, for any router and any gate
+    activation: ``x [N, h]`` in the compute dtype, the router's picks
+    ``idx [N, k]`` (a pick outside ``[lo, hi)`` is another chip's, or
+    nobody's: a dead decode row's sentinel) and their float32
+    ``weights [N, k]``, the held experts' ``w_gate_up [G, h, 2 f]`` and
+    ``w_down [G, f, h]``. Returns ``(sum over held picks of w_e
+    (activation(x W_gate,e) * (x W_up,e)) W_down,e  [N, h], plan)``."""
+    k = idx.shape[1]
+    f = w_down.shape[1]
+    plan = plan_dispatch(idx, lo, hi, block_rows(idx.size, hi - lo))
+    gathers = (plan["row_pick"], plan["row_valid"], plan["pick_row"],
+               plan["pick_held"])
+    xs = dispatch(x, *gathers, k)
+    gu = grouped_product(xs, w_gate_up, plan)
+    hidden = activation(gu[:, :f]) * gu[:, f:]
+    y = grouped_product(hidden, w_down, plan)
+    metrics.inc("moe/experts_held", hi - lo)
+    return combine(y, weights, *gathers, k), plan
 
 
 def _init(cfg: DeepSeekV3Config):
@@ -250,16 +286,10 @@ class DroplessMoE(nn.Module):
                              w_gate.astype(jnp.float32),
                              bias.astype(jnp.float32), k,
                              cfg.routed_scaling_factor)
-        plan = plan_dispatch(idx, lo, hi, BLOCK_M)
-        gathers = (plan["row_pick"], plan["row_valid"], plan["pick_row"],
-                   plan["pick_held"])
-        xs = dispatch(x.astype(dtype), *gathers, k)
-        gu = grouped_product(
-            xs, w_gate_up.reshape(groups, h, 2 * f).astype(dtype), plan)
-        hidden = jax.nn.silu(gu[:, :f]) * gu[:, f:]
-        y = grouped_product(hidden, w_down.astype(dtype), plan)
-        metrics.inc("moe/experts_held", groups)
-        routed = combine(y, weights, *gathers, k)
+        routed, plan = routed_experts(
+            x.astype(dtype), idx, weights,
+            w_gate_up.reshape(groups, h, 2 * f).astype(dtype),
+            w_down.astype(dtype), lo, hi)
 
         sizes = plan["sizes"].astype(jnp.float32)
         held = jnp.sum(sizes)

@@ -371,6 +371,13 @@ class GPTConfig:
         return self.hidden_size // self.num_attention_heads
 
     @property
+    def num_kv_heads(self) -> int:
+        """Heads of the KV cache's leaves: multi-head attention caches
+        one K/V head a query head (``core/paging.py`` sizes pools by
+        this name on every served config)."""
+        return self.num_attention_heads
+
+    @property
     def cache_capacity(self) -> int:
         """Decode KV-cache slots per row: ``max_position_embeddings``
         rounded UP to a multiple of 128 (the TPU lane width and the

@@ -153,6 +153,81 @@ def _gather_kv_pages(pool, page_table):
     return g.transpose(0, 2, 3, 1, 4).reshape(b, h, d, m * p)
 
 
+#: pages one step of :func:`paged_prefill_attention` gathers a row
+PREFILL_SPAN_PAGES = 8
+
+
+def paged_prefill_attention(q, k, v, chunk_start, page_table,
+                            sliding_window=None):
+    """A prefill chunk's attention through the page table, walking only
+    the pages its queries can see. ``q [n, c, h, d]`` sits at positions
+    ``chunk_start[i] .. + c - 1`` of row ``i``; ``k`` / ``v`` are the
+    pool ``[P, g, d, page]`` (``g`` dividing ``h``: grouped-query heads
+    share the gathered pages, nothing is repeated), the chunk's own
+    keys already written; ``page_table [n, max_pages]``.
+
+    An online softmax over spans of :data:`PREFILL_SPAN_PAGES` pages
+    in a ``fori_loop`` of dynamic length: from page 0, or with
+    ``sliding_window`` from the page of key ``chunk_start + 1 -
+    sliding_window`` (no page behind the window is gathered), to the
+    chunk's last page. Work and memory follow the context that is
+    there, not the table's capacity (the whole-table gather of
+    :func:`dot_product_attention`'s dense path costs a 16 k-position
+    row ``[h, c, 16384]`` float32 scores whatever the prompt's
+    length). Scores, softmax statistics and the accumulator are
+    float32; probabilities meet ``v`` in its dtype, as on the dense
+    path. Plain XLA: gathers and einsums, no kernel."""
+    n, c, h, d = q.shape
+    g, page = k.shape[1], k.shape[3]
+    m = h // g
+    span = PREFILL_SPAN_PAGES
+    pt = jnp.asarray(page_table, jnp.int32)
+    c0 = jnp.asarray(chunk_start, jnp.int32)
+    q_pos = c0[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
+    last = (c0 + (c - 1)) // page                          # [n]
+    first = jnp.zeros_like(c0) if sliding_window is None else \
+        jnp.maximum((c0 + (1 - sliding_window)) // page, 0)
+    steps = jnp.max(-(-(last + 1 - first) // span))
+    qg = q.reshape(n, c, g, m, d)
+    metrics.inc("attention/paged_prefill_walk")
+
+    def body(i, carry):
+        """Span ``i`` of every row: gather, mask, online update."""
+        m_prev, l_prev, acc = carry
+        pages = first[:, None] + i * span + \
+            jnp.arange(span, dtype=jnp.int32)[None, :]     # [n, span]
+        pids = jnp.take_along_axis(
+            pt, jnp.minimum(pages, pt.shape[1] - 1), axis=1)
+
+        def rows(pool):                     # -> [n, g, d, span * page]
+            return jnp.take(pool, pids, axis=0).transpose(
+                0, 2, 3, 1, 4).reshape(n, g, d, span * page)
+        k_pos = (pages[:, :, None] * page + jnp.arange(
+            page, dtype=jnp.int32)[None, None, :]).reshape(n, -1)
+        live = (k_pos[:, None, :] <= q_pos[:, :, None]) & \
+            (pages <= last[:, None]).repeat(page, axis=1)[:, None, :]
+        if sliding_window is not None:
+            live &= k_pos[:, None, :] > q_pos[:, :, None] - sliding_window
+        s = jnp.einsum("bqgmd,bgdk->bgmqk", qg, rows(k),
+                       preferred_element_type=jnp.float32) * d ** -0.5
+        s = jnp.where(live[:, None, None], s, NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(live[:, None, None], jnp.exp(s - m_new), 0.0)
+        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc = acc * alpha + jnp.einsum(
+            "bgmqk,bgdk->bgmqd", p.astype(v.dtype), rows(v),
+            preferred_element_type=jnp.float32)
+        return m_new, l_new, acc
+
+    init = (jnp.full((n, g, m, c, 1), NEG_INF, jnp.float32),
+            jnp.zeros((n, g, m, c, 1), jnp.float32),
+            jnp.zeros((n, g, m, c, d), jnp.float32))
+    _, l_fin, acc = jax.lax.fori_loop(0, steps, body, init)
+    out = acc / jnp.maximum(l_fin, 1e-30)
+    return out.transpose(0, 3, 1, 2, 4).reshape(n, c, h, d).astype(q.dtype)
+
+
 def kv_cache_write(writes, rows, cols, use_flash: bool = True,
                    paged: bool = True):
     """Write fresh key/value columns into the decode cache leaves of
@@ -218,10 +293,20 @@ def _kv_write_leaves(leaves, news, rows, cols, use_flash, paged):
 
 def _xla_attention(q, k, v, bias, causal, query_offset, dropout_rate,
                    dropout_rng, deterministic, softmax_in_fp32,
-                   kv_cache_layout=False, sm_scale=None):
+                   kv_cache_layout=False, sm_scale=None,
+                   sliding_window=None):
     scale = q.shape[-1] ** -0.5 if sm_scale is None else sm_scale
     k_eq = "bhdk" if kv_cache_layout else "bkhd"
-    scores = jnp.einsum(f"bqhd,{k_eq}->bhqk", q * scale, k)
+    nh, g = q.shape[2], k.shape[1 if kv_cache_layout else 2]
+    if g != nh:
+        # grouped-query heads: query head m * i + j reads K/V head i;
+        # the group axis rides the einsum, K/V are never repeated
+        qg = (q * scale).reshape(q.shape[:2] + (g, nh // g, q.shape[3]))
+        scores = jnp.einsum(
+            f"bqgmd,{k_eq.replace('h', 'g')}->bgmqk", qg, k).reshape(
+                q.shape[0], nh, q.shape[1], -1)
+    else:
+        scores = jnp.einsum(f"bqhd,{k_eq}->bhqk", q * scale, k)
     if softmax_in_fp32:
         scores = scores.astype(jnp.float32)
     if causal:
@@ -239,7 +324,10 @@ def _xla_attention(q, k, v, bias, causal, query_offset, dropout_rate,
         else:
             q_pos = jnp.arange(sq)[:, None] + off  # [sq, 1]
         k_pos = jnp.arange(sk)[None, :]
-        scores = jnp.where(k_pos <= q_pos, scores, NEG_INF)
+        live = k_pos <= q_pos
+        if sliding_window is not None:
+            live &= k_pos > q_pos - sliding_window
+        scores = jnp.where(live, scores, NEG_INF)
     if bias is not None:
         scores = scores + bias.astype(scores.dtype)
     weights = jax.nn.softmax(scores, axis=-1)
@@ -250,7 +338,13 @@ def _xla_attention(q, k, v, bias, causal, query_offset, dropout_rate,
         weights = weights * keep / (1.0 - dropout_rate)
     weights = weights.astype(v.dtype)
     v_eq = "bhdk" if kv_cache_layout else "bkhd"
-    out = jnp.einsum(f"bhqk,{v_eq}->bqhd", weights, v)
+    if g != nh:
+        out = jnp.einsum(
+            f"bgmqk,{v_eq.replace('h', 'g')}->bqgmd",
+            weights.reshape(weights.shape[0], g, nh // g,
+                            *weights.shape[2:]), v).reshape(q.shape)
+    else:
+        out = jnp.einsum(f"bhqk,{v_eq}->bqhd", weights, v)
     return checkpoint_name(out, "core_attn")
 
 
@@ -323,7 +417,8 @@ def dot_product_attention(
         k_scale: Optional[jax.Array] = None,
         v_scale: Optional[jax.Array] = None,
         heads_axis: str = "act_heads",
-        sm_scale: Optional[float] = None) -> jax.Array:
+        sm_scale: Optional[float] = None,
+        sliding_window: Optional[int] = None) -> jax.Array:
     """Causal attention; dispatches to the Pallas flash kernel on TPU.
 
     ``v`` may be narrower than ``q``/``k`` (latent attention scores at
@@ -358,6 +453,15 @@ def dot_product_attention(
     dense fallback dequantizes the gathered rows up front and is the
     parity oracle (dispatch matrix: docs/quantization.md).
 
+    Grouped-query heads and a sliding window (cached decode only;
+    the training flash kernels take neither): ``k``/``v`` may hold
+    ``g`` heads under ``h = g * m`` query heads, query head ``m * i +
+    j`` reading K/V head ``i`` (``attention/paged_gqa``), and with
+    ``sliding_window`` key ``j`` is visible to the query at ``i`` iff
+    ``i - sliding_window < j <= i`` (``attention/window_layers``).
+    ``flash_decode_paged`` takes both by shape; so does the dense
+    fallback.
+
     ``heads_axis`` is the logical axis the heads dim is sharded by
     ("act_heads", or "act_heads_cp" under Ulysses): under a
     multi-device mesh the training flash kernel runs per device on
@@ -375,6 +479,16 @@ def dot_product_attention(
         if not kv_cache_layout:
             raise ValueError("page_table requires kv_cache_layout")
         skv = page_table.shape[1] * k.shape[3]
+    grouped = k.shape[1 if kv_cache_layout else 2] != q.shape[2]
+    if (grouped or sliding_window is not None) and \
+            not (kv_cache_layout and page_table is not None):
+        # one dense expression serves the uncached forward; the
+        # contiguous-cache and training kernels know neither
+        use_flash = False
+    if grouped and page_table is not None:
+        metrics.inc("attention/paged_gqa")
+    if sliding_window is not None:
+        metrics.inc("attention/window_layers")
     # training dropout on the kernel path: in-kernel philox masks
     # (reference fused softmax-with-dropout, hybrid_model.py:277-285).
     # Bias (ERNIE padding masks, GPT attn_mask) rides into the kernel
@@ -430,7 +544,8 @@ def dot_product_attention(
                     out = fa.flash_decode_paged(q, k, v, query_offset,
                                                 page_table,
                                                 k_scale=k_scale,
-                                                v_scale=v_scale)
+                                                v_scale=v_scale,
+                                                reach=sliding_window)
                     if k_scale is not None:
                         metrics.inc("attention/flash_decode_paged_int8")
                     else:
@@ -445,7 +560,8 @@ def dot_product_attention(
                     out = fa.flash_decode_paged(q, k, v, query_offset,
                                                 page_table,
                                                 k_scale=k_scale,
-                                                v_scale=v_scale)
+                                                v_scale=v_scale,
+                                                reach=sliding_window)
                     if k_scale is not None:
                         metrics.inc(
                             "attention/flash_decode_paged_verify_int8")
@@ -541,4 +657,4 @@ def dot_product_attention(
     return _xla_attention(q, k, v, bias, causal, query_offset, dropout_rate,
                           dropout_rng, deterministic, softmax_in_fp32,
                           kv_cache_layout=kv_cache_layout,
-                          sm_scale=sm_scale)
+                          sm_scale=sm_scale, sliding_window=sliding_window)

@@ -1092,8 +1092,20 @@ def _decode_block(q, k, v, live, sm_scale, m_prev, l_prev, acc_prev,
     Takes and returns the running ``m [h, 1]``, ``l [h, 1]``, ``acc
     [h, d]``, all fp32. Shared by the contiguous kernels and the paged
     one, so a row's number does not depend on which of them walked
-    its blocks."""
-    s = jnp.sum(q * k, axis=1) * sm_scale          # [h, bkv] f32
+    its blocks.
+
+    Grouped-query heads come by shape: with ``k`` / ``v`` of ``g``
+    heads under ``h = g * m`` query heads (``m`` a multiple of 8, the
+    caller pads: a group is then whole sublane tiles and the two
+    reshapes move nothing), the ``m`` heads of a group share the
+    resident block; at ``g == h`` the expressions are the ungrouped
+    ones."""
+    h, g = q.shape[0], k.shape[0]
+    if g == h:
+        s = jnp.sum(q * k, axis=1) * sm_scale      # [h, bkv] f32
+    else:
+        s = jnp.sum(q.reshape(g, h // g, *q.shape[1:]) * k[:, None],
+                    axis=2).reshape(h, -1) * sm_scale
     if bias is not None:
         s = s + bias                               # [1, bkv] broadcasts
     s = jnp.where(live, s, NEG_INF)
@@ -1102,8 +1114,12 @@ def _decode_block(q, k, v, live, sm_scale, m_prev, l_prev, acc_prev,
     p = jnp.exp(s - m_new)                         # [h, bkv]
     l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
     # output: broadcast p over d, reduce over the key lanes
-    acc_new = acc_prev * alpha + jnp.sum(p[:, None, :] * v, axis=2)
-    return m_new, l_new, acc_new
+    if g == h:
+        pv = jnp.sum(p[:, None, :] * v, axis=2)
+    else:
+        pv = jnp.sum(p.reshape(g, h // g, 1, -1) * v[:, None],
+                     axis=3).reshape(h, -1)
+    return m_new, l_new, acc_prev * alpha + pv
 
 
 def _decode_kernel(off_ref, q_ref, k_ref, v_ref, *refs, sm_scale,
@@ -1492,23 +1508,36 @@ VMEM_MOST = 48 * 1024 * 1024
 SMEM_MOST = 768 * 1024
 
 
-def _paged_walk(offs, pt, window: int, block_kv: int, num_kv: int):
+def _reach_first(offs, reach, block_kv: int):
+    """First block a query at ``offs`` reads: block 0, or with a
+    sliding window of ``reach`` keys (key ``j`` visible iff ``off -
+    reach < j <= off``) the block of key ``off + 1 - reach``."""
+    if reach is None:
+        return 0
+    return jnp.maximum((offs + (1 - reach)) // block_kv, 0)
+
+
+def _paged_walk(offs, pt, window: int, block_kv: int, num_kv: int,
+                reach=None):
     """The grid of one paged decode call: ``(rows [T], blocks [T],
     steps)`` with ``T = b * num_kv``, of which the first ``steps``
     entries are the (slot, logical block) pairs the kernel visits —
     slots ascending, each slot's blocks ascending.
 
     A live slot (its page-table row does not start with
-    :data:`NULL_PAGE`) takes blocks ``0 .. (off + W - 1) // block_kv``;
-    a dead one takes none, except that the FIRST slot of every group of
-    :data:`LANES` always takes one step, dead or not: the step that
+    :data:`NULL_PAGE`) takes blocks ``first .. (off + W - 1) //
+    block_kv``, ``first`` by :func:`_reach_first` (0 without a sliding
+    window); a dead one takes none, except that the FIRST slot of every
+    group of :data:`LANES` always takes one step, dead or not: the step that
     zeroes the group's output block, so no output block is left
     unvisited and ``steps >= 1``. A handful of integer ops on ``[b]``
     and ``[T]``; every layer of a tick builds the same walk from the
     same operands, and XLA keeps one (tests/test_chip_compile.py)."""
     b = offs.shape[0]
     last = jnp.minimum((offs + (window - 1)) // block_kv, num_kv - 1)
-    n = jnp.where(pt[:, 0] != NULL_PAGE, jnp.maximum(last + 1, 0), 0)
+    first = _reach_first(offs, reach, block_kv)
+    n = jnp.where(pt[:, 0] != NULL_PAGE,
+                  jnp.maximum(last + 1 - first, 0), 0)
     n = jnp.maximum(
         n, (jnp.arange(b, dtype=jnp.int32) % LANES == 0).astype(
             jnp.int32))
@@ -1521,13 +1550,15 @@ def _paged_walk(offs, pt, window: int, block_kv: int, num_kv: int):
     before = end[None, :] <= t[:, None]
     rows = jnp.minimum(jnp.sum(before, axis=1, dtype=jnp.int32), b - 1)
     began = jnp.sum(jnp.where(before, n[None, :], 0), axis=1)
-    blocks = jnp.clip(t - began, 0, num_kv - 1)
-    return rows, blocks, end[-1]
+    blocks = t - began
+    if reach is not None:
+        blocks = blocks + first[rows]
+    return rows, jnp.clip(blocks, 0, num_kv - 1), end[-1]
 
 
 def _paged_kernel(off_ref, pt_ref, row_ref, blk_ref, q_ref, k_ref,
                   v_ref, *refs, sm_scale, block_kv, num_kv, window,
-                  quantized):
+                  quantized, reach=None):
     """One grid step = one live (slot, block) pair of
     :func:`_paged_walk`: the math of :func:`_decode_kernel` (``window``
     1) and :func:`_verify_kernel` per pair, with the slot taken from
@@ -1548,6 +1579,12 @@ def _paged_kernel(off_ref, pt_ref, row_ref, blk_ref, q_ref, k_ref,
     into its lane of the output block. The group's first step zeroes
     the block, so a slot the walk never reaches reads zeros, not what
     the buffer held.
+
+    With ``reach`` (a sliding window of that many keys) a slot's walk
+    begins at :func:`_reach_first` instead of block 0, that block is
+    where its state is initialised, and a key at or behind ``offset +
+    j - reach`` is masked inside it. ``k`` / ``v`` may hold fewer
+    heads than ``q`` (:func:`_decode_block`).
     """
     refs = list(refs)
     if quantized:
@@ -1558,15 +1595,16 @@ def _paged_kernel(off_ref, pt_ref, row_ref, blk_ref, q_ref, k_ref,
     row, kb = row_ref[t], blk_ref[t]
     offset = off_ref[row]
     last = jnp.minimum((offset + (window - 1)) // block_kv, num_kv - 1)
-    alive = jnp.logical_and(pt_ref[row, 0] != NULL_PAGE, last >= 0)
+    first = _reach_first(offset, reach, block_kv)
+    alive = jnp.logical_and(pt_ref[row, 0] != NULL_PAGE, last >= first)
     mine = jax.lax.broadcasted_iota(
         jnp.int32, q_ref.shape[1:], 2) == row % LANES    # [h, d, 128]
 
-    @pl.when(jnp.logical_and(row % LANES == 0, kb == 0))
+    @pl.when(jnp.logical_and(row % LANES == 0, kb == first))
     def _zero():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    @pl.when(jnp.logical_and(alive, kb == 0))
+    @pl.when(jnp.logical_and(alive, kb == first))
     def _first():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
@@ -1597,9 +1635,11 @@ def _paged_kernel(off_ref, pt_ref, row_ref, blk_ref, q_ref, k_ref,
             # the column on every lane: one copy per 128 keys of the
             # block, whole vregs side by side
             q = jnp.concatenate([q_scr[j]] * (block_kv // LANES), axis=2)
+            live = k_pos <= offset + j
+            if reach is not None:
+                live = jnp.logical_and(live, k_pos > offset + j - reach)
             m_scr[j], l_scr[j], acc_scr[j] = _decode_block(
-                q, k, v, k_pos <= offset + j, sm_scale,
-                m_scr[j], l_scr[j], acc_scr[j])
+                q, k, v, live, sm_scale, m_scr[j], l_scr[j], acc_scr[j])
 
     @pl.when(jnp.logical_and(alive, kb == last))
     def _finish():
@@ -1612,26 +1652,27 @@ def _paged_kernel(off_ref, pt_ref, row_ref, blk_ref, q_ref, k_ref,
 
 
 def _paged_vmem_bytes(window, h, d, block_kv, q_item, kv_item,
-                      quantized) -> int:
+                      quantized, kv_heads=None) -> int:
     """What one grid step of the paged kernel holds in VMEM: the
     ``q`` block (one buffer) and the output block (two), the K and V
-    blocks double-buffered (with their scale blocks), the lifted
-    query columns (fp32, on all 128 lanes), and the block math's
-    widened K and V, one ``[h, d, bkv]`` product and a score / prob
-    pair per window position."""
+    blocks of ``kv_heads`` heads (``h`` unless grouped) double-buffered
+    (with their scale blocks), the lifted query columns (fp32, on all
+    128 lanes), and the block math's widened K and V, one ``[h, d,
+    bkv]`` product and a score / prob pair per window position."""
     dd = max(d, 8)
+    g = kv_heads or h
     n = 3 * window * h * dd * LANES * q_item
-    n += 4 * h * dd * block_kv * kv_item
+    n += 4 * g * dd * block_kv * kv_item
     if quantized:
-        n += 4 * h * 8 * block_kv * 4
+        n += 4 * g * 8 * block_kv * 4
     n += window * h * dd * LANES * 4
-    n += 3 * h * dd * block_kv * 4 + window * 2 * h * block_kv * 4
+    n += (2 * g + h) * dd * block_kv * 4 + window * 2 * h * block_kv * 4
     return n
 
 
 def flash_decode_paged(q, k, v, query_offsets, page_table, bias=None,
                        block_kv: int = DEFAULT_BLOCK_KV,
-                       k_scale=None, v_scale=None):
+                       k_scale=None, v_scale=None, reach=None):
     """Per-row decode through a PAGED KV pool: row ``i`` of
     ``q [b, 1, h, d]`` attends to positions ``<= query_offsets[i]`` of
     its logical cache, whose physical storage is scattered across the
@@ -1662,6 +1703,16 @@ def flash_decode_paged(q, k, v, query_offsets, page_table, bias=None,
     ``i`` sits at ``query_offsets[i] + j`` and sees keys up to there
     (the within-window causal mask of :func:`flash_decode_ragged`).
 
+    Both of these are read off the operands, one kernel either way.
+    Grouped-query heads: a pool of ``g`` heads under ``h = g * m``
+    query heads (query head ``m * i + j`` reads K/V head ``i``); the
+    ``m`` heads of a group share each resident block, nothing is
+    repeated in HBM, and ``m`` is padded to whole sublane tiles (zero
+    query rows, dropped from the output). ``reach``: a sliding window
+    of that many keys (key ``j`` visible iff ``off - reach < j <=
+    off``); a row's walk then starts at the window's first block,
+    which is masked inside.
+
     Inference-only; no bias operand (serving decode carries none —
     per-slot validity lives in the offsets and the table). Raises
     NotImplementedError where the caller must fall back to the XLA
@@ -1683,11 +1734,19 @@ def flash_decode_paged(q, k, v, query_offsets, page_table, bias=None,
         raise NotImplementedError("empty decode window")
     if d % 8:
         raise NotImplementedError(f"head_dim {d} unsupported")
-    if k.ndim != 4 or k.shape[1] != h or k.shape[2] != d:
+    if k.ndim != 4 or h % k.shape[1] or k.shape[2] != d:
         raise NotImplementedError(
-            f"paged pool must be [P, {h}, {d}, page], got {k.shape}")
-    page = k.shape[3]
-    quantized = _check_kv_scales(k, v, k_scale, v_scale, h, page)
+            f"paged pool must be [P, g, {d}, page] with g dividing "
+            f"{h}, got {k.shape}")
+    page, g = k.shape[3], k.shape[1]
+    quantized = _check_kv_scales(k, v, k_scale, v_scale, g, page)
+    if g != h:
+        # a group's query heads on whole sublane tiles
+        m = h // g
+        pad = -m % 8
+        q = jnp.pad(q.reshape(b, window, g, m, d),
+                    ((0, 0),) * 3 + ((0, pad), (0, 0))
+                    ).reshape(b, window, g * (m + pad), d)
     offs = jnp.asarray(query_offsets, jnp.int32)
     if offs.ndim != 1 or offs.shape[0] != b:
         raise NotImplementedError(
@@ -1702,8 +1761,9 @@ def flash_decode_paged(q, k, v, query_offsets, page_table, bias=None,
     block_kv = _auto_block(page, block_kv, 128)
 
     def vmem(bkv):
-        return _paged_vmem_bytes(window, h, d, bkv, q.dtype.itemsize,
-                                 k.dtype.itemsize, quantized)
+        return _paged_vmem_bytes(window, q.shape[2], d, bkv,
+                                 q.dtype.itemsize, k.dtype.itemsize,
+                                 quantized, g)
 
     while block_kv > 128 and page % (block_kv // 2) == 0 and \
             vmem(block_kv) > VMEM_DEFAULT // 2:
@@ -1720,17 +1780,22 @@ def flash_decode_paged(q, k, v, query_offsets, page_table, bias=None,
     operands = (q, k, v, offs, pt)
     if quantized:
         operands += (k_scale, v_scale)
-    return _flash_decode_paged_call(
+    out = _flash_decode_paged_call(
         *operands, block_kv=block_kv,
         vmem_limit=max(vmem(block_kv) * 5 // 4, VMEM_DEFAULT),
-        interpret=_interpret())
+        interpret=_interpret(), reach=reach)
+    if g != h:
+        out = out.reshape(b, window, g, -1, d)[:, :, :, :h // g].reshape(
+            b, window, h, d)
+    return out
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_kv", "vmem_limit", "interpret"),
+    jax.jit,
+    static_argnames=("block_kv", "vmem_limit", "interpret", "reach"),
     inline=True)
 def _flash_decode_paged_call(q, k, v, offs, pt, *scales, block_kv,
-                             vmem_limit, interpret):
+                             vmem_limit, interpret, reach=None):
     """The walk and the ``pallas_call``, jitted so that a model's
     layers trace ONE kernel per shape (the 24-layer tick lowers in 1.1
     s where 24 traces took 1.9 s), and ``inline`` with no ``name=`` on
@@ -1743,7 +1808,8 @@ def _flash_decode_paged_call(q, k, v, offs, pt, *scales, block_kv,
     bpp = page // block_kv                     # blocks per page
     num_kv = pt.shape[1] * bpp                 # a row's capacity walk
     rows, blocks, steps = _paged_walk(offs, pt, window, block_kv,
-                                      num_kv)
+                                      num_kv, reach)
+    g = k.shape[1]
 
     def kv_block(t, off, pt, rows, blocks):
         kb = blocks[t]
@@ -1761,17 +1827,18 @@ def _flash_decode_paged_call(q, k, v, offs, pt, *scales, block_kv,
         # buffer (a wide window's second would not fit)
         pl.BlockSpec((window, h, d, LANES), lane_group,
                      pipeline_mode=pl.Buffered(1)),
-        pl.BlockSpec((1, h, d, block_kv), kv_block),
-        pl.BlockSpec((1, h, d, block_kv), kv_block),
+        pl.BlockSpec((1, g, d, block_kv), kv_block),
+        pl.BlockSpec((1, g, d, block_kv), kv_block),
     ]
     # scale pools redirect through the SAME page-table index map as
     # their K/V tiles (d axis collapsed to 1)
-    in_specs += [pl.BlockSpec((1, h, 1, block_kv), kv_block)
+    in_specs += [pl.BlockSpec((1, g, 1, block_kv), kv_block)
                  for _ in scales]
     out = pl.pallas_call(
         functools.partial(_paged_kernel, sm_scale=d ** -0.5,
                           block_kv=block_kv, num_kv=num_kv,
-                          window=window, quantized=bool(scales)),
+                          window=window, quantized=bool(scales),
+                          reach=reach),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(steps,),

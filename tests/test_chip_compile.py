@@ -609,3 +609,60 @@ def test_grouped_matmul_compiles_on_2x2_mesh(mesh4, as_tpu):
     # fwd: 2 GEMMs; bwd: dw for each + dx of the second (x itself is
     # not differentiated here)
     assert txt.count("tpu_custom_call") >= 5
+
+
+# -- one chip: the SmallThinker serving cell's kernels ------------------
+#
+# smallthinker.serve-mixed-len: 48 slots, 28 query heads over 4 pooled
+# K/V heads of 128, pages of 128, a table of 128 pages; the window
+# class's leaf is one ring of 35 pages a slot; 64 experts of 2560 x 768.
+
+ST_SLOTS, ST_H, ST_G, ST_D, ST_PAGES = 48, 28, 4, 128, 128
+
+
+@pytest.mark.parametrize("reach", [None, 4096])
+@pytest.mark.parametrize("window", [1, 5])
+def test_flash_decode_paged_gqa_window_compiles(window, reach, one_chip,
+                                                as_tpu):
+    """Grouped-query heads (7 query heads a pooled head, padded to a
+    sublane tile) and the walk that starts at the window's first
+    block: Mosaic takes both."""
+    from paddlefleetx_tpu.ops.pallas import flash_attention as fa
+    pool = 1 + ST_SLOTS * 35
+    q = _sds((ST_SLOTS, window, ST_H, ST_D), BF16, one_chip)
+    off = _sds((ST_SLOTS,), jnp.int32, one_chip)
+    table = _sds((ST_SLOTS, ST_PAGES), jnp.int32, one_chip)
+    kv = [_sds((pool, ST_G, ST_D, 128), BF16, one_chip)] * 2
+    fn = functools.partial(fa.flash_decode_paged, reach=reach)
+    assert "tpu_custom_call" in _compile(fn, q, *kv, off, table).as_text()
+
+
+def test_kv_write_compiles_at_four_pooled_heads(one_chip, as_tpu):
+    """The write kernel takes a pool of 4 heads of 128 by shape."""
+    from paddlefleetx_tpu.ops.pallas import kv_write as kw
+    shape = (1 + ST_SLOTS * 35, ST_G, ST_D, 128)
+    leaves = [_sds(shape, BF16, one_chip)] * 2
+    news = [_sds((ST_SLOTS, 1, ST_G, ST_D), BF16, one_chip)] * 2
+    idx = _sds((ST_SLOTS, 1), jnp.int32, one_chip)
+    compiled = jax.jit(kw.kv_write, donate_argnums=0).lower(
+        leaves, idx, idx, news).compile()
+    assert _kv_write_calls(compiled) == 1
+
+
+@pytest.mark.parametrize("rows", [ST_SLOTS, 256])
+def test_ragged_matmul_compiles_at_decode_tiles(rows, one_chip, as_tpu):
+    """The grouped products of a decode tick (48 rows x 6 picks over
+    64 experts: tiles of 16 rows) and of a prefill chunk (256 rows:
+    tiles of 32), gate|up and down."""
+    from paddlefleetx_tpu.models.deepseek_v3.moe import block_rows
+    from paddlefleetx_tpu.ops.pallas.grouped_matmul import ragged_matmul
+    block = block_rows(rows * 6, 64)
+    tiles = -(-rows * 6 // block) + 64
+    for k, n in ((2560, 1536), (768, 2560)):
+        x = _sds((tiles * block, k), BF16, one_chip)
+        w = _sds((64, k, n), BF16, one_chip)
+        table = _sds((tiles,), jnp.int32, one_chip)
+        used = _sds((), jnp.int32, one_chip)
+        fn = functools.partial(ragged_matmul, block_m=block)
+        assert "tpu_custom_call" in _compile(
+            fn, x, w, table, used).as_text()
