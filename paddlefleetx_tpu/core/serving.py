@@ -72,7 +72,12 @@ round-trip per up-to-T ticks instead of per tick — the host-overhead
 kill for latency-bound small-batch decode (docs/inference.md
 "Device-resident decode"). T=1 (the default) launches the one-tick
 ``decode_step``/``verify_step`` programs from the same step body; any
-T commits the same tokens.
+T commits the same tokens. Whichever of the four programs runs, the
+host reads the device ONCE a launch: tokens, counts, ``finished``,
+``dec_count``, ticks run and exit code come home in one int32 array
+(``generation.pack_harvest``) whose copy ``_launch`` asks for at the
+dispatch (``copy_to_host_async``), so it starts when the program ends
+and not when the host has noticed (docs/inference.md "The harvest").
 
 Graceful degradation (docs/robustness.md): per-request deadlines/TTL
 (``submit(deadline_s=...)`` or a server-wide ``request_ttl_s``) evict
@@ -96,8 +101,10 @@ spec decode 1 tick != 1 token), the tiered ``serving/spill`` /
 ``serving/spec_accepted`` counters + ``serving/spec_accept_rate``
 gauge, the ``serving/device_ticks`` counter and per-reason
 ``serving/loop_exit/{finished,admission,budget,drain}`` counters of
-the fused loop, the ``serving/slow_steps`` /
-``serving/slow_step/<phase>`` counters of the slow-step record, and a
+the fused loop, the ``serving/d2h_reads`` counter (arrays pulled to
+the host inside ``step()``: one a decoding step), the
+``serving/slow_steps`` / ``serving/slow_step/<phase>`` counters of the
+slow-step record, and a
 tokens/s + TTFT p50/p99 summary;
 an optional flight recorder mirrors admissions/evictions to an
 ``events.jsonl`` stream CI's failure-diagnostics artifact collects.
@@ -143,11 +150,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.gpt.generation import (
-    LOOP_EXIT_BUDGET, LOOP_EXIT_FINISHED, GenerationConfig,
-    _unrolled_twin, activate_slot, copy_kv_pages, decode_loop,
-    decode_step, gather_kv_pages, init_page_pool, init_slot_cache,
-    init_slot_state, prefill_chunk_paged, prefill_into_slots,
-    scatter_kv_pages, verify_loop, verify_step,
+    LOOP_EXIT_BUDGET, LOOP_EXIT_FINISHED, LOOP_EXIT_NONE,
+    GenerationConfig, _unrolled_twin, activate_slot, copy_kv_pages,
+    decode_loop, decode_step, gather_kv_pages, init_page_pool,
+    init_slot_cache, init_slot_state, prefill_chunk_paged,
+    prefill_into_slots, scatter_kv_pages, unpack_harvest, verify_loop,
+    verify_step,
 )
 from ..observability import metrics
 from ..observability import server as obs_server
@@ -1262,6 +1270,7 @@ class GenerationServer:
         with annotate("serving/step/prefill_harvest", ph):
             # the last real token sits at chunk row L - 1 - c0
             last = np.asarray(logits[0, L - 1 - c0])
+            metrics.inc("serving/d2h_reads")
         with annotate("serving/step/prefill_pump", ph):
             self._activate(slot, last)
             # adapter-tinted KV must never enter the shared registries
@@ -1361,6 +1370,7 @@ class GenerationServer:
             # round trip or the resumed stream's next draw is biased
             req["spec_rejected"] = int(
                 np.asarray(self._state.rejected)[victim])
+            metrics.inc("serving/d2h_reads")
         self._release_pages(victim)
         # the pin drops but the adapter stays resident/warm —
         # re-admission re-pins it (a hit) and resumes token-exactly
@@ -1907,42 +1917,38 @@ class GenerationServer:
             return need > self._alloc.free_pages
         return False
 
-    def _launch(self, drafts, host_flag: bool):
+    def _launch(self, drafts, host_flag: bool) -> jax.Array:
         """The step's one device launch, and the only place that
-        knows which of the four tick programs runs. Hands back device
-        arrays of the tokens (``slots x ticks x (k+1)`` of them,
-        whatever their shape) and, speculative only, the counts
-        committed per tick; the ticks run; the loop's exit code
-        (None from a one-tick program, which has none)."""
+        knows which of the four tick programs runs. Hands back the
+        launch's harvest array (``generation.pack_harvest``: tokens,
+        counts, ``finished``, ``dec_count``, ticks run, exit code)
+        with its copy to the host already asked for: the copy is
+        queued behind the program and starts when it ends, without
+        waiting for the host to notice."""
         T = self._loop_ticks
         pt = self._pt_dev_dec if self.paged else None
-        if T == 1:
-            if self.spec:
-                self._cache, self._state, window, counts = \
-                    verify_step(
-                        self.model, self.params, self._cache,
-                        self._state, jnp.asarray(drafts[:, 0]),
-                        self._rng, self.gen_cfg, pt, self._aid_arg())
-                return window, counts, 1, None
-            self._cache, self._state, tok = decode_step(
-                self.model, self.params, self._cache,
-                self._state, self._rng, self.gen_cfg, pt,
-                self._aid_arg())
-            return tok, None, 1, None
-        if self.spec:
-            (self._cache, self._state, window, counts,
-             ticks_run, exit_code) = verify_loop(
+        if T == 1 and self.spec:
+            self._cache, self._state, harvest = verify_step(
+                self.model, self.params, self._cache, self._state,
+                jnp.asarray(drafts[:, 0]), self._rng, self.gen_cfg,
+                pt, self._aid_arg())
+        elif T == 1:
+            self._cache, self._state, harvest = decode_step(
+                self.model, self.params, self._cache, self._state,
+                self._rng, self.gen_cfg, pt, self._aid_arg())
+        elif self.spec:
+            self._cache, self._state, harvest = verify_loop(
                 self.model, self.params, self._cache, self._state,
                 jnp.asarray(drafts), self._rng, self.gen_cfg,
                 jnp.int32(host_flag), pt, self._aid_arg(),
                 loop_ticks=T)
-            return window, counts, ticks_run, exit_code
-        (self._cache, self._state, tokens, ticks_run,
-         exit_code) = decode_loop(
-            self.model, self.params, self._cache, self._state,
-            self._rng, self.gen_cfg, jnp.int32(host_flag), pt,
-            self._aid_arg(), loop_ticks=T)
-        return tokens, None, ticks_run, exit_code
+        else:
+            self._cache, self._state, harvest = decode_loop(
+                self.model, self.params, self._cache, self._state,
+                self._rng, self.gen_cfg, jnp.int32(host_flag), pt,
+                self._aid_arg(), loop_ticks=T)
+        harvest.copy_to_host_async()
+        return harvest
 
     def _run_step(self, rec: StepRecord) -> List[Completion]:
         """The body of :meth:`step`: schedule, draft, map pages,
@@ -2007,21 +2013,13 @@ class GenerationServer:
             with annotate("serving/step/table_sync", ph):
                 self._sync_pt()
         with annotate("serving/step/decode_dispatch", ph):
-            tokens, counts, ticks_run, exit_code = self._launch(
-                drafts, host_flag)
+            harvest = self._launch(drafts, host_flag)
         with annotate("serving/step/decode_harvest", ph):
-            # the host blocked on the launch
-            n_ticks = int(ticks_run)
-            window = np.asarray(tokens).reshape(
-                self.num_slots, T, k + 1)
-            if self.spec:
-                counts = np.asarray(counts).reshape(self.num_slots, T)
-            else:
-                counts = np.zeros((self.num_slots, T), np.int32)
-                counts[:, :n_ticks] = 1
-        with annotate("serving/step/state_fetch", ph):
-            finished = np.asarray(self._state.finished)
-            dec_count = np.asarray(self._state.dec_count)
+            # the host blocked on the launch: the step's ONE read of
+            # the device, everything the commit below needs
+            window, counts, finished, dec_count, n_ticks, exit_code = \
+                unpack_harvest(np.asarray(harvest), self.num_slots, T, k)
+            metrics.inc("serving/d2h_reads")
         with annotate("serving/step/commit", ph):
             if self._watchdog is not None:
                 self._watchdog.disarm()
@@ -2029,8 +2027,7 @@ class GenerationServer:
             self._roundtrips += 1
             rec.ticks = n_ticks
             metrics.inc("serving/device_ticks", n_ticks)
-            if exit_code is not None:
-                exit_code = int(exit_code)
+            if exit_code != LOOP_EXIT_NONE:
                 metrics.inc(
                     "serving/loop_exit/finished"
                     if exit_code == LOOP_EXIT_FINISHED

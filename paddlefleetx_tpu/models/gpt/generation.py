@@ -13,6 +13,7 @@ static number of decode steps, cache preallocated at
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from collections.abc import Mapping
 from typing import NamedTuple, Optional
@@ -731,11 +732,14 @@ def decode_step(model, params, cache, state: SlotState,
     writes land at their stale position and are overwritten before any
     later read (prefill rewrites the full row at admission).
 
-    Returns ``(cache, state, tokens)`` — ``tokens [slots]`` is what
-    each slot emitted this tick (pad for finished/inactive slots).
+    Returns ``(cache, state, harvest)`` — :func:`pack_harvest` at
+    ``T = 1, k = 0``: its ``window[:, 0, 0]`` is what each slot
+    emitted this tick (pad for finished/inactive slots).
     """
-    return _decode_tick_impl(model, params, cache, state, rng,
-                             gen_cfg, page_table, adapter_ids)
+    cache, state, token = _decode_tick_impl(
+        model, params, cache, state, rng, gen_cfg, page_table,
+        adapter_ids)
+    return cache, state, pack_harvest(token[:, None, None], None, state)
 
 
 #: fold_in salt separating a verify tick's ACCEPT uniform at request
@@ -905,13 +909,17 @@ def verify_step(model, params, cache, state: SlotState,
     before any masked read reaches them (paged: the server frees/nulls
     pages past the accepted point).
 
-    Returns ``(cache, state, window, counts)`` — ``window [slots,
-    k+1]`` holds the tick's token run (entry 0 = ``t0``), ``counts
-    [slots]`` how many of them committed (1..k+1; the host appends
-    ``window[slot, :counts[slot]]``).
+    Returns ``(cache, state, harvest)`` — :func:`pack_harvest` at
+    ``T = 1``: ``window[:, 0] [slots, k+1]`` holds the tick's token
+    run (entry 0 = ``t0``), ``counts[:, 0] [slots]`` how many of them
+    committed (1..k+1; the host appends
+    ``window[slot, 0, :counts[slot, 0]]``).
     """
-    return _verify_tick_impl(model, params, cache, state, drafts,
-                             rng, gen_cfg, page_table, adapter_ids)
+    cache, state, window, counts = _verify_tick_impl(
+        model, params, cache, state, drafts, rng, gen_cfg, page_table,
+        adapter_ids)
+    return cache, state, pack_harvest(window[:, None], counts[:, None],
+                                      state)
 
 
 # -- device-resident decode: T ticks per host round-trip ---------------
@@ -930,6 +938,8 @@ def verify_step(model, params, cache, state: SlotState,
 # can count serving/loop_exit/{finished,admission,budget,drain}
 # (docs/inference.md "Device-resident decode").
 
+#: what a one-tick program reports: it has no loop to exit
+LOOP_EXIT_NONE = 0
 #: a slot emitted EOS — the host must evict before the next tick
 LOOP_EXIT_FINISHED = 1
 #: a slot's decode budget expired (dec_count hit max_dec_len), or the
@@ -938,6 +948,86 @@ LOOP_EXIT_BUDGET = 2
 #: the host-signaled flag was set at launch (pending admission, drain,
 #: or page-pool preemption risk) — the loop ran exactly one tick
 LOOP_EXIT_HOST = 3
+
+
+# -- the harvest: what the host reads after a launch, in ONE array -----
+#
+# A launch's host-read outputs (tokens, counts, ``finished`` and
+# ``dec_count`` as they stand after it, the loop's ticks and exit code)
+# are ready at the same moment, and each device-to-host read is a round
+# trip of pure latency. The four tick programs therefore hand them over
+# as one flat int32 array whose layout depends on the static
+# ``(slots, T, k)`` alone; the server asks for its copy at the launch
+# and reads it once (core/serving.py, docs/inference.md "The harvest").
+
+class Harvest(NamedTuple):
+    """One launch as the host reads it (:func:`unpack_harvest`)."""
+    #: [slots, T, k+1] int32 — tick ``j``'s token run per slot (pad
+    #: beyond ``ticks_run``)
+    window: np.ndarray
+    #: [slots, T] int32 — how many of them committed (without
+    #: speculation 1 in each tick run; 0 beyond ``ticks_run``)
+    counts: np.ndarray
+    #: [slots] bool — ``SlotState.finished`` after the launch
+    finished: np.ndarray
+    #: [slots] int32 — ``SlotState.dec_count`` after the launch
+    dec_count: np.ndarray
+    #: ticks executed (1..T)
+    ticks_run: int
+    #: a ``LOOP_EXIT_*`` code; ``LOOP_EXIT_NONE`` from a one-tick program
+    exit_code: int
+
+
+def _harvest_fields(slots: int, ticks: int, k: int):
+    """``((name, shape), ...)`` in the array's order — the layout's
+    one definition, shared by :func:`pack_harvest` and
+    :func:`unpack_harvest`."""
+    return (("window", (slots, ticks, k + 1)),
+            ("counts", (slots, ticks)),
+            ("finished", (slots,)),
+            ("dec_count", (slots,)),
+            ("ticks_run", ()),
+            ("exit_code", ()))
+
+
+def pack_harvest(window: jax.Array, counts, state: SlotState,
+                 ticks_run=1, exit_code=LOOP_EXIT_NONE) -> jax.Array:
+    """The launch's harvest array (trace-level; one small
+    concatenation). ``window`` is ``[slots, T, k+1]``; ``counts``
+    ``[slots, T]``, or None without speculation (every tick run
+    commits its one token)."""
+    slots, ticks, width = window.shape
+    if counts is None:
+        counts = jnp.broadcast_to(
+            jnp.arange(ticks, dtype=jnp.int32)[None, :] < ticks_run,
+            (slots, ticks))
+    parts = dict(window=window, counts=counts, finished=state.finished,
+                 dec_count=state.dec_count,
+                 ticks_run=jnp.asarray(ticks_run),
+                 exit_code=jnp.asarray(exit_code))
+    flat = []
+    for name, shape in _harvest_fields(slots, ticks, width - 1):
+        if parts[name].shape != shape:
+            raise ValueError(f"harvest field {name!r} is "
+                             f"{parts[name].shape}, its layout {shape}")
+        flat.append(jnp.ravel(parts[name]).astype(jnp.int32))
+    return jnp.concatenate(flat)
+
+
+def unpack_harvest(flat, slots: int, ticks: int, k: int) -> Harvest:
+    """The host copy of a :func:`pack_harvest` array, by field."""
+    flat = np.asarray(flat)
+    out, at = {}, 0
+    for name, shape in _harvest_fields(slots, ticks, k):
+        n = math.prod(shape)
+        out[name] = flat[at:at + n].reshape(shape)
+        at += n
+    if flat.shape != (at,):
+        raise ValueError(f"a harvest of {flat.shape} is not the layout "
+                         f"of {slots} slots x {ticks} ticks, k = {k}")
+    return Harvest(out["window"], out["counts"],
+                   out["finished"].astype(bool), out["dec_count"],
+                   int(out["ticks_run"]), int(out["exit_code"]))
 
 
 def _ring_write(buf: jax.Array, vals: jax.Array, tick: jax.Array,
@@ -993,11 +1083,11 @@ def decode_loop(model, params, cache, state: SlotState,
     has pending admission/drain/preemption work and wants control back
     after one tick; traced so flag flips never recompile).
 
-    Returns ``(cache, state, tokens_buf, ticks_run, exit_reason)`` —
-    ``tokens_buf [slots, loop_ticks]`` holds tick ``j``'s emitted
-    token per slot in column ``j`` (pad beyond ``ticks_run``),
-    ``ticks_run`` int32 how many ticks executed (1..loop_ticks), and
-    ``exit_reason`` one of the ``LOOP_EXIT_*`` codes.
+    Returns ``(cache, state, harvest)`` — :func:`pack_harvest` at
+    ``T = loop_ticks, k = 0``: ``window[:, j, 0]`` holds tick ``j``'s
+    emitted token per slot (pad beyond ``ticks_run``), ``ticks_run``
+    how many ticks executed (1..loop_ticks), and ``exit_code`` one of
+    the ``LOOP_EXIT_*`` codes.
     """
     if loop_ticks < 1:
         raise ValueError(f"loop_ticks must be >= 1, got {loop_ticks}")
@@ -1022,8 +1112,9 @@ def decode_loop(model, params, cache, state: SlotState,
 
     cache, state, tokens_buf, ticks = jax.lax.while_loop(
         cond, body, (cache, state, tokens_buf, jnp.int32(0)))
-    return (cache, state, tokens_buf, ticks,
-            _loop_exit_reason(state, gen_cfg, host_flag))
+    return cache, state, pack_harvest(
+        tokens_buf[:, :, None], None, state, ticks,
+        _loop_exit_reason(state, gen_cfg, host_flag))
 
 
 @partial(jax.jit, static_argnames=("model", "gen_cfg", "loop_ticks"),
@@ -1046,10 +1137,10 @@ def verify_loop(model, params, cache, state: SlotState,
     Exit conditions and the ``host_flag`` contract match
     :func:`decode_loop`.
 
-    Returns ``(cache, state, window_buf, counts_buf, ticks_run,
-    exit_reason)`` — tick ``j``'s token run is
-    ``window_buf[:, j] [slots, k+1]`` of which
-    ``counts_buf[:, j]`` committed per slot (0 beyond ``ticks_run``).
+    Returns ``(cache, state, harvest)`` — :func:`pack_harvest` at
+    ``T = loop_ticks``: tick ``j``'s token run is
+    ``window[:, j] [slots, k+1]`` of which ``counts[:, j]`` committed
+    per slot (0 beyond ``ticks_run``).
     """
     if loop_ticks < 1:
         raise ValueError(f"loop_ticks must be >= 1, got {loop_ticks}")
@@ -1084,8 +1175,9 @@ def verify_loop(model, params, cache, state: SlotState,
     cache, state, window_buf, counts_buf, ticks = jax.lax.while_loop(
         cond, body,
         (cache, state, window_buf, counts_buf, jnp.int32(0)))
-    return (cache, state, window_buf, counts_buf, ticks,
-            _loop_exit_reason(state, gen_cfg, host_flag))
+    return cache, state, pack_harvest(
+        window_buf, counts_buf, state, ticks,
+        _loop_exit_reason(state, gen_cfg, host_flag))
 
 
 # -- paged KV primitives (core/paging.py owns the host bookkeeping) ----

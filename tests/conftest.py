@@ -25,6 +25,18 @@ def _reset_global_mesh():
     set_mesh(None)
 
 
+@pytest.fixture(autouse=True)
+def _restore_global_telemetry():
+    """The process-wide metrics registry goes back to the on/off state
+    a test found it in: a test that enables it (or an Engine built
+    with ``Telemetry.enable``) must not decide what the next file in
+    the same xdist worker measures."""
+    from paddlefleetx_tpu.observability import metrics
+    prior = metrics.get_registry().enabled
+    yield
+    metrics.set_enabled(prior)
+
+
 # -- quick tier --------------------------------------------------------
 # `pytest -m "not slow"` is the fast feedback loop (<10 min); the full
 # suite runs everything. Centralized here (not as scattered decorators)

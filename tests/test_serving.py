@@ -71,6 +71,15 @@ def _snapshot(cache):
     return jax.tree.map(jnp.copy, cache)
 
 
+def _harvest(harvest, ticks=1, k=0):
+    """A tick program's third output, unpacked on the host (the slot
+    count follows from the array's length and the static ``T, k``)."""
+    from paddlefleetx_tpu.models.gpt.generation import unpack_harvest
+    flat = np.asarray(harvest)
+    slots = (flat.size - 2) // (ticks * (k + 2) + 2)
+    return unpack_harvest(flat, slots, ticks, k)
+
+
 def _lockstep(model, params, prompts, gen_cfg):
     """Reference rows from the lockstep path, truncated at EOS
     (inclusive) — exactly what a Completion.tokens should hold."""
@@ -789,18 +798,18 @@ def test_spec_greedy_chain_stops_at_first_mismatch(model_and_params):
     seq = []
     c, s = _snapshot(cache), state
     for _ in range(4):
-        c, s, tok = decode_step(model_u, params_u, c, s,
-                                srv._rng, gen_cfg)
-        seq.append(np.asarray(tok))
+        c, s, h = decode_step(model_u, params_u, c, s,
+                              srv._rng, gen_cfg)
+        seq.append(_harvest(h).window[:, 0, 0])
     seq = np.stack(seq, 1)                    # [slots, 4]
     drafts = seq[:, 1:].copy()
     drafts[:, 2] = (seq[:, 3] + 7) % 90       # wrong at j=3
-    _, s2, window, counts = verify_step(
+    _, s2, h = verify_step(
         model_u, params_u, cache, state,
         jnp.asarray(drafts, jnp.int32), srv._rng, gen_cfg)
-    assert np.asarray(counts).tolist() == [3, 3]
-    np.testing.assert_array_equal(np.asarray(window)[:, :3],
-                                  seq[:, :3])
+    got = _harvest(h, k=3)
+    assert got.counts[:, 0].tolist() == [3, 3]
+    np.testing.assert_array_equal(got.window[:, 0, :3], seq[:, :3])
     # lengths/dec_count advanced by the per-slot committed counts
     assert (np.asarray(s2.lengths) - np.asarray(state.lengths)
             ).tolist() == [3, 3]
@@ -831,26 +840,27 @@ def test_spec_sampling_accept_rule(model_and_params):
     seq = []
     c, s = _snapshot(cache), state
     for _ in range(3):
-        c, s, tok = decode_step(model_u, params_u, c, s,
-                                srv._rng, gen_cfg)
-        seq.append(np.asarray(tok))
+        c, s, h = decode_step(model_u, params_u, c, s,
+                              srv._rng, gen_cfg)
+        seq.append(_harvest(h).window[:, 0, 0])
     seq = np.stack(seq, 1)                    # [slots, 3]
     # (a) true continuation -> all accepted (p(draft) ~ 1)
-    _, s_ok, window, counts = verify_step(
+    _, s_ok, h = verify_step(
         model_u, params_u, _snapshot(cache), state,
         jnp.asarray(seq[:, 1:], jnp.int32), srv._rng, gen_cfg)
-    assert np.asarray(counts).tolist() == [3, 3]
-    np.testing.assert_array_equal(np.asarray(window), seq)
+    got = _harvest(h, k=2)
+    assert got.counts[:, 0].tolist() == [3, 3]
+    np.testing.assert_array_equal(got.window[:, 0], seq)
     assert np.asarray(s_ok.rejected).tolist() == [-1, -1]
     # (b) wrong first draft -> rejected (p(draft) ~ 0), only t0
     # commits, and the reject is recorded for the next tick's draw
     wrong = (seq[:, 1:].copy() + 11) % 90
-    _, s_rej, window2, counts2 = verify_step(
+    _, s_rej, h2 = verify_step(
         model_u, params_u, cache, state,
         jnp.asarray(wrong, jnp.int32), srv._rng, gen_cfg)
-    assert np.asarray(counts2).tolist() == [1, 1]
-    np.testing.assert_array_equal(np.asarray(window2)[:, 0],
-                                  seq[:, 0])
+    got2 = _harvest(h2, k=2)
+    assert got2.counts[:, 0].tolist() == [1, 1]
+    np.testing.assert_array_equal(got2.window[:, 0, 0], seq[:, 0])
     assert np.asarray(s_rej.rejected).tolist() == \
         wrong[:, 0].tolist()
 
@@ -874,16 +884,14 @@ def test_spec_rejected_token_excluded_from_next_draw(model_and_params):
     cache, state = srv._cache, srv._state
     k = 2
     zeros = jnp.zeros((2, k), jnp.int32)
-    _, _, window, _ = verify_step(srv.model, srv.params,
-                                  _snapshot(cache), state, zeros,
-                                  srv._rng, gen_cfg)
-    t0 = np.asarray(window)[:, 0]             # the point-mass tokens
+    _, _, h = verify_step(srv.model, srv.params, _snapshot(cache),
+                          state, zeros, srv._rng, gen_cfg)
+    t0 = _harvest(h, k=k).window[:, 0, 0]     # the point-mass tokens
     state_rej = state._replace(
         rejected=jnp.asarray(t0, jnp.int32))
-    _, _, window2, _ = verify_step(srv.model, srv.params, cache,
-                                   state_rej, zeros, srv._rng,
-                                   gen_cfg)
-    t0_excl = np.asarray(window2)[:, 0]
+    _, _, h2 = verify_step(srv.model, srv.params, cache, state_rej,
+                           zeros, srv._rng, gen_cfg)
+    t0_excl = _harvest(h2, k=k).window[:, 0, 0]
     assert all(a != b for a, b in zip(t0_excl, t0))
 
 
@@ -1579,7 +1587,7 @@ def test_decode_loop_t1_matches_decode_step(model_and_params):
     contract — a structure change would silently recompile every
     launch)."""
     from paddlefleetx_tpu.models.gpt.generation import (
-        LOOP_EXIT_BUDGET, decode_loop, decode_step,
+        LOOP_EXIT_BUDGET, LOOP_EXIT_NONE, decode_loop, decode_step,
     )
     model, params = model_and_params
     gen_cfg = _greedy_cfg()
@@ -1589,15 +1597,20 @@ def test_decode_loop_t1_matches_decode_step(model_and_params):
     srv._admit()
     model_u, params_u = srv.model, srv.params
     cache, state = srv._cache, srv._state
-    c1, s1, tok = decode_step(model_u, params_u, _snapshot(cache),
-                              state, srv._rng, gen_cfg)
-    c2, s2, buf, ticks, reason = decode_loop(
+    c1, s1, h1 = decode_step(model_u, params_u, _snapshot(cache),
+                             state, srv._rng, gen_cfg)
+    c2, s2, h2 = decode_loop(
         model_u, params_u, cache, state, srv._rng, gen_cfg,
         jnp.int32(0), loop_ticks=1)
-    assert int(ticks) == 1
-    assert int(reason) == LOOP_EXIT_BUDGET  # full-T run, nothing else
-    np.testing.assert_array_equal(np.asarray(buf)[:, 0],
-                                  np.asarray(tok))
+    one, loop = _harvest(h1), _harvest(h2)
+    assert loop.ticks_run == 1
+    # full-T run, nothing else
+    assert loop.exit_code == LOOP_EXIT_BUDGET
+    # the one-tick program has no loop to exit; otherwise field for
+    # field the same harvest
+    assert one.exit_code == LOOP_EXIT_NONE
+    for a, b in zip(one[:-1], loop[:-1]):
+        np.testing.assert_array_equal(a, b)
     assert jax.tree_util.tree_structure(s2) == \
         jax.tree_util.tree_structure(state)
     assert jax.tree_util.tree_structure(c2) == \
@@ -1624,17 +1637,20 @@ def test_decode_loop_host_flag_exits_after_one_tick(model_and_params):
         srv.submit(p)
     srv._admit()
     cache, state = srv._cache, srv._state
-    _, _, tok = decode_step(srv.model, srv.params, _snapshot(cache),
-                            state, srv._rng, gen_cfg)
-    _, _, buf, ticks, reason = decode_loop(
+    _, _, h1 = decode_step(srv.model, srv.params, _snapshot(cache),
+                           state, srv._rng, gen_cfg)
+    _, _, h8 = decode_loop(
         srv.model, srv.params, cache, state, srv._rng, gen_cfg,
         jnp.int32(1), loop_ticks=8)
-    assert int(ticks) == 1
-    assert int(reason) == LOOP_EXIT_HOST
-    np.testing.assert_array_equal(np.asarray(buf)[:, 0],
-                                  np.asarray(tok))
-    # columns past ticks_run stay at the pad sentinel
-    assert (np.asarray(buf)[:, 1:] == PAD).all()
+    got = _harvest(h8, ticks=8)
+    assert got.ticks_run == 1
+    assert got.exit_code == LOOP_EXIT_HOST
+    np.testing.assert_array_equal(got.window[:, 0, 0],
+                                  _harvest(h1).window[:, 0, 0])
+    # columns past ticks_run stay at the pad sentinel, and commit
+    # nothing
+    assert (got.window[:, 1:] == PAD).all()
+    assert got.counts.tolist() == [[1] + [0] * 7] * 2
 
 
 def test_decode_loop_budget_exit(model_and_params):
@@ -1650,12 +1666,14 @@ def test_decode_loop_budget_exit(model_and_params):
     for p in PROMPTS[:2]:
         srv.submit(p)
     srv._admit()
-    _, s2, _, ticks, reason = decode_loop(
+    _, s2, h = decode_loop(
         srv.model, srv.params, srv._cache, srv._state, srv._rng,
         gen_cfg, jnp.int32(0), loop_ticks=16)
-    assert int(ticks) == 3
-    assert int(reason) == LOOP_EXIT_BUDGET
+    got = _harvest(h, ticks=16)
+    assert got.ticks_run == 3
+    assert got.exit_code == LOOP_EXIT_BUDGET
     assert np.asarray(s2.dec_count).tolist() == [3, 3]
+    assert got.dec_count.tolist() == [3, 3]
 
 
 def test_device_loop_exit_counters(model_and_params):
@@ -2328,8 +2346,8 @@ def _phase_server(paged512_model_and_params, **kw):
 #: (speculative servers add ``draft``)
 _DECODING_PHASES = ("expire", "spill_drain", "admit", "prefill_pump",
                     "table_sync", "page_maintenance", "draft",
-                    "decode_dispatch", "decode_harvest", "state_fetch",
-                    "commit", "ship_spills")
+                    "decode_dispatch", "decode_harvest", "commit",
+                    "ship_spills")
 
 
 @pytest.mark.parametrize("spec", [False, True], ids=["plain", "spec"])
@@ -2378,6 +2396,160 @@ def test_one_step_body_launches_the_modes_own_program(
     assert orders == {tuple(p for p in _DECODING_PHASES
                             if spec or p != "draft")
                       + ("unaccounted",)}
+
+
+@pytest.mark.parametrize("spec", [False, True], ids=["plain", "spec"])
+@pytest.mark.parametrize("loop_ticks", [1, 4])
+def test_the_launchs_harvest_holds_what_the_program_computed(
+        model_and_params, loop_ticks, spec):
+    """The ONE array a launch hands the host (``pack_harvest``, asked
+    home by ``_launch``) unpacks to exactly the tokens and counts the
+    tick body computes, ``finished`` and ``dec_count`` as the
+    ``SlotState`` the program returned holds them, the ticks run and
+    the exit code — for each of the four programs; first over a full
+    launch, then with a finished slot, a slot that reaches
+    ``max_dec_len`` and a free slot among the rows."""
+    from paddlefleetx_tpu.models.gpt import generation as g
+    model, params = model_and_params
+    T, k, slots, max_dec = loop_ticks, (2 if spec else 0), 4, 30
+    # EOS is held off so that the full launch runs its T ticks
+    gen_cfg = dataclasses.replace(_greedy_cfg(max_dec),
+                                  min_dec_len=max_dec)
+    if spec:
+        gen_cfg = _spec_cfg(gen_cfg, k)
+    srv = GenerationServer(model, params, gen_cfg, num_slots=slots,
+                           device_loop_ticks=T)
+    for p in PROMPTS[:3]:
+        srv.submit(p)
+    srv._admit()                              # slot 3 stays free
+    if spec:
+        body = jax.jit(g._verify_tick_impl,
+                       static_argnames=("model", "gen_cfg"))
+    else:
+        body = jax.jit(g._decode_tick_impl,
+                       static_argnames=("model", "gen_cfg"))
+    drafts = np.arange(slots * T * k, dtype=np.int32).reshape(
+        slots, T, k) % 90 if spec else None
+
+    def launch(want_ticks, want_exit):
+        """One ``_launch`` beside ``want_ticks`` runs of the tick body
+        from a copy of the same cache and state."""
+        cache, state = _snapshot(srv._cache), srv._state
+        window = np.full((slots, T, k + 1), PAD, np.int32)
+        counts = np.zeros((slots, T), np.int32)
+        for j in range(want_ticks):
+            if spec:
+                cache, state, w, c = body(
+                    srv.model, srv.params, cache, state,
+                    jnp.asarray(drafts[:, j]), srv._rng, gen_cfg)
+                window[:, j], counts[:, j] = np.asarray(w), np.asarray(c)
+            else:
+                cache, state, tok = body(srv.model, srv.params, cache,
+                                         state, srv._rng, gen_cfg)
+                window[:, j, 0], counts[:, j] = np.asarray(tok), 1
+        got = g.unpack_harvest(srv._launch(drafts, False), slots, T, k)
+        np.testing.assert_array_equal(got.window, window)
+        np.testing.assert_array_equal(got.counts, counts)
+        # the body's state and the state the program handed back
+        for st in (state, srv._state):
+            np.testing.assert_array_equal(got.finished,
+                                          np.asarray(st.finished))
+            np.testing.assert_array_equal(got.dec_count,
+                                          np.asarray(st.dec_count))
+        assert got.finished.dtype == bool
+        assert (got.ticks_run, got.exit_code) == (want_ticks, want_exit)
+        return got
+
+    try:
+        full = launch(T, g.LOOP_EXIT_BUDGET if T > 1
+                      else g.LOOP_EXIT_NONE)
+        assert not full.finished.any() and (full.counts[:3] >= 1).all()
+        assert full.dec_count[3] == 0
+        # slot 1 has emitted EOS and awaits its eviction, slot 2 has
+        # one token of budget left, slot 3 is free: a loop hands
+        # control back after one tick
+        srv._state = srv._state._replace(
+            finished=srv._state.finished.at[1].set(True),
+            dec_count=srv._state.dec_count.at[2].set(max_dec - 1))
+        mixed = launch(1, g.LOOP_EXIT_FINISHED if T > 1
+                       else g.LOOP_EXIT_NONE)
+        assert mixed.finished.tolist() == [False, True, False, False]
+        assert mixed.dec_count[2] == max_dec and mixed.dec_count[3] == 0
+        assert mixed.window[1, 0, 0] == PAD == mixed.window[3, 0, 0]
+        assert mixed.counts[2, 0] == 1        # t0 alone fits the budget
+    finally:
+        srv.close()
+
+
+def _steady_server(paged512_model_and_params, max_dec=40):
+    """A paged T = 1 server whose requests never hit EOS."""
+    model, params = paged512_model_and_params
+    gen_cfg = dataclasses.replace(_greedy_cfg(max_dec),
+                                  min_dec_len=max_dec)
+    return GenerationServer(model, params, gen_cfg, num_slots=2,
+                            page_size=128, prefill_chunk_pages=1)
+
+
+def test_a_decoding_step_reads_the_device_once(paged512_model_and_params):
+    """``serving/d2h_reads`` beside ``serving/device_ticks``: a paged
+    server in steady decode at T = 1 pulls ONE array to the host a
+    step (the harvest), and one more on the step that ends a prompt's
+    last chunk (``prefill_harvest``'s logits row)."""
+    metrics.set_enabled(True)
+    reg = metrics.get_registry()
+    reg.reset()
+    srv = _steady_server(paged512_model_and_params)
+
+    def step():
+        before = (reg.counter("serving/d2h_reads"),
+                  reg.counter("serving/device_ticks"))
+        srv.step()
+        return (reg.counter("serving/d2h_reads") - before[0],
+                reg.counter("serving/device_ticks") - before[1],
+                srv.last_step.chunks)
+    try:
+        srv.submit([5, 9, 2])
+        # its only chunk is its last: the logits row, then the tick
+        assert step() == (2, 1, 1)
+        assert [step() for _ in range(20)] == [(1, 1, 0)] * 20
+        assert reg.counter("serving/d2h_reads") - 1 == \
+            reg.counter("serving/device_ticks") == 21
+        # a second prompt, two chunks long: the first chunk's step
+        # reads the harvest alone, the last chunk's one more
+        srv.submit(list(range(1, 90)) * 2)
+        assert step() == (1, 1, 1)
+        assert step() == (2, 1, 1)
+        assert step() == (1, 1, 0)
+    finally:
+        srv.close()
+        metrics.set_enabled(False)
+        reg.reset()
+
+
+def test_step_record_has_no_state_fetch_phase(paged512_model_and_params):
+    """The reads of ``finished`` and ``dec_count`` came home with the
+    tokens: no ``serving/step/state_fetch`` in a decoding step's
+    record, and ``tick_seconds()`` is still the dispatch plus the
+    harvest."""
+    srv = _steady_server(paged512_model_and_params, max_dec=6)
+    try:
+        srv.submit([5, 9, 2])
+        seen = 0
+        while srv.pending or srv.occupancy:
+            srv.step()
+            rec = srv.last_step
+            if not rec.ticks:
+                continue
+            seen += 1
+            assert "serving/step/state_fetch" not in rec.phases
+            assert "state_fetch" not in rec.phases_ms()
+            assert rec.tick_seconds() == pytest.approx(
+                rec.phases["serving/step/decode_dispatch"]
+                + rec.phases["serving/step/decode_harvest"])
+            assert 0.0 < rec.tick_seconds() <= rec.seconds
+        assert seen == 6
+    finally:
+        srv.close()
 
 
 def test_health_snapshot_follows_a_t1_servers_steps(

@@ -249,6 +249,7 @@ def served(params, ref_forward):
     ``submit`` / ``step``; after every step the logits the next token is
     sampled from, beside the reference's full forward of the same
     sequence, and the page accounting."""
+    prior = metrics.get_registry().enabled
     metrics.set_enabled(True)
     metrics.get_registry().reset()
     gen = GenerationConfig(max_dec_len=DEC, decode_strategy="greedy_search",
@@ -277,6 +278,7 @@ def served(params, ref_forward):
     out = dict(srv=srv, ids=ids, prompts=prompts, steps=steps, done=done,
                most_global=most_global, summary=srv.summary(),
                counters=dict(metrics.get_registry().snapshot()["counters"]))
+    metrics.set_enabled(prior)
     yield out
     srv.close()
 

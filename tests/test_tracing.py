@@ -579,13 +579,15 @@ def test_annotate_cost_with_no_profiler_session_stays_in_budget():
     import timeit
 
     from paddlefleetx_tpu.observability.trace import annotate
-    assert not metrics.get_registry().enabled
     rec = {}
 
     def one():
         with annotate("serving/step/admit", rec):
             pass
     n = 10_000
+    # the state the budget is stated for is this test's to set, not a
+    # neighbour's to have left behind (``conftest.py`` restores it)
+    metrics.set_enabled(False)
     per_call = min(timeit.timeit(one, number=n) for _ in range(5)) / n
     assert per_call < 0.01 * 0.010, per_call
     assert rec["serving/step/admit"] > 0.0
@@ -674,8 +676,7 @@ def test_server_phases_land_on_the_profilers_clock(tmp_path):
     assert set(phases) == {
         "expire", "spill_drain", "admit", "prefill_pump",
         "prefill_harvest", "page_maintenance", "table_sync",
-        "decode_dispatch", "decode_harvest", "state_fetch", "commit",
-        "ship_spills"}
+        "decode_dispatch", "decode_harvest", "commit", "ship_spills"}
     for name, spans in phases.items():
         for s, e in spans:
             assert any(rs <= s and e <= re_ for rs, re_ in roots), name
