@@ -346,6 +346,10 @@ class GenerationServer:
                 # page class: a ring of pages a slot (below)
                 cfg = cfg.window_class(num_slots,
                                        page_size * prefill_chunk_pages)
+            if hasattr(cfg, "state_class"):
+                # a model with linear-attention layers brings a third
+                # kind of cache: a row of recurrent state a slot (below)
+                cfg = cfg.state_class(num_slots)
             model = type(model)(cfg)
             if cfg.max_kv_pages % prefill_chunk_pages:
                 raise ValueError(
@@ -373,8 +377,36 @@ class GenerationServer:
             self._ring_cols = (
                 1 + np.arange(num_slots, dtype=np.int32)[:, None]
                 * self._ring + np.arange(self._ring, dtype=np.int32))
-            self._prefix_refused = bool(prefix_sharing and self._ring)
-            self._prefix_sharing = bool(prefix_sharing) and not self._ring
+            # the state class (models/solar_open2): each slot owns ONE
+            # row of every linear-attention layer's state leaves (a
+            # float32 state a head and a convolution tail; not paged,
+            # not growing), row 0 the null row. The row's id rides in
+            # one more column of the device table (_sync_pt): 1 + slot
+            # in the prefill view, 0 for a non-active slot in the
+            # decode view, so a tick leaves a free or still-prefilling
+            # slot's state alone. The model zeroes a row where a
+            # sequence starts (chunk_start == 0), on the device. A
+            # state is the whole prefix folded together: a page
+            # registry cannot hand one over, so such a model shares no
+            # prefix (serving/prefix_refused_recurrent), verifies no
+            # drafts (a rejected draft would need the state rolled
+            # back) and hands no KV to a peer
+            self._state_layers = getattr(cfg, "state_layers", 0)
+            self._state_cols = 1 + np.arange(
+                num_slots, dtype=np.int32)[:, None]
+            #: layers whose K/V live in the allocator's pages
+            self._kv_layers = getattr(cfg, "kv_layers", cfg.num_layers) \
+                - self._window_layers
+            unshared = "window" if self._ring else \
+                "recurrent" if self._state_layers else None
+            #: why a server asked to share prefixes does not
+            self._prefix_refused = unshared if prefix_sharing else None
+            self._prefix_sharing = bool(prefix_sharing) and not unshared
+            if self._state_layers and gen_cfg.spec_method is not None:
+                raise ValueError(
+                    "spec_method is refused on a model with recurrent "
+                    "state: a rejected draft has already been folded "
+                    "into the state, and there is no rollback")
             self._moe_published = np.zeros((4,), np.int64)
             # hierarchical KV cache (docs/inference.md): a bounded
             # pinned-host spill tier sized dtype-aware from a BYTE
@@ -1021,14 +1053,19 @@ class GenerationServer:
             if r is not None and r.get("active"):
                 act[s, 0] = True
         dec = np.where(act, self._pt, NULL_PAGE).astype(np.int32)
+        full = self._pt
         if self._ring:
             # the slots' ring pages behind the global columns
-            self._pt_dev = jnp.asarray(
-                np.concatenate([self._pt, self._ring_cols], axis=1))
+            full = np.concatenate([full, self._ring_cols], axis=1)
             dec = np.concatenate([dec, self._ring_cols], axis=1)
-        else:
-            self._pt_dev = jnp.asarray(self._pt)
-        self._pt_dev_dec = jnp.asarray(dec)
+        if self._state_layers:
+            # the slots' state rows behind those: the null row for a
+            # slot the tick must not touch
+            full = np.concatenate([full, self._state_cols], axis=1)
+            dec = np.concatenate(
+                [dec, np.where(act, self._state_cols, 0)], axis=1)
+        self._pt_dev = jnp.asarray(full)
+        self._pt_dev_dec = jnp.asarray(dec.astype(np.int32))
         self._pt_dirty = False
 
     def _place(self, req: dict, slot: int, num_pages: int) -> None:
@@ -1162,8 +1199,10 @@ class GenerationServer:
                 # recompute locally with the rest of the prompt
                 cpp = self._chunk // self._page
                 del shared_pids[len(shared_pids) - len(shared_pids) % cpp:]
-            if self._prefix_refused:
+            if self._prefix_refused == "window":
                 metrics.inc("serving/prefix_refused_window")
+            elif self._prefix_refused == "recurrent":
+                metrics.inc("serving/prefix_refused_recurrent")
             start = len(shared_pids) * self._page
             n_chunks = -(-(L - start) // self._chunk)
             total_pages = (start + n_chunks * self._chunk) // self._page
@@ -1234,16 +1273,20 @@ class GenerationServer:
             c0 = req["prefill_pos"]
             row = np.full((1, self._chunk), self.gen_cfg.pad_token_id,
                           np.int32)
-            row[0, :len(seq[c0:c0 + self._chunk])] = \
-                seq[c0:c0 + self._chunk]
+            real = min(self._chunk, L - c0)
+            row[0, :real] = seq[c0:c0 + real]
             self._sync_pt()
             self._cache, logits = prefill_chunk_paged(
                 self.model, self.params, self._cache, jnp.asarray(row),
                 jnp.asarray([c0], jnp.int32),
                 self._pt_dev[slot:slot + 1],
                 jnp.asarray([int(self._aid_np[slot])], jnp.int32)
-                if self._adapters is not None else None)
+                if self._adapters is not None else None,
+                jnp.asarray([real], jnp.int32))
             req["prefill_pos"] = c0 + self._chunk
+            if self._state_layers and c0 == 0:
+                # the model zeroed the slot's rows on the device
+                metrics.inc("serving/state_resets", self._state_layers)
             if self._ring:
                 # the chunk's pages past the ring's first lap each went
                 # over a page that had fallen behind the window
@@ -1255,8 +1298,7 @@ class GenerationServer:
             rec.chunks += 1
             metrics.inc("serving/prefill_chunks")
             self._emit("serving_prefill_chunk", request=req["id"],
-                       slot=slot, start=c0,
-                       tokens=min(self._chunk, L - c0),
+                       slot=slot, start=c0, tokens=real,
                        trace=self._trace_id(req))
             if req["prefill_pos"] < L:
                 return
@@ -1601,6 +1643,10 @@ class GenerationServer:
         with self._surface_lock:
             if not self.paged:
                 return None
+            if self._state_layers:
+                # pages are not the whole of such a model's cache
+                metrics.inc("serving/kv_handoff_refused_recurrent")
+                return None
             hit = self._alloc.lookup_prompt(
                 prompt_key([int(t) for t in tokens]))
             if hit is None:
@@ -1643,6 +1689,9 @@ class GenerationServer:
         import pins must not eat), or the prompt is already
         resident."""
         with self._surface_lock:
+            if self.paged and self._state_layers:
+                metrics.inc("serving/kv_handoff_refused_recurrent")
+                return False
             if not self.paged or not self._prefix_sharing:
                 return False
             seq = [int(t) for t in tokens]
@@ -1840,6 +1889,14 @@ class GenerationServer:
             for s in live))
         metrics.inc("serving/decode_blocks_capacity",
                     self.num_slots * self._max_pages)
+        # what the walked slots hold, by class: allocator pages on the
+        # layers that keep whole sequences, a row of state on the
+        # linear-attention layers (ring pages: _count_page_classes)
+        metrics.inc("serving/pages_global_held", self._kv_layers * sum(
+            self._slots[s]["num_pages"] for s in live))
+        if self._state_layers:
+            metrics.inc("serving/state_rows_held",
+                        self._state_layers * len(live))
         if self._ring:
             self._count_page_classes(live, window)
 
@@ -1849,13 +1906,12 @@ class GenerationServer:
         sequences and window layers no more than their ring; the
         blocks the walk visits against what it would without windows;
         ring pages written over for the first time this tick."""
-        glob = self.model.config.num_layers - self._window_layers
+        glob = self._kv_layers
         reach = self.model.config.sliding_window_size
-        pages = held = whole = walked = reused = 0
+        held = whole = walked = reused = 0
         for s in live:
             req = self._slots[s]
             cur, n = req["cur_len"], req["num_pages"]
-            pages += n
             held += min(n, self._ring)
             last = min((cur + window - 1) // self._page,
                        self._max_pages - 1)
@@ -1863,7 +1919,6 @@ class GenerationServer:
             walked += last + 1 - max((cur + 1 - reach) // self._page, 0)
             reused += cur % self._page == 0 and cur // self._page \
                 >= self._ring
-        metrics.inc("serving/pages_global_held", glob * pages)
         metrics.inc("serving/pages_window_held",
                     self._window_layers * held)
         metrics.inc("serving/window_pages_reused",
@@ -2252,8 +2307,10 @@ class GenerationServer:
             # density accounting (docs/quantization.md): same pool
             # BYTES admit ~1.9x the pages under int8 + fp32 scales
             s["kv_cache_dtype"] = mcfg.kv_cache_dtype
+            # cache bytes by class: the allocator's pages on the
+            # layers that hold K/V, the rings, the state rows
             s["pool_bytes"] = pool_bytes(
-                mcfg.num_layers - self._window_layers, mcfg.num_kv_heads,
+                self._kv_layers, mcfg.num_kv_heads,
                 mcfg.head_dim, self._page, self._alloc.num_pages,
                 mcfg.kv_cache_dtype)
             if self._ring:
@@ -2262,7 +2319,14 @@ class GenerationServer:
                     self._window_layers, mcfg.num_kv_heads,
                     mcfg.head_dim, self._page, mcfg.window_pool_pages,
                     mcfg.kv_cache_dtype)
-                s["prefix_refused_window"] = self._prefix_refused
+                s["prefix_refused_window"] = \
+                    self._prefix_refused == "window"
+            if self._state_layers:
+                s["state_bytes"] = self._state_layers \
+                    * mcfg.state_rows * mcfg.state_row_bytes
+                s["state_rows_held"] = self._state_layers * self.occupancy
+                s["prefix_refused_recurrent"] = \
+                    self._prefix_refused == "recurrent"
             if "moe_stats" in self._cache:
                 # what the jitted ticks counted on the device, read
                 # here, outside any tick: picks dispatched and distinct

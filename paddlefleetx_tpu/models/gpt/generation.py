@@ -1213,7 +1213,7 @@ def init_page_pool(model, params, num_slots: int):
          donate_argnames=("cache",))
 def prefill_chunk_paged(model, params, cache, input_chunk: jax.Array,
                         chunk_start: jax.Array, page_table: jax.Array,
-                        adapter_ids=None):
+                        adapter_ids=None, chunk_valid=None):
     """One page-aligned chunk of a chunked prefill.
 
     ``input_chunk`` is ``[n, chunk]`` token ids (the tail past the
@@ -1222,7 +1222,11 @@ def prefill_chunk_paged(model, params, cache, input_chunk: jax.Array,
     the first decode writes overwrite); ``chunk_start`` ``[n]`` is each
     row's absolute position of the chunk's first token (a multiple of
     ``kv_page_size``); ``page_table`` ``[n, max_kv_pages]`` carries
-    just the prefilling rows. The chunk's KV scatters straight into
+    just the prefilling rows; ``chunk_valid`` ``[n]`` counts each
+    row's real tokens (the rest is that padding). A model whose cache
+    is keys and values ignores it; one with a recurrent state
+    (``models/solar_open2``) reads everything it is fed, and leaves
+    its state where the last real token put it. The chunk's KV scatters straight into
     its physical pages (model.py ``chunk_start`` branch) while the
     queries attend every earlier position through the page-table
     gather. Returns ``(cache, logits)`` with fp32 ``[n, chunk, V]``
@@ -1239,7 +1243,8 @@ def prefill_chunk_paged(model, params, cache, input_chunk: jax.Array,
         {"params": params, "cache": cache}, input_chunk,
         position_ids=pos, use_cache=True, deterministic=True,
         chunk_start=chunk_start, page_table=page_table,
-        adapter_ids=adapter_ids, mutable=["cache"])
+        chunk_valid=chunk_valid, adapter_ids=adapter_ids,
+        mutable=["cache"])
     return (_constrain_slot_cache(mutated["cache"]),
             logits.astype(jnp.float32))
 

@@ -902,7 +902,10 @@ class GPTForPretraining(nn.Module):
     def __call__(self, input_ids, position_ids=None, attn_bias=None,
                  use_cache: bool = False, deterministic: bool = True,
                  position_offset=0, cache_lengths=None,
-                 page_table=None, chunk_start=None, adapter_ids=None):
+                 page_table=None, chunk_start=None, adapter_ids=None,
+                 chunk_valid=None):
+        # chunk_valid: a K/V cache masks a padded tail by position
+        del chunk_valid
         x = GPTModel(self.config, name="gpt")(
             input_ids, position_ids, attn_bias, use_cache, deterministic,
             position_offset, cache_lengths, page_table, chunk_start,

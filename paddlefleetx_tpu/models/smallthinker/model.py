@@ -95,10 +95,13 @@ def window_table(page_table, cfg: SmallThinkerConfig):
 class Attention(nn.Module):
     """Grouped-query attention of one layer; ``rope`` and ``window``
     are the layer's entries of ``rope_layout`` and
-    ``sliding_window_layout``."""
+    ``sliding_window_layout``. With ``gate`` (``models/solar_open2``;
+    any config with this one's attention keys) the heads' output meets
+    ``sigmoid(h W_gate)``, one a channel, before ``W_o``."""
     config: SmallThinkerConfig
     rope: bool
     window: bool
+    gate: bool = False
 
     @nn.compact
     def __call__(self, h, positions, use_cache=False, cache_lengths=None,
@@ -127,6 +130,9 @@ class Attention(nn.Module):
         else:
             out = self._paged(q, k, v, reach, cache_lengths, tables,
                               chunk_start)
+        if self.gate:
+            out = out * jax.nn.sigmoid(dense((nh, d), "gate_proj")(
+                h).astype(jnp.float32)).astype(out.dtype)
         return dense(cfg.hidden_size, "o_proj", axis=(-2, -1))(out)
 
     def _paged(self, q, k, v, reach, cache_lengths, tables, chunk_start):
@@ -260,8 +266,9 @@ class SmallThinkerForCausalLM(nn.Module):
     def __call__(self, input_ids, position_ids=None,
                  use_cache: bool = False, deterministic: bool = True,
                  cache_lengths=None, page_table=None, chunk_start=None,
-                 adapter_ids=None):
-        del deterministic, adapter_ids          # no dropout, no adapters
+                 chunk_valid=None, adapter_ids=None):
+        # no dropout, no adapters; a padded tail is masked by position
+        del deterministic, adapter_ids, chunk_valid
         cfg = self.config
         dtype, pdtype = jnp.dtype(cfg.dtype), jnp.dtype(cfg.param_dtype)
         table = self.param("embed_tokens", _init(cfg),

@@ -666,3 +666,66 @@ def test_ragged_matmul_compiles_at_decode_tiles(rows, one_chip, as_tpu):
         fn = functools.partial(ragged_matmul, block_m=block)
         assert "tpu_custom_call" in _compile(
             fn, x, w, table, used).as_text()
+
+
+# -- one chip: the Solar-Open2 serving cell's kernels --------------------
+#
+# solaropen2.serve-reasoning: 96 slots; 64 linear-attention heads with a
+# 128 x 128 float32 state each, one row a slot behind the null row; 64
+# query heads over 8 pooled K/V heads of 128 (a whole sublane tile a
+# group); 40 held experts of 4096 x 1280 picked 8 of 320.
+
+SO_SLOTS, SO_H, SO_D = 96, 64, 128
+
+
+def test_kda_decode_compiles_and_updates_the_state_in_place(one_chip,
+                                                            as_tpu):
+    """The delta rule's decode kernel at the published widths: Mosaic
+    takes the operand tile's turn and the dynamic grid, and the donated
+    state leaf (407 MB) comes back aliased, not copied."""
+    from paddlefleetx_tpu.ops.pallas import kda
+    state = _sds((1 + SO_SLOTS, SO_H, SO_D, SO_D), jnp.float32, one_chip)
+    rows = _sds((SO_SLOTS,), jnp.int32, one_chip)
+    vec = _sds((SO_SLOTS, SO_H, SO_D), jnp.float32, one_chip)
+    beta = _sds((SO_SLOTS, SO_H), jnp.float32, one_chip)
+    compiled = jax.jit(kda.kda_decode, donate_argnums=0).lower(
+        state, rows, vec, vec, vec, vec, beta).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%kda_decode\S* = .*tpu_custom_call", text)) == 1
+    leaf = math.prod(state.shape) * 4
+    assert compiled.memory_analysis().alias_size_in_bytes >= leaf
+    assert compiled.memory_analysis().temp_size_in_bytes < leaf // 8
+
+
+def test_flash_decode_paged_compiles_at_eight_heads_a_group(one_chip,
+                                                            as_tpu):
+    """64 query heads over 8 pooled heads, no window, a table of 256
+    pages: by shape, the kernel SmallThinker's global layers run."""
+    from paddlefleetx_tpu.ops.pallas import flash_attention as fa
+    q = _sds((SO_SLOTS, 1, SO_H, SO_D), BF16, one_chip)
+    off = _sds((SO_SLOTS,), jnp.int32, one_chip)
+    table = _sds((SO_SLOTS, 256), jnp.int32, one_chip)
+    kv = [_sds((6001, 8, SO_D, 128), BF16, one_chip)] * 2
+    assert "tpu_custom_call" in _compile(
+        fa.flash_decode_paged, q, *kv, off, table).as_text()
+
+
+@pytest.mark.parametrize("rows", [SO_SLOTS, 512])
+def test_ragged_matmul_compiles_at_a_share_of_the_experts(rows, one_chip,
+                                                          as_tpu):
+    """The grouped products over 40 held experts when 8 of 320 are
+    picked a token: a tick's 96 rows and a chunk's 512 size their
+    tiles by ALL the picks (most land on absent experts), gate|up and
+    down."""
+    from paddlefleetx_tpu.models.deepseek_v3.moe import block_rows
+    from paddlefleetx_tpu.ops.pallas.grouped_matmul import ragged_matmul
+    block = block_rows(rows * 8, 40)
+    tiles = -(-rows * 8 // block) + 40
+    for k, n in ((4096, 2560), (1280, 4096)):
+        x = _sds((tiles * block, k), BF16, one_chip)
+        w = _sds((40, k, n), BF16, one_chip)
+        table = _sds((tiles,), jnp.int32, one_chip)
+        used = _sds((), jnp.int32, one_chip)
+        fn = functools.partial(ragged_matmul, block_m=block)
+        assert "tpu_custom_call" in _compile(
+            fn, x, w, table, used).as_text()
