@@ -1082,30 +1082,71 @@ def flash_attention_with_lse(q, k, v, causal: bool = True,
 # -- cached decode -----------------------------------------------------
 
 
+def _group_dot(a, b, contract):
+    """``a [g, r, x] . b [g, y, z]`` contracted over ``contract`` (one
+    dim of each), a product a pooled head on the MXU, fp32 out.
+    bfloat16 operands multiply exactly into the fp32 accumulator;
+    fp32 operands take Mosaic's multi-pass product, which keeps their
+    24 bits."""
+    precision = jax.lax.Precision.HIGHEST \
+        if a.dtype == jnp.float32 else None
+    return jax.lax.dot_general(
+        a, b, ((contract[0], contract[1]), ((0,), (0,))),
+        precision=precision, preferred_element_type=jnp.float32)
+
+
+def _group_pv(p, v):
+    """``p [g, r, bkv]`` (fp32) against ``v [g, d, bkv]``, both
+    contracted over their lanes: ``[g, r, d]`` fp32. The probabilities
+    keep fp32's 24 bits against a bfloat16 block too: ``p = hi + mid +
+    lo`` exactly, three bfloat16 parts of 8 bits each, stacked as ``3
+    * r`` rows of ONE product, so the resident V block is the MXU's
+    weight once."""
+    if v.dtype != jnp.bfloat16:
+        return _group_dot(p, v, ((2,), (2,)))
+    r = p.shape[1]
+    hi = p.astype(jnp.bfloat16).astype(jnp.float32)
+    rest = p - hi
+    mid = rest.astype(jnp.bfloat16).astype(jnp.float32)
+    parts = jnp.concatenate([hi, mid, rest - mid], axis=1)
+    o = _group_dot(parts.astype(jnp.bfloat16), v, ((2,), (2,)))
+    return o[:, :r] + (o[:, r:2 * r] + o[:, 2 * r:])
+
+
 def _decode_block(q, k, v, live, sm_scale, m_prev, l_prev, acc_prev,
                   bias=None):
-    """One key block of the cached-decode online softmax for ONE query
-    token, every head in one vectorized pass (a per-head loop would
-    issue ~6x num_heads small VPU ops and dominate the call): ``q
-    [h, d, 1]`` against the fp32 ``k`` / ``v`` ``[h, d, bkv]`` of the
-    resident block, ``live [1, bkv]`` the keys this query may see.
-    Takes and returns the running ``m [h, 1]``, ``l [h, 1]``, ``acc
-    [h, d]``, all fp32. Shared by the contiguous kernels and the paged
-    one, so a row's number does not depend on which of them walked
-    its blocks.
+    """One key block of the cached-decode online softmax, every head
+    in one vectorized pass (a per-head loop would issue ~6x num_heads
+    small VPU ops and dominate the call). Takes and returns the
+    running ``m [h, 1]``, ``l [h, 1]``, ``acc [h, d]``, all fp32, and
+    the softmax between the two products is fp32 on the VPU over ``[h,
+    bkv]`` whatever formed them. Shared by the contiguous kernels and
+    the paged ones, so a row's number does not depend on which of them
+    walked its blocks.
 
-    Grouped-query heads come by shape: with ``k`` / ``v`` of ``g``
-    heads under ``h = g * m`` query heads (``m`` a multiple of 8, the
-    caller pads: a group is then whole sublane tiles and the two
-    reshapes move nothing), the ``m`` heads of a group share the
-    resident block; at ``g == h`` the expressions are the ungrouped
-    ones."""
-    h, g = q.shape[0], k.shape[0]
+    One K/V head a query head (``k`` / ``v`` ``[h, d, bkv]`` fp32):
+    ONE query token, ``q [h, d, 1]`` (or its column on every lane),
+    ``live [1, bkv]`` the keys it may see. A head's scores are a
+    matvec, so both products are VPU broadcast-multiply-reduces over
+    the cache's native ``[d, bkv]`` tiles; the MXU would have one row
+    to share a weight load with.
+
+    Grouped-query heads come by shape: ``k`` / ``v`` ``[g, d, bkv]``
+    of ``g`` pooled heads under ``h = g * r`` query rows, ``q [g, r,
+    d]`` (``d`` on the lanes, the operands in one dtype: the pool's
+    bfloat16, or fp32), ``live [1 | h, bkv]``. The ``r`` rows of a
+    group (its query heads padded to whole sublane tiles, times the
+    verify window's positions, each with its own row of ``live``)
+    share the resident block as the MXU's weight: ``q_group [r, d] .
+    k [d, bkv]`` and ``p [r, bkv] . v [d, bkv]^T``
+    (:func:`_group_dot`, :func:`_group_pv`), two products a pooled
+    head a block where the VPU multiplied and reduced ``[g, r, d,
+    bkv]`` element by element."""
+    h, g = m_prev.shape[0], k.shape[0]
     if g == h:
         s = jnp.sum(q * k, axis=1) * sm_scale      # [h, bkv] f32
     else:
-        s = jnp.sum(q.reshape(g, h // g, *q.shape[1:]) * k[:, None],
-                    axis=2).reshape(h, -1) * sm_scale
+        s = _group_dot(q, k, ((2,), (1,))).reshape(h, -1) * sm_scale
     if bias is not None:
         s = s + bias                               # [1, bkv] broadcasts
     s = jnp.where(live, s, NEG_INF)
@@ -1113,12 +1154,11 @@ def _decode_block(q, k, v, live, sm_scale, m_prev, l_prev, acc_prev,
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.exp(s - m_new)                         # [h, bkv]
     l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-    # output: broadcast p over d, reduce over the key lanes
     if g == h:
+        # output: broadcast p over d, reduce over the key lanes
         pv = jnp.sum(p[:, None, :] * v, axis=2)
     else:
-        pv = jnp.sum(p.reshape(g, h // g, 1, -1) * v[:, None],
-                     axis=3).reshape(h, -1)
+        pv = _group_pv(p.reshape(g, h // g, -1), v).reshape(h, -1)
     return m_new, l_new, acc_prev * alpha + pv
 
 
@@ -1127,14 +1167,21 @@ def _decode_kernel(off_ref, q_ref, k_ref, v_ref, *refs, sm_scale,
                    quantized=False):
     """Single-token decode over the fixed-capacity KV cache.
 
-    Decode attention is a matvec, not a matmul — per (head, key-block)
-    the scores are ``sum_d q[d] * k[d, S]`` and the output is
-    ``sum_S p[S] * v[d, S]``, both VPU broadcast-multiply-reduces over
-    the cache's native ``[d, S]`` tiles. An MXU formulation pays
-    fixed issue latency per tiny matmul (measured 512 matmuls/call =
-    ~370us); this kernel folds ALL heads into one program per
-    (batch, key-block) so the grid is ``b * num_kv`` programs of pure
-    VPU streaming.
+    With one K/V head a query head, decode attention is a matvec, not
+    a matmul — per (head, key-block) the scores are ``sum_d q[d] *
+    k[d, S]`` and the output is ``sum_S p[S] * v[d, S]``, both VPU
+    broadcast-multiply-reduces over the cache's native ``[d, S]``
+    tiles. On the MXU a head's product would be ONE row against a
+    ``[d, S]`` weight load, the load's fixed latency paid for nothing
+    (measured 2026-07-30, commit 49ad5b5, at one row a product: 512
+    matmuls/call = ~370 us); this kernel folds ALL heads into one
+    program per (batch, key-block) so the grid is ``b * num_kv``
+    programs of pure VPU streaming. That note is about one-row
+    products only: where the heads of a group SHARE a K/V head the
+    rows of a product are the group's heads, and the paged kernel
+    takes the MXU (:func:`_paged_gqa_kernel`, PR 32). The contiguous
+    kernels know no groups (``ops/attention.py`` sends grouped
+    operands without a page table to the dense path).
 
     The live length is DYNAMIC (the decode loop's cache index), so it
     arrives as a prefetched scalar: blocks wholly past the last valid
@@ -1308,6 +1355,11 @@ def _flash_decode_call(q, k, v, off, bias, block_kv: int, ragged: bool,
             "verify window (sq > 1) takes no bias (per-slot validity "
             "is the offsets')")
     skv = k.shape[3]
+    if k.shape[1] != h:
+        raise NotImplementedError(
+            f"the contiguous decode kernels know no grouped heads "
+            f"({h} query heads over {k.shape[1]}): only the paged "
+            f"kernel does")
     quantized = _check_kv_scales(k, v, k_scale, v_scale, h, skv)
     # largest 128-aligned divisor <= block_kv: capacities that are
     # 128-multiples but not block_kv-multiples (e.g. 1280) stay on the
@@ -1556,6 +1608,21 @@ def _paged_walk(offs, pt, window: int, block_kv: int, num_kv: int,
     return rows, jnp.clip(blocks, 0, num_kv - 1), end[-1]
 
 
+def _paged_pair(off_ref, pt_ref, row_ref, blk_ref, window, block_kv,
+                num_kv, reach):
+    """This grid step's pair of :func:`_paged_walk`: ``(slot, block,
+    the slot's offset, its first and last block, whether it is
+    live)``, the walk's own rule read back from the prefetched
+    scalars."""
+    t = pl.program_id(0)
+    row, kb = row_ref[t], blk_ref[t]
+    offset = off_ref[row]
+    last = jnp.minimum((offset + (window - 1)) // block_kv, num_kv - 1)
+    first = _reach_first(offset, reach, block_kv)
+    alive = jnp.logical_and(pt_ref[row, 0] != NULL_PAGE, last >= first)
+    return row, kb, offset, first, last, alive
+
+
 def _paged_kernel(off_ref, pt_ref, row_ref, blk_ref, q_ref, k_ref,
                   v_ref, *refs, sm_scale, block_kv, num_kv, window,
                   quantized, reach=None):
@@ -1583,20 +1650,18 @@ def _paged_kernel(off_ref, pt_ref, row_ref, blk_ref, q_ref, k_ref,
     With ``reach`` (a sliding window of that many keys) a slot's walk
     begins at :func:`_reach_first` instead of block 0, that block is
     where its state is initialised, and a key at or behind ``offset +
-    j - reach`` is masked inside it. ``k`` / ``v`` may hold fewer
-    heads than ``q`` (:func:`_decode_block`).
+    j - reach`` is masked inside it. This is the kernel of one K/V
+    head a query head; grouped-query heads take
+    :func:`_paged_gqa_kernel` over the same walk.
     """
     refs = list(refs)
     if quantized:
         ks_ref, vs_ref = refs[0], refs[1]
         refs = refs[2:]
     o_ref, q_scr, m_scr, l_scr, acc_scr = refs
-    t = pl.program_id(0)
-    row, kb = row_ref[t], blk_ref[t]
-    offset = off_ref[row]
-    last = jnp.minimum((offset + (window - 1)) // block_kv, num_kv - 1)
-    first = _reach_first(offset, reach, block_kv)
-    alive = jnp.logical_and(pt_ref[row, 0] != NULL_PAGE, last >= first)
+    row, kb, offset, first, last, alive = _paged_pair(
+        off_ref, pt_ref, row_ref, blk_ref, window, block_kv, num_kv,
+        reach)
     mine = jax.lax.broadcasted_iota(
         jnp.int32, q_ref.shape[1:], 2) == row % LANES    # [h, d, 128]
 
@@ -1651,22 +1716,124 @@ def _paged_kernel(off_ref, pt_ref, row_ref, blk_ref, q_ref, k_ref,
         jax.lax.fori_loop(0, window, put, 0)
 
 
+def _paged_gqa_kernel(off_ref, pt_ref, row_ref, blk_ref, q_ref, k_ref,
+                      v_ref, *refs, sm_scale, block_kv, num_kv, window,
+                      quantized, reach=None):
+    """:func:`_paged_kernel` for grouped-query heads: the same walk,
+    the same state from a slot's first block to its last, the block
+    math on the MXU (:func:`_decode_block`'s grouped form).
+
+    ``q`` and the output are a block a SLOT, ``[g, W * m8, d]`` with
+    ``d`` on the lanes: the rows of pooled head ``i`` are its ``m8``
+    query heads (``m`` padded to whole sublane tiles) at each of the
+    ``W`` window positions, which is the left operand of both
+    products as it stands, so nothing is lifted out of a lane and no
+    copy of the query is kept: the block is resident while the walk
+    stays in the slot, fetched and written back once a slot by the
+    pipeline. All ``W`` positions go through ONE pair of products a
+    pooled head (a wider window only adds rows under the same weight
+    load); row ``r`` of a group sits at ``offset + r // m8`` and
+    masks for itself. A slot the walk never reaches has no output
+    block written: the caller zeroes dead rows.
+
+    My chip runs, PR 32 (one v5e, pages of 128 keys, bfloat16 pool,
+    a block a page; the VPU form this replaced beside each): at 4
+    pooled heads x 7 query heads (SmallThinker) a step costs 0.61 us
+    (1.76-1.83) for 256 KB of K and V, 0.32 us at 819 GB/s; at 8 x 8
+    (Solar-Open2) 0.80 us (3.12-3.17) for 512 KB, 0.64 us. By pooled
+    heads a step is 0.44 / 0.50 / 0.61 / 0.80 us at 1 / 2 / 4 / 8:
+    ~0.4 us of grid step and ~0.05 us a pooled head (two weight
+    loads, and its bytes from 4 heads up). The rows are free: 16 or
+    32 query heads a group cost 0.62 and 0.64 us a step (3.07 and
+    5.83), a verify window of 5 0.65 (7.52). A live row adds 0.09 us
+    (2.2: the lift and the masked put are gone), a call 13 us with
+    the walk's XLA ops either way. The three-part split of the
+    probabilities costs 0.01-0.02 us a step over one cast; fp32
+    probabilities against a widened block 0.05-0.18 more, three
+    products of one part each 0.00-0.06 more. Pages of 256 and 512
+    keys (a step 2 and 4 times the bytes) run at 0.81 and 1.41 us a
+    step, 79% and 91% of the bytes' floor at 4 x 7.
+    """
+    refs = list(refs)
+    if quantized:
+        ks_ref, vs_ref = refs[0], refs[1]
+        refs = refs[2:]
+    o_ref, m_scr, l_scr, acc_scr = refs
+    row, kb, offset, first, last, alive = _paged_pair(
+        off_ref, pt_ref, row_ref, blk_ref, window, block_kv, num_kv,
+        reach)
+    g, rows, d = q_ref.shape[1:]
+    h = g * rows
+
+    @pl.when(jnp.logical_and(alive, kb == first))
+    def _first():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(alive)
+    def _block():
+        k_pos = kb * block_kv + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_kv), 1)
+        q = q_ref[0]                               # [g, W * m8, d]
+        k = k_ref[0].astype(q.dtype)               # [g, d, bkv]
+        v = v_ref[0].astype(q.dtype)
+        if quantized:
+            k = k * ks_ref[0]                      # [g, 1, bkv] bcast
+            v = v * vs_ref[0]
+        at = offset
+        if window > 1:
+            # a block participates when ANY window query can see it
+            # (the walk's bound); each row masks at its own position
+            at = offset + jax.lax.broadcasted_iota(
+                jnp.int32, (g, window, rows // window, 1), 1
+            ).reshape(h, 1)
+        live = k_pos <= at
+        if reach is not None:
+            live = jnp.logical_and(live, k_pos > at - reach)
+        m_scr[...], l_scr[...], acc_scr[...] = _decode_block(
+            q, k, v, live, sm_scale, m_scr[...], l_scr[...], acc_scr[...])
+
+    @pl.when(jnp.logical_and(alive, kb == last))
+    def _finish():
+        o = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)    # [h, d]
+        o_ref[0] = o.reshape(g, rows, d).astype(o_ref.dtype)
+
+
 def _paged_vmem_bytes(window, h, d, block_kv, q_item, kv_item,
                       quantized, kv_heads=None) -> int:
-    """What one grid step of the paged kernel holds in VMEM: the
-    ``q`` block (one buffer) and the output block (two), the K and V
-    blocks of ``kv_heads`` heads (``h`` unless grouped) double-buffered
-    (with their scale blocks), the lifted query columns (fp32, on all
-    128 lanes), and the block math's widened K and V, one ``[h, d,
-    bkv]`` product and a score / prob pair per window position."""
+    """What one grid step of the paged kernels holds in VMEM; ``h``
+    counts the query rows a window position (a group's heads padded
+    to whole sublane tiles), ``q_item`` the bytes of the ``q``
+    operand's dtype, which grouped heads multiply in.
+
+    One K/V head a query head (:func:`_paged_kernel`): the ``q``
+    block (one buffer) and the output block (two), the K and V blocks
+    double-buffered (with their scale blocks), the lifted query
+    columns (fp32, on all 128 lanes), and the block math's widened K
+    and V, one ``[h, d, bkv]`` product and a score / prob pair per
+    window position.
+
+    Grouped (:func:`_paged_gqa_kernel`, ``kv_heads`` pooled heads):
+    the K and V blocks double-buffered, the ``q`` and output blocks of
+    one slot, two buffers each, the state (``m`` and ``l`` a lane
+    tile wide), and three ``[h * W, bkv]`` fp32 rows of the softmax
+    (scores, probabilities, their stacked parts: at one window
+    position they live in registers, a wide window spills them). A
+    pooled head's widened or dequantised block goes through registers
+    and is not held. Within 1.1-1.6x of what the chip's compiler
+    scopes at ``W`` 1, 5 and 32 (tests/test_chip_compile.py)."""
     dd = max(d, 8)
     g = kv_heads or h
-    n = 3 * window * h * dd * LANES * q_item
-    n += 4 * g * dd * block_kv * kv_item
+    n = 4 * g * dd * block_kv * kv_item
     if quantized:
         n += 4 * g * 8 * block_kv * 4
+    if g != h:
+        return n + window * h * (
+            4 * dd * q_item + (2 * LANES + dd + 3 * block_kv) * 4)
+    n += 3 * window * h * dd * LANES * q_item
     n += window * h * dd * LANES * 4
-    n += (2 * g + h) * dd * block_kv * 4 + window * 2 * h * block_kv * 4
+    n += 3 * h * dd * block_kv * 4 + window * 2 * h * block_kv * 4
     return n
 
 
@@ -1693,25 +1860,32 @@ def flash_decode_paged(q, k, v, query_offsets, page_table, bias=None,
     page ``page_table[i, kb // blocks_per_page]``; block size is the
     largest 128-aligned divisor of the page size that fits the VMEM
     budget, so a block never straddles two (physically unrelated)
-    pages. ``q`` goes in and the output comes out rows-on-lanes
-    (``[W, h, d, b]`` padded to 128 slots a group, one VMEM-resident
-    block per group: :func:`_paged_kernel`) — a ``[b, h, d, W]``
-    operand pads its minor dim of ``W`` to 128 lanes in HBM. A dead
-    row's output is zeros.
+    pages. With one K/V head a query head ``q`` goes in and the
+    output comes out rows-on-lanes (``[W, h, d, b]`` padded to 128
+    slots a group, one VMEM-resident block per group:
+    :func:`_paged_kernel`) — a ``[b, h, d, W]`` operand pads its
+    minor dim of ``W`` to 128 lanes in HBM. A dead row's output is
+    zeros.
 
     ``sq > 1`` is the speculative VERIFY window: query ``j`` of row
     ``i`` sits at ``query_offsets[i] + j`` and sees keys up to there
     (the within-window causal mask of :func:`flash_decode_ragged`).
 
-    Both of these are read off the operands, one kernel either way.
-    Grouped-query heads: a pool of ``g`` heads under ``h = g * m``
-    query heads (query head ``m * i + j`` reads K/V head ``i``); the
-    ``m`` heads of a group share each resident block, nothing is
-    repeated in HBM, and ``m`` is padded to whole sublane tiles (zero
-    query rows, dropped from the output). ``reach``: a sliding window
-    of that many keys (key ``j`` visible iff ``off - reach < j <=
-    off``); a row's walk then starts at the window's first block,
-    which is masked inside.
+    Both of these are read off the operands, one walk and one block
+    math (:func:`_decode_block`) either way. Grouped-query heads: a
+    pool of ``g`` heads under ``h = g * m`` query heads (query head
+    ``m * i + j`` reads K/V head ``i``); nothing is repeated in HBM,
+    the ``m`` heads of a group (padded to whole sublane tiles: zero
+    query rows, dropped from the output) are the rows of two products
+    a pooled head a block on the MXU, the resident K and V block
+    their weight, and ``q`` / the output are a ``[g, W * m8, d]``
+    block a slot (:func:`_paged_gqa_kernel`). The operands multiply
+    in the pool's bfloat16 when the queries are bfloat16 too and in
+    fp32 otherwise (an fp32 pool, an int8 one); the probabilities
+    keep fp32's 24 bits in both (:func:`_group_pv`). ``reach``: a
+    sliding window of that many keys (key ``j`` visible iff ``off -
+    reach < j <= off``); a row's walk then starts at the window's
+    first block, which is masked inside.
 
     Inference-only; no bias operand (serving decode carries none —
     per-slot validity lives in the offsets and the table). Raises
@@ -1740,13 +1914,12 @@ def flash_decode_paged(q, k, v, query_offsets, page_table, bias=None,
             f"{h}, got {k.shape}")
     page, g = k.shape[3], k.shape[1]
     quantized = _check_kv_scales(k, v, k_scale, v_scale, g, page)
+    q_item, rows = q.dtype.itemsize, h
     if g != h:
-        # a group's query heads on whole sublane tiles
-        m = h // g
-        pad = -m % 8
-        q = jnp.pad(q.reshape(b, window, g, m, d),
-                    ((0, 0),) * 3 + ((0, pad), (0, 0))
-                    ).reshape(b, window, g * (m + pad), d)
+        # a group's query heads on whole sublane tiles, in the dtype
+        # of the products
+        rows = g * _group_rows(h // g)
+        q_item = jnp.dtype(_group_dtype(q, k)).itemsize
     offs = jnp.asarray(query_offsets, jnp.int32)
     if offs.ndim != 1 or offs.shape[0] != b:
         raise NotImplementedError(
@@ -1761,9 +1934,8 @@ def flash_decode_paged(q, k, v, query_offsets, page_table, bias=None,
     block_kv = _auto_block(page, block_kv, 128)
 
     def vmem(bkv):
-        return _paged_vmem_bytes(window, q.shape[2], d, bkv,
-                                 q.dtype.itemsize, k.dtype.itemsize,
-                                 quantized, g)
+        return _paged_vmem_bytes(window, rows, d, bkv, q_item,
+                                 k.dtype.itemsize, quantized, g)
 
     while block_kv > 128 and page % (block_kv // 2) == 0 and \
             vmem(block_kv) > VMEM_DEFAULT // 2:
@@ -1780,14 +1952,24 @@ def flash_decode_paged(q, k, v, query_offsets, page_table, bias=None,
     operands = (q, k, v, offs, pt)
     if quantized:
         operands += (k_scale, v_scale)
-    out = _flash_decode_paged_call(
+    return _flash_decode_paged_call(
         *operands, block_kv=block_kv,
         vmem_limit=max(vmem(block_kv) * 5 // 4, VMEM_DEFAULT),
         interpret=_interpret(), reach=reach)
-    if g != h:
-        out = out.reshape(b, window, g, -1, d)[:, :, :, :h // g].reshape(
-            b, window, h, d)
-    return out
+
+
+def _group_rows(m: int) -> int:
+    """The rows a pooled head's ``m`` query heads take: whole sublane
+    tiles (zero query rows, dropped from the output)."""
+    return m + -m % 8
+
+
+def _group_dtype(q, k):
+    """What grouped heads multiply in: the pool's bfloat16 as it
+    stands when the queries are bfloat16 too, else fp32 (an fp32 pool;
+    an int8 one, dequantised in VMEM)."""
+    both = q.dtype == k.dtype == jnp.bfloat16
+    return jnp.bfloat16 if both else jnp.float32
 
 
 @functools.partial(
@@ -1801,15 +1983,14 @@ def _flash_decode_paged_call(q, k, v, offs, pt, *scales, block_kv,
     s where 24 traces took 1.9 s), and ``inline`` with no ``name=`` on
     the call: a Mosaic call is named after the innermost scope, and
     the chip's op line has to stay ``self_attn.<n> custom-call``,
-    which the benchmark's roofline share reads (a plain inner jit
+    which the benchmark's roofline shares read (a plain inner jit
     would make it ``_flash_decode_paged_call.<n>``)."""
     b, window, h, d = q.shape
-    page = k.shape[3]
+    page, g = k.shape[3], k.shape[1]
     bpp = page // block_kv                     # blocks per page
     num_kv = pt.shape[1] * bpp                 # a row's capacity walk
     rows, blocks, steps = _paged_walk(offs, pt, window, block_kv,
                                       num_kv, reach)
-    g = k.shape[1]
 
     def kv_block(t, off, pt, rows, blocks):
         kb = blocks[t]
@@ -1818,15 +1999,41 @@ def _flash_decode_paged_call(q, k, v, offs, pt, *scales, block_kv,
     def lane_group(t, off, pt, rows, blocks):
         return (0, 0, 0, rows[t] // LANES)
 
-    # [b, W, h, d] -> [W, h, d, b]: the slots on the lanes, whole
-    # groups of 128
-    qp = jnp.pad(q.transpose(1, 2, 3, 0),
-                 ((0, 0),) * 3 + ((0, -b % LANES),))
-    in_specs = [
+    def slot(t, off, pt, rows, blocks):
+        return (rows[t], 0, 0, 0)
+
+    if g != h:
+        # [b, W, g * m, d] -> [b, g, W * m8, d]: a slot's block is the
+        # left operand of its products
+        m, m8 = h // g, _group_rows(h // g)
+        qp = jnp.pad(q.reshape(b, window, g, m, d),
+                     ((0, 0),) * 3 + ((0, m8 - m), (0, 0)))
+        qp = qp.transpose(0, 2, 1, 3, 4).reshape(
+            b, g, window * m8, d).astype(_group_dtype(q, k))
+        kernel, q_spec = _paged_gqa_kernel, pl.BlockSpec(
+            (1, g, window * m8, d), slot)
+        out_spec = q_spec
+        scratch = [pltpu.VMEM((window * g * m8, 1), jnp.float32)] * 2 + [
+            pltpu.VMEM((window * g * m8, d), jnp.float32)]
+    else:
+        # [b, W, h, d] -> [W, h, d, b]: the slots on the lanes, whole
+        # groups of 128
+        qp = jnp.pad(q.transpose(1, 2, 3, 0),
+                     ((0, 0),) * 3 + ((0, -b % LANES),))
         # resident while the walk stays in its group of 128 slots: one
         # buffer (a wide window's second would not fit)
-        pl.BlockSpec((window, h, d, LANES), lane_group,
-                     pipeline_mode=pl.Buffered(1)),
+        kernel, q_spec = _paged_kernel, pl.BlockSpec(
+            (window, h, d, LANES), lane_group,
+            pipeline_mode=pl.Buffered(1))
+        out_spec = pl.BlockSpec((window, h, d, LANES), lane_group)
+        scratch = [
+            pltpu.VMEM((window, h, d, LANES), jnp.float32),
+            pltpu.VMEM((window, h, 1), jnp.float32),
+            pltpu.VMEM((window, h, 1), jnp.float32),
+            pltpu.VMEM((window, h, d), jnp.float32),
+        ]
+    in_specs = [
+        q_spec,
         pl.BlockSpec((1, g, d, block_kv), kv_block),
         pl.BlockSpec((1, g, d, block_kv), kv_block),
     ]
@@ -1835,7 +2042,7 @@ def _flash_decode_paged_call(q, k, v, offs, pt, *scales, block_kv,
     in_specs += [pl.BlockSpec((1, g, 1, block_kv), kv_block)
                  for _ in scales]
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, sm_scale=d ** -0.5,
+        functools.partial(kernel, sm_scale=d ** -0.5,
                           block_kv=block_kv, num_kv=num_kv,
                           window=window, quantized=bool(scales),
                           reach=reach),
@@ -1843,18 +2050,20 @@ def _flash_decode_paged_call(q, k, v, offs, pt, *scales, block_kv,
             num_scalar_prefetch=4,
             grid=(steps,),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((window, h, d, LANES), lane_group),
-            scratch_shapes=[
-                pltpu.VMEM((window, h, d, LANES), jnp.float32),
-                pltpu.VMEM((window, h, 1), jnp.float32),
-                pltpu.VMEM((window, h, 1), jnp.float32),
-                pltpu.VMEM((window, h, d), jnp.float32),
-            ],
+            out_specs=out_spec,
+            scratch_shapes=scratch,
         ),
-        out_shape=_sds(qp.shape, q.dtype, q),
+        out_shape=_sds(qp.shape, qp.dtype, q),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit),
         interpret=interpret,
     )(offs, pt, rows, blocks, qp, k, v, *scales)
+    if g != h:
+        # a slot the walk never reached has no block written: zeros;
+        # [b, g, W * m8, d] -> [b, W, g * m, d]
+        out = jnp.where((pt[:, 0] != NULL_PAGE)[:, None, None, None],
+                        out, 0).astype(q.dtype)
+        return out.reshape(b, g, window, m8, d)[:, :, :, :m].transpose(
+            0, 2, 1, 3, 4).reshape(b, window, h, d)
     # [W, h, d, b] -> [b, W, h, d]
     return out[..., :b].transpose(3, 0, 1, 2)

@@ -81,6 +81,25 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile()
 
 
+def _mosaic_calls(compiled):
+    """``(ops, scoped VMEM bytes)`` of every Mosaic call in a compiled
+    program: the operation names its body holds (the serialized MLIR
+    module keeps them as plain strings) and what the chip's compiler
+    says the call uses of its scoped VMEM."""
+    import base64
+    out = []
+    for line in re.findall(r"[^\n]*custom_call_target=\"tpu_custom_call\""
+                           r"[^\n]*", compiled.as_text()):
+        body = base64.b64decode(
+            re.search(r'"body":"([^"]*)"', line).group(1))
+        used = re.search(
+            r'"used_scoped_memory_configs":\[\{"memory_space":"1",'
+            r'"offset":"0","size":"(\d+)"', line)
+        out.append((set(re.findall(rb"matmul|multi_reduction", body)),
+                    int(used.group(1))))
+    return out
+
+
 def _qkv(sh, b=B, s=S, h=H, d=D):
     return [_sds((b, s, h, d), BF16, sh)] * 3
 
@@ -200,7 +219,11 @@ def test_flash_decode_paged_compiles(window, int8, slots, pages,
     else:
         fn = fa.flash_decode_paged
         args = (q, *kv, off, table)
-    assert "tpu_custom_call" in _compile(fn, *args).as_text()
+    compiled = _compile(fn, *args)
+    assert "tpu_custom_call" in compiled.as_text()
+    # one K/V head a query head: a matvec a head, nothing for the MXU
+    (ops, _), = _mosaic_calls(compiled)
+    assert ops == {b"multi_reduction"}
 
 
 # -- one chip: the serving cell's cache programs, in place -------------
@@ -362,6 +385,11 @@ def test_decode_tick_walks_once_and_pads_no_lanes(window, kv_dtype,
     assert f"[{SLOTS},{H},{D},{window}]" not in two.as_text()
     for text in calls:                   # operands and result alike
         assert not re.search(r"\[[\d,]+,1\]", text), text[:400]
+    # GPT's heads are not grouped: the decode kernel's products stay
+    # VPU reductions, and no Mosaic call of the tick holds a matmul
+    ops = [o for o, _ in _mosaic_calls(two)]
+    assert len(ops) == 2 + _kv_write_calls(two)
+    assert all(o <= {b"multi_reduction"} for o in ops), ops
     assert scans(one) >= 2
     assert scans(two) == scans(one)
     assert _kv_write_calls(two) == 2 * _kv_write_calls(one)
@@ -626,7 +654,10 @@ def test_flash_decode_paged_gqa_window_compiles(window, reach, one_chip,
                                                 as_tpu):
     """Grouped-query heads (7 query heads a pooled head, padded to a
     sublane tile) and the walk that starts at the window's first
-    block: Mosaic takes both."""
+    block: Mosaic takes both, the two products of a block are matmuls
+    (the softmax between them keeps its lane reductions), and the
+    kernel's own count of its VMEM covers what the compiler scopes
+    without doubling it."""
     from paddlefleetx_tpu.ops.pallas import flash_attention as fa
     pool = 1 + ST_SLOTS * 35
     q = _sds((ST_SLOTS, window, ST_H, ST_D), BF16, one_chip)
@@ -634,7 +665,11 @@ def test_flash_decode_paged_gqa_window_compiles(window, reach, one_chip,
     table = _sds((ST_SLOTS, ST_PAGES), jnp.int32, one_chip)
     kv = [_sds((pool, ST_G, ST_D, 128), BF16, one_chip)] * 2
     fn = functools.partial(fa.flash_decode_paged, reach=reach)
-    assert "tpu_custom_call" in _compile(fn, q, *kv, off, table).as_text()
+    (ops, used), = _mosaic_calls(_compile(fn, q, *kv, off, table))
+    assert ops == {b"matmul", b"multi_reduction"}
+    counted = fa._paged_vmem_bytes(window, ST_G * 8, ST_D, 128, 2, 2,
+                                   False, ST_G)
+    assert used <= counted <= 2 * used, (used, counted)
 
 
 def test_kv_write_compiles_at_four_pooled_heads(one_chip, as_tpu):
@@ -700,14 +735,18 @@ def test_kda_decode_compiles_and_updates_the_state_in_place(one_chip,
 def test_flash_decode_paged_compiles_at_eight_heads_a_group(one_chip,
                                                             as_tpu):
     """64 query heads over 8 pooled heads, no window, a table of 256
-    pages: by shape, the kernel SmallThinker's global layers run."""
+    pages: by shape, the kernel SmallThinker's global layers run, its
+    products on the MXU."""
     from paddlefleetx_tpu.ops.pallas import flash_attention as fa
     q = _sds((SO_SLOTS, 1, SO_H, SO_D), BF16, one_chip)
     off = _sds((SO_SLOTS,), jnp.int32, one_chip)
     table = _sds((SO_SLOTS, 256), jnp.int32, one_chip)
     kv = [_sds((6001, 8, SO_D, 128), BF16, one_chip)] * 2
-    assert "tpu_custom_call" in _compile(
-        fa.flash_decode_paged, q, *kv, off, table).as_text()
+    (ops, used), = _mosaic_calls(_compile(
+        fa.flash_decode_paged, q, *kv, off, table))
+    assert ops == {b"matmul", b"multi_reduction"}
+    counted = fa._paged_vmem_bytes(1, SO_H, SO_D, 128, 2, 2, False, 8)
+    assert used <= counted <= 2 * used, (used, counted)
 
 
 @pytest.mark.parametrize("rows", [SO_SLOTS, 512])

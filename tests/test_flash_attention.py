@@ -452,6 +452,24 @@ def test_flash_decode_ragged_rejects_bad_offset_shapes():
         flash_decode_ragged(q, k, v, jnp.zeros((2, 2), jnp.int32))
 
 
+@pytest.mark.parametrize("window", [1, 3])
+def test_contiguous_decode_kernels_refuse_grouped_heads(window):
+    """Only the paged kernel knows groups: a contiguous cache of fewer
+    K/V heads than query heads is refused by name, single token and
+    verify window alike, and the caller's dense path serves."""
+    from paddlefleetx_tpu.ops.pallas.flash_attention import (
+        flash_decode, flash_decode_ragged,
+    )
+    q, k, v = _decode_batch(b=2, S=128, seed=13)
+    q = jnp.concatenate([q] * window, axis=1)
+    offs = jnp.asarray([5, 100], jnp.int32)
+    with pytest.raises(NotImplementedError, match="grouped"):
+        flash_decode_ragged(q, k[:, :1], v[:, :1], offs)
+    if window == 1:
+        with pytest.raises(NotImplementedError, match="grouped"):
+            flash_decode(q, k[:, :1], v[:, :1], 5)
+
+
 def test_ragged_decode_dispatch_and_counter():
     """dot_product_attention routes a [b] query_offset to the ragged
     kernel (counter `attention/flash_decode_ragged`), falls back to

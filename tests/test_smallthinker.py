@@ -100,12 +100,15 @@ def test_the_window_and_the_positions_matter(params):
 
 # -- the paged decode kernel: grouped heads, the window's walk ----------
 
-def _decode_case(groups, heads, d=16, slots=5, pages=6, window=1, seed=0):
+def _decode_case(groups, heads, d=16, slots=5, pages=6, window=1, seed=0,
+                 dtype=jnp.float32):
+    """Slot 0 walks one block, slot 3 is free between live ones, slot 2
+    ends on the table's last key."""
     rng = np.random.default_rng(seed)
     pool = 1 + slots * pages
-    k = jnp.asarray(rng.normal(size=(pool, groups, d, PAGE)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(pool, groups, d, PAGE)), jnp.float32)
-    q = jnp.asarray(rng.normal(size=(slots, window, heads, d)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(pool, groups, d, PAGE)), dtype)
+    v = jnp.asarray(rng.normal(size=(pool, groups, d, PAGE)), dtype)
+    q = jnp.asarray(rng.normal(size=(slots, window, heads, d)), dtype)
     table = 1 + np.arange(slots * pages, dtype=np.int32).reshape(
         slots, pages)
     rng.shuffle(table.reshape(-1))
@@ -114,24 +117,99 @@ def _decode_case(groups, heads, d=16, slots=5, pages=6, window=1, seed=0):
     return q, k, v, jnp.asarray(offs), jnp.asarray(table)
 
 
-@pytest.mark.parametrize("window", [1, 3])
-@pytest.mark.parametrize("reach", [None, WINDOW, 128, 1])
-@pytest.mark.parametrize("groups,heads", [(4, 4), (2, 14), (2, 16)])
-def test_flash_decode_paged_gqa_window(groups, heads, reach, window):
-    """Interpret mode against the dense oracle: one query head a K/V
-    head (the GPT shape) and 7 or 8 a head; no window, a window that
-    starts inside a block (its first block masked within), one of
-    exactly a block, one of a single key; a verify window of 3."""
-    q, k, v, offs, table = _decode_case(groups, heads, window=window)
-    got = fa.flash_decode_paged(q, k, v, offs, table, reach=reach)
-    want = attn._xla_attention(
+def _dense_oracle(q, k, v, offs, table, reach):
+    """The gather path in float32, whatever the operands came in."""
+    q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+    return attn._xla_attention(
         q, attn._gather_kv_pages(k, table), attn._gather_kv_pages(v, table),
         None, True, offs, 0.0, None, True, True, kv_cache_layout=True,
         sliding_window=reach)
+
+
+@pytest.mark.parametrize("window", [1, 3, 5])
+@pytest.mark.parametrize("reach", [None, WINDOW, 128, 1])
+@pytest.mark.parametrize("groups,heads", [
+    (4, 4), (2, 14), (2, 16), (4, 28), (8, 64)])
+def test_flash_decode_paged_gqa_window(groups, heads, reach, window):
+    """Interpret mode against the dense oracle: one query head a K/V
+    head (the GPT shape, the VPU form) and 7 or 8 a head over 2, 4 and
+    8 pooled heads (the two served shapes; the MXU form); no window, a
+    window that starts inside a block (its first block masked within),
+    one of exactly a block, one of a single key; verify windows of 3
+    and 5, whose positions are rows of one product."""
+    q, k, v, offs, table = _decode_case(groups, heads, window=window)
+    got = fa.flash_decode_paged(q, k, v, offs, table, reach=reach)
+    want = _dense_oracle(q, k, v, offs, table, reach)
     live = np.asarray(table[:, 0] != NULL_PAGE)
     np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
                                atol=2e-5, rtol=0)
     assert not np.asarray(got)[~live].any()     # a dead row reads zeros
+
+
+@pytest.mark.parametrize("window", [1, 5])
+@pytest.mark.parametrize("reach", [None, WINDOW])
+@pytest.mark.parametrize("groups,heads", [(2, 14), (4, 28), (8, 64)])
+def test_flash_decode_paged_gqa_bfloat16_pool(groups, heads, reach, window):
+    """The served dtype: bfloat16 queries against a bfloat16 pool, the
+    operands of both products as they stand. Against the float32 oracle
+    on the same values nothing is left but the output's own rounding to
+    bfloat16 (half an ulp: at most 2**-8 of the value)."""
+    q, k, v, offs, table = _decode_case(groups, heads, d=128, window=window,
+                                        dtype=jnp.bfloat16)
+    got = fa.flash_decode_paged(q, k, v, offs, table, reach=reach)
+    assert got.dtype == jnp.bfloat16
+    got = np.asarray(got.astype(jnp.float32))
+    want = np.asarray(_dense_oracle(q, k, v, offs, table, reach))
+    live = np.asarray(table[:, 0] != NULL_PAGE)
+    np.testing.assert_allclose(got[live], want[live], atol=2e-5,
+                               rtol=2.0 ** -8)
+    assert not got[~live].any()
+
+
+@pytest.mark.parametrize("window", [1, 5])
+def test_flash_decode_paged_gqa_int8_pool(window):
+    """Grouped heads over an int8 pool: dequantised in VMEM, float32
+    products."""
+    q, k, v, offs, table = _decode_case(2, 14, window=window)
+    rng = np.random.default_rng(3)
+    k8, v8 = (jnp.asarray(rng.integers(-127, 128, k.shape), jnp.int8)
+              for _ in range(2))
+    ks, vs = (jnp.asarray(rng.uniform(0.002, 0.02, k.shape[:2] + (1, PAGE)),
+                          jnp.float32) for _ in range(2))
+    got = fa.flash_decode_paged(q, k8, v8, offs, table, k_scale=ks,
+                                v_scale=vs, reach=WINDOW)
+    want = _dense_oracle(q, k8.astype(jnp.float32) * ks,
+                         v8.astype(jnp.float32) * vs, offs, table, WINDOW)
+    live = np.asarray(table[:, 0] != NULL_PAGE)
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               atol=2e-5, rtol=0)
+    assert not np.asarray(got)[~live].any()
+
+
+@pytest.mark.parametrize("rows", [8, 24, 40])
+def test_group_pv_keeps_the_probabilities_24_bits(rows):
+    """``p`` against a bfloat16 V block: the three-part split is exact
+    (``hi + mid + lo == p`` bit for bit, each part a bfloat16), so the
+    product differs from float64's by float32's sums alone; one cast of
+    ``p`` to bfloat16, which this is not, would be off by 2**-9 or so."""
+    rng = np.random.default_rng(rows)
+    p = jnp.asarray(rng.uniform(0, 1, (2, rows, PAGE)) *
+                    10.0 ** rng.uniform(-6, 0, (2, rows, 1)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(2, 16, PAGE)), jnp.bfloat16)
+    hi = p.astype(jnp.bfloat16).astype(jnp.float32)
+    mid = (p - hi).astype(jnp.bfloat16).astype(jnp.float32)
+    lo = p - hi - mid
+    assert jnp.array_equal(lo.astype(jnp.bfloat16).astype(jnp.float32), lo)
+    assert jnp.array_equal(hi + (mid + lo), p)
+    want = np.einsum("grk,gdk->grd", np.asarray(p, np.float64),
+                     np.asarray(v.astype(jnp.float32), np.float64))
+    scale = np.abs(want).max(axis=(1, 2), keepdims=True)
+    got = np.asarray(fa._group_pv(p, v), np.float64)
+    assert np.max(np.abs(got - want) / scale) < 2e-6
+    cast = np.einsum("grk,gdk->grd", np.asarray(
+        p.astype(jnp.bfloat16).astype(jnp.float32), np.float64),
+        np.asarray(v.astype(jnp.float32), np.float64))
+    assert np.max(np.abs(cast - want) / scale) > 2e-4
 
 
 @pytest.mark.parametrize("reach", [None, WINDOW])
