@@ -103,7 +103,8 @@ gauge, the ``serving/device_ticks`` counter and per-reason
 ``serving/loop_exit/{finished,admission,budget,drain}`` counters of
 the fused loop, the ``serving/d2h_reads`` counter (arrays pulled to
 the host inside ``step()``: one a decoding step), the
-``serving/slow_steps`` / ``serving/slow_step/<phase>`` counters of the
+``serving/slow_steps`` / ``serving/slow_step/<phase>`` /
+``serving/slow_step_cause/{host_busy,host_waiting}`` counters of the
 slow-step record, and a
 tokens/s + TTFT p50/p99 summary;
 an optional flight recorder mirrors admissions/evictions to an
@@ -129,8 +130,14 @@ inside one ``serving/step/<phase>`` annotation under the root
 ``serving/step`` (``observability/trace.py``: a profiler annotation
 on the device trace's clock, and the seconds of the step's
 :class:`StepRecord`). The record feeds ``serving/tick_ms``,
-``serving/host_roundtrip_ms`` and ``summary()``'s decode time, and
-names the phase of a slow step (docs/observability.md, "Host phases").
+``serving/host_roundtrip_ms`` and ``summary()``'s decode time, names
+the phase of a slow step and, from the driving thread's CPU seconds,
+whether the host was busy or waiting in it; while a profiler session
+runs its counts follow the root onto the trace as one
+``serving/step_account ticks=.. chunks=.. live=..`` point, so a
+trace's reader can tell a step that carried a prefill chunk from one
+that did not and knows the batch each tick ran at
+(docs/observability.md, "Host phases").
 """
 
 from __future__ import annotations
@@ -161,7 +168,7 @@ from ..observability import metrics
 from ..observability import server as obs_server
 from ..observability.recorder import FlightRecorder
 from ..observability.spans import Tracer
-from ..observability.trace import annotate, unaccounted
+from ..observability.trace import annotate, point, unaccounted
 from ..utils.log import logger
 from .adapters import AdapterCache, AdapterCacheFull, insert_adapter
 from .host_tier import HostSpillTier, RehydrateMiss, model_fingerprint
@@ -198,21 +205,46 @@ def default_prefill_buckets(max_prompt_len: int) -> Tuple[int, ...]:
 STEP = "serving/step"
 #: a step is SLOW when it took longer than both this many seconds and
 #: ``SLOW_STEP_FACTOR`` x the median of the last ``SLOW_STEP_HISTORY``
-#: decoding steps (judged once ``SLOW_STEP_MIN_HISTORY`` of them are
-#: in — the first steps of a server compile)
-SLOW_STEP_SECONDS = 0.25
+#: decoding steps of its own kind, with a prefill chunk or without
+#: (judged once ``SLOW_STEP_MIN_HISTORY`` of them are in — the first
+#: steps of a server compile). The floor lies under the 110-145 ms
+#: steps that set a serving cell's spread and over every step the
+#: benchmark's cells take in the normal way (3-48 ms)
+SLOW_STEP_SECONDS = 0.05
 SLOW_STEP_FACTOR = 5.0
 SLOW_STEP_HISTORY = 64
 SLOW_STEP_MIN_HISTORY = 8
+#: the point that puts a step's counts on a profiler session's clock
+#: (``observability/trace.py::point``); no ``serving/step`` or
+#: ``serving/step/*`` pattern matches it
+STEP_ACCOUNT = "serving/step_account ticks=%d chunks=%d live=%d"
+_thread = threading.local()
+
+
+def _cpu_mark() -> Tuple[float, float]:
+    """``(perf_counter, thread_time)`` of the calling thread, read
+    together at most ``SLOW_STEP_SECONDS`` ago. The thread's CPU clock
+    is a system call (6 us on the chip's host, and more in place), so
+    a step does not read it: it takes the thread's last reading, and
+    reads anew only when that is older than the slow-step floor."""
+    now = time.perf_counter()
+    mark = getattr(_thread, "cpu_mark", None)
+    if mark is None or now - mark[0] > SLOW_STEP_SECONDS:
+        mark = _thread.cpu_mark = (now, time.thread_time())
+    return mark
 
 
 class StepRecord:
     """The host's account of one ``step()``: wall time of its start,
     seconds by phase (``phases``, filled by ``annotate``; the root's
-    duration under ``STEP``), and what the step did."""
+    duration under ``STEP``), what the step did, and, for a step over
+    the slow-step floor, the driving thread's CPU seconds over
+    ``cpu_span``: the root and what lay between it and the thread's
+    last reading of its CPU clock, under ``SLOW_STEP_SECONDS`` and so
+    the lesser part."""
 
     __slots__ = ("start", "phases", "live", "queued", "chunks",
-                 "ticks", "tokens")
+                 "ticks", "tokens", "cpu_seconds", "cpu_span")
 
     def __init__(self):
         self.start = time.time()
@@ -222,6 +254,9 @@ class StepRecord:
         self.chunks = 0      # prefill chunks dispatched
         self.ticks = 0       # decode ticks run on the device
         self.tokens = 0      # tokens committed
+        #: None on a step under the slow-step floor
+        self.cpu_seconds: Optional[float] = None
+        self.cpu_span: Optional[float] = None
 
     @property
     def seconds(self) -> float:
@@ -247,8 +282,12 @@ class StepRecord:
     def as_dict(self) -> dict:
         """The whole record, as the slow-step log line and event
         carry it."""
+        def ms(seconds):
+            return None if seconds is None else round(seconds * 1e3, 3)
         return {"start": round(self.start, 3),
-                "dur_ms": round(self.seconds * 1e3, 3),
+                "dur_ms": ms(self.seconds),
+                "cpu_ms": ms(self.cpu_seconds),
+                "cpu_span_ms": ms(self.cpu_span),
                 "phases_ms": self.phases_ms(), "live": self.live,
                 "queued": self.queued, "chunks": self.chunks,
                 "ticks": self.ticks, "tokens": self.tokens}
@@ -532,8 +571,10 @@ class GenerationServer:
         self._tick_time = 0.0
         #: the newest finished step's record (always on, in memory)
         self.last_step: Optional[StepRecord] = None
-        #: durations of the last decoding steps: the slow-step baseline
-        self._recent_steps: deque = deque(maxlen=SLOW_STEP_HISTORY)
+        #: durations of the last decoding steps, those with no prefill
+        #: chunk and those with one: the slow-step baselines
+        self._recent_steps = (deque(maxlen=SLOW_STEP_HISTORY),
+                              deque(maxlen=SLOW_STEP_HISTORY))
         # latency histograms live in a server-local always-on registry
         # (summary percentiles must work with global telemetry off);
         # fixed-memory log buckets replace the old unbounded TTFT list
@@ -1258,10 +1299,14 @@ class GenerationServer:
         decode tick proceeds, so a long admission never freezes
         tokens/s (the chunked-prefill contract of ROADMAP item 1).
 
-        Phases: ``prefill_pump`` is the host side up to and including
-        the chunk's dispatch, and the slot's activation after a
-        prompt's last chunk; ``prefill_harvest`` between them is the
-        read of that chunk's last logits row, a device sync."""
+        Phases, siblings under the root: ``prefill_pump`` is the
+        host's bookkeeping (the chunk's row built in numpy, the page
+        table's upload, counters, and after a prompt's last chunk the
+        slot's activation and the registries); ``prefill_dispatch`` is
+        the chunk's jitted call with the upload of its operands, there
+        if and only if the step launched a chunk; ``prefill_harvest``
+        is the read of a last chunk's last logits row, a device
+        sync."""
         ph = rec.phases
         with annotate("serving/step/prefill_pump", ph):
             if not self._prefilling:
@@ -1276,6 +1321,7 @@ class GenerationServer:
             real = min(self._chunk, L - c0)
             row[0, :real] = seq[c0:c0 + real]
             self._sync_pt()
+        with annotate("serving/step/prefill_dispatch", ph):
             self._cache, logits = prefill_chunk_paged(
                 self.model, self.params, self._cache, jnp.asarray(row),
                 jnp.asarray([c0], jnp.int32),
@@ -1283,6 +1329,7 @@ class GenerationServer:
                 jnp.asarray([int(self._aid_np[slot])], jnp.int32)
                 if self._adapters is not None else None,
                 jnp.asarray([real], jnp.int32))
+        with annotate("serving/step/prefill_pump", ph):
             req["prefill_pos"] = c0 + self._chunk
             if self._state_layers and c0 == 0:
                 # the model zeroed the slot's rows on the device
@@ -1602,6 +1649,7 @@ class GenerationServer:
             to back off instead of spinning, and to keep no-op polls
             off the thread timeline."""
         rec = StepRecord()
+        mark = _cpu_mark()
         with annotate("serving/step", rec.phases):
             with self._surface_lock:
                 if self._closed:
@@ -1621,7 +1669,7 @@ class GenerationServer:
                 if self._tier is not None:
                     self._tier.ship()
         with self._surface_lock:
-            self._account_step(rec)
+            self._account_step(rec, mark)
         return progress
 
     def prompt_ready(self, tokens: Sequence[int]) -> bool:
@@ -1782,6 +1830,7 @@ class GenerationServer:
         lock is released so the writer thread can never be fed from
         inside the critical section."""
         rec = StepRecord()
+        mark = _cpu_mark()
         with annotate("serving/step", rec.phases):
             with self._surface_lock:
                 if self._closed:
@@ -1791,16 +1840,31 @@ class GenerationServer:
                 if self._tier is not None:
                     self._tier.ship()
         with self._surface_lock:
-            self._account_step(rec)
+            self._account_step(rec, mark)
         return out
 
-    def _account_step(self, rec: StepRecord) -> None:
+    def _account_step(self, rec: StepRecord,
+                      mark: Tuple[float, float]) -> None:
         """Feed one finished step's record to what is kept of the
-        series (each interval was clocked once, by ``annotate``) and
-        judge it against the slow-step thresholds. Under the surface
-        lock, like every other write to the server's counters."""
-        self.last_step = rec
+        series (each interval was clocked once, by ``annotate``),
+        put its counts on a running profiler session's clock, directly
+        after the root they belong to, and judge it against the
+        slow-step thresholds: its own floor and the median of the last
+        decoding steps of its kind, since a step that carries a
+        prefill chunk is a few times one that does not. ``mark`` is
+        the driving thread's ``_cpu_mark()`` from before the root;
+        only a step over the floor reads the thread's CPU clock, here.
+        Under the surface lock, like every other write to the
+        server's counters."""
         seconds = rec.seconds
+        over_floor = seconds > SLOW_STEP_SECONDS
+        if over_floor:
+            now = _thread.cpu_mark = (time.perf_counter(),
+                                      time.thread_time())
+            rec.cpu_span = now[0] - mark[0]
+            rec.cpu_seconds = now[1] - mark[1]
+        self.last_step = rec
+        point(STEP_ACCOUNT, rec.ticks, rec.chunks, rec.live)
         if rec.ticks:
             tick_s = rec.tick_seconds()
             self._tick_time += tick_s
@@ -1813,29 +1877,38 @@ class GenerationServer:
             # compares against tick_ms to show the amortization win
             self._metrics.observe("serving/host_roundtrip_ms",
                                   seconds * 1000.0)
-        recent = self._recent_steps
-        if seconds > SLOW_STEP_SECONDS and \
-                len(recent) >= SLOW_STEP_MIN_HISTORY:
+        recent = self._recent_steps[rec.chunks > 0]
+        if over_floor and len(recent) >= SLOW_STEP_MIN_HISTORY:
             median = statistics.median(recent)
             if seconds > SLOW_STEP_FACTOR * median:
-                self._slow_step(rec, median)
+                self._slow_step(rec, median, len(recent))
         if rec.ticks:
             recent.append(seconds)
 
-    def _slow_step(self, rec: StepRecord, median_s: float) -> None:
+    def _slow_step(self, rec: StepRecord, median_s: float,
+                   history: int) -> None:
         """Put a slow step on the record: which phase took most of
-        it, and the whole per-phase account."""
+        it, whether the thread was at work in it (CPU time at least
+        half of ``cpu_span``, of which the step is the greater part:
+        ``host_busy``) or was not (``host_waiting``: blocked in the
+        runtime, or not scheduled), and the whole account."""
         record = rec.as_dict()
         phases = record["phases_ms"]
         worst = max(phases, key=phases.get)
+        cause = "host_busy" if rec.cpu_seconds >= 0.5 * rec.cpu_span \
+            else "host_waiting"
         metrics.inc("serving/slow_steps")
         metrics.inc("serving/slow_step/" + worst)
+        metrics.inc("serving/slow_step_cause/" + cause)
         logger.warning(
-            "slow step(): %.0f ms against a median of %.0f ms over "
-            "the last %d decoding steps, most of it in %s: %s",
-            record["dur_ms"], median_s * 1e3, len(self._recent_steps),
-            worst, json.dumps(record))
-        self._emit("serving_slow_step", worst=worst,
+            "slow step(): %.0f ms (%.0f ms of CPU in %.0f ms: %s) "
+            "against a median of %.0f ms over the last %d decoding "
+            "steps %s a chunk, most of it in %s: %s",
+            record["dur_ms"], record["cpu_ms"], record["cpu_span_ms"],
+            cause, median_s * 1e3, history,
+            "with" if rec.chunks else "without", worst,
+            json.dumps(record))
+        self._emit("serving_slow_step", worst=worst, cause=cause,
                    median_ms=round(median_s * 1e3, 3), **record)
 
     def _schedule(self, rec: StepRecord
@@ -2049,6 +2122,7 @@ class GenerationServer:
                 live = [s for s in live
                         if (req := self._slots[s]) is not None
                         and req.get("active")]
+                rec.live = len(live)
         drafts = None
         if self.spec:
             with annotate("serving/step/draft", ph):

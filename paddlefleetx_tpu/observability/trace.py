@@ -10,6 +10,13 @@ interval, clocked once, feeding the trace and the always-on
 accounting alike. With no session running it costs about a
 microsecond.
 
+``point(template, *values)`` is its sibling for a fact with no
+duration: one TraceMe of next to no length whose NAME is
+``template % values``, on the same clock and line, so what a step
+counted sits in the trace directly after the interval it belongs to.
+The name is only formatted while a session records; with none the
+call is one ``TraceMe.is_enabled()``.
+
 Names are slash-paths whose parent is their prefix
 (``serving/step/admit`` lies inside ``serving/step``, ``h2d/pretreat``
 inside ``h2d``), so nesting can be rebuilt from names and intervals
@@ -51,6 +58,17 @@ class annotate:
         if self._record is not None:
             self._record[self.name] = \
                 self._record.get(self.name, 0.0) + self.seconds
+
+
+def point(template: str, *values) -> None:
+    """Mark the instant ``template % values`` on the calling thread's
+    line of a running profiler session; nothing, and nothing
+    formatted, with no session. The values ride in the name because a
+    reduction that keeps ``(name, start, duration)`` of an event drops
+    a TraceMe's metadata."""
+    if TraceAnnotation.is_enabled():
+        with TraceAnnotation(template % values):
+            pass
 
 
 def annotate_step(name: str, step_num: int) -> StepTraceAnnotation:

@@ -26,7 +26,8 @@ import pytest
 
 from paddlefleetx_tpu.core.paging import pool_bytes
 from paddlefleetx_tpu.core.serving import (
-    GenerationServer, RequestShed, default_prefill_buckets,
+    SLOW_STEP_SECONDS, GenerationServer, RequestShed,
+    default_prefill_buckets,
 )
 from paddlefleetx_tpu.models.gpt import GPTConfig, GPTForPretraining
 from paddlefleetx_tpu.models.gpt.generation import (
@@ -2636,7 +2637,19 @@ def test_step_record_phases_sum_to_the_root(paged512_model_and_params,
         assert all(v >= 0.0 for k, v in ms.items()
                    if k != "unaccounted")
         assert (rec.ticks > 0) == ("decode_harvest" in ms)
+        assert (rec.chunks > 0) == ("prefill_dispatch" in ms)
         assert rec.live <= 2 and rec.chunks in (0, 1)
+        # the thread's CPU clock is read for a step over the slow-step
+        # floor alone (here the ones that compiled), over the step and
+        # the lesser span back to the thread's last reading; it lags
+        # the wall clock by up to a scheduler tick at each end
+        slow = rec.seconds > SLOW_STEP_SECONDS
+        assert (d["cpu_ms"] is None) == (d["cpu_span_ms"] is None) \
+            == (not slow)
+        if slow:
+            assert d["dur_ms"] <= d["cpu_span_ms"] < \
+                d["dur_ms"] + SLOW_STEP_SECONDS * 1e3 + 5
+            assert 0.0 <= d["cpu_ms"] <= d["cpu_span_ms"] + 10
     assert sum(r.chunks for r in records) == summ["prefill_chunks"]
     assert sum(r.ticks for r in records) == summ["decode_ticks"]
     assert sum(r.tokens for r in records) == summ["decode_tokens"]
@@ -2653,6 +2666,15 @@ def test_step_record_phases_sum_to_the_root(paged512_model_and_params,
         sum(r.tick_seconds() for r in decoding), abs=1e-4)
 
 
+def _quiet_floor(monkeypatch):
+    """Six test workers share this host's cores, and a step of a toy
+    server that lost its core for 50 ms is no finding: a test that
+    asserts NO slow step puts the floor where only its own stall (or a
+    fault) reaches it."""
+    import paddlefleetx_tpu.core.serving as serving_mod
+    monkeypatch.setattr(serving_mod, "SLOW_STEP_SECONDS", 0.25)
+
+
 def test_slow_step_names_its_phase(paged512_model_and_params, tmp_path,
                                    monkeypatch, caplog):
     """A ``step()`` slowed by one stubbed phase raises
@@ -2661,6 +2683,7 @@ def test_slow_step_names_its_phase(paged512_model_and_params, tmp_path,
     stream; the steps around it raise neither."""
     import logging
     import time as _time
+    _quiet_floor(monkeypatch)
     events = tmp_path / "events.jsonl"
     metrics.set_enabled(True)
     reg = metrics.get_registry()
@@ -2670,8 +2693,8 @@ def test_slow_step_names_its_phase(paged512_model_and_params, tmp_path,
     try:
         srv.submit([5, 9, 2])
         srv.submit([7, 1])
-        for _ in range(9):                  # the history to judge by
-            srv.step()
+        for _ in range(11):                 # the history to judge by:
+            srv.step()                      # two chunk steps, nine plain
         assert srv.last_step.ticks == 1
         assert reg.counter("serving/slow_steps") == 0
         plain = srv._page_maintenance
@@ -2705,16 +2728,19 @@ def test_slow_step_names_its_phase(paged512_model_and_params, tmp_path,
     assert ev["dur_ms"] > 5 * ev["median_ms"]
     assert sum(ev["phases_ms"].values()) == \
         pytest.approx(ev["dur_ms"], abs=0.02)
-    for key in ("start", "live", "queued", "chunks", "ticks", "tokens"):
+    for key in ("start", "live", "queued", "chunks", "ticks", "tokens",
+                "cpu_ms", "cpu_span_ms", "cause"):
         assert key in ev, key
     (line,) = [r.getMessage() for r in caplog.records
                if "slow step()" in r.getMessage()]
     assert "page_maintenance" in line and '"phases_ms"' in line
 
 
-def test_a_normal_run_has_no_slow_step(paged512_model_and_params):
+def test_a_normal_run_has_no_slow_step(paged512_model_and_params,
+                                       monkeypatch):
     """Compilation in a server's first steps is not a slow step (too
     little history to judge by), and nothing after it is."""
+    _quiet_floor(monkeypatch)
     metrics.set_enabled(True)
     reg = metrics.get_registry()
     reg.reset()
@@ -2731,3 +2757,210 @@ def test_a_normal_run_has_no_slow_step(paged512_model_and_params):
         srv.close()
         metrics.set_enabled(False)
         reg.reset()
+
+
+def _busy(seconds):
+    import time as _time
+    end = _time.perf_counter() + seconds
+    while _time.perf_counter() < end:
+        pass
+
+
+@pytest.mark.parametrize("stall, cause", [
+    ("sleep", "host_waiting"), ("busy", "host_busy")])
+def test_slow_step_says_whether_the_host_was_busy_or_waiting(
+        paged512_model_and_params, tmp_path, monkeypatch, stall, cause):
+    """The floor is 50 ms: a step stalled for 0.2 s is caught, and the
+    thread's CPU time names the cause beside the phase: a sleep (the
+    thread blocked: next to no CPU) counts
+    ``serving/slow_step_cause/host_waiting``, a busy loop
+    ``host_busy``, although the CPU reading reaches back before the
+    step, to a reading the steps before it shared: that reach is under
+    the floor and the step over it. Record and event carry ``cpu_ms``
+    and the ``cpu_span_ms`` it was spent in."""
+    import statistics
+    import time as _time
+
+    import paddlefleetx_tpu.core.serving as serving_mod
+    assert serving_mod.SLOW_STEP_SECONDS == 0.05
+    events = tmp_path / "events.jsonl"
+    metrics.set_enabled(True)
+    reg = metrics.get_registry()
+    reg.reset()
+    srv = _phase_server(paged512_model_and_params,
+                        events_path=str(events))
+    try:
+        srv.submit([5, 9, 2])
+        srv.submit([7, 1])
+        for _ in range(11):                 # the history to judge by:
+            srv.step()                      # two chunk steps, nine plain
+        reg.reset()                         # a loaded host's own, if any
+        plain = srv._page_maintenance
+
+        def stalled(*a, **kw):
+            (_time.sleep if stall == "sleep" else _busy)(0.2)
+            return plain(*a, **kw)
+        monkeypatch.setattr(srv, "_page_maintenance", stalled)
+        # a fifth of a second at five times a median well under 40 ms
+        median = statistics.median(srv._recent_steps[False])
+        assert median < 0.04, median
+        # the worst a step can meet: a reading nearly as old as it
+        # may be, and the thread at work (before a sleep) or idle
+        # (before a busy loop) ever since
+        del serving_mod._thread.cpu_mark
+        serving_mod._cpu_mark()
+        (_busy if stall == "sleep" else _time.sleep)(
+            SLOW_STEP_SECONDS - 0.012)
+        srv.step()
+        slow = srv.last_step
+    finally:
+        srv.close()
+        metrics.set_enabled(False)
+    counters = reg.snapshot()["counters"]
+    reg.reset()
+    assert counters["serving/slow_steps"] == 1
+    assert counters["serving/slow_step/page_maintenance"] == 1
+    assert [k for k in counters
+            if k.startswith("serving/slow_step_cause/")] \
+        == ["serving/slow_step_cause/" + cause]
+    assert slow.seconds >= 0.2
+    assert slow.seconds <= slow.cpu_span < slow.seconds + SLOW_STEP_SECONDS
+    if stall == "sleep":
+        assert slow.cpu_seconds < 0.5 * slow.cpu_span
+    else:
+        assert slow.cpu_seconds >= 0.5 * slow.cpu_span
+    (ev,) = [e for e in read_events(str(events))
+             if e["event"] == "serving_slow_step"]
+    assert ev["cause"] == cause and ev["worst"] == "page_maintenance"
+    assert ev["cpu_ms"] == round(slow.cpu_seconds * 1e3, 3)
+    assert ev["cpu_span_ms"] == round(slow.cpu_span * 1e3, 3)
+
+
+def test_a_step_is_judged_against_steps_of_its_own_kind(
+        paged512_model_and_params):
+    """A step that carries a prefill chunk is a few times one that does
+    not, so each kind has its own history: a prompt's last chunk after
+    64 short decoding steps (70 ms against a 13 ms median: the first
+    request on an empty server) is no slow step, whether the chunk
+    steps' own history is still too short to judge by or holds 45 ms
+    steps; a 70 ms step WITHOUT a chunk is one, and so is a chunk step
+    of five times its own kind's median."""
+    from paddlefleetx_tpu.core.serving import (SLOW_STEP_HISTORY, STEP,
+                                               StepRecord, _cpu_mark)
+    metrics.set_enabled(True)
+    reg = metrics.get_registry()
+    reg.reset()
+    srv = _phase_server(paged512_model_and_params)
+
+    def step(ms, chunks, worst):
+        rec = StepRecord()
+        rec.ticks, rec.chunks, rec.live = 1, chunks, 1
+        rec.phases = {STEP: ms / 1e3, STEP + "/" + worst: 0.9 * ms / 1e3}
+        with srv._surface_lock:
+            srv._account_step(rec, _cpu_mark())
+        return reg.counter("serving/slow_steps")
+    try:
+        for _ in range(SLOW_STEP_HISTORY):
+            assert step(13, 0, "decode_harvest") == 0
+        assert step(70, 1, "prefill_harvest") == 0      # no history yet
+        for _ in range(8):
+            assert step(45, 1, "decode_harvest") == 0
+        assert step(70, 1, "prefill_harvest") == 0      # under 5 x 45
+        assert len(srv._recent_steps[True]) == 10
+        assert len(srv._recent_steps[False]) == SLOW_STEP_HISTORY
+        assert step(70, 0, "decode_harvest") == 1       # over 5 x 13
+        assert step(240, 1, "prefill_harvest") == 2     # over 5 x 45
+        counters = reg.snapshot()["counters"]
+    finally:
+        srv.close()
+        metrics.set_enabled(False)
+        reg.reset()
+    assert counters["serving/slow_step/decode_harvest"] == 1
+    assert counters["serving/slow_step/prefill_harvest"] == 1
+    assert sum(v for k, v in counters.items()
+               if k.startswith("serving/slow_step_cause/")) == 2
+
+
+class _RecordedTraceMe:
+    """Stands in for ``jax.profiler.TraceAnnotation`` while a session
+    records: every begin and end, in order."""
+
+    log: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("B", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("E", self.name))
+
+    @staticmethod
+    def is_enabled():
+        return True
+
+
+def _account_of(name):
+    head, *fields = name.split(" ")
+    assert head == "serving/step_account", name
+    return {k: int(v) for k, v in (f.split("=") for f in fields)}
+
+
+@pytest.mark.parametrize("drive", ["step", "prefill_step"])
+def test_every_step_puts_its_account_after_its_root(
+        paged512_model_and_params, monkeypatch, drive):
+    """While a profiler session records, every ``step()`` and every
+    ``prefill_step()`` writes ONE ``serving/step_account`` point,
+    directly after the root it belongs to, whose counts are
+    ``last_step``'s; a step has the phase ``prefill_dispatch`` exactly
+    when it launched a chunk, and the phases still sum to the root."""
+    import paddlefleetx_tpu.observability.trace as trace_mod
+    monkeypatch.setattr(_RecordedTraceMe, "log", [])
+    monkeypatch.setattr(trace_mod, "TraceAnnotation", _RecordedTraceMe)
+    srv = _phase_server(paged512_model_and_params)
+    rng = np.random.default_rng(4)
+    for n in (5, 300, 9):
+        srv.submit(rng.integers(0, EOS, n).tolist())
+    records = []
+    try:
+        if drive == "step":
+            while srv.pending or srv.occupancy:
+                srv.step()
+                records.append(srv.last_step)
+        else:
+            progress = True
+            while progress:                 # the last makes none: a root
+                progress = srv.prefill_step()
+                records.append(srv.last_step)
+    finally:
+        srv.close()
+    log = _RecordedTraceMe.log
+    ends = [i for i, ev in enumerate(log) if ev == ("E", "serving/step")]
+    assert len(ends) == len(records) >= 4
+    for i, rec in zip(ends, records):
+        # the point opens and closes straight after the root closes
+        (b, name), (e, same) = log[i + 1], log[i + 2]
+        assert (b, e) == ("B", "E") and name == same
+        assert _account_of(name) == {
+            "ticks": rec.ticks, "chunks": rec.chunks, "live": rec.live}
+        ms = rec.phases_ms()
+        assert (rec.chunks > 0) == ("prefill_dispatch" in ms)
+        assert sum(ms.values()) == pytest.approx(
+            rec.seconds * 1e3, abs=0.02)
+        assert ms["unaccounted"] >= -0.01
+    assert sum(n.startswith("serving/step_account")
+               for b, n in log if b == "B") == len(records)
+    # neither the root's pattern nor its children's matches the point
+    assert not [n for _, n in log if n.startswith("serving/step_account")
+                and (n == "serving/step" or n.startswith("serving/step/"))]
+    chunks = sum(r.chunks for r in records)
+    if drive == "step":
+        assert chunks == 5                  # 1 + 3 + 1 chunks of 128
+        assert sum(r.ticks for r in records) > 8
+        assert any(r.ticks and r.chunks for r in records)
+    else:
+        # two slots and nothing decodes: the third prompt stays queued
+        assert chunks == 4 and records[-1].queued == 1
+        assert not any(r.ticks or r.tokens for r in records)

@@ -593,6 +593,57 @@ def test_annotate_cost_with_no_profiler_session_stays_in_budget():
     assert rec["serving/step/admit"] > 0.0
 
 
+def test_a_steps_account_with_no_profiler_session_stays_in_budget():
+    """What every ``step()`` pays for its account with no session
+    running: the point is one ``TraceMe.is_enabled()`` and formats
+    nothing, and the thread's CPU clock, a system call, is read anew
+    only when the thread's last reading is older than the slow-step
+    floor — a loop of steps makes one such call in 50 ms, not one a
+    step, and pays well under a microsecond a step for both."""
+    import time
+    import timeit
+
+    import paddlefleetx_tpu.core.serving as serving_mod
+    from paddlefleetx_tpu.core.serving import STEP_ACCOUNT, _cpu_mark
+    from paddlefleetx_tpu.observability.trace import point
+    # nothing is formatted: values that do not fit the template pass
+    point("%d of %d", "no number")
+    n = 10_000
+
+    def account():
+        point(STEP_ACCOUNT, 1, 0, 17)
+    metrics.set_enabled(False)
+    cost = {f.__name__: min(timeit.timeit(f, number=n)
+                            for _ in range(5)) / n
+            for f in (account, _cpu_mark)}
+    assert cost["account"] < 1e-6, cost
+    assert cost["account"] + cost["_cpu_mark"] < 2e-6, cost
+    # one reading serves every step that starts within the floor of it
+    reads = []
+    plain = time.thread_time
+
+    def counted():
+        reads.append(time.perf_counter())
+        return plain()
+    first = _cpu_mark()
+    time.thread_time = counted
+    try:
+        end = first[0] + 2.5 * serving_mod.SLOW_STEP_SECONDS
+        marks = []
+        while time.perf_counter() < end:
+            marks.append(_cpu_mark())
+    finally:
+        time.thread_time = plain
+    assert len(marks) > 100 and 1 <= len(reads) <= 3, len(reads)
+    assert len(set(marks)) == len(reads) + (marks[0] == first)
+    # no mark is older than the floor when a step takes it, and the
+    # CPU clock on it moves with the busy loop above (it may lag by a
+    # scheduler tick, and a loaded host takes the thread off its core)
+    wall, cpu = _cpu_mark()
+    assert time.perf_counter() - wall <= serving_mod.SLOW_STEP_SECONDS + 0.02
+    assert 0.0 <= cpu - first[1] < 2.5 * serving_mod.SLOW_STEP_SECONDS + 0.03
+
+
 def _main_thread_annotations(trace_dir, marker):
     """``[(name, start_ns, end_ns)]`` of the host line that holds
     ``marker``: the thread that drove the program."""
@@ -675,8 +726,9 @@ def test_server_phases_land_on_the_profilers_clock(tmp_path):
     # what a paged, non-speculative, untiered run exercises
     assert set(phases) == {
         "expire", "spill_drain", "admit", "prefill_pump",
-        "prefill_harvest", "page_maintenance", "table_sync",
-        "decode_dispatch", "decode_harvest", "commit", "ship_spills"}
+        "prefill_dispatch", "prefill_harvest", "page_maintenance",
+        "table_sync", "decode_dispatch", "decode_harvest", "commit",
+        "ship_spills"}
     for name, spans in phases.items():
         for s, e in spans:
             assert any(rs <= s and e <= re_ for rs, re_ in roots), name
@@ -687,3 +739,28 @@ def test_server_phases_land_on_the_profilers_clock(tmp_path):
     # phases of one step do not overlap: the line is flat under a root
     flat = sorted(x for spans in phases.values() for x in spans)
     assert all(a[1] <= b[0] for a, b in zip(flat, flat[1:]))
+    # one chunk's dispatch a chunk, each between two pieces of the pump
+    summ = srv.summary()
+    assert len(phases["prefill_dispatch"]) == summ["prefill_chunks"] == 4
+    # every root is followed by its account, a point of next to no
+    # length on the same line, before the next root opens; the counts
+    # in its name are the run's
+    points = sorted((s, e, n) for n, s, e in evs
+                    if n.startswith("serving/step_account "))
+    assert len(points) == len(roots)
+    for (rs, re_), (ps, pe, _), nxt in zip(
+            roots, points, roots[1:] + [(float("inf"),) * 2]):
+        assert re_ <= ps <= pe <= nxt[0]
+        assert pe - ps < 1e6                    # well under a millisecond
+    accounts = [dict(f.split("=") for f in n.split(" ")[1:])
+                for _, _, n in points]
+    assert all(list(a) == ["ticks", "chunks", "live"] for a in accounts)
+    assert sum(int(a["ticks"]) for a in accounts) == summ["decode_ticks"]
+    assert sum(int(a["chunks"]) for a in accounts) == 4
+    assert max(int(a["live"]) for a in accounts) == 2
+    decoding = [a for a in accounts if int(a["ticks"])]
+    assert len(decoding) == summ["host_roundtrips"]
+    # the point matches no pattern that reads the root or its phases
+    from fnmatch import fnmatch
+    assert not [n for _, _, n in points if fnmatch(n, "serving/step")
+                or fnmatch(n, "serving/step/*")]
