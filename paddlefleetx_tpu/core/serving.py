@@ -78,6 +78,62 @@ host reads the device ONCE a launch: tokens, counts, ``finished``,
 (``generation.pack_harvest``) whose copy ``_launch`` asks for at the
 dispatch (``copy_to_host_async``), so it starts when the program ends
 and not when the host has noticed (docs/inference.md "The harvest").
+A fused launch is read in the step that made it (below).
+
+Deferred harvest (the plain one-tick server; docs/inference.md "The
+harvest"): a decoding ``step()`` launches tick n BEFORE it reads tick
+n-1 — schedule, prefill chunk, page maintenance, table upload,
+``decode_dispatch`` of n, then ``decode_harvest`` and ``commit`` of
+n-1 — so the device always has a tick queued while the host reads,
+commits, returns to its caller, schedules and dispatches. One launch
+deep, never deeper: the host runs at most one tick ahead of what it
+has read. The scheduler's contract under it:
+
+- **What the host decides on is one tick old.** ``finished`` and
+  ``dec_count`` of tick n-1 arrive after tick n went down, so a
+  request that ended on EOS in n-1 is ticked once more. On the device
+  that row is finished and emits ``pad``; on the host it is VOID: a
+  harvest is committed to the ``(slot, request)`` pairs it was
+  launched for, and a row whose slot no longer holds that request
+  commits nothing (``serving/harvest_rows_void``). Its one write, at
+  the request's last length, went into a page that was the slot's
+  alone at the launch and that the eviction has released since (or
+  the slot's ring, or its state row); whoever is handed any of them
+  next got there through a LATER launch, which the device runs after
+  it (:meth:`GenerationServer._read_launch`).
+- **A budget is known a tick ahead.** The one-tick program checks no
+  ``max_dec_len``; the host does not launch a row whose tick in
+  flight is the last its budget buys (``_retire``: tokens committed
+  plus ticks unread), so no token past the budget is ever computed,
+  let alone committed.
+- **Pages follow the device's length.** The host's ``cur_len`` is a
+  tick old at the launch: page growth and copy-on-write are decided
+  at ``cur_len + ahead``, the position THIS launch writes, and the
+  commit's trim keeps the page the launch in flight is writing into
+  (at a page boundary it holds nothing committed yet).
+- **What must see a request's newest token reads first** (a flush,
+  ``serving/harvest_flushed/<why>``): :meth:`~GenerationServer.drain`
+  (and every step while draining: ``max_ticks`` counts ticks),
+  :meth:`~GenerationServer.preempt`, page-pool exhaustion before it
+  preempts anyone (``preempt``; the read may free what it needs),
+  :meth:`~GenerationServer.close`, and the last launch before the
+  server runs empty (``idle``). What needs NO flush: deadline expiry
+  (the partial holds what was committed; the row in flight goes
+  void), and the KV handoff's and spill tier's page reads, which are
+  device programs queued behind the tick in flight and read registry
+  pages no decode tick writes.
+- **Who never defers.** Speculation drafts from the newest committed
+  tokens, so ``spec_method`` reads every launch at once
+  (``harvest_flushed/spec``); so does ``device_loop_ticks > 1``
+  (``harvest_flushed/loop``), whose fused launch already stops itself
+  on a finished slot or a spent budget and gives the host back its
+  round trip T ticks at a time. Properties the server observes of
+  itself (``_read_now``): there is no option.
+- **Times.** A token's stamp is when the host received it, one step
+  after its tick, for every token alike: ``tpot`` keeps its meaning,
+  ``ttft`` (``serving/ttft_ms``, the ``first_token`` point,
+  ``Completion.ttft_ms``) grows by at most one step.
+  ``prefill_harvest`` stays a synchronous read, once a prompt.
 
 Graceful degradation (docs/robustness.md): per-request deadlines/TTL
 (``submit(deadline_s=...)`` or a server-wide ``request_ttl_s``) evict
@@ -103,6 +159,8 @@ gauge, the ``serving/device_ticks`` counter and per-reason
 ``serving/loop_exit/{finished,admission,budget,drain}`` counters of
 the fused loop, the ``serving/d2h_reads`` counter (arrays pulled to
 the host inside ``step()``: one a decoding step), the
+``serving/harvest_deferred`` / ``serving/harvest_flushed/<why>`` /
+``serving/harvest_rows_void`` counters of the deferred harvest, the
 ``serving/slow_steps`` / ``serving/slow_step/<phase>`` /
 ``serving/slow_step_cause/{host_busy,host_waiting}`` counters of the
 slow-step record, and a
@@ -150,7 +208,7 @@ import threading
 import time
 from collections import deque
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -249,10 +307,12 @@ class StepRecord:
     def __init__(self):
         self.start = time.time()
         self.phases: Dict[str, float] = {}
-        self.live = 0        # slots the decode launch ticked
+        #: rows of the launch whose harvest the step committed (of
+        #: its own launch where it committed none)
+        self.live = 0
         self.queued = 0      # queue depth once admission had run
         self.chunks = 0      # prefill chunks dispatched
-        self.ticks = 0       # decode ticks run on the device
+        self.ticks = 0       # decode ticks whose harvest it committed
         self.tokens = 0      # tokens committed
         #: None on a step under the slow-step floor
         self.cpu_seconds: Optional[float] = None
@@ -264,8 +324,10 @@ class StepRecord:
         return self.phases.get(STEP, 0.0)
 
     def tick_seconds(self) -> float:
-        """The decode launch as the host saw it: dispatch, then the
-        wait for its tokens."""
+        """What the decode launches cost the host in this step: the
+        dispatch of its own, and the wait for the tokens of the one
+        it read (the same launch on a synchronous step; the PREVIOUS
+        one, long finished as a rule, where the read is deferred)."""
         return self.phases.get(STEP + "/decode_dispatch", 0.0) + \
             self.phases.get(STEP + "/decode_harvest", 0.0)
 
@@ -291,6 +353,15 @@ class StepRecord:
                 "phases_ms": self.phases_ms(), "live": self.live,
                 "queued": self.queued, "chunks": self.chunks,
                 "ticks": self.ticks, "tokens": self.tokens}
+
+
+class _Launch(NamedTuple):
+    """One decode launch the host has not read yet."""
+    #: the launch's harvest array, its copy to the host asked for
+    harvest: jax.Array
+    #: ``(slot, request)`` of every row it ticked, as launched: by the
+    #: time it is read a slot may hold no one, or someone else
+    rows: List[Tuple[int, dict]]
 
 
 @dataclass
@@ -547,9 +618,13 @@ class GenerationServer:
             self._aid_np = np.zeros((num_slots,), np.int32)
             self._aid_dev = jnp.asarray(self._aid_np)
             self._aid_dirty = False
-        #: admission-time request failures (e.g. unknown adapter id)
-        #: surfaced as completions from the next step()
-        self._dead: List[Completion] = []
+        #: completions the next step() hands out: admission-time
+        #: request failures (e.g. unknown adapter id), and what a read
+        #: outside step() found finished (_flush)
+        self._done: List[Completion] = []
+        #: the decode launch whose harvest has not been read (module
+        #: docstring, "Deferred harvest"); never more than one
+        self._inflight: Optional[_Launch] = None
         self._ticks = 0
         # graceful degradation (docs/robustness.md)
         self.request_ttl_s = request_ttl_s
@@ -748,19 +823,22 @@ class GenerationServer:
 
     def work_pending(self) -> bool:
         """True while a :meth:`step` could make progress: queued
-        admissions, an occupied slot, an unfinished chunked prefill,
-        or tiered spill work (pinned pages awaiting their yield-point
-        drain, or collected writer items awaiting shipment). Async
+        admissions, an occupied slot, a decode launch not read yet,
+        an unfinished chunked prefill, or tiered spill work (pinned
+        pages awaiting their yield-point drain, or collected writer
+        items awaiting shipment). Async
         fleet worker threads poll this to park when their replica is
         idle (docs/fleet_serving.md "Async router")."""
         with self._surface_lock:
             if self._queue or any(s is not None for s in self._slots):
                 return True
+            if self._inflight is not None:
+                return True
             if self.paged and self._prefilling:
                 return True
             if self._tier is not None and self._tier.work_pending():
                 return True
-            if self._dead:
+            if self._done:
                 return True
             return False
 
@@ -1003,13 +1081,13 @@ class GenerationServer:
         self._emit("serving_evict", request=req["id"], slot=-1,
                    reason=reason, tokens=len(req["tokens"]),
                    trace=self._trace_id(req))
-        self._dead.append(Completion(
+        self._done.append(Completion(
             request_id=req["id"], prompt=req["prompt"],
             tokens=req["tokens"], finish_reason=reason,
             trace_id=self._trace_id(req)))
 
-    def _take_dead(self) -> List[Completion]:
-        out, self._dead = self._dead, []
+    def _take_done(self) -> List[Completion]:
+        out, self._done = self._done, []
         return out
 
     def _sync_aid(self) -> None:
@@ -1066,6 +1144,7 @@ class GenerationServer:
                 self._state = self._state._replace(
                     dec_count=self._state.dec_count.at[slot].set(
                         len(req["tokens"])))
+            req["active"], req["ahead"] = True, 0
             self._slots[slot] = req
             self._counts["admitted"] += 1
             metrics.inc("serving/admitted")
@@ -1142,7 +1221,7 @@ class GenerationServer:
             jnp.asarray(appeared),
             jnp.asarray(last_logits_row, jnp.float32),
             jnp.int32(req.pop("spec_rejected", -1)))
-        req["active"] = True
+        req["active"], req["ahead"] = True, 0
         req["cur_len"] = len(seq)
         self._pt_dirty = True   # decode view must unhide this row
         self._phase(req, "serving/decode", slot=slot)
@@ -1467,9 +1546,7 @@ class GenerationServer:
         if victim in self._prefilling:
             self._prefilling.remove(victim)
         self._slots[victim] = None
-        self._state = self._state._replace(
-            active=self._state.active.at[victim].set(False),
-            finished=self._state.finished.at[victim].set(False))
+        self._deactivate(victim)
         req["active"] = False
         req.pop("prefill_pos", None)
         # the SAME root span survives the round trip: the running
@@ -1484,46 +1561,67 @@ class GenerationServer:
                    reason="pages", tokens=len(req["tokens"]),
                    trace=self._trace_id(req))
 
-    def _page_maintenance(self, window: int = 1) -> None:
-        """Before every decode tick: each active slot's next ``window``
-        write positions (``cur_len .. cur_len + window - 1`` — one for
-        a plain tick, k+1 for a verify tick) must land in pages it owns
-        exclusively — map fresh pages at page boundaries, and split
-        shared pages copy-on-write (device page copy + host refcount
-        handoff) at the first divergent write. Pages mapped for window
-        positions past a verify tick's accepted point are returned to
-        the pool by the post-launch rollback in :meth:`_run_step`."""
-        for slot in range(self.num_slots):
+    def _page_needs(self, live: List[int], window: int
+                    ) -> List[Tuple[int, dict, int]]:
+        """``(slot, request, column)`` of every page the launch's
+        write window still wants: each live slot's next ``window``
+        positions (one for a plain tick, k+1 a verify tick) start at
+        ``cur_len + ahead``, the length the DEVICE holds (``ahead``
+        counts the row's launches the host has not read: 1 while a
+        one-tick launch is in flight, the only kind ever left unread,
+        and the host's ``cur_len`` then a tick old; 0 whenever a
+        speculative or fused server maps pages), and must land in
+        pages the slot owns alone: a column past its pages wants a
+        fresh one, a shared page a copy. Columns in rising order a
+        slot; at most one page a need."""
+        cap = self.model.config.cache_capacity
+        out = []
+        for slot in live:
             req = self._slots[slot]
-            if req is None or not req.get("active"):
+            pos = req["cur_len"] + req["ahead"]
+            # length bound enforced at submit; a verify window's tail
+            # past capacity clips to capacity - 1 and is never
+            # committed (mmax)
+            for j in range(pos // self._page,
+                           -(-min(pos + window, cap) // self._page)):
+                if j >= req["num_pages"] or self._alloc.refcount(
+                        int(self._pt[slot, j])) > 1:
+                    out.append((slot, req, j))
+        return out
+
+    def _page_maintenance(self, needs: List[Tuple[int, dict, int]]
+                          ) -> None:
+        """Before every decode launch: serve its ``_page_needs`` —
+        map fresh pages at page boundaries, and split shared pages
+        copy-on-write (device page copy + host refcount handoff) at
+        the first divergent write; the pool running dry preempts the
+        youngest other slot (:meth:`_alloc_or_preempt`), which only
+        happens with no launch unread (:meth:`_run_step` reads it
+        first). Pages mapped for window positions past a verify
+        tick's accepted point are returned to the pool by the commit's
+        trim."""
+        for slot, req, j in needs:
+            if self._slots[slot] is not req:
+                continue    # preempted for an earlier need
+            if j >= req["num_pages"]:
+                self._pt[slot, j] = self._alloc_or_preempt(slot)
+                req["num_pages"] = j + 1
+                self._pt_dirty = True
                 continue
-            for w in range(window):
-                pos = req["cur_len"] + w
-                if pos >= self.model.config.cache_capacity:
-                    # length bound enforced at submit; a verify
-                    # window's tail past capacity clips to
-                    # capacity - 1 and is never committed (mmax)
-                    break
-                j = pos // self._page
-                if j >= req["num_pages"]:
-                    self._pt[slot, j] = self._alloc_or_preempt(slot)
-                    req["num_pages"] = j + 1
-                    self._pt_dirty = True
-                else:
-                    pid = int(self._pt[slot, j])
-                    if self._alloc.refcount(pid) > 1:
-                        new = self._alloc_or_preempt(slot)
-                        self._cache = copy_kv_pages(
-                            self._cache, jnp.asarray([pid], jnp.int32),
-                            jnp.asarray([new], jnp.int32))
-                        self._release_page(pid)
-                        self._pt[slot, j] = new
-                        self._pt_dirty = True
-                        self._alloc.stats["cow_splits"] += 1
-                        metrics.inc("serving/cow_splits")
-                        self._emit("serving_cow_split",
-                                   request=req["id"], slot=slot,
-                                   page=j, src=pid, dst=new)
+            pid = int(self._pt[slot, j])
+            if self._alloc.refcount(pid) > 1:
+                new = self._alloc_or_preempt(slot)
+                self._cache = copy_kv_pages(
+                    self._cache, jnp.asarray([pid], jnp.int32),
+                    jnp.asarray([new], jnp.int32))
+                self._release_page(pid)
+                self._pt[slot, j] = new
+                self._pt_dirty = True
+                self._alloc.stats["cow_splits"] += 1
+                metrics.inc("serving/cow_splits")
+                self._emit("serving_cow_split",
+                           request=req["id"], slot=slot,
+                           page=j, src=pid, dst=new)
 
     def _evict(self, slot: int, reason: str) -> Completion:
         req = self._slots[slot]
@@ -1533,9 +1631,7 @@ class GenerationServer:
                 self._prefilling.remove(slot)
         self._release_adapter(slot, req)
         self._slots[slot] = None
-        self._state = self._state._replace(
-            active=self._state.active.at[slot].set(False),
-            finished=self._state.finished.at[slot].set(False))
+        self._deactivate(slot)
         self._counts["evicted"] += 1
         metrics.inc("serving/evicted")
         if reason == "preempted":
@@ -1567,6 +1663,13 @@ class GenerationServer:
             return self._preempt_impl(request_id)
 
     def _preempt_impl(self, request_id: int) -> Optional[Completion]:
+        # the partial holds the request's newest token: read what is
+        # in flight first. If that read finished the request, its
+        # completion is the answer
+        self._flush("preempt")
+        for i, comp in enumerate(self._done):
+            if comp.request_id == request_id:
+                return self._done.pop(i)
         for slot, req in enumerate(self._slots):
             if req is not None and req["id"] == request_id:
                 return self._evict(slot, "preempted")
@@ -1816,10 +1919,14 @@ class GenerationServer:
 
     def step(self) -> List[Completion]:
         """Admit what fits, advance at most one prefill chunk (paged),
-        tick every ACTIVE slot — one token plain, 1..k+1 committed
-        tokens speculative — then evict and return whatever finished
-        (deadline-expired requests included, as ``deadline_exceeded``
-        partials). While draining, admission is skipped.
+        launch a tick of every ACTIVE slot — one token plain, 1..k+1
+        committed tokens speculative — then read and commit the launch
+        BEFORE this one (this one on a speculative, fused-loop or
+        draining server: module docstring, "Deferred harvest"), evict
+        and return whatever that found finished (deadline-expired
+        requests included, as ``deadline_exceeded`` partials). A
+        completion so comes out of the ``step()`` after the one whose
+        tick ended it. While draining, admission is skipped.
 
         With ``device_loop_ticks > 1`` one call runs up to that many
         ticks in a single fused device program (:meth:`_launch`) —
@@ -1939,41 +2046,69 @@ class GenerationServer:
             if self.paged:
                 metrics.get_registry().set_gauge(
                     "serving/pages_in_use", self._alloc.pages_in_use)
-            live = [s for s, r in enumerate(self._slots)
-                    if r is not None
-                    and (not self.paged or r.get("active"))]
+            live = []
+            for slot, req in enumerate(self._slots):
+                if req is None or not req.get("active"):
+                    continue
+                if len(req["tokens"]) + req["ahead"] >= \
+                        self.gen_cfg.max_dec_len:
+                    # the tick in flight is the last its budget buys
+                    self._retire(slot, req)
+                    continue
+                live.append(slot)
             rec.live = len(live)
             if live:
                 self._sync_aid()
         return expired, live
 
-    def _count_decode_walk(self, live: List[int], window: int) -> None:
+    def _retire(self, slot: int, req: dict) -> None:
+        """Take a row off the device whose LAST tick is still unread:
+        with it the request has all the tokens ``max_dec_len`` allows,
+        whatever it holds, so no launch ticks the row again (the
+        one-tick program checks no budget; a tick past it would write
+        one position past what submit() bounded). The slot keeps the
+        request until that harvest is committed."""
+        req["active"] = False
+        self._deactivate(slot)
+        if self.paged:
+            self._pt_dirty = True   # the decode view nulls the row
+
+    def _deactivate(self, slot: int) -> None:
+        """The device's side of a slot given up: no tick advances the
+        row, and no stale ``finished`` meets its next request."""
+        self._state = self._state._replace(
+            active=self._state.active.at[slot].set(False),
+            finished=self._state.finished.at[slot].set(False))
+
+    def _count_decode_walk(self, rows: List[Tuple[int, dict]],
+                           window: int) -> None:
         """One tick of the paged decode kernel, as the host knows it
-        without a device read: the slots it walks (``live``, at their
-        lengths before the tick) of those it was launched for, and
+        without a device read: the rows it walks (``rows`` of the
+        launch, at their lengths before the tick; a void row was
+        walked like any other) of the slots it was launched for, and
         the pages (its blocks, at the cells' page size) it walks of
         the table's capacity."""
         last = self._max_pages - 1
-        metrics.inc("serving/decode_rows_live", len(live))
+        metrics.inc("serving/decode_rows_live", len(rows))
         metrics.inc("serving/decode_rows_slots", self.num_slots)
         metrics.inc("serving/decode_blocks_live", sum(
-            min((self._slots[s]["cur_len"] + window - 1) // self._page,
-                last) + 1
-            for s in live))
+            min((req["cur_len"] + window - 1) // self._page, last) + 1
+            for _, req in rows))
         metrics.inc("serving/decode_blocks_capacity",
                     self.num_slots * self._max_pages)
         # what the walked slots hold, by class: allocator pages on the
         # layers that keep whole sequences, a row of state on the
         # linear-attention layers (ring pages: _count_page_classes)
         metrics.inc("serving/pages_global_held", self._kv_layers * sum(
-            self._slots[s]["num_pages"] for s in live))
+            req["num_pages"] for _, req in rows))
         if self._state_layers:
             metrics.inc("serving/state_rows_held",
-                        self._state_layers * len(live))
+                        self._state_layers * len(rows))
         if self._ring:
-            self._count_page_classes(live, window)
+            self._count_page_classes(rows, window)
 
-    def _count_page_classes(self, live: List[int], window: int) -> None:
+    def _count_page_classes(self, rows: List[Tuple[int, dict]],
+                            window: int) -> None:
         """The same tick by page class (a model with window layers):
         pages held by the slots it walks, global layers whole
         sequences and window layers no more than their ring; the
@@ -1982,8 +2117,7 @@ class GenerationServer:
         glob = self._kv_layers
         reach = self.model.config.sliding_window_size
         held = whole = walked = reused = 0
-        for s in live:
-            req = self._slots[s]
+        for _, req in rows:
             cur, n = req["cur_len"], req["num_pages"]
             held += min(n, self._ring)
             last = min((cur + window - 1) // self._page,
@@ -2005,8 +2139,9 @@ class GenerationServer:
     #
     # One step() makes ONE device launch: a one-tick program, or with
     # device_loop_ticks > 1 a fused loop (module docstring,
-    # "Device-resident decode"); when the host flags pending
-    # scheduling work the loop runs exactly one tick, so
+    # "Device-resident decode"), and as a rule ONE read: of the launch
+    # before it, or of its own ("Deferred harvest"). When the host
+    # flags pending scheduling work the loop runs exactly one tick, so
     # drain(max_ticks) and chunked prefill keep their
     # one-unit-of-progress-per-step contracts.
 
@@ -2031,18 +2166,9 @@ class GenerationServer:
                 # tick so the writer gets its work this round-trip
                 return True
             per_tick = (self._spec_k + 1) if self.spec else 1
-            span = self._loop_ticks * per_tick
-            cap = self.model.config.cache_capacity
-            need = 0
-            for slot in live:
-                req = self._slots[slot]
-                first = req["cur_len"] // self._page
-                last = -(-min(req["cur_len"] + span, cap) // self._page)
-                for j in range(first, last):
-                    if j >= req["num_pages"] or self._alloc.refcount(
-                            int(self._pt[slot, j])) > 1:
-                        need += 1   # fresh map, or a COW split's copy
-            return need > self._alloc.free_pages
+            return len(self._page_needs(
+                live, self._loop_ticks * per_tick)) \
+                > self._alloc.free_pages
         return False
 
     def _launch(self, drafts, host_flag: bool) -> jax.Array:
@@ -2078,83 +2204,166 @@ class GenerationServer:
         harvest.copy_to_host_async()
         return harvest
 
+    def _read_now(self) -> Optional[str]:
+        """Why this server's launches are read in the step that makes
+        them, or None where the read waits for the next launch. What
+        the server observes of itself, not an option: speculation
+        drafts from the newest committed tokens; a fused loop's launch
+        stops itself on what the host would otherwise learn a launch
+        late; drain() counts ticks and ends on an empty server."""
+        if self.spec:
+            return "spec"
+        if self._loop_ticks > 1:
+            return "loop"
+        if self._draining:
+            return "drain"
+        return None
+
     def _run_step(self, rec: StepRecord) -> List[Completion]:
         """The body of :meth:`step`: schedule, draft, map pages,
-        launch, then replay what came back one tick at a time so
-        ``serving/decode_tokens``, the TTFT stamps (a fused launch's
-        are interpolated across its wall time), ``serving/tick_ms``
-        and the per-tick ``serving_spec`` events stay tick-accurate.
-        Greedy/seeded output is token-exact whatever
+        launch, THEN read and commit the launch before this one
+        (module docstring, "Deferred harvest"), or this one where
+        :meth:`_read_now` names a cause. Greedy/seeded output is
+        token-exact whatever the order of read and launch and whatever
         ``device_loop_ticks`` is (tests/test_serving.py parity
-        matrix)."""
+        matrices)."""
         ph = rec.phases
         expired, live = self._schedule(rec)
-        if not live:
-            # nothing decodable yet (empty, or every occupant is still
-            # mid-chunked-prefill) — the pump still made progress
-            with annotate("serving/step/commit", ph):
-                metrics.get_registry().set_gauge(
-                    "serving/slot_occupancy", self.occupancy)
-                self._refresh_health()
-                return expired + self._take_dead()
         T = self._loop_ticks
         k = self._spec_k if self.spec else 0
-        if self._watchdog is not None:
-            self._watchdog.arm(
-                tag=f"ticks {self._ticks + 1}..{self._ticks + T}")
-        host_flag = False
-        if T > 1:
-            with annotate("serving/step/page_maintenance", ph):
-                host_flag = self._loop_host_flag(live)
-        # flag up -> the loop exits after one tick, so drafting and
-        # page pre-mapping cover one tick's window only (the launch
-        # shape stays [slots, T, ...]: loop_ticks is static, the flag
-        # is traced, nothing recompiles)
-        eff_ticks = 1 if host_flag else T
-        if self.paged:
-            with annotate("serving/step/page_maintenance", ph):
-                # growth/COW decisions against the PRE-launch lengths,
-                # over the launch's whole write window. A slot
-                # preempted out from under the launch (pool
-                # exhaustion) goes down nulled: nothing is drafted
-                # for it and nothing of it is committed
-                self._page_maintenance(window=eff_ticks * (k + 1))
-                live = [s for s in live
-                        if (req := self._slots[s]) is not None
-                        and req.get("active")]
-                rec.live = len(live)
-        drafts = None
-        if self.spec:
-            with annotate("serving/step/draft", ph):
-                # host drafts ride down with the launch, k per tick,
-                # all proposed from the pre-launch history (tick j
-                # verifies chunk j); inactive rows are zeros the
-                # verify mask never commits
-                drafts = np.zeros((self.num_slots, T, k), np.int32)
-                for slot in live:
-                    req = self._slots[slot]
-                    drafts[slot, :eff_ticks] = np.asarray(
-                        self._draft.propose(
-                            req["prompt"] + req["tokens"],
-                            k * eff_ticks),
-                        np.int32).reshape(eff_ticks, k)
-        if self.paged:
-            with annotate("serving/step/table_sync", ph):
-                self._sync_pt()
-        with annotate("serving/step/decode_dispatch", ph):
-            harvest = self._launch(drafts, host_flag)
-        with annotate("serving/step/decode_harvest", ph):
-            # the host blocked on the launch: the step's ONE read of
-            # the device, everything the commit below needs
-            window, counts, finished, dec_count, n_ticks, exit_code = \
-                unpack_harvest(np.asarray(harvest), self.num_slots, T, k)
-            metrics.inc("serving/d2h_reads")
+        launch = None
+        if live:
+            host_flag = False
+            if T > 1:
+                with annotate("serving/step/page_maintenance", ph):
+                    host_flag = self._loop_host_flag(live)
+            # flag up -> the loop exits after one tick, so drafting
+            # and page pre-mapping cover one tick's window only (the
+            # launch shape stays [slots, T, ...]: loop_ticks is
+            # static, the flag is traced, nothing recompiles)
+            eff_ticks = 1 if host_flag else T
+            if self.paged:
+                live = self._map_pages(rec, live, eff_ticks * (k + 1))
+        if live:
+            drafts = None
+            if self.spec:
+                with annotate("serving/step/draft", ph):
+                    # host drafts ride down with the launch, k per
+                    # tick, all proposed from the pre-launch history
+                    # (tick j verifies chunk j); inactive rows are
+                    # zeros the verify mask never commits
+                    drafts = np.zeros((self.num_slots, T, k), np.int32)
+                    for slot in live:
+                        req = self._slots[slot]
+                        drafts[slot, :eff_ticks] = np.asarray(
+                            self._draft.propose(
+                                req["prompt"] + req["tokens"],
+                                k * eff_ticks),
+                            np.int32).reshape(eff_ticks, k)
+            if self.paged:
+                with annotate("serving/step/table_sync", ph):
+                    self._sync_pt()
+            with annotate("serving/step/decode_dispatch", ph):
+                launch = _Launch(self._launch(drafts, host_flag),
+                                 [(s, self._slots[s]) for s in live])
+                for _, req in launch.rows:
+                    req["ahead"] += 1
+        if self._inflight is not None:
+            # the launch above is queued behind it on the device
+            self._read_launch(self._inflight, rec,
+                              None if launch else "idle")
+        if launch is not None:
+            why = self._read_now()
+            if why is None:
+                self._inflight = launch
+            else:
+                self._read_launch(launch, rec, why)
         with annotate("serving/step/commit", ph):
+            metrics.get_registry().set_gauge(
+                "serving/slot_occupancy", self.occupancy)
+            self._refresh_health()
+            return expired + self._take_done()
+
+    def _map_pages(self, rec: StepRecord, live: List[int],
+                   window: int) -> List[int]:
+        """Page growth and copy-on-write for the launch's write
+        window, decided against the lengths the device holds
+        (:meth:`_page_needs`); returns the slots still to launch. A
+        slot preempted out from under the launch (pool exhaustion)
+        goes down nulled: nothing is drafted for it and nothing of it
+        is committed. The pool is only ever robbed with no launch
+        unread: where the needs outrun the free pages the launch in
+        flight is read first, which frees what it finished and leaves
+        every victim's newest token committed."""
+        ph = rec.phases
+
+        def still_live(slots):
+            return [s for s in slots
+                    if (req := self._slots[s]) is not None
+                    and req.get("active")]
+        with annotate("serving/step/page_maintenance", ph):
+            needs = self._page_needs(live, window)
+            read_first = self._inflight is not None and \
+                len(needs) > self._alloc.free_pages
+            if not read_first:
+                self._page_maintenance(needs)
+                live = still_live(live)
+        if read_first:
+            self._read_launch(self._inflight, rec, "preempt")
+            with annotate("serving/step/page_maintenance", ph):
+                live = still_live(live)
+                self._page_maintenance(self._page_needs(live, window))
+                live = still_live(live)
+        rec.live = len(live)
+        return live
+
+    def _read_launch(self, launch: _Launch, rec: StepRecord,
+                     why: Optional[str]) -> None:
+        """Read ``launch``'s harvest, the ONE read of the device a
+        launch costs, and commit it: replay what came back one tick at
+        a time so ``serving/decode_tokens``, the TTFT stamps (a fused
+        launch's are interpolated across its wall time),
+        ``serving/tick_ms`` and the per-tick ``serving_spec`` events
+        stay tick-accurate, then evict what finished; its completions
+        join ``_done``. ``why`` names the cause of a read that did not
+        wait for the next launch (None: it did).
+
+        A row is committed to the request it was launched for. By the
+        time of the read its slot may hold no one, or someone else:
+        the commit of the launch before found the request finished,
+        its deadline passed, a client cancelled it. Such a row is
+        VOID: nothing of it is committed. What it wrote is one column
+        at its request's last length, in a page that was the slot's
+        alone when it was launched (:meth:`_page_needs`) and that the
+        eviction has since released, in the slot's ring, or in its
+        state row; whoever holds any of them next got it through a
+        LATER launch (a prefill chunk, a page copy, a scatter, a
+        tick), which the device runs after this one, and reads no
+        position it has not itself written since."""
+        ph = rec.phases
+        if launch is self._inflight:
+            self._inflight = None
+        T = self._loop_ticks
+        k = self._spec_k if self.spec else 0
+        with annotate("serving/step/decode_harvest", ph):
+            if self._watchdog is not None:
+                self._watchdog.arm(
+                    tag=f"ticks {self._ticks + 1}..{self._ticks + T}")
+            window, counts, finished, dec_count, n_ticks, exit_code = \
+                unpack_harvest(np.asarray(launch.harvest),
+                               self.num_slots, T, k)
+            metrics.inc("serving/d2h_reads")
             if self._watchdog is not None:
                 self._watchdog.disarm()
+        with annotate("serving/step/commit", ph):
+            if why is None:
+                metrics.inc("serving/harvest_deferred")
+            else:
+                metrics.inc("serving/harvest_flushed/" + why)
             self._ticks += n_ticks
             self._roundtrips += 1
-            rec.ticks = n_ticks
+            rec.ticks += n_ticks
+            rec.live = len(launch.rows)
             metrics.inc("serving/device_ticks", n_ticks)
             if exit_code != LOOP_EXIT_NONE:
                 metrics.inc(
@@ -2164,20 +2373,26 @@ class GenerationServer:
                     if exit_code == LOOP_EXIT_BUDGET
                     else ("serving/loop_exit/drain" if self._draining
                           else "serving/loop_exit/admission"))
-            reg = metrics.get_registry()
+            valid = [(slot, req) for slot, req in launch.rows
+                     if self._slots[slot] is req]
+            if len(valid) < len(launch.rows):
+                metrics.inc("serving/harvest_rows_void",
+                            len(launch.rows) - len(valid))
             # a launch is one opaque device program; the stamps of a
             # fused one's earlier ticks interpolate its wall time so
-            # TTFT/TPOT stay comparable across device_loop_ticks
+            # TTFT/TPOT stay comparable across device_loop_ticks. A
+            # token's stamp is when the host received it: one step
+            # after its tick where the read is deferred, for every
+            # token alike
             now = time.time()
             per_tick_s = rec.tick_seconds() / n_ticks
             committed = 0
             for j in range(n_ticks):
                 t_j = now - (n_ticks - 1 - j) * per_tick_s
                 if self.paged:
-                    self._count_decode_walk(live, k + 1)
+                    self._count_decode_walk(launch.rows, k + 1)
                 tick_committed = 0
-                for slot in live:
-                    req = self._slots[slot]
+                for slot, req in valid:
                     m = int(counts[slot, j])
                     req["tokens"].extend(
                         int(t) for t in window[slot, j, :m])
@@ -2195,9 +2410,9 @@ class GenerationServer:
                 committed += tick_committed
                 self._decode_tokens += tick_committed
                 if self.spec:
-                    drafted = k * len(live)
+                    drafted = k * len(valid)
                     # each slot's t0 is sampled, not drafted
-                    accepted = tick_committed - len(live)
+                    accepted = tick_committed - len(valid)
                     self._spec_drafted += drafted
                     self._spec_accepted += accepted
                     metrics.inc("serving/spec_drafted", drafted)
@@ -2205,30 +2420,44 @@ class GenerationServer:
                     self._emit("serving_spec", drafted=drafted,
                                accepted=accepted,
                                committed=tick_committed)
-            rec.tokens = committed
+            rec.tokens += committed
             metrics.inc("serving/decode_tokens", committed)
             if self.spec:
-                reg.set_gauge(
+                metrics.get_registry().set_gauge(
                     "serving/spec_accept_rate",
                     self._spec_accepted / max(self._spec_drafted, 1))
-            done: List[Completion] = []
-            for slot in live:
+            for slot, req in valid:
+                req["ahead"] -= 1
                 if self.paged:
-                    # pages wholly past the committed point go
+                    # pages wholly past what the device holds go
                     # straight back to the pool: the pre-mapped tail
                     # of an early exit, and spec's rejected KV (the
                     # partial page's stale columns sit past cur_len
-                    # and are overwritten before any masked read)
-                    req = self._slots[slot]
-                    self._trim_pages(
-                        slot, req, -(-req["cur_len"] // self._page))
+                    # and are overwritten before any masked read).
+                    # The page a launch still unread writes into
+                    # stays: at a page boundary it holds nothing
+                    # committed yet
+                    self._trim_pages(slot, req, -(
+                        -(req["cur_len"] + req["ahead"]) // self._page))
                 if finished[slot]:
-                    done.append(self._evict(slot, "eos"))
+                    self._done.append(self._evict(slot, "eos"))
                 elif dec_count[slot] >= self.gen_cfg.max_dec_len:
-                    done.append(self._evict(slot, "length"))
-            reg.set_gauge("serving/slot_occupancy", self.occupancy)
-            self._refresh_health()
-            return expired + self._take_dead() + done
+                    self._done.append(self._evict(slot, "length"))
+
+    def _flush(self, why: str) -> None:
+        """Read and commit the launch in flight NOW, outside
+        :meth:`step`, as a short step of its own (a root, an account):
+        what must see every request's newest token calls this first —
+        :meth:`drain`, :meth:`preempt`, :meth:`close`. What finished
+        comes out of the next ``step()`` (or of the caller, which
+        knows where to look). Rare; the common path never flushes."""
+        if self._inflight is None:
+            return
+        rec = StepRecord()
+        mark = _cpu_mark()
+        with annotate("serving/step", rec.phases):
+            self._read_launch(self._inflight, rec, why)
+        self._account_step(rec, mark)
 
     def drain(self, max_ticks: Optional[int] = None
               ) -> List[Completion]:
@@ -2253,6 +2482,10 @@ class GenerationServer:
             self._refresh_health()
             self._emit("serving_drain_start", signum=None,
                        pending=self.pending, occupancy=self.occupancy)
+        # no committed token is lost and max_ticks counts ticks: what
+        # is in flight is read before anything is handed back, and
+        # every step below reads its own launch (_read_now)
+        self._flush("drain")
         out: List[Completion] = self._flush_queue()
         ticks = 0
         while not self._closed and self.occupancy and \
@@ -2265,7 +2498,7 @@ class GenerationServer:
         # a pool-exhaustion preempt during the tick loop requeues to
         # the (no longer admitting) queue — hand those back too
         out.extend(self._flush_queue())
-        out.extend(self._take_dead())
+        out.extend(self._take_done())
         self._refresh_health()
         self._emit("serving_drain_end", completions=len(out),
                    ticks=ticks)
@@ -2296,6 +2529,8 @@ class GenerationServer:
         server closed — a racing step() from another thread returns
         [] instead of touching torn-down state. Idempotent."""
         with self._surface_lock:
+            if not self._closed:
+                self._flush("close")   # leave nothing queued unread
             self._closed = True
         if self._watchdog is not None:
             self._watchdog.stop()
@@ -2327,6 +2562,9 @@ class GenerationServer:
                 break
             for c in self.step():
                 done[c.request_id] = c
+        with self._surface_lock:
+            # a launch made before the last EOS was read: all void
+            self._flush("idle")
         return [done[i] for i in ids]
 
     def summary(self) -> dict:

@@ -287,7 +287,10 @@ def test_bench_serving_runs_offline(monkeypatch, capsys):
     assert t4["metric"] == \
         "gpt345m_serving_decode_tokens_per_sec_per_chip_loop_t4"
     assert t4["loop_ticks"] == 4 and t4["value"] > 0
-    assert t4["decode_ticks"] == rec["decode_ticks"]
+    # (the T=1 server reads a launch after the next one, so an EOS it
+    # had not seen yet may cost it a tick whose rows are all void)
+    assert 0 <= rec["decode_ticks"] - t4["decode_ticks"] <= \
+        rec["requests"]
     assert t4["host_roundtrips"] < rec["host_roundtrips"]
     assert t4["tick_p99_ms"] > 0
     assert t4["host_roundtrip_p99_ms"] >= t4["host_roundtrip_p50_ms"]

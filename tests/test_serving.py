@@ -1050,8 +1050,8 @@ def test_deadline_exceeded_mid_decode_returns_partial(model_and_params):
     srv = GenerationServer(model, params, _greedy_cfg(),
                            num_slots=1, request_ttl_s=3600.0)
     a = srv.submit(PROMPTS[0])
-    srv.step()
-    srv.step()
+    for _ in range(3):      # the first launches, the next two commit
+        srv.step()
     (slot,) = [i for i, r in enumerate(srv._slots) if r is not None]
     srv._slots[slot]["deadline"] = 1.0          # long expired
     (c,) = srv.step()
@@ -1059,6 +1059,10 @@ def test_deadline_exceeded_mid_decode_returns_partial(model_and_params):
     assert c.finish_reason == "deadline_exceeded"
     assert len(c.tokens) == 2                   # partial kept
     assert srv.occupancy == 0                   # slot freed
+    # the tick that was in flight for it was read in the same step,
+    # with no one to launch for, and committed nothing: a void row
+    assert not srv.work_pending() and srv._inflight is None
+    assert srv.summary()["decode_ticks"] == 3
 
 
 def test_queue_depth_shedding(model_and_params):
@@ -1482,7 +1486,11 @@ def test_device_loop_parity_unpaged(model_and_params, loop_ticks,
     assert out == ref
     assert summ["decode_tokens"] == ref_summ["decode_tokens"]
     assert summ["host_roundtrips"] < ref_summ["host_roundtrips"]
-    assert summ["device_ticks"] == ref_summ["device_ticks"]
+    # the T = 1 server reads a launch after the next: a slot is freed,
+    # and taken again, a step later, and an EOS it had not seen yet
+    # may cost it a tick whose rows are all void
+    assert 0 <= ref_summ["device_ticks"] - summ["device_ticks"] <= \
+        len(out)
 
 
 @pytest.mark.parametrize("loop_ticks", [4, 16])
@@ -2359,7 +2367,10 @@ def test_one_step_body_launches_the_modes_own_program(
     launch — ``decode_step`` / ``verify_step`` at T = 1 and never a
     loop, ``decode_loop`` / ``verify_loop`` at T = 4 and never a
     one-tick program — and leaves the same phases in the same order in
-    both modes."""
+    every mode. The plain T = 1 server reads a launch after the next,
+    so its first launching step commits no tick and its last
+    committing step launches none: there the launches are held to the
+    ticks over the run."""
     import paddlefleetx_tpu.core.serving as serving_mod
     calls = dict.fromkeys(
         ("decode_step", "verify_step", "decode_loop", "verify_loop"), 0)
@@ -2388,10 +2399,14 @@ def test_one_step_body_launches_the_modes_own_program(
             launched = {n: calls[n] - before[n] for n in calls
                         if calls[n] != before[n]}
             rec = srv.last_step
-            assert launched == ({mine: 1} if rec.ticks else {})
-            if rec.ticks and not rec.chunks:
+            if spec or loop_ticks > 1:
+                assert launched == ({mine: 1} if rec.ticks else {})
+            else:
+                assert launched in ({mine: 1}, {})
+            if rec.ticks and launched and not rec.chunks:
                 orders.add(tuple(rec.phases_ms()))
         assert srv.summary()["decode_ticks"] >= 4
+        assert srv.summary()["host_roundtrips"] == calls[mine]
     finally:
         srv.close()
     assert orders == {tuple(p for p in _DECODING_PHASES
@@ -2494,8 +2509,9 @@ def _steady_server(paged512_model_and_params, max_dec=40):
 def test_a_decoding_step_reads_the_device_once(paged512_model_and_params):
     """``serving/d2h_reads`` beside ``serving/device_ticks``: a paged
     server in steady decode at T = 1 pulls ONE array to the host a
-    step (the harvest), and one more on the step that ends a prompt's
-    last chunk (``prefill_harvest``'s logits row)."""
+    step (the harvest of the launch before its own), and one more on
+    the step that ends a prompt's last chunk (``prefill_harvest``'s
+    logits row)."""
     metrics.set_enabled(True)
     reg = metrics.get_registry()
     reg.reset()
@@ -2510,9 +2526,10 @@ def test_a_decoding_step_reads_the_device_once(paged512_model_and_params):
                 srv.last_step.chunks)
     try:
         srv.submit([5, 9, 2])
-        # its only chunk is its last: the logits row, then the tick
-        assert step() == (2, 1, 1)
-        assert [step() for _ in range(20)] == [(1, 1, 0)] * 20
+        # its only chunk is its last: the logits row, then the first
+        # launch, which the NEXT step reads
+        assert step() == (1, 0, 1)
+        assert [step() for _ in range(21)] == [(1, 1, 0)] * 21
         assert reg.counter("serving/d2h_reads") - 1 == \
             reg.counter("serving/device_ticks") == 21
         # a second prompt, two chunks long: the first chunk's step
@@ -2531,7 +2548,8 @@ def test_step_record_has_no_state_fetch_phase(paged512_model_and_params):
     """The reads of ``finished`` and ``dec_count`` came home with the
     tokens: no ``serving/step/state_fetch`` in a decoding step's
     record, and ``tick_seconds()`` is still the dispatch plus the
-    harvest."""
+    harvest (of the launch before; the last committing step has no
+    launch of its own)."""
     srv = _steady_server(paged512_model_and_params, max_dec=6)
     try:
         srv.submit([5, 9, 2])
@@ -2545,7 +2563,7 @@ def test_step_record_has_no_state_fetch_phase(paged512_model_and_params):
             assert "serving/step/state_fetch" not in rec.phases
             assert "state_fetch" not in rec.phases_ms()
             assert rec.tick_seconds() == pytest.approx(
-                rec.phases["serving/step/decode_dispatch"]
+                rec.phases.get("serving/step/decode_dispatch", 0.0)
                 + rec.phases["serving/step/decode_harvest"])
             assert 0.0 < rec.tick_seconds() <= rec.seconds
         assert seen == 6
@@ -2964,3 +2982,396 @@ def test_every_step_puts_its_account_after_its_root(
         # two slots and nothing decodes: the third prompt stays queued
         assert chunks == 4 and records[-1].queued == 1
         assert not any(r.ticks or r.tokens for r in records)
+
+
+# -- the deferred harvest -------------------------------------------------
+#
+# A plain T = 1 server launches tick n+1 before it reads tick n
+# (core/serving.py, "Deferred harvest"). The reference below is the
+# SAME server held to the synchronous order through the one thing that
+# decides it, ``_read_now``: one step body, two orders, equal tokens.
+
+def _synchronous(monkeypatch):
+    """Every server built from here on reads each launch in the step
+    that made it (the order before the harvest was deferred)."""
+    monkeypatch.setattr(GenerationServer, "_read_now",
+                        lambda self: "test")
+
+
+def _small_family(name):
+    """``(model, params, server kwargs, vocab)`` of a tiny member of
+    each family the server holds a cache for: contiguous rows, the
+    page pool, rings of window pages beside it (SmallThinker), rows of
+    recurrent state beside it (Solar-Open2)."""
+    if name in ("gpt", "gpt-paged"):
+        cfg = dataclasses.replace(CFG, max_position_embeddings=512) \
+            if name == "gpt-paged" else CFG
+        model = GPTForPretraining(cfg)
+        kw = dict(page_size=128, prefill_chunk_pages=1, pool_pages=9) \
+            if name == "gpt-paged" else {}
+    elif name == "smallthinker":
+        from paddlefleetx_tpu.models.smallthinker import (
+            SmallThinkerConfig, SmallThinkerForCausalLM)
+        model = SmallThinkerForCausalLM(SmallThinkerConfig(
+            vocab_size=96, hidden_size=32, num_hidden_layers=4,
+            num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+            moe_ffn_hidden_size=16, moe_num_primary_experts=4,
+            moe_num_active_primary_experts=2, sliding_window_size=160,
+            max_position_embeddings=1024, initializer_range=0.2))
+        kw = dict(page_size=128, prefill_chunk_pages=1, pool_pages=12)
+    else:
+        from paddlefleetx_tpu.models.solar_open2 import (
+            SolarOpen2Config, SolarOpen2ForCausalLM)
+        model = SolarOpen2ForCausalLM(SolarOpen2Config(
+            vocab_size=96, hidden_size=32, num_hidden_layers=4,
+            num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+            linear_num_heads=8, linear_head_dim=128, n_routed_experts=4,
+            num_experts_per_tok=2, moe_intermediate_size=16,
+            max_position_embeddings=1024, initializer_range=0.2))
+        kw = dict(page_size=128, prefill_chunk_pages=1, pool_pages=12)
+    params = model.init({"params": jax.random.key(0)},
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    return model, params, kw
+
+
+@pytest.fixture(scope="module", params=["gpt", "gpt-paged",
+                                        "smallthinker", "solar-open2"])
+def family(request):
+    return (request.param,) + _small_family(request.param)
+
+
+def _staggered(srv, prompts, after=()):
+    """Two prompts at once, the rest one a step from the third step
+    on (slots at ragged depths, each freed slot taken at once);
+    ``after(srv)`` after every step. Tokens by submission order."""
+    done, ids = {}, [srv.submit(p) for p in prompts[:2]]
+    rest = list(prompts[2:])
+    steps = 0
+    while srv.work_pending() or rest:
+        if rest and steps >= 2:
+            ids.append(srv.submit(rest.pop(0)))
+        for c in srv.step():
+            done[c.request_id] = c
+        steps += 1
+        for check in after:
+            check(srv)
+        assert steps < 2000
+    assert srv._inflight is None
+    return [done[i] for i in ids]
+
+
+def _unread_write_is_mapped(srv):
+    """Hazard 2: the page the launch in flight writes into is still
+    the slot's, whatever the commit trimmed."""
+    srv.check_alloc()
+    if not srv.paged:
+        return
+    for slot, req in enumerate(srv._slots):
+        if req is not None and req.get("active") and req["ahead"]:
+            j = req["cur_len"] // srv._page
+            assert j < req["num_pages"], (req["cur_len"], j)
+            assert srv._pt[slot, j] != 0
+
+
+@pytest.mark.parametrize("strategy", ["greedy", "sampling"])
+def test_deferred_order_commits_the_synchronous_servers_tokens(
+        family, strategy, monkeypatch):
+    """The staggered-admission matrix, each family of cache: a server
+    that reads a launch after the next one completes every request
+    with the tokens, and for the reason, of the same server held to
+    the synchronous order. EOS is a token the run really emits, so
+    some requests end on a read the next launch did not wait for."""
+    name, model, params, kw = family
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(1, 90, n).tolist()
+               for n in (5, 2, 130, 3, 1, 9)]
+    if name == "gpt":
+        prompts[2] = prompts[2][:30]
+
+    def run(eos):
+        if strategy == "greedy":
+            gen_cfg = dataclasses.replace(_greedy_cfg(12),
+                                          eos_token_id=eos,
+                                          pad_token_id=eos)
+        else:
+            gen_cfg = GenerationConfig(
+                max_dec_len=12, decode_strategy="sampling", top_k=8,
+                top_p=0.9, temperature=0.9, eos_token_id=eos,
+                pad_token_id=eos)
+        srv = GenerationServer(model, params, gen_cfg, num_slots=2,
+                               rng=jax.random.key(7), **kw)
+        try:
+            return _staggered(srv, prompts, (_unread_write_is_mapped,)), \
+                srv.summary()
+        finally:
+            srv.close()
+    # an EOS that cuts some answers short: the fourth token of the
+    # first answer of a run that has none
+    plain, _ = run(95)
+    eos = plain[0].tokens[3]
+    deferred, summ = run(eos)
+    _synchronous(monkeypatch)
+    want, want_summ = run(eos)
+    assert [(c.tokens, c.finish_reason) for c in deferred] == \
+        [(c.tokens, c.finish_reason) for c in want]
+    assert {c.finish_reason for c in want} == {"eos", "length"}
+    assert summ["decode_tokens"] == want_summ["decode_tokens"]
+    assert all(c.ttft_ms is not None and c.ttft_ms > 0 for c in deferred)
+
+
+@pytest.mark.parametrize("case", ["length_on_a_boundary",
+                                  "eos_on_a_boundary",
+                                  "slot_taken_at_once"])
+def test_a_page_boundary_and_a_slot_taken_at_once(
+        paged512_model_and_params, monkeypatch, case):
+    """Hazards 1-3 of the deferred read, ``check_alloc()`` after every
+    step. A request whose tokens fill a page to its last column: the
+    commit that brings ``cur_len`` to the boundary must not trim the
+    page the launch in flight is writing into (its first column), or
+    the remapped page lacks that token's K/V. One that ends exactly
+    there on EOS: its void row wrote into a page the eviction has
+    released. And a pool so small that the next request is admitted
+    into the freed slot AND pages in the very next step, under the
+    void row's write."""
+    model, params = paged512_model_and_params
+    if case == "slot_taken_at_once":
+        # 381 + 3 = 384: three pages full, and 4 pages in the pool
+        lens, slots, pool = (381, 3, 382, 2), 1, 5
+    else:
+        lens, slots, pool = (125, 250, 3), 2, 9
+
+    def run(prompts, eos):
+        gen_cfg = dataclasses.replace(
+            _greedy_cfg(10), eos_token_id=eos, pad_token_id=eos)
+        srv = GenerationServer(model, params, gen_cfg, num_slots=slots,
+                               page_size=128, prefill_chunk_pages=1,
+                               pool_pages=pool)
+        try:
+            out = _staggered(srv, prompts, (_unread_write_is_mapped,))
+            assert srv._alloc.pages_in_use == 0
+            return out
+        finally:
+            srv.close()
+    # prompts whose first answer's third token is new in it: as EOS
+    # it ends that request with its last page full to the last column
+    for seed in range(40):
+        rng = np.random.default_rng(seed)
+        prompts = [rng.integers(1, 90, n).tolist() for n in lens]
+        plain = run(prompts, 95)
+        first = plain[0].tokens
+        if first[2] not in first[:2]:
+            break
+    assert all(len(c.tokens) == 10 for c in plain)
+    eos = 95 if case == "length_on_a_boundary" else first[2]
+    got = run(prompts, eos)
+    _synchronous(monkeypatch)
+    want = run(prompts, eos)
+    assert [(c.tokens, c.finish_reason) for c in got] == \
+        [(c.tokens, c.finish_reason) for c in want]
+    if case != "length_on_a_boundary":
+        assert got[0].finish_reason == "eos" and len(got[0].tokens) == 3
+        assert (lens[0] + 3) % 128 == 0
+
+
+def _launches_made(srv):
+    """Decode launches a T = 1 server has made: read, or in flight."""
+    return srv._roundtrips + (srv._inflight is not None)
+
+
+@pytest.mark.parametrize("how", ["drain_now", "drain_two_ticks",
+                                 "preempt", "close"])
+def test_a_forced_read_loses_no_token_and_commits_none_twice(
+        paged512_model_and_params, how):
+    """drain(), preempt() and close() with a launch in flight read it
+    first: the partial holds one token for every launch made, each
+    once, it is a prefix of the uninterrupted answer, and a resumed
+    request ends on that answer."""
+    metrics.set_enabled(True)
+    reg = metrics.get_registry()
+    reg.reset()
+    model, params = paged512_model_and_params
+    gen_cfg = dataclasses.replace(_greedy_cfg(16), min_dec_len=16)
+    prompts = [[5, 9, 2, 7], [11, 3, 8]]
+
+    def server():
+        return GenerationServer(model, params, gen_cfg, num_slots=2,
+                                page_size=128, prefill_chunk_pages=1)
+    ref = server()
+    want = [c.tokens for c in ref.run(prompts)]
+    ref.close()
+    srv = server()
+    try:
+        ids = [srv.submit(p) for p in prompts]
+        for _ in range(6):
+            srv.step()
+        assert srv._inflight is not None and srv.work_pending()
+        made = _launches_made(srv)
+        held = [len(r["tokens"]) for r in srv._slots]
+        assert held[0] == held[1] + 1 == made - 1   # one launch unread
+        if how == "preempt":
+            part = srv.preempt(ids[0])
+            assert reg.counter("serving/harvest_flushed/preempt") == 1
+            assert part.finish_reason == "preempted"
+            assert part.tokens == want[0][:made]
+            # the other request's row of the same read was committed
+            assert len(srv._slots[1]["tokens"]) == made - 1
+            done = {}
+            while srv.work_pending():
+                for c in srv.step():
+                    done[c.request_id] = c
+            assert done[ids[1]].tokens == want[1]
+        elif how == "close":
+            srv.close()
+            assert reg.counter("serving/harvest_flushed/close") == 1
+            assert srv._inflight is None
+            assert [len(r["tokens"]) for r in srv._slots] == \
+                [made, made - 1]
+        else:
+            ticks = 0 if how == "drain_now" else 2
+            parts = {c.request_id: c
+                     for c in srv.drain(max_ticks=ticks)}
+            assert reg.counter("serving/harvest_flushed/drain") == \
+                1 + ticks
+            assert srv._inflight is None and not srv.occupancy
+            for i, rid in enumerate(ids):
+                assert parts[rid].finish_reason == "preempted"
+                assert parts[rid].tokens == \
+                    want[i][:made + ticks - i]
+            srv.close()
+            again = server()
+            new = [again.submit(prompts[i],
+                                resume_tokens=parts[rid].tokens)
+                   for i, rid in enumerate(ids)]
+            done = {}
+            while again.work_pending():
+                for c in again.step():
+                    done[c.request_id] = c
+            assert [done[n].tokens for n in new] == want
+            again.close()
+    finally:
+        srv.close()
+        metrics.set_enabled(False)
+        reg.reset()
+
+
+def test_pool_exhaustion_reads_the_launch_in_flight_before_it_preempts(
+        paged512_model_and_params):
+    """Where the launch's page needs outrun the free pages the launch
+    in flight is read first (``harvest_flushed/preempt``), so the
+    victim goes back to the queue with its newest token and the
+    answers are the roomy pool's."""
+    metrics.set_enabled(True)
+    reg = metrics.get_registry()
+    reg.reset()
+    model, params = paged512_model_and_params
+    gen_cfg = dataclasses.replace(_greedy_cfg(40), min_dec_len=40)
+    rng = np.random.default_rng(2)
+    prompts = [rng.integers(1, 90, n).tolist() for n in (250, 240)]
+
+    def run(pool):
+        srv = GenerationServer(model, params, gen_cfg, num_slots=2,
+                               page_size=128, prefill_chunk_pages=1,
+                               pool_pages=pool, prefix_sharing=False)
+        try:
+            out = _staggered(srv, prompts, (_unread_write_is_mapped,))
+            return [c.tokens for c in out], srv.summary()
+        finally:
+            srv.close()
+    want, _ = run(9)
+    assert reg.counter("serving/harvest_flushed/preempt") == 0
+    got, summ = run(5)              # 4 pages, and each wants a third
+    assert summ["preempted"] >= 1
+    assert reg.counter("serving/harvest_flushed/preempt") >= 1
+    assert got == want
+    metrics.set_enabled(False)
+    reg.reset()
+
+
+def test_the_deferred_harvests_counters(paged512_model_and_params):
+    """What the mechanism counts: nearly every decoding step's read
+    came after the next launch; the device is still read once a
+    launch and once a prompt; a void row at most once a request that
+    ended, and never for one that ended on its budget (its row is not
+    launched past it: the device's ``dec_count`` never passes
+    ``max_dec_len``)."""
+    metrics.set_enabled(True)
+    reg = metrics.get_registry()
+    reg.reset()
+    model, params = paged512_model_and_params
+    rng = np.random.default_rng(9)
+    prompts = [rng.integers(1, 90, n).tolist() for n in (4, 140, 7, 2)]
+
+    def budget_kept(srv):
+        assert int(np.asarray(srv._state.dec_count).max()) <= 10
+    try:
+        gen_cfg = dataclasses.replace(_greedy_cfg(10), min_dec_len=10)
+        srv = GenerationServer(model, params, gen_cfg, num_slots=2,
+                               page_size=128, prefill_chunk_pages=1)
+        out = _staggered(srv, prompts, (budget_kept,))
+        srv.close()
+        assert all(c.finish_reason == "length" for c in out)
+        c = reg.counter
+        steps = c("serving/device_ticks")      # T = 1: a tick a read
+        flushed = c("serving/harvest_flushed/idle")
+        assert steps == srv.summary()["host_roundtrips"] > 20
+        assert c("serving/harvest_deferred") == steps - flushed
+        assert c("serving/harvest_deferred") >= \
+            steps - (len(prompts) + flushed)
+        assert 1 <= flushed <= len(prompts)
+        assert c("serving/d2h_reads") == steps + len(prompts)
+        assert c("serving/harvest_rows_void") == 0
+        assert c("serving/decode_tokens") == 10 * len(prompts)
+        # answers that end on EOS: a void row for some, one at most
+        reg.reset()
+        eos = out[0].tokens[4]
+        srv = GenerationServer(
+            model, params, dataclasses.replace(
+                _greedy_cfg(10), eos_token_id=eos, pad_token_id=eos),
+            num_slots=2, page_size=128, prefill_chunk_pages=1)
+        out = _staggered(srv, prompts)
+        srv.close()
+        ended = sum(x.finish_reason == "eos" for x in out)
+        assert ended >= 1
+        assert 1 <= c("serving/harvest_rows_void") <= ended
+        assert c("serving/d2h_reads") == \
+            c("serving/device_ticks") + len(prompts)
+    finally:
+        metrics.set_enabled(False)
+        reg.reset()
+
+
+@pytest.mark.parametrize("workers", ["lockstep", "async"])
+def test_a_fleet_of_deferred_servers_completes_every_request(
+        model_and_params, workers):
+    """Replicas behind a ``FleetRouter`` hand their completions out a
+    ``step()`` late and park on ``work_pending()``, which a launch not
+    read yet holds true: every request still completes, with a single
+    server's tokens, and the replicas' reads really were deferred."""
+    from paddlefleetx_tpu.core.fleet import FleetRouter
+    metrics.set_enabled(True)
+    reg = metrics.get_registry()
+    reg.reset()
+    model, params = model_and_params
+    gen_cfg = _greedy_cfg(8)
+
+    def factory(name):
+        return GenerationServer(model, params, gen_cfg, num_slots=2,
+                                rng=jax.random.PRNGKey(7))
+    try:
+        single = factory("single")
+        want = [c.tokens for c in single.run(PROMPTS)]
+        single.close()
+        reg.reset()
+        fleet = FleetRouter(factory, 2, async_workers=workers == "async")
+        got = fleet.run(PROMPTS)
+        fleet.close()
+        assert [c.tokens for c in got] == want
+        assert all(c.finish_reason in ("eos", "length") for c in got)
+        assert reg.counter("serving/harvest_deferred") > 0
+        assert reg.counter("serving/harvest_deferred") + sum(
+            reg.counter("serving/harvest_flushed/" + why)
+            for why in ("idle", "close")) == \
+            reg.counter("serving/device_ticks")
+    finally:
+        metrics.set_enabled(False)
+        reg.reset()

@@ -22,6 +22,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
+from _served_rows import ServedRows  # noqa: E402
 from paddlefleetx_tpu.core.paging import (  # noqa: E402
     NULL_PAGE, kv_page_bytes, pool_bytes, pool_pages_for_bytes,
 )
@@ -339,20 +340,18 @@ def served(params, ref_forward):
     prompts = [rng.integers(0, 500, n).tolist() for n in LENGTHS]
     ids = [srv.submit(p) for p in prompts]
     steps, done, most_global = [], {}, 0
+    rows = ServedRows(srv)
     while srv.work_pending():
         for c in srv.step():
             done[c.request_id] = c
         srv.check_alloc()
-        logits = np.asarray(srv._state.last_logits)
-        for slot, req in enumerate(srv._slots):
-            if req is None or not req.get("active"):
-                continue
-            seq = req["prompt"] + req["tokens"]
+        for req, seq, got in rows.after_step():
             pad = -len(seq) % 512            # a few compiled lengths
             want = np.asarray(ref_forward(
                 params, jnp.asarray([seq + [0] * pad])))[0, len(seq) - 1]
-            steps.append((len(seq), logits[slot], want))
-            most_global = max(most_global, req["num_pages"])
+            steps.append((len(seq), got, want))
+        most_global = max([most_global] + [
+            req["num_pages"] for req in srv._slots if req is not None])
     out = dict(srv=srv, ids=ids, prompts=prompts, steps=steps, done=done,
                most_global=most_global, summary=srv.summary(),
                counters=dict(metrics.get_registry().snapshot()["counters"]))

@@ -28,6 +28,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
+from _served_rows import ServedRows  # noqa: E402
 from paddlefleetx_tpu.core.paging import NULL_PAGE, pool_bytes  # noqa: E402
 from paddlefleetx_tpu.core.serving import GenerationServer  # noqa: E402
 from paddlefleetx_tpu.models.gpt.generation import (  # noqa: E402
@@ -326,19 +327,16 @@ DEC = 6
 def _drive(srv, prompts, params, ref_forward, steps):
     ids = [srv.submit(p) for p in prompts]
     done = {}
+    rows = ServedRows(srv)
     while srv.work_pending():
         for c in srv.step():
             done[c.request_id] = c
         srv.check_alloc()
-        logits = np.asarray(srv._state.last_logits)
-        for slot, req in enumerate(srv._slots):
-            if req is None or not req.get("active"):
-                continue
-            seq = req["prompt"] + req["tokens"]
+        for req, seq, got in rows.after_step():
             pad = -len(seq) % 256            # a few compiled lengths
             want = np.asarray(ref_forward(
                 params, jnp.asarray([seq + [0] * pad])))[0, len(seq) - 1]
-            steps.append((req["id"], len(seq), logits[slot], want))
+            steps.append((req["id"], len(seq), got, want))
     return ids, done
 
 
@@ -501,15 +499,14 @@ def test_a_decode_tick_leaves_free_and_prefilling_slots_alone(
         assert (after[3] == 7.0).all()
         assert np.abs(before[1] - after[1]).max() > 0
     worst = []
+    rows, mine = ServedRows(srv), srv._slots[1]
     while srv.work_pending():
         srv.step()
-        req = srv._slots[1]
-        if req is not None and req.get("active"):
-            seq = req["prompt"] + req["tokens"]
-            want = np.asarray(ref_forward(params, jnp.asarray(
-                [seq + [0] * (-len(seq) % 256)])))[0, len(seq) - 1]
-            worst.append(float(np.max(np.abs(
-                np.asarray(srv._state.last_logits)[1] - want))))
+        for req, seq, got in rows.after_step():
+            if req is mine:
+                want = np.asarray(ref_forward(params, jnp.asarray(
+                    [seq + [0] * (-len(seq) % 256)])))[0, len(seq) - 1]
+                worst.append(float(np.max(np.abs(got - want))))
     assert worst and max(worst) < TOL
     srv.close()
 
