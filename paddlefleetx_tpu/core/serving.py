@@ -457,8 +457,9 @@ class GenerationServer:
                 cfg = cfg.window_class(num_slots,
                                        page_size * prefill_chunk_pages)
             if hasattr(cfg, "state_class"):
-                # a model with linear-attention layers brings a third
-                # kind of cache: a row of recurrent state a slot (below)
+                # a model with recurrent layers (linear-attention,
+                # state-space) brings a third kind of cache: a row of
+                # recurrent state a slot (below)
                 cfg = cfg.state_class(num_slots)
             model = type(model)(cfg)
             if cfg.max_kv_pages % prefill_chunk_pages:
@@ -487,10 +488,13 @@ class GenerationServer:
             self._ring_cols = (
                 1 + np.arange(num_slots, dtype=np.int32)[:, None]
                 * self._ring + np.arange(self._ring, dtype=np.int32))
-            # the state class (models/solar_open2): each slot owns ONE
-            # row of every linear-attention layer's state leaves (a
+            # the state class (models/solar_open2's delta-rule layers,
+            # models/granite_hybrid's state-space layers): each slot
+            # owns ONE row of every recurrent layer's state leaves (a
             # float32 state a head and a convolution tail; not paged,
-            # not growing), row 0 the null row. The row's id rides in
+            # not growing; the model's config gives their count and
+            # bytes: state_layers, state_row_bytes), row 0 the null
+            # row. The row's id rides in
             # one more column of the device table (_sync_pt): 1 + slot
             # in the prefill view, 0 for a non-active slot in the
             # decode view, so a tick leaves a free or still-prefilling
@@ -1963,6 +1967,11 @@ class GenerationServer:
         only a step over the floor reads the thread's CPU clock, here.
         Under the surface lock, like every other write to the
         server's counters."""
+        # what a burst does to a server whose slots are bounded (by the
+        # state rows, on a model with recurrent state); counted at 0
+        # too, so that a reader tells "never" from "no such counter"
+        metrics.inc("serving/slots_full_steps",
+                    int(bool(self._queue) and None not in self._slots))
         seconds = rec.seconds
         over_floor = seconds > SLOW_STEP_SECONDS
         if over_floor:
@@ -2098,7 +2107,7 @@ class GenerationServer:
                     self.num_slots * self._max_pages)
         # what the walked slots hold, by class: allocator pages on the
         # layers that keep whole sequences, a row of state on the
-        # linear-attention layers (ring pages: _count_page_classes)
+        # recurrent layers (ring pages: _count_page_classes)
         metrics.inc("serving/pages_global_held", self._kv_layers * sum(
             req["num_pages"] for _, req in rows))
         if self._state_layers:

@@ -1225,9 +1225,10 @@ def prefill_chunk_paged(model, params, cache, input_chunk: jax.Array,
     just the prefilling rows; ``chunk_valid`` ``[n]`` counts each
     row's real tokens (the rest is that padding). A model whose cache
     is keys and values ignores it; one with a recurrent state
-    (``models/solar_open2``) reads everything it is fed, and leaves
-    its state where the last real token put it. The chunk's KV scatters straight into
-    its physical pages (model.py ``chunk_start`` branch) while the
+    (``models/solar_open2``, ``models/granite_hybrid``) reads
+    everything it is fed, and leaves its state where the last real
+    token put it. The chunk's KV scatters straight into its physical
+    pages (model.py ``chunk_start`` branch) while the
     queries attend every earlier position through the page-table
     gather. Returns ``(cache, logits)`` with fp32 ``[n, chunk, V]``
     logits — the server picks row ``prompt_len - 1 - chunk_start`` of

@@ -97,11 +97,19 @@ class Attention(nn.Module):
     are the layer's entries of ``rope_layout`` and
     ``sliding_window_layout``. With ``gate`` (``models/solar_open2``;
     any config with this one's attention keys) the heads' output meets
-    ``sigmoid(h W_gate)``, one a channel, before ``W_o``."""
+    ``sigmoid(h W_gate)``, one a channel, before ``W_o``. With
+    ``query_scale`` (``models/granite_hybrid``, whose scores are scaled
+    by a multiplier of its own) the queries are multiplied by it before
+    any path reads them, so that the paths' ``head_dim ** -0.5`` makes
+    the model's scale of it: every path, dense, paged prefill and the
+    decode kernel, then scores alike with no argument threaded through
+    them; the config keeps the factor a power of two, which no float
+    dtype rounds."""
     config: SmallThinkerConfig
     rope: bool
     window: bool
     gate: bool = False
+    query_scale: float = 1.0
 
     @nn.compact
     def __call__(self, h, positions, use_cache=False, cache_lengths=None,
@@ -122,6 +130,8 @@ class Attention(nn.Module):
         if self.rope:
             q = apply_rope(q, positions, cfg.rope_theta)
             k = apply_rope(k, positions, cfg.rope_theta)
+        if self.query_scale != 1.0:
+            q = q * jnp.asarray(self.query_scale, q.dtype)
         reach = cfg.sliding_window_size if self.window else None
         if not use_cache:
             out = dot_product_attention(

@@ -65,15 +65,61 @@ def state_rows(page_table, cfg: SolarOpen2Config):
     return pt[:, :pages], pt[:, pages]
 
 
-def short_conv(seq, weight):
-    """``c_t = silu(sum_j w_j x_{t - (taps - 1) + j})`` over ``seq [n,
-    taps - 1 + L, C]`` (the tail before the sequence, then its ``L``
-    inputs) with ``weight [taps, C]``; float32 ``[n, L, C]``."""
+def short_conv(seq, weight, bias=None):
+    """``c_t = silu(b + sum_j w_j x_{t - (taps - 1) + j})`` over ``seq
+    [n, taps - 1 + L, C]`` (the tail before the sequence, then its
+    ``L`` inputs) with ``weight [taps, C]`` and, where the layer has
+    one (``models/granite_hybrid``), ``bias [C]``; float32 ``[n, L,
+    C]``."""
     taps = weight.shape[0]
     length = seq.shape[1] - (taps - 1)
     seq, weight = seq.astype(jnp.float32), weight.astype(jnp.float32)
-    return jax.nn.silu(sum(weight[j] * seq[:, j:j + length]
-                           for j in range(taps)))
+    out = sum(weight[j] * seq[:, j:j + length] for j in range(taps))
+    if bias is not None:
+        out = out + bias.astype(jnp.float32)
+    return jax.nn.silu(out)
+
+
+def carried_in(state, tail, rows, taps, chunk_start=None):
+    """``(before [n, taps - 1, C], s0)``: what rows ``rows [n]`` of a
+    recurrent layer's two leaves (``state [R, ...]`` float32, ``tail
+    [R, (taps - 1) C]``) carry into this call. A prefill chunk
+    (``chunk_start [n]``) that starts a sequence starts from nothing,
+    whatever the slot's last tenant left in the row; a decode tick
+    (``chunk_start`` None) reads its state in the kernel: ``s0`` is
+    None."""
+    n = rows.shape[0]
+    before = tail.value[rows].reshape(n, taps - 1, -1)
+    if chunk_start is None:
+        return before, None
+    start = jnp.asarray(chunk_start, jnp.int32) == 0
+    lead = (n,) + (1,) * (state.value.ndim - 1)
+    return (jnp.where(start[:, None, None], 0, before),
+            jnp.where(start.reshape(lead), 0.0, state.value[rows]))
+
+
+def keep_tick_tail(tail, rows, seq):
+    """A tick's batch row i is slot i, whose row is 1 + i where it is
+    live: a select over the slots' rows where they lie (a scatter by
+    ``rows`` is one serial write a slot: 0.65 ms a layer at 96 slots;
+    my chip run, PR 31)."""
+    n = rows.shape[0]
+    mine = tail.value[1:1 + n]
+    tail.value = tail.value.at[1:1 + n].set(jnp.where(
+        (rows != 0)[:, None], seq[:, 1:].reshape(n, -1), mine))
+
+
+def keep_chunk(state, tail, rows, s_end, seq, valid, taps):
+    """A chunk's end: the state after its last real token, and the
+    ``taps - 1`` last inputs at or before it (``seq [n, taps - 1 + L,
+    C]``, ``valid [n]`` real tokens). A chunk's rows are slots' own:
+    no two alike."""
+    n = rows.shape[0]
+    state.value = state.value.at[rows].set(s_end, unique_indices=True)
+    tail.value = tail.value.at[rows].set(jax.vmap(
+        lambda s, at: jax.lax.dynamic_slice_in_dim(
+            s, at, taps - 1))(seq, valid).reshape(n, -1),
+        unique_indices=True)
 
 
 def _l2_normalize(x):
@@ -144,15 +190,9 @@ class DeltaAttention(nn.Module):
             tail = self.variable(
                 "cache", "conv_tail", jnp.zeros,
                 (cfg.state_rows, (taps - 1) * chans), dtype)
-            before = tail.value[rows].reshape(n, taps - 1, chans)
-            s0 = None
-            if not decode:
-                # a sequence's first chunk starts from nothing,
-                # whatever the slot's last tenant left in the row
-                start = jnp.asarray(chunk_start, jnp.int32) == 0
-                before = jnp.where(start[:, None, None], 0, before)
-                s0 = jnp.where(start[:, None, None, None], 0.0,
-                               state.value[rows])
+            before, s0 = carried_in(
+                state, tail, rows, taps,
+                None if decode else chunk_start)
         else:
             before = jnp.zeros((n, taps - 1, chans), dtype)
             s0 = jnp.zeros((n, heads, d, d), f32)
@@ -163,13 +203,7 @@ class DeltaAttention(nn.Module):
         v = conv[:, :, 2]
 
         if decode:
-            # a tick's batch row i is slot i, whose row is 1 + i where
-            # it is live: a select over the slots' rows where they
-            # lie (a scatter by ``rows`` is one serial write a slot:
-            # 0.65 ms a layer at 96 slots; my chip run, PR 31)
-            mine = tail.value[1:1 + n]
-            tail.value = tail.value.at[1:1 + n].set(jnp.where(
-                (rows != 0)[:, None], seq[:, 1:].reshape(n, -1), mine))
+            keep_tick_tail(tail, rows, seq)
             state.value, out = kda_step(
                 state.value, rows, q[:, 0], k[:, 0], v[:, 0],
                 jnp.exp(log_a[:, 0]), beta[:, 0],
@@ -184,15 +218,7 @@ class DeltaAttention(nn.Module):
                 q, k, v, jnp.where(real[..., None, None], log_a, 0.0),
                 jnp.where(real[..., None], beta, 0.0), s0)
             if use_cache:
-                # a chunk's rows are slots' own: no two alike
-                state.value = state.value.at[rows].set(
-                    s_end, unique_indices=True)
-                # the last taps - 1 inputs at or before the last real
-                # token
-                tail.value = tail.value.at[rows].set(jax.vmap(
-                    lambda s, at: jax.lax.dynamic_slice_in_dim(
-                        s, at, taps - 1))(seq, valid).reshape(n, -1),
-                    unique_indices=True)
+                keep_chunk(state, tail, rows, s_end, seq, valid, taps)
         out = RMSNorm(cfg, name="o_norm")(out).astype(f32) \
             * jax.nn.sigmoid(low_rank("g_proj") + gate_bias.astype(f32))
         return dense(cfg.hidden_size, "o_proj", axis=(-2, -1))(

@@ -12,12 +12,12 @@ layer's ``q``, ``k``, ``v`` of the one new token:
 The state leaf ``[R, H, d_k, d_v]`` holds a row a slot behind the null
 row 0 (``core/serving.py``: the state class). As plain XLA the step is
 a gather of the live rows, the update and a scatter back: three passes
-over states of 4 MB a slot a layer. Here the grid's first axis has the
-dynamic extent of the LIVE rows (``kv_write._live_rows``'s walk, by
-scalar prefetch), each step's ``(1, heads, d_k, d_v)`` block is picked
-by the row's id, comes in through the block pipeline and goes back to
-the SAME block of the aliased output: a free or still-prefilling slot
-(row id 0) costs no step and its state is never touched.
+over states of 4 MB a slot a layer. Here the launch is
+``state_rows.live_rows_call``'s, which the state-space layer's kernel
+(``ssd.py``) shares: a grid over the LIVE rows only, each step's ``(1,
+heads, d_k, d_v)`` block picked by the row's id and handed back to the
+SAME block of the aliased output; a free or still-prefilling slot (row
+id 0) costs no step and its state is never touched.
 
 All of it is float32 on the VPU (no matrix unit: a product of one row
 with a ``128 x 128`` state would leave it idle, and its float32 passes
@@ -33,43 +33,26 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import LANES, VMEM_DEFAULT, _interpret, _sds
-from .kv_write import _live_rows
+from .flash_attention import LANES, _interpret
+from .state_rows import TILE_ROWS, live_rows_call
 
 #: heads of one grid step: 8 states of 64 KB in, 8 out, double-buffered
 HEAD_BLOCK = 8
-#: rows of the operand tile: a, k, q, v, b and three of padding
-TILE_ROWS = 8
 
 
-def _kda_decode_kernel(rows_ref, order_ref, ops_ref, s_ref, o_ref,
-                       s_out_ref, *, heads):
-    """One grid step = ``heads`` heads of one live row ``i =
-    order[t]``. A step on a dead row (the one a tick with nothing live
-    still takes) hands its block back as it came."""
-    i = order_ref[pl.program_id(0)]
-    live = rows_ref[i] != 0
-
-    @pl.when(live)
-    def _():
-        for h in range(heads):
-            tile = ops_ref[0, h]                       # [8, d]
-            cols = tile.T                              # [d, 8]
-            a, k, q = cols[:, 0:1], cols[:, 1:2], cols[:, 2:3]
-            v, b = tile[3:4, :], tile[4:5, :]          # [1, d]
-            s = s_ref[0, h] * a
-            u = (v - jnp.sum(s * k, axis=0, keepdims=True)) * b
-            s = s + k * u
-            s_out_ref[0, h] = s
-            o_ref[0, h:h + 1, :] = jnp.sum(s * q, axis=0, keepdims=True)
-
-    @pl.when(jnp.logical_not(live))
-    def _():
-        s_out_ref[...] = s_ref[...]
-        o_ref[...] = jnp.zeros_like(o_ref)
+def _kda_decode_step(ops_ref, s_ref, o_ref, s_out_ref, *, heads):
+    """One grid step = ``heads`` heads of one live row."""
+    for h in range(heads):
+        tile = ops_ref[0, h]                       # [8, d]
+        cols = tile.T                              # [d, 8]
+        a, k, q = cols[:, 0:1], cols[:, 1:2], cols[:, 2:3]
+        v, b = tile[3:4, :], tile[4:5, :]          # [1, d]
+        s = s_ref[0, h] * a
+        u = (v - jnp.sum(s * k, axis=0, keepdims=True)) * b
+        s = s + k * u
+        s_out_ref[0, h] = s
+        o_ref[0, h:h + 1, :] = jnp.sum(s * q, axis=0, keepdims=True)
 
 
 def kda_decode(state, rows, q, k, v, a, b):
@@ -108,45 +91,14 @@ def _kda_decode_call(state, rows, q, k, v, a, b, *, interpret):
     that a model's layers trace one kernel."""
     _, heads, d, _ = state.shape
     n = rows.shape[0]
-    order, live = _live_rows(rows[:, None], True)
     f32 = jnp.float32
     tiles = jnp.stack(
         [a.astype(f32), k.astype(f32), q.astype(f32), v.astype(f32),
          jnp.broadcast_to(b.astype(f32)[..., None], (n, heads, d))]
         + [jnp.zeros((n, heads, d), f32)] * (TILE_ROWS - 5), axis=2)
     hb = HEAD_BLOCK
-
-    def of_row(t, j, rows, order):
-        return (order[t], j, 0, 0)
-
-    def of_state(t, j, rows, order):
-        return (rows[order[t]], j, 0, 0)
-
-    o, state = pl.pallas_call(
-        functools.partial(_kda_decode_kernel, heads=hb),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            # nothing live still takes one step: a dead row's
-            grid=(jnp.maximum(live, 1), heads // hb),
-            in_specs=[
-                pl.BlockSpec((1, hb, TILE_ROWS, d), of_row),
-                pl.BlockSpec((1, hb, d, d), of_state),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, hb, d), lambda t, j, rows, order:
-                             (order[t], j, 0)),
-                pl.BlockSpec((1, hb, d, d), of_state),
-            ],
-        ),
-        out_shape=[_sds((n, heads, d), f32, state),
-                   _sds(state.shape, f32, state)],
-        # operands: rows, order, the tiles, the state -> the state IS
-        # the second output
-        input_output_aliases={3: 1},
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=VMEM_DEFAULT),
-        interpret=interpret,
-        name="kda_decode",
-    )(rows, order, tiles, state)
-    # a dead row's block of ``o`` was never visited
-    return state, jnp.where((rows != 0)[:, None, None], o, 0.0)
+    return live_rows_call(
+        functools.partial(_kda_decode_step, heads=hb), "kda_decode",
+        state, rows, [(tiles, (hb, TILE_ROWS, d), lambda j: (j, 0, 0))],
+        ((n, heads, d), (hb, d), lambda j: (j, 0)), head_block=hb,
+        interpret=interpret)

@@ -768,3 +768,97 @@ def test_ragged_matmul_compiles_at_a_share_of_the_experts(rows, one_chip,
         fn = functools.partial(ragged_matmul, block_m=block)
         assert "tpu_custom_call" in _compile(
             fn, x, w, table, used).as_text()
+
+
+# -- one chip: the Granite hybrid serving cell's kernels -----------------
+#
+# granite4h.serve-chat-bursty: 64 slots; 64 state-space heads with a
+# 64 x 128 float32 state each, one row a slot behind the null row, on
+# 36 of 40 layers; 32 query heads over 8 pooled K/V heads of 64 (groups
+# of 4 on a sublane tile of 8) on the other 4.
+
+GH_SLOTS, GH_H, GH_P, GH_N = 64, 64, 64, 128
+
+
+def test_ssd_decode_compiles_and_updates_the_state_in_place(one_chip,
+                                                            as_tpu):
+    """The state-space decode kernel at the published widths: Mosaic
+    takes the turn of the [8, 512] operand tile, the float32 readout
+    on the matrix unit and the dynamic grid, and the donated state
+    leaf (136 MB) comes back aliased, not copied."""
+    from paddlefleetx_tpu.ops.pallas import ssd
+    state = _sds((1 + GH_SLOTS, GH_H, GH_P, GH_N), jnp.float32, one_chip)
+    rows = _sds((GH_SLOTS,), jnp.int32, one_chip)
+    x = _sds((GH_SLOTS, GH_H, GH_P), jnp.float32, one_chip)
+    head = _sds((GH_SLOTS, GH_H), jnp.float32, one_chip)
+    bc = _sds((GH_SLOTS, GH_N), jnp.float32, one_chip)
+    skip = _sds((GH_H,), jnp.float32, one_chip)
+    compiled = jax.jit(ssd.ssd_decode, donate_argnums=0).lower(
+        state, rows, x, head, head, bc, bc, skip).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%ssd_decode\S* = .*tpu_custom_call", text)) == 1
+    (ops, _), = _mosaic_calls(compiled)
+    assert ops == {b"matmul"}
+    leaf = math.prod(state.shape) * 4
+    assert compiled.memory_analysis().alias_size_in_bytes >= leaf
+    assert compiled.memory_analysis().temp_size_in_bytes < leaf // 8
+
+
+def test_flash_decode_paged_compiles_at_four_heads_of_64_a_group(one_chip,
+                                                                 as_tpu):
+    """32 query heads over 8 pooled heads of 64, a table of 64 pages:
+    by shape, the grouped kernel the other served families run at
+    heads of 128; a group's 4 query heads pad to a sublane tile."""
+    from paddlefleetx_tpu.ops.pallas import flash_attention as fa
+    q = _sds((GH_SLOTS, 1, 32, 64), BF16, one_chip)
+    off = _sds((GH_SLOTS,), jnp.int32, one_chip)
+    table = _sds((GH_SLOTS, 64), jnp.int32, one_chip)
+    kv = [_sds((1665, 8, 64, 128), BF16, one_chip)] * 2
+    (ops, used), = _mosaic_calls(_compile(
+        fa.flash_decode_paged, q, *kv, off, table))
+    assert ops == {b"matmul", b"multi_reduction"}
+    counted = fa._paged_vmem_bytes(1, 8 * 8, 64, 128, 2, 2, False, 8)
+    assert used <= counted <= 2 * used, (used, counted)
+
+
+def test_granite_tick_keeps_both_cache_classes_in_place(one_chip, as_tpu):
+    """``decode_step`` over one state-space and one softmax layer at
+    the published widths and the cell's 64 slots: one kernel each for
+    the state step, the K/V write and the paged decode, and every byte
+    of the state rows and of the page pool aliased to the outputs."""
+    from paddlefleetx_tpu.models.gpt import generation as g
+    from paddlefleetx_tpu.models.granite_hybrid import (
+        GraniteHybridConfig, GraniteHybridForCausalLM,
+    )
+    cfg = GraniteHybridConfig(
+        num_hidden_layers=2, layer_types=("mamba", "attention"),
+        max_position_embeddings=8192, dtype="bfloat16", kv_page_size=128,
+        kv_pool_pages=1665).state_class(GH_SLOTS)
+    model = GraniteHybridForCausalLM(cfg)
+
+    def on_chip(tree, dtype=None):
+        return jax.tree.map(
+            lambda a: _sds(a.shape, dtype or a.dtype, one_chip), tree)
+    params = on_chip(jax.eval_shape(
+        model.init, {"params": jax.random.key(0)},
+        jnp.zeros((1, 8), jnp.int32))["params"], BF16)
+    cache = on_chip(jax.eval_shape(
+        lambda p: g.init_page_pool(model, p, GH_SLOTS), params))
+    state = on_chip(jax.eval_shape(
+        lambda: g.init_slot_state(GH_SLOTS, cfg.vocab_size)))
+    rng = on_chip(jax.eval_shape(lambda: jax.random.key(1)))
+    table = _sds((GH_SLOTS, cfg.max_kv_pages + 1), jnp.int32, one_chip)
+    gen_cfg = g.GenerationConfig(
+        max_dec_len=256, decode_strategy="greedy_search",
+        eos_token_id=cfg.vocab_size - 1, pad_token_id=cfg.vocab_size - 1)
+    compiled = g.decode_step.lower(
+        model, params, cache, state, rng, gen_cfg,
+        page_table=table).compile()
+    text = compiled.as_text()
+    for name in ("ssd_decode", "kv_write", "self_attn"):
+        assert len(re.findall(
+            rf"%{name}\S* = [^\n]*custom-call\(", text)) == 1, name
+    held = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    assert held == (1 + GH_SLOTS) * cfg.state_row_bytes \
+        + 2 * 1665 * 8 * 64 * 128 * 2
+    assert compiled.memory_analysis().alias_size_in_bytes >= held
