@@ -402,6 +402,34 @@ def _flash_per_device(q, k, v, bias, causal, query_offset,
     return shard_kernel(per_device, args, in_axes, qkv_axes)
 
 
+def _count_causal_tiles(q, k, v, causal, plain):
+    """Trace-time counters of what a causal training-flash call's
+    kernels do with the blocks that cross the diagonal:
+    ``attention/flash_causal_staircase`` where the forward or the
+    backward it gets (``plain``: no in-kernel dropout, no bias) walks
+    them as a staircase of sub-tiles, ``flash_causal_whole_block``
+    where both compute them whole, and the gauge
+    ``flash_causal_tile_share``: the score elements the two execute
+    over what whole blocks would (the sequence is never sharded, so
+    the global lengths are each device's)."""
+    if not causal:
+        return
+    from .pallas import flash_attention as fa
+    sq, skv, d, d_v = q.shape[1], k.shape[1], q.shape[-1], v.shape[-1]
+    try:
+        blocks = fa.check_shapes(sq, skv, d, d_v=d_v)
+    except NotImplementedError:
+        # the call above succeeded, so only a stand-in kernel gets
+        # here; counting must never steer the dispatch
+        return
+    executed, whole = fa.causal_step_elements(
+        sq, skv, d, d_v, q.dtype.itemsize, *blocks, plain=plain)
+    metrics.inc("attention/flash_causal_staircase" if executed < whole
+                else "attention/flash_causal_whole_block")
+    metrics.get_registry().set_gauge(
+        "attention/flash_causal_tile_share", executed / whole)
+
+
 def dot_product_attention(
         q: jax.Array, k: jax.Array, v: jax.Array,
         bias: Optional[jax.Array] = None,
@@ -509,6 +537,7 @@ def dot_product_attention(
                     q, k, v, bias, causal, query_offset, dropout_rate,
                     dropout_rng, heads_axis, sm_scale)
                 metrics.inc("attention/flash_dropout")
+                _count_causal_tiles(q, k, v, causal, plain=False)
                 return out
             except MeshIndivisible:
                 metrics.inc("attention/fallback/mesh_sharded")
@@ -627,6 +656,7 @@ def dot_product_attention(
                 metrics.inc("attention/flash_mla"
                             if v.shape[-1] != q.shape[-1]
                             else "attention/flash")
+                _count_causal_tiles(q, k, v, causal, plain=bias is None)
                 return out
             metrics.inc("attention/fallback/kv_cache_layout"
                         if kv_cache_layout

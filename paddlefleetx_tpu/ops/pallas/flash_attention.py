@@ -21,9 +21,14 @@ Measured forward throughput on one v5e (b=2, h=16, d=64, causal, r2):
 (4.1x dense), ``s=8192`` 23 TF/s (dense materializes [b,h,s,s] and
 stops being viable). Utilization grows with s because the fraction of
 fully-live interior blocks (which skip mask arithmetic) grows and the
-per-program overhead amortizes; at short s the kernel is bound by the
-online-softmax exp passes, not the MXU (see
-projects/gpt/docs/single_card.md for the step-level analysis).
+per-program overhead amortizes. A block that crosses the causal
+diagonal is walked as a staircase of ``CAUSAL_SUBTILE`` sub-tiles
+where that is static (:func:`_staircase`), so the masked half of it
+is mostly not computed: at s=1024, one block a head, the backward
+executes 10 of 16 tiles (PR 37: 0.947 -> 0.652 ms for 128 heads of 64
+on one v5e), while the one-block forward, whose time follows its rows
+and not the keys they score, keeps the whole block
+(:func:`_forward_staircase`).
 """
 
 from __future__ import annotations
@@ -57,6 +62,9 @@ def _bf16_exp() -> bool:
     return os.environ.get("PFX_FLASH_BF16_EXP") == "1"
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_KV = 1024
+#: rows (and keys) of one sub-tile of the staircase a diagonal-crossing
+#: causal block is walked as (:func:`_staircase`)
+CAUSAL_SUBTILE = 256
 
 
 def _dropout_threshold(rate: float):
@@ -90,13 +98,17 @@ def _interpret_random_bits(seed, fold, block_q, block_kv):
     return x ^ (x >> 15)
 
 
-def _block_keep_mask(seed_ref, b, qi, ki, n_q, n_kv, rate, block_q,
-                     block_kv):
-    """Regenerable [block_q, block_kv] keep mask for score block
-    (b, qi, ki): the per-core PRNG is reseeded from (run seed, block
-    coordinates) so forward and every backward kernel reproduce the
-    SAME mask for the same block regardless of their grid iteration
-    order (the backward grids iterate (ki, qi)).
+def _block_random_bits(seed_ref, b, qi, ki, n_q, n_kv, block_q,
+                       block_kv):
+    """Regenerable [block_q, block_kv] uint32 bits for score block
+    (b, qi, ki), a lane kept iff its bits fall below
+    :func:`_dropout_threshold`: the per-core PRNG is reseeded from (run
+    seed, block coordinates) so forward and every backward kernel
+    reproduce the SAME mask for the same block regardless of their grid
+    iteration order (the backward grids iterate (ki, qi)). The bits are
+    always drawn for the WHOLE block; a causal staircase compares its
+    sub-tile's static slice of them, so both directions still see one
+    mask a block.
 
     The coordinates are folded mixed-radix into ONE value — Mosaic's
     ``prng_set_seed_32`` rejects more than two seed operands on v5e
@@ -108,22 +120,27 @@ def _block_keep_mask(seed_ref, b, qi, ki, n_q, n_kv, rate, block_q,
     the (TPU-only) hardware PRNG."""
     fold = (b * n_q + qi) * n_kv + ki
     if _interpret():
-        bits = _interpret_random_bits(seed_ref[0], fold, block_q,
+        return _interpret_random_bits(seed_ref[0], fold, block_q,
                                       block_kv)
-    else:
-        pltpu.prng_seed(seed_ref[0], fold)
-        bits = pltpu.bitcast(pltpu.prng_random_bits((block_q, block_kv)),
-                             jnp.uint32)
-    return bits < _dropout_threshold(rate)
+    pltpu.prng_seed(seed_ref[0], fold)
+    return pltpu.bitcast(pltpu.prng_random_bits((block_q, block_kv)),
+                         jnp.uint32)
+
+
+def _dropped(x, keep, rate):
+    """``x`` with its dropped lanes zeroed and the kept ones rescaled
+    by ``1 / keep_prob``."""
+    return jnp.where(keep, x * (1.0 / (1.0 - rate)), jnp.zeros_like(x))
 
 
 def _auto_block(s: int, target: int, align: int) -> int:
     """Largest power-of-two-shrunk block <= target that divides s.
-    1024 blocks measure fastest on v5e at training shapes (b=8/h=16/
-    s=1024/d=64: fwd+bwd 1.89 ms vs 2.42 ms with 512 blocks — fewer
-    program launches and mask-free interior work amortize better);
-    halving keeps odd lengths (1536, 2560, ...) on the kernel instead
-    of falling back to the dense path."""
+    One 1024 block a head at the training shapes keeps the backward on
+    its one-pass kernel and the grid at one step a head (a 256 x 256
+    grid is 16 steps of ~0.35 us, dead ones included); what causality
+    saves inside such a block is the staircase's (:func:`_staircase`),
+    not the grid's. Halving keeps odd lengths (1536, 2560, ...) on the
+    kernel instead of falling back to the dense path."""
     b = min(target, s)
     while b > align and (s % b or b % align):
         b //= 2
@@ -138,6 +155,92 @@ def _causal_mask(qi, ki, block_q, block_kv, offset):
     return k_pos <= q_pos
 
 
+def _diagonal_mask(shape, row0=0):
+    """Key ``j`` of row ``i`` is seen iff ``j <= i + row0``: the causal
+    mask of a score tile whose first row is ``row0`` rows below its
+    corner on the diagonal."""
+    return jax.lax.broadcasted_iota(jnp.int32, shape, 1) \
+        <= jax.lax.broadcasted_iota(jnp.int32, shape, 0) + row0
+
+
+def _staircase(block_q, block_kv, causal=True, query_offset=0):
+    """``((lo, hi), ...)``: the sub-tile bounds, rows and keys alike,
+    of a diagonal-crossing causal block walked as a staircase, or
+    ``()`` where such a block is computed whole and masked.
+
+    The backward scores keys ``lo:hi`` against rows ``lo:`` only, the
+    forward rows ``lo:hi`` against keys ``:hi`` only; the masked upper
+    triangle shrinks from half the block to half of each sub-tile on
+    the diagonal. It engages where that is exact and static: square
+    blocks at no query offset, so a block crosses the diagonal iff
+    ``qi == ki``, its corner lies ON the diagonal and no extent
+    depends on a program id. Any other masked block (``block_q !=
+    block_kv``, a sub-tile that does not divide the block or is the
+    block) keeps the whole-block path.
+
+    On one v5e at ``CAUSAL_SUBTILE`` 128 / 256 / 512 against the whole
+    block (bf16, device time of the kernel alone, PR 37): the one-block
+    backward at s=1024 0.947 -> 0.717 / 0.652 / 0.724 ms (128 heads of
+    64) and 0.454 -> 0.339 / 0.309 / 0.344 ms (64 heads of 128)."""
+    sub = CAUSAL_SUBTILE
+    if not causal or not isinstance(query_offset, int) or query_offset \
+            or block_q != block_kv or block_q % sub or block_q == sub:
+        return ()
+    return tuple((lo, lo + sub) for lo in range(0, block_q, sub))
+
+
+def _forward_staircase(block_q, block_kv, num_kv, causal=True,
+                       query_offset=0):
+    """The forward's :func:`_staircase`: where a head's keys come in
+    several blocks. The time of a head that is ONE block follows its
+    rows, not the keys they score (the per-row softmax bookkeeping on
+    ``[bq, 1]`` columns, not the products and not the operands'
+    traffic: the same BlockSpecs with no products take 0.134 ms), and
+    on the chip every staircase tried there took longer than the masked
+    whole block although it skips 6 of 16 tiles (s=1024, one v5e, PR
+    37: 0.464 -> 0.572 ms at d=64, 0.210 -> 0.278 ms at d=128; two row
+    strips of 512 that skip nothing already take 0.523, and 0.521 with
+    the dead quadrant skipped). With the keys in several blocks the
+    same walk pays: s=2048 0.868 -> 0.807 ms at d=64, 0.452 -> 0.426
+    at d=128; s=4096 at 192/128 8.29 -> 7.66."""
+    return _staircase(block_q, block_kv, causal, query_offset) \
+        if num_kv > 1 else ()
+
+
+def causal_score_elements(sq, skv, block_q, block_kv, steps):
+    """``(executed, whole)`` score elements of one causal head over
+    the ``(sq // block_q, skv // block_kv)`` grid: what a kernel
+    computes when it walks its diagonal-crossing blocks by ``steps``
+    (:func:`_staircase`; ``()`` computes them whole), and what it
+    would with every live block computed whole."""
+    executed = whole = 0
+    for qi in range(sq // block_q):
+        for ki in range(skv // block_kv):
+            live, interior = _live_interior(qi, ki, block_q, block_kv,
+                                            True, 0)
+            if not live:
+                continue
+            whole += block_q * block_kv
+            executed += sum((hi - lo) * hi for lo, hi in steps) \
+                if steps and not interior else block_q * block_kv
+    return executed, whole
+
+
+def causal_step_elements(sq, skv, d, d_v, itemsize, block_q, block_kv,
+                         plain=True):
+    """``(executed, whole)`` score elements of one causal head through
+    the forward kernel and the backward it gets (``plain``: neither
+    in-kernel dropout nor a bias), staircases counted where they
+    engage: what ``attention/flash_causal_tile_share`` reports."""
+    fwd = causal_score_elements(
+        sq, skv, block_q, block_kv,
+        _forward_staircase(block_q, block_kv, skv // block_kv))
+    _, bq, bkv = _backward_plan(sq, skv, d, d_v, itemsize, block_q,
+                                block_kv, plain)
+    bwd = causal_score_elements(sq, skv, bq, bkv, _staircase(bq, bkv))
+    return fwd[0] + bwd[0], fwd[1] + bwd[1]
+
+
 def _dot(a, b, trans_a=False, trans_b=False):
     dims = ((0,) if trans_a else (1,), (1,) if trans_b else (0,))
     return jax.lax.dot_general(a, b, (dims, ((), ())),
@@ -147,9 +250,11 @@ def _dot(a, b, trans_a=False, trans_b=False):
 # -- forward -----------------------------------------------------------
 
 
-def _online_update(s, v, m_scr, l_scr, acc_scr, drop_fn=None):
-    """One online-softmax accumulator step over a masked score block
-    (the training forward's MXU formulation; the decode kernel
+def _online_update(s, v, m_scr, l_scr, acc_scr, drop_fn=None,
+                   rows=slice(None)):
+    """One online-softmax accumulator step on ``rows`` of the scratch
+    over their masked scores ``s [rows, keys]`` against ``v [keys,
+    d_v]`` (the training forward's MXU formulation; the decode kernel
     vectorizes the same recurrence over heads with VPU reduces —
     semantic parity between the two is pinned by
     ``tests/test_flash_attention.py`` decode-vs-XLA cases).
@@ -159,30 +264,32 @@ def _online_update(s, v, m_scr, l_scr, acc_scr, drop_fn=None):
     probabilities, and the row division by ``l`` is uniform, so
     ``dropout(softmax(s)) @ v == (sum keep*p/keep_prob @ v) / l`` —
     while only the value-matmul operand is masked+rescaled."""
-    m_prev = m_scr[:]                              # [bq, 1]
+    m_prev = m_scr[rows]                           # [bq, 1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
     if _bf16_exp():
         # bf16 transcendental, fp32 accumulate (lever #2; opt-in)
         p = jnp.exp((s - m_new).astype(jnp.bfloat16))
-        l_scr[:] = l_scr[:] * alpha + jnp.sum(
+        l_scr[rows] = l_scr[rows] * alpha + jnp.sum(
             p.astype(jnp.float32), axis=1, keepdims=True)
     else:
         p = jnp.exp(s - m_new)
-        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        l_scr[rows] = l_scr[rows] * alpha + jnp.sum(p, axis=1,
+                                                    keepdims=True)
     pv = p if drop_fn is None else drop_fn(p)
-    acc_scr[:] = acc_scr[:] * alpha + _dot(pv.astype(v.dtype), v)
-    m_scr[:] = m_new
+    acc_scr[rows] = acc_scr[rows] * alpha + _dot(pv.astype(v.dtype), v)
+    m_scr[rows] = m_new
 
 
 def _live_interior(qi, ki, block_q, block_kv, causal, query_offset):
     """(live, interior): whether the (qi, ki) score block has any
     unmasked entry, and whether it is FULLY unmasked (strictly below
     the causal diagonal). Interior blocks skip the iota/compare/where
-    mask arithmetic entirely. At s=1024/512-blocks only a third of
-    live blocks are interior, so the gain is within measurement noise
-    there (the kernel is exp-pass-bound); the fraction — and the
-    payoff — grows with sequence length (78% interior at s=4096)."""
+    mask arithmetic entirely; a block that crosses the diagonal is
+    masked, whole or as a staircase (:func:`_staircase`). With the
+    default 1024 blocks s=1024 is one block a head and it crosses;
+    at s=4096 6 of the 10 live blocks are interior, and 28 of the 36
+    the fused backward walks at its 512."""
     if not causal:
         return ki >= 0, True
     live = qi * block_q + block_q - 1 + query_offset >= ki * block_kv
@@ -225,31 +332,45 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, sm_scale, causal, block_q,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
+    steps = _forward_staircase(block_q, block_kv, num_kv, causal,
+                               query_offset)
+
     def _block(masked: bool):
-        q, k, v = q_ref[0], k_ref[0], v_ref[0]
         # sm_scale rides on q ([bq, d]) instead of on the [bq, bkv]
         # score block — 1/8th the multiplies at d=64/bkv=512
-        q = (q.astype(jnp.float32) * sm_scale).astype(q.dtype)
-        s = _dot(q, k, trans_b=True)                   # [bq, bkv] f32
-        if masked:
-            s = jnp.where(
-                _causal_mask(qi, ki, block_q, block_kv, query_offset),
-                s, NEG_INF)
-        if has_bias:
-            # additive bias tile ([bq|1, bkv] broadcasts over rows for
-            # the [b,1,1,sk] padding-mask form), AFTER the causal mask
-            # like the XLA path — -1e9-style mask values on top of the
-            # -1e30 causal fill stay very negative
-            s = s + bias_ref[0, 0].astype(jnp.float32)
-        drop_fn = None
+        q = (q_ref[0].astype(jnp.float32) * sm_scale).astype(q_ref.dtype)
+        bits = None
         if dropout_rate > 0.0:
-            def drop_fn(p):
-                keep = _block_keep_mask(
-                    seed_ref, bhi, qi, ki, num_q, num_kv,
-                    dropout_rate, block_q, block_kv)
-                return jnp.where(keep, p / (1.0 - dropout_rate),
-                                 jnp.zeros_like(p))
-        _online_update(s, v, m_scr, l_scr, acc_scr, drop_fn)
+            bits = _block_random_bits(seed_ref, bhi, qi, ki, num_q,
+                                      num_kv, block_q, block_kv)
+        stairs = steps if masked else ()
+        # a staircase's rows lo:hi see no key past hi
+        spans = [(slice(lo, hi), slice(0, hi)) for lo, hi in stairs] \
+            or [(slice(None), slice(None))]
+        for rows, cols in spans:
+            s = _dot(q[rows], k_ref[0, cols, :], trans_b=True)  # f32
+            if stairs:
+                s = jnp.where(_diagonal_mask(s.shape, rows.start), s,
+                              NEG_INF)
+            elif masked:
+                s = jnp.where(
+                    _causal_mask(qi, ki, block_q, block_kv,
+                                 query_offset), s, NEG_INF)
+            if has_bias:
+                # additive bias tile ([bq|1, bkv] broadcasts over rows
+                # for the [b,1,1,sk] padding-mask form), AFTER the
+                # causal mask like the XLA path — -1e9-style mask
+                # values on top of the -1e30 causal fill stay very
+                # negative
+                b_rows = rows if bias_ref.shape[2] > 1 else slice(None)
+                s = s + bias_ref[0, 0, b_rows, cols].astype(jnp.float32)
+            drop_fn = None
+            if bits is not None:
+                keep = bits[rows, cols] < _dropout_threshold(dropout_rate)
+                drop_fn = functools.partial(_dropped, keep=keep,
+                                            rate=dropout_rate)
+            _online_update(s, v_ref[0, cols, :], m_scr, l_scr, acc_scr,
+                           drop_fn, rows)
 
     _masked_dispatch(_block, qi, ki, block_q, block_kv, causal,
                      query_offset)
@@ -361,47 +482,72 @@ def _flash_forward(q, k, v, sm_scale, causal, query_offset, block_q,
 # -- backward ----------------------------------------------------------
 
 
-def _bwd_block_math(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    masked, qi, ki, sm_scale, block_q, block_kv,
-                    query_offset, dropout_rate=0.0, seed_ref=None,
-                    num_q=None, num_kv=None, bias_ref=None, bhi=None):
-    """Score-block recomputation shared by all backward kernels:
-    ``(q_s, p_dv, ds)`` with q pre-scaled (so dk = ds^T @ q_s absorbs
-    one sm_scale factor and the OTHER stays pending on dq — the caller
-    applies it once on [bq, d]). Single definition so the backward
-    kernels cannot diverge (same contract as ``_masked_dispatch``).
+def _bwd_strips(q_s, k, v, do, lse, delta, steps, mask, bias_ref=None,
+                bits=None, dropout_rate=0.0):
+    """Score recomputation shared by all backward kernels, over one
+    ``[bq, bkv]`` block given as values (``q_s`` pre-scaled, so dk =
+    ds^T @ q_s absorbs one sm_scale factor and the OTHER stays pending
+    on dq — the caller applies it once on [bq, d]). Yields ``(rows,
+    cols, q_s[rows], do[rows], k[cols], p_dv, ds)`` a strip of the
+    block, ``p_dv`` and ``ds`` being ``[rows, cols]``: the whole block
+    as one strip under ``mask`` (None on an interior block), or, with
+    the ``steps`` of :func:`_staircase` on a diagonal-crossing one,
+    keys ``lo:hi`` against rows ``lo:`` only. Single definition so the
+    backward kernels cannot diverge (same contract as
+    ``_masked_dispatch``).
 
-    With dropout the SAME per-block keep mask as the forward is
-    regenerated from (seed, b, qi, ki). Writing the dropped
+    With dropout the SAME per-block random ``bits`` as the forward's
+    are regenerated from (seed, b, qi, ki). Writing the dropped
     probabilities p~ = keep*p/keep_prob, the chain rule gives
     ``dv = p~^T @ do`` and ``ds = p * (keep*dp/keep_prob - delta)``
     with ``delta = rowsum(do*o) = rowsum(p~ * dp)`` — the caller's
     delta needs no change."""
-    q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-    lse, delta = lse_ref[0], delta_ref[0]               # [bq, 1]
-    q_s = (q.astype(jnp.float32) * sm_scale).astype(q.dtype)
-    s = _dot(q_s, k, trans_b=True)                      # [bq, bkv]
-    if masked:
-        s = jnp.where(
-            _causal_mask(qi, ki, block_q, block_kv, query_offset),
-            s, NEG_INF)
-    if bias_ref is not None:
-        # same post-mask position as the forward: lse was computed on
-        # the biased scores, so p = exp(s + bias - lse) reconstructs
-        # the forward's probabilities exactly
-        s = s + bias_ref[0, 0].astype(jnp.float32)
-    p = jnp.exp(s - lse)                                # [bq, bkv]
-    dp = _dot(do, v, trans_b=True)                      # [bq, bkv]
-    p_dv = p
+    spans = [(slice(lo, None), slice(lo, hi)) for lo, hi in steps] \
+        or [(slice(None), slice(None))]
+    for rows, cols in spans:
+        q_r, do_r, k_c = q_s[rows], do[rows], k[cols]
+        s = _dot(q_r, k_c, trans_b=True)                # [rows, cols]
+        if steps:
+            # the strip's corner lies on the diagonal
+            s = jnp.where(_diagonal_mask(s.shape), s, NEG_INF)
+        elif mask is not None:
+            s = jnp.where(mask, s, NEG_INF)
+        if bias_ref is not None:
+            # same post-mask position as the forward: lse was computed
+            # on the biased scores, so p = exp(s + bias - lse)
+            # reconstructs the forward's probabilities exactly
+            b_rows = rows if bias_ref.shape[2] > 1 else slice(None)
+            s = s + bias_ref[0, 0, b_rows, cols].astype(jnp.float32)
+        p = jnp.exp(s - lse[rows])
+        dp = _dot(do_r, v[cols], trans_b=True)
+        p_dv = p
+        if bits is not None:
+            keep = bits[rows, cols] < _dropout_threshold(dropout_rate)
+            p_dv = _dropped(p, keep, dropout_rate)
+            dp = _dropped(dp, keep, dropout_rate)
+        yield rows, cols, q_r, do_r, k_c, p_dv, p * (dp - delta[rows])
+
+
+def _bwd_block_strips(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      masked, qi, ki, sm_scale, block_q, block_kv,
+                      causal, query_offset, dropout_rate=0.0,
+                      seed_ref=None, num_q=None, num_kv=None,
+                      bias_ref=None, bhi=None):
+    """:func:`_bwd_strips` of the grid's score block (qi, ki), read
+    from the kernels' block refs."""
+    q = q_ref[0]
+    steps = _staircase(block_q, block_kv, causal, query_offset) \
+        if masked else ()
+    mask = _causal_mask(qi, ki, block_q, block_kv, query_offset) \
+        if masked and not steps else None
+    bits = None
     if dropout_rate > 0.0:
-        keep = _block_keep_mask(seed_ref, bhi, qi, ki,
-                                num_q, num_kv, dropout_rate, block_q,
-                                block_kv)
-        inv = 1.0 / (1.0 - dropout_rate)
-        p_dv = jnp.where(keep, p * inv, jnp.zeros_like(p))
-        dp = jnp.where(keep, dp * inv, jnp.zeros_like(dp))
-    ds = p * (dp - delta)
-    return q_s, p_dv, ds
+        bits = _block_random_bits(seed_ref, bhi, qi, ki, num_q, num_kv,
+                                  block_q, block_kv)
+    return _bwd_strips(
+        (q.astype(jnp.float32) * sm_scale).astype(q.dtype), k_ref[0],
+        v_ref[0], do_ref[0], lse_ref[0], delta_ref[0], steps, mask,
+        bias_ref, bits, dropout_rate)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -422,13 +568,13 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
     def _block(masked: bool):
-        q_s, p_dv, ds = _bwd_block_math(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, masked,
-            qi, ki, sm_scale, block_q, block_kv, query_offset,
-            dropout_rate, seed_ref, num_q, num_kv, bias_ref, bhi)
-        dv_scr[:] += _dot(p_dv.astype(do_ref.dtype), do_ref[0],
-                          trans_a=True)
-        dk_scr[:] += _dot(ds.astype(q_s.dtype), q_s, trans_a=True)
+        for _, cols, q_s, do, _, p_dv, ds in _bwd_block_strips(
+                q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, masked,
+                qi, ki, sm_scale, block_q, block_kv, causal,
+                query_offset, dropout_rate, seed_ref, num_q, num_kv,
+                bias_ref, bhi):
+            dv_scr[cols] += _dot(p_dv.astype(do.dtype), do, trans_a=True)
+            dk_scr[cols] += _dot(ds.astype(q_s.dtype), q_s, trans_a=True)
 
     _masked_dispatch(_block, qi, ki, block_q, block_kv, causal,
                      query_offset)
@@ -456,11 +602,12 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
     def _block(masked: bool):
-        _, _, ds = _bwd_block_math(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, masked,
-            qi, ki, sm_scale, block_q, block_kv, query_offset,
-            dropout_rate, seed_ref, num_q, num_kv, bias_ref, bhi)
-        dq_scr[:] += _dot(ds.astype(k_ref.dtype), k_ref[0])
+        for rows, _, _, _, k, _, ds in _bwd_block_strips(
+                q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, masked,
+                qi, ki, sm_scale, block_q, block_kv, causal,
+                query_offset, dropout_rate, seed_ref, num_q, num_kv,
+                bias_ref, bhi):
+            dq_scr[rows] += _dot(ds.astype(k.dtype), k)
 
     _masked_dispatch(_block, qi, ki, block_q, block_kv, causal,
                      query_offset)
@@ -496,15 +643,16 @@ def _bwd_combined_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
     def _block(masked: bool):
-        q_s, p_dv, ds = _bwd_block_math(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, masked,
-            0, ki, sm_scale, block_q, block_kv, query_offset,
-            dropout_rate, seed_ref, 1, num_kv, bias_ref, bhi)
-        dv_ref[0] = _dot(p_dv.astype(do_ref.dtype), do_ref[0],
-                         trans_a=True).astype(dv_ref.dtype)
-        dk_ref[0] = _dot(ds.astype(q_s.dtype), q_s,
-                         trans_a=True).astype(dk_ref.dtype)
-        dq_scr[:] += _dot(ds.astype(k_ref.dtype), k_ref[0])
+        for rows, cols, q_s, do, k, p_dv, ds in _bwd_block_strips(
+                q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, masked,
+                0, ki, sm_scale, block_q, block_kv, causal,
+                query_offset, dropout_rate, seed_ref, 1, num_kv,
+                bias_ref, bhi):
+            dv_ref[0, cols] = _dot(p_dv.astype(do.dtype), do,
+                                   trans_a=True).astype(dv_ref.dtype)
+            dk_ref[0, cols] = _dot(ds.astype(q_s.dtype), q_s,
+                                   trans_a=True).astype(dk_ref.dtype)
+            dq_scr[rows] += _dot(ds.astype(k.dtype), k)
 
     _masked_dispatch(_block, 0, ki, block_q, block_kv, causal,
                      query_offset)
@@ -543,6 +691,10 @@ FUSED_BWD_BLOCK_KV = 512
 FUSED_BWD_WIDE_VMEM_LIMIT = 32 * 1024 * 1024
 
 
+def _stacked(parts):
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+
+
 def _lanes(width: int) -> int:
     return -(-width // 128) * 128
 
@@ -566,24 +718,26 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         dq_ref[0] = jnp.zeros_like(dq_ref[0])
 
+    steps = _staircase(block_q, block_kv, causal, query_offset)
+
     def _compute(qi, dk_acc, dv_acc, masked):
         sl = pl.ds(qi * block_q, block_q)
         q = q_ref[0, sl, :]
-        do = do_ref[0, sl, :]
-        lse = lse_ref[0, sl, :]
-        delta = delta_ref[0, sl, :]
         q_s = (q.astype(jnp.float32) * sm_scale).astype(q.dtype)
-        s = _dot(q_s, k, trans_b=True)
-        if masked:
-            s = jnp.where(
-                _causal_mask(qi, ki, block_q, block_kv, query_offset),
-                s, NEG_INF)
-        p = jnp.exp(s - lse)
-        dp = _dot(do, v, trans_b=True)
-        ds = p * (dp - delta)
-        return (dk_acc + _dot(ds.astype(q_s.dtype), q_s, trans_a=True),
-                dv_acc + _dot(p.astype(do.dtype), do, trans_a=True),
-                _dot(ds.astype(k.dtype), k))
+        mask = _causal_mask(qi, ki, block_q, block_kv, query_offset) \
+            if masked and not steps else None
+        dks, dvs, dq_blk = [], [], None
+        for rows, _, q_r, do, k_c, p, ds in _bwd_strips(
+                q_s, k, v, do_ref[0, sl, :], lse_ref[0, sl, :],
+                delta_ref[0, sl, :], steps if masked else (), mask):
+            dks.append(_dot(ds.astype(q_r.dtype), q_r, trans_a=True))
+            dvs.append(_dot(p.astype(do.dtype), do, trans_a=True))
+            dq = _dot(ds.astype(k_c.dtype), k_c)        # rows lo:
+            lo = rows.start or 0
+            dq_blk = dq if dq_blk is None else jnp.concatenate(
+                [dq_blk[:lo], dq_blk[lo:] + dq])
+        # a staircase's strips stack to the block's keys
+        return (dk_acc + _stacked(dks), dv_acc + _stacked(dvs), dq_blk)
 
     def _body(qi, carry):
         dk_acc, dv_acc = carry
@@ -614,6 +768,38 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dv_ref[0] = dv_acc.astype(dv_ref.dtype)
 
 
+def _fused_backward_fits(sq, skv, d, d_v, itemsize):
+    """Whether ``_bwd_fused_kernel`` can hold the shape's resident
+    tensors in its VMEM budget."""
+    if sq % FUSED_BWD_BLOCK_Q or skv % FUSED_BWD_BLOCK_KV:
+        return False
+    # q and do (the out-cotangent) are resident at the input dtype,
+    # dq at fp32, lse+delta at fp32 — fp32 inputs must not sneak past
+    # a bf16-sized estimate into a Mosaic allocation failure
+    if d % 128 and d > 128:
+        # a width between lane multiples (192) sits in VMEM padded to
+        # the next one; the chip's compiler counted 16.55 MB for
+        # s=4096 at 192/128 against its default 16 MB of scoped VMEM,
+        # so this case reckons with padded lanes and asks for more
+        return sq * (_lanes(d) * (itemsize + 4) + d_v * itemsize + 8) \
+            <= FUSED_BWD_WIDE_VMEM_LIMIT // 4
+    return sq * (d * (itemsize + 4) + d_v * itemsize + 8) \
+        <= FUSED_BWD_RESIDENT_BUDGET
+
+
+def _backward_plan(sq, skv, d, d_v, itemsize, block_q, block_kv, plain):
+    """``(fused, block_q, block_kv)``: whether the backward is the
+    fused kernel's (several q blocks that fit its VMEM budget,
+    ``plain``: it regenerates no dropout mask and has no bias
+    plumbing), and the blocks it scores at: that kernel's own, else
+    the forward's (the one-pass kernel of a single q block, the split
+    pair)."""
+    if sq // block_q > 1 and plain and \
+            _fused_backward_fits(sq, skv, d, d_v, itemsize):
+        return True, FUSED_BWD_BLOCK_Q, FUSED_BWD_BLOCK_KV
+    return False, block_q, block_kv
+
+
 def _flash_backward_fused(q, k, v, g, lse, delta, sm_scale, causal,
                           query_offset):
     """Dispatch wrapper for ``_bwd_fused_kernel``; returns None when
@@ -622,26 +808,13 @@ def _flash_backward_fused(q, k, v, g, lse, delta, sm_scale, causal,
     bh, sq, d = q.shape
     skv, d_v = k.shape[1], v.shape[2]
     bq, bkv = FUSED_BWD_BLOCK_Q, FUSED_BWD_BLOCK_KV
-    if sq % bq or skv % bkv:
+    if not _fused_backward_fits(sq, skv, d, d_v,
+                                jnp.dtype(q.dtype).itemsize):
         return None
-    # q and do (the out-cotangent) are resident at the input dtype,
-    # dq at fp32, lse+delta at fp32 — fp32 inputs must not sneak past
-    # a bf16-sized estimate into a Mosaic allocation failure
-    itemsize = jnp.dtype(q.dtype).itemsize
-    resident = sq * (d * (itemsize + 4) + d_v * itemsize + 8)
     params = {}
     if d % 128 and d > 128:
-        # a width between lane multiples (192) sits in VMEM padded to
-        # the next one; the chip's compiler counted 16.55 MB for
-        # s=4096 at 192/128 against its default 16 MB of scoped VMEM,
-        # so this case reckons with padded lanes and asks for more
-        resident = sq * (_lanes(d) * (itemsize + 4) + d_v * itemsize + 8)
         params["compiler_params"] = pltpu.CompilerParams(
             vmem_limit_bytes=FUSED_BWD_WIDE_VMEM_LIMIT)
-        if resident > FUSED_BWD_WIDE_VMEM_LIMIT // 4:
-            return None
-    elif resident > FUSED_BWD_RESIDENT_BUDGET:
-        return None
     # the resident tensors' block index never changes within one bh —
     # single-buffer them so the pipeline does not allocate a useless
     # second copy of the largest VMEM tenants
@@ -769,7 +942,7 @@ def _flash_backward(res, g, sm_scale, causal, query_offset, block_q,
         # the fused kernel tiles at its own internal block sizes, so
         # its regenerated dropout masks could not match the forward's
         # (and it has no bias plumbing) — those cases use the split
-        # pair below instead
+        # pair below instead (:func:`_backward_plan` says the same)
         fused = _flash_backward_fused(q, k, v, g, lse, delta, sm_scale,
                                       causal, query_offset)
         if fused is not None:

@@ -121,6 +121,9 @@ def _flash_cases():
     def biased(q, k, v, bias):
         return fa.flash_attention(q, k, v, causal=False, bias=bias)
 
+    def causal_biased(q, k, v, bias):
+        return fa.flash_attention(q, k, v, causal=True, bias=bias)
+
     def with_lse(q, k, v):
         out, lse = fa.flash_attention_with_lse(q, k, v)
         return out.astype(jnp.float32).sum() + lse.sum()
@@ -129,26 +132,36 @@ def _flash_cases():
         "fwd": (fa.flash_attention, {}),
         "fwd_bwd": (_grad_sum(fa.flash_attention, (0, 1, 2)), {}),
         "dropout_fwd_bwd": (_grad_sum(dropout, (0, 1, 2)), {}),
+        # several q blocks with dropout: each block's bits sliced by
+        # the forward's staircase and by the split backward pair's
+        "dropout_s2048": (_grad_sum(dropout, (0, 1, 2)),
+                          dict(b=2, s=2048)),
         "d128_s2048": (_grad_sum(fa.flash_attention, (0, 1, 2)),
                        dict(b=2, s=2048, h=8, d=128)),
         "s8192": (_grad_sum(fa.flash_attention, (0, 1, 2)),
                   dict(b=1, s=8192)),
         "noncausal_bias": (_grad_sum(biased, (0, 1, 2)),
                            dict(bias=True)),
+        # several q blocks with a bias: the causal staircase in the
+        # forward and in the split backward pair, the bias tile sliced
+        "causal_bias_s2048": (_grad_sum(causal_biased, (0, 1, 2)),
+                              dict(bias=True, b=2, s=2048)),
         "with_lse": (jax.grad(with_lse, (0, 1, 2)), {}),
     }
 
 
 @pytest.mark.parametrize("case", [
-    "fwd", "fwd_bwd", "dropout_fwd_bwd", "d128_s2048", "s8192",
-    "noncausal_bias", "with_lse"])
+    "fwd", "fwd_bwd", "dropout_fwd_bwd", "dropout_s2048", "d128_s2048",
+    "s8192",
+    "noncausal_bias", "causal_bias_s2048", "with_lse"])
 def test_flash_training_compiles_on_one_chip(case, one_chip, as_tpu):
     fn, kw = _flash_cases()[case]
     kw = dict(kw)
     has_bias = kw.pop("bias", False)
     args = _qkv(one_chip, **kw)
     if has_bias:
-        args.append(_sds((B, 1, 1, S), jnp.float32, one_chip))
+        args.append(_sds((kw.get("b", B), 1, 1, kw.get("s", S)),
+                         jnp.float32, one_chip))
     assert "tpu_custom_call" in _compile(fn, *args).as_text()
 
 

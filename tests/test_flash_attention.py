@@ -1315,3 +1315,202 @@ def test_mesh_indivisible_and_decode_take_the_counted_dense_path():
         assert reg.counter("attention/flash_decode_ragged") == 0
     finally:
         metrics.set_enabled(False)
+
+
+# -- the causal staircase ------------------------------------------------
+#
+# A diagonal-crossing causal block is walked as a staircase of
+# CAUSAL_SUBTILE sub-tiles (flash_attention.py::_staircase): every case
+# holds forward, log-sum-exp and dq / dk / dv to the dense XLA path.
+
+def _dense_lse(q, k, sm_scale):
+    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
+                   k.astype(jnp.float32)) * sm_scale
+    keep = jnp.tril(jnp.ones(s.shape[-2:], bool))
+    return jax.nn.logsumexp(jnp.where(keep, s, -jnp.inf), axis=-1)
+
+
+def _dense_dropout_oracle(q, k, v, seed, rate, block):
+    """Causal attention with the keep mask the interpret-mode kernels
+    draw: block (b, qi, ki) of head-row ``b`` is
+    ``_interpret_random_bits(seed, (b * n + qi) * n + ki)``, a lane
+    kept iff its bits fall under the threshold."""
+    from paddlefleetx_tpu.ops.pallas import flash_attention as fa
+    b, s, h, d = q.shape
+    n = s // block
+    keep = jnp.concatenate([jnp.concatenate([
+        jnp.stack([fa._interpret_random_bits(
+            seed[0], (bh * n + qi) * n + ki, block, block)
+            for bh in range(b * h)]) for ki in range(n)], axis=2)
+        for qi in range(n)], axis=1) < fa._dropout_threshold(rate)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * d ** -0.5
+    scores = jnp.where(jnp.tril(jnp.ones((s, s), bool)), scores, -1e30)
+    p = jax.nn.softmax(scores, axis=-1)
+    p = jnp.where(keep.reshape(b, h, s, s), p / (1.0 - rate), 0.0)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+_STAIR_CASES = {
+    # s, heads, d, d_v, dtype, kwargs, tile share of forward + backward
+    # one 1024 block a head: whole forward, staircase in the one-pass
+    # backward (_bwd_combined_kernel)
+    "s1024_one_block": (1024, 2, 64, 64, jnp.float32, {}, 26 / 32),
+    # auto blocks at s=2048: diagonal and interior forward blocks, the
+    # forward's staircase, _bwd_fused_kernel at its 512 blocks
+    "s2048_auto_blocks": (2048, 1, 64, 64, jnp.float32, {},
+                          (36 + 36) / (48 + 40)),
+    # q/k of 192 over v of 128 (latent attention)
+    "s1024_mla": (1024, 1, 192, 128, jnp.float32, {}, 26 / 32),
+    "s1024_bf16": (1024, 2, 64, 64, jnp.bfloat16, {}, 26 / 32),
+    # unequal blocks keep the masked whole block
+    "unequal_blocks": (1024, 1, 64, 64, jnp.float32,
+                       dict(block_q=1024, block_kv=512), 1.0),
+    # a bias sends several q blocks to the split pair (_bwd_dkv_kernel,
+    # _bwd_dq_kernel): staircase in the forward and in both
+    "s2048_bias_split_pair": (2048, 1, 64, 64, jnp.float32,
+                              dict(bias=True), 36 / 48),
+}
+
+
+@pytest.mark.parametrize("case", list(_STAIR_CASES) + ["s2048_dropout"])
+def test_causal_staircase_matches_dense(case, monkeypatch):
+    from paddlefleetx_tpu.ops.pallas import flash_attention as fa
+    masks = []
+    diagonal_mask = fa._diagonal_mask
+    monkeypatch.setattr(fa, "_diagonal_mask", lambda *a: (
+        masks.append(a), diagonal_mask(*a))[1])
+    rng = np.random.default_rng(23)
+    if case == "s2048_dropout":
+        # forward and backward (the split pair) slice ONE mask a block:
+        # the gradients of a fixed seed are the dense oracle's
+        s, rate = 2048, 0.25
+        q, k, v = (jnp.asarray(rng.normal(size=(1, s, 1, 64)),
+                               jnp.float32) for _ in range(3))
+        key = jax.random.key(5)
+        seed = jax.random.randint(key, (1,), 0, 2 ** 31 - 1,
+                                  dtype=jnp.int32)
+
+        def flash(q, k, v):
+            return fa.flash_attention(q, k, v, dropout_rate=rate,
+                                      dropout_rng=key)
+
+        def dense(q, k, v):
+            return _dense_dropout_oracle(q, k, v, seed, rate, 1024)
+        atol, gtol = 2e-5, 1e-3
+    else:
+        s, h, d, d_v, dtype, kw, share = _STAIR_CASES[case]
+        kw = dict(kw)
+        q, k, v = (jnp.asarray(rng.normal(size=(1, s, h, w)), dtype)
+                   for w in (d, d, d_v))
+        bias = None
+        if kw.pop("bias", False):
+            valid = np.ones((1, 1, 1, s), bool)
+            valid[..., 700:900] = False
+            bias = jnp.where(jnp.asarray(valid), 0.0, -1e9)
+            kw["bias"] = bias
+        blocks = fa.check_shapes(s, s, d, kw.get("block_q"),
+                                 kw.get("block_kv"), d_v=d_v)
+        executed, whole = fa.causal_step_elements(
+            s, s, d, d_v, q.dtype.itemsize, *blocks, plain=bias is None)
+        assert executed / whole == pytest.approx(share)
+
+        def flash(q, k, v):
+            return fa.flash_attention(q, k, v, **kw)
+
+        def dense(q, k, v):
+            return _xla_attention(q, k, v, bias, True, 0, 0.0, None,
+                                  True, True, sm_scale=d ** -0.5)
+        # bf16: the tolerances of
+        # test_bf16_training_dtype_matches_xla_within_tolerance
+        atol, gtol = (5e-2, 0.5) if dtype == jnp.bfloat16 \
+            else (2e-5, 1e-3)
+        if d == d_v and bias is None:
+            _, lse = fa.flash_attention_with_lse(
+                q, k, v, **{n: kw[n] for n in kw})
+            np.testing.assert_allclose(
+                np.asarray(lse), np.asarray(_dense_lse(q, k, d ** -0.5)),
+                atol=5e-2 if dtype == jnp.bfloat16 else 2e-5,
+                rtol=1e-2 if dtype == jnp.bfloat16 else 2e-5)
+
+    def loss(fn):
+        return lambda q, k, v: (fn(q, k, v).astype(jnp.float32)
+                                ** 2).sum()
+    np.testing.assert_allclose(
+        np.asarray(flash(q, k, v), np.float32),
+        np.asarray(dense(q, k, v), np.float32), atol=atol, rtol=atol)
+    gf = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(gf, gr):
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b, np.float32),
+            atol=gtol, rtol=0.1 if gtol > 0.1 else gtol)
+    # the staircase's sub-tile mask was built iff the case engages it
+    assert bool(masks) == (case != "unequal_blocks")
+
+
+def test_causal_tile_counts():
+    """The pure helper the kernels' loops and the counters share."""
+    from paddlefleetx_tpu.ops.pallas import flash_attention as fa
+    tile = fa.CAUSAL_SUBTILE ** 2
+    steps = fa._staircase(1024, 1024)
+    assert steps == ((0, 256), (256, 512), (512, 768), (768, 1024))
+    assert fa.causal_score_elements(1024, 1024, 1024, 1024, steps) \
+        == (10 * tile, 16 * tile)
+    # s=4096 in 1024 blocks: 6 interior blocks whole, 4 diagonal ones
+    # as staircases
+    assert fa.causal_score_elements(
+        4096, 4096, 1024, 1024, fa._forward_staircase(1024, 1024, 4)) \
+        == (136 * tile, 160 * tile)
+    # a head that is one block keeps the whole block in the forward
+    assert fa._forward_staircase(1024, 1024, 1) == ()
+    # where it cannot be static it does not engage: unequal blocks, a
+    # query offset, a block that is one sub-tile, no causal mask
+    assert fa._staircase(1024, 512) == fa._staircase(256, 256) == ()
+    assert fa._staircase(1024, 1024, True, 128) == ()
+    assert fa._staircase(1024, 1024, False) == ()
+    executed, whole = fa.causal_score_elements(
+        1024, 1024, 1024, 512, fa._staircase(1024, 512))
+    assert executed == whole == 1024 * 1024
+    # forward + backward of a step: one block a head, only the one-pass
+    # backward engages; s=4096 at 192/128, the fused backward's 512
+    assert fa.causal_step_elements(1024, 1024, 64, 64, 2, 1024, 1024) \
+        == (26 * tile, 32 * tile)
+    assert fa.causal_step_elements(4096, 4096, 192, 128, 2, 1024, 1024) \
+        == ((136 + 136) * tile, (160 + 144) * tile)
+    # in-kernel dropout or a bias keeps several q blocks on the split
+    # pair, at the forward's blocks
+    assert fa.causal_step_elements(4096, 4096, 192, 128, 2, 1024, 1024,
+                                   plain=False) \
+        == (2 * 136 * tile, 2 * 160 * tile)
+
+
+def test_causal_staircase_counters():
+    from paddlefleetx_tpu.observability import metrics
+    from paddlefleetx_tpu.ops.attention import dot_product_attention
+    reg = metrics.get_registry()
+    metrics.set_enabled(True)
+    reg.reset()
+    try:
+        q, k, v = _rand(s=1024, h=1)
+        jax.eval_shape(lambda *a: dot_product_attention(
+            *a, causal=True, use_flash=True), q, k, v)
+        assert reg.counter("attention/flash") == 1
+        assert reg.counter("attention/flash_causal_staircase") == 1
+        assert reg.counter("attention/flash_causal_whole_block") == 0
+        assert reg.gauge("attention/flash_causal_tile_share") == 26 / 32
+        # a non-causal call has no diagonal: it counts neither
+        q, k, v = _rand(s=2048, h=1)
+        jax.eval_shape(lambda *a: dot_product_attention(
+            *a, causal=False, use_flash=True), q, k, v)
+        assert reg.counter("attention/flash") == 2
+        assert reg.counter("attention/flash_causal_staircase") == 1
+        assert reg.counter("attention/flash_causal_whole_block") == 0
+        # a block of one sub-tile is computed whole
+        q, k, v = _rand(s=256, h=1)
+        jax.eval_shape(lambda *a: dot_product_attention(
+            *a, causal=True, use_flash=True), q, k, v)
+        assert reg.counter("attention/flash_causal_whole_block") == 1
+        assert reg.gauge("attention/flash_causal_tile_share") == 1.0
+    finally:
+        metrics.set_enabled(False)
+        reg.reset()
