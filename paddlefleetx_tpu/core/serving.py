@@ -49,8 +49,11 @@ block on the swap, and ``export_prefix_store`` /
 
 Speculative decoding (``GenerationConfig.spec_method``/``spec_tokens``):
 decode at small batch is latency-bound on the per-step collectives, so
-the tick instead drafts ``k`` tokens per slot from a host draft source
-(``core/spec.py`` — n-gram self-speculation by default), scores the
+the tick instead takes ``k`` drafted tokens per slot from a draft
+source (``core/spec.py``: a host object the server asks before the
+launch — n-gram self-speculation — or the model's own
+multi-token-prediction block, which drafts inside the tick program),
+scores the
 whole ``[slots, k+1]`` window in ONE jitted forward (``verify_step``'s
 within-window causal mask over the same ragged/paged attention), and
 commits the per-slot accepted prefix — 1..k+1 tokens per tick, so
@@ -110,7 +113,11 @@ has read. The scheduler's contract under it:
   tick old at the launch: page growth and copy-on-write are decided
   at ``cur_len + ahead``, the position THIS launch writes, and the
   commit's trim keeps the page the launch in flight is writing into
-  (at a page boundary it holds nothing committed yet).
+  (at a page boundary it holds nothing committed yet). A verify tick
+  commits 1..k+1 tokens, so under a source on the device the length
+  the device holds is only bounded: pages are mapped from ``cur_len +
+  ahead`` up to ``cur_len + (k + 1) * ahead`` plus the window, and the
+  commit's trim returns what was not used.
 - **What must see a request's newest token reads first** (a flush,
   ``serving/harvest_flushed/<why>``): :meth:`~GenerationServer.drain`
   (and every step while draining: ``max_ticks`` counts ticks),
@@ -122,9 +129,11 @@ has read. The scheduler's contract under it:
   void), and the KV handoff's and spill tier's page reads, which are
   device programs queued behind the tick in flight and read registry
   pages no decode tick writes.
-- **Who never defers.** Speculation drafts from the newest committed
-  tokens, so ``spec_method`` reads every launch at once
-  (``harvest_flushed/spec``); so does ``device_loop_ticks > 1``
+- **Who never defers.** A HOST draft source proposes from the newest
+  committed tokens, so its server reads every launch at once
+  (``harvest_flushed/spec``); a source on the device
+  (``spec_method="mtp"``) needs nothing from the tick in flight and
+  defers like a plain server. ``device_loop_ticks > 1`` reads at once too
   (``harvest_flushed/loop``), whose fused launch already stops itself
   on a finished slot or a spent budget and gives the host back its
   round trip T ticks at a time. Properties the server observes of
@@ -192,9 +201,10 @@ on the device trace's clock, and the seconds of the step's
 the phase of a slow step and, from the driving thread's CPU seconds,
 whether the host was busy or waiting in it; while a profiler session
 runs its counts follow the root onto the trace as one
-``serving/step_account ticks=.. chunks=.. live=..`` point, so a
-trace's reader can tell a step that carried a prefill chunk from one
-that did not and knows the batch each tick ran at
+``serving/step_account ticks=.. chunks=.. live=.. committed=..``
+point, so a trace's reader can tell a step that carried a prefill
+chunk from one that did not, knows the batch each tick ran at and
+what a verify tick committed
 (docs/observability.md, "Host phases").
 """
 
@@ -275,7 +285,8 @@ SLOW_STEP_MIN_HISTORY = 8
 #: the point that puts a step's counts on a profiler session's clock
 #: (``observability/trace.py::point``); no ``serving/step`` or
 #: ``serving/step/*`` pattern matches it
-STEP_ACCOUNT = "serving/step_account ticks=%d chunks=%d live=%d"
+STEP_ACCOUNT = "serving/step_account ticks=%d chunks=%d live=%d " \
+    "committed=%d"
 _thread = threading.local()
 
 
@@ -383,6 +394,11 @@ class Completion:
     #: request never decoded here) — the fleet router aggregates these
     #: into its own latency histogram (core/fleet.py)
     ttft_ms: Optional[float] = None
+    #: a speculative server's drafts for this request, each ``(i, d)``:
+    #: the source proposed ``d`` for ``tokens[i]`` (the verify tick
+    #: whose sampled token is ``tokens[i - 1]``), accepted or not; a
+    #: re-admitted request's run on. None without speculation.
+    drafts: Optional[List[Tuple[int, int]]] = None
 
 
 class GenerationServer:
@@ -573,13 +589,32 @@ class GenerationServer:
         self.model, self.params = model, params
         self.gen_cfg = gen_cfg
         self.num_slots = num_slots
-        # speculative decoding: the host draft source proposes, the
-        # jitted verify_step scores/commits; spec-off is the plain
+        # speculative decoding: the draft source proposes (a host
+        # object, or the model's own block inside the tick program),
+        # the jitted verify_step scores/commits; spec-off is the plain
         # decode_step tick
         self.spec = gen_cfg.spec_method is not None
         self._spec_k = gen_cfg.spec_tokens
-        self._draft = make_draft_source(gen_cfg.spec_method) \
+        self._draft = make_draft_source(gen_cfg.spec_method, model=model) \
             if self.spec else None
+        #: the source drafts inside the tick program: the host fills no
+        #: draft array and has no reason to read a launch at once
+        self._device_draft = getattr(self._draft, "on_device", False)
+        if self._device_draft:
+            if self._spec_k != self._draft.tokens:
+                raise ValueError(
+                    f"spec_tokens ({self._spec_k}) must be what the "
+                    f"model's multi-token-prediction blocks draft a "
+                    f"tick ({self._draft.tokens})")
+            if not self.paged or self._prefix_sharing or \
+                    self._loop_ticks > 1:
+                raise ValueError(
+                    "spec_method='mtp' is served paged, one tick a "
+                    "launch and without prefix sharing: the block's "
+                    "cache is prefilled beside the model's own, and a "
+                    "fused launch read late is not implemented")
+        if self.spec:
+            metrics.inc("serving/spec_source/" + gen_cfg.spec_method)
         self._spec_drafted = 0
         self._spec_accepted = 0
         self._max_prompt = cfg.max_position_embeddings - gen_cfg.max_dec_len
@@ -1403,6 +1438,15 @@ class GenerationServer:
                           np.int32)
             real = min(self._chunk, L - c0)
             row[0, :real] = seq[c0:c0 + real]
+            shifted = None
+            if self._device_draft:
+                # the block at position i reads token i + 1: the same
+                # row a token on (the prompt's last position has none
+                # yet: the first tick folds it)
+                shifted = np.full_like(row, self.gen_cfg.pad_token_id)
+                nxt = seq[c0 + 1:c0 + real + 1]
+                shifted[0, :len(nxt)] = nxt
+                metrics.inc("serving/mtp_positions/prefill", len(nxt))
             self._sync_pt()
         with annotate("serving/step/prefill_dispatch", ph):
             self._cache, logits = prefill_chunk_paged(
@@ -1411,7 +1455,9 @@ class GenerationServer:
                 self._pt_dev[slot:slot + 1],
                 jnp.asarray([int(self._aid_np[slot])], jnp.int32)
                 if self._adapters is not None else None,
-                jnp.asarray([real], jnp.int32))
+                jnp.asarray([real], jnp.int32),
+                None if shifted is None else
+                (jnp.asarray(shifted), jnp.asarray([slot], jnp.int32)))
         with annotate("serving/step/prefill_pump", ph):
             req["prefill_pos"] = c0 + self._chunk
             if self._state_layers and c0 == 0:
@@ -1570,24 +1616,28 @@ class GenerationServer:
         """``(slot, request, column)`` of every page the launch's
         write window still wants: each live slot's next ``window``
         positions (one for a plain tick, k+1 a verify tick) start at
-        ``cur_len + ahead``, the length the DEVICE holds (``ahead``
-        counts the row's launches the host has not read: 1 while a
-        one-tick launch is in flight, the only kind ever left unread,
-        and the host's ``cur_len`` then a tick old; 0 whenever a
-        speculative or fused server maps pages), and must land in
+        the length the DEVICE holds: ``cur_len + ahead`` at least
+        (``ahead`` counts the row's launches the host has not read: 1
+        while a one-tick launch is in flight, the only kind ever left
+        unread, and the host's ``cur_len`` then a tick old; 0 whenever
+        a host-drafting or fused server maps pages) and, where the
+        unread launch is a verify tick that may have committed k+1,
+        ``cur_len + (k + 1) * ahead`` at most; they must land in
         pages the slot owns alone: a column past its pages wants a
         fresh one, a shared page a copy. Columns in rising order a
         slot; at most one page a need."""
         cap = self.model.config.cache_capacity
+        most = (self._spec_k + 1) if self.spec else 1
         out = []
         for slot in live:
             req = self._slots[slot]
             pos = req["cur_len"] + req["ahead"]
+            end = req["cur_len"] + most * req["ahead"] + window
             # length bound enforced at submit; a verify window's tail
             # past capacity clips to capacity - 1 and is never
             # committed (mmax)
             for j in range(pos // self._page,
-                           -(-min(pos + window, cap) // self._page)):
+                           -(-min(end, cap) // self._page)):
                 if j >= req["num_pages"] or self._alloc.refcount(
                         int(self._pt[slot, j])) > 1:
                     out.append((slot, req, j))
@@ -1657,7 +1707,8 @@ class GenerationServer:
                           tokens=req["tokens"], finish_reason=reason,
                           trace_id=self._trace_id(req),
                           ttft_ms=round(req["ttft"] * 1000.0, 3)
-                          if "ttft" in req else None)
+                          if "ttft" in req else None,
+                          drafts=req.get("drafts"))
 
     def preempt(self, request_id: int) -> Optional[Completion]:
         """Cancel a request (client abort / scheduler decision): evict
@@ -1980,7 +2031,7 @@ class GenerationServer:
             rec.cpu_span = now[0] - mark[0]
             rec.cpu_seconds = now[1] - mark[1]
         self.last_step = rec
-        point(STEP_ACCOUNT, rec.ticks, rec.chunks, rec.live)
+        point(STEP_ACCOUNT, rec.ticks, rec.chunks, rec.live, rec.tokens)
         if rec.ticks:
             tick_s = rec.tick_seconds()
             self._tick_time += tick_s
@@ -2193,8 +2244,8 @@ class GenerationServer:
         if T == 1 and self.spec:
             self._cache, self._state, harvest = verify_step(
                 self.model, self.params, self._cache, self._state,
-                jnp.asarray(drafts[:, 0]), self._rng, self.gen_cfg,
-                pt, self._aid_arg())
+                None if drafts is None else jnp.asarray(drafts[:, 0]),
+                self._rng, self.gen_cfg, pt, self._aid_arg())
         elif T == 1:
             self._cache, self._state, harvest = decode_step(
                 self.model, self.params, self._cache, self._state,
@@ -2216,11 +2267,13 @@ class GenerationServer:
     def _read_now(self) -> Optional[str]:
         """Why this server's launches are read in the step that makes
         them, or None where the read waits for the next launch. What
-        the server observes of itself, not an option: speculation
-        drafts from the newest committed tokens; a fused loop's launch
-        stops itself on what the host would otherwise learn a launch
-        late; drain() counts ticks and ends on an empty server."""
-        if self.spec:
+        the server observes of itself, not an option: a host draft
+        source proposes from the newest committed tokens (a source on
+        the device needs nothing from the tick in flight); a fused
+        loop's launch stops itself on what the host would otherwise
+        learn a launch late; drain() counts ticks and ends on an empty
+        server."""
+        if self.spec and not self._device_draft:
             return "spec"
         if self._loop_ticks > 1:
             return "loop"
@@ -2255,7 +2308,7 @@ class GenerationServer:
                 live = self._map_pages(rec, live, eff_ticks * (k + 1))
         if live:
             drafts = None
-            if self.spec:
+            if self.spec and not self._device_draft:
                 with annotate("serving/step/draft", ph):
                     # host drafts ride down with the launch, k per
                     # tick, all proposed from the pre-launch history
@@ -2403,6 +2456,11 @@ class GenerationServer:
                 tick_committed = 0
                 for slot, req in valid:
                     m = int(counts[slot, j])
+                    if k:
+                        at = len(req["tokens"])
+                        req.setdefault("drafts", []).extend(
+                            (at + i, int(window[slot, j, i]))
+                            for i in range(1, k + 1))
                     req["tokens"].extend(
                         int(t) for t in window[slot, j, :m])
                     if "ttft" not in req:
@@ -2426,6 +2484,15 @@ class GenerationServer:
                     self._spec_accepted += accepted
                     metrics.inc("serving/spec_drafted", drafted)
                     metrics.inc("serving/spec_accepted", accepted)
+                    # window columns written and not committed: what
+                    # the next tick writes over
+                    metrics.inc("serving/spec_rollback_columns",
+                                (k + 1) * len(valid) - tick_committed)
+                    if self._device_draft:
+                        # what this tick committed the block folds in
+                        # the next
+                        metrics.inc("serving/mtp_positions/tick",
+                                    tick_committed)
                     self._emit("serving_spec", drafted=drafted,
                                accepted=accepted,
                                committed=tick_committed)
@@ -2443,11 +2510,12 @@ class GenerationServer:
                     # of an early exit, and spec's rejected KV (the
                     # partial page's stale columns sit past cur_len
                     # and are overwritten before any masked read).
-                    # The page a launch still unread writes into
-                    # stays: at a page boundary it holds nothing
-                    # committed yet
+                    # The pages a launch still unread writes into
+                    # stay (k+1 columns of a verify tick): at a page
+                    # boundary they hold nothing committed yet
                     self._trim_pages(slot, req, -(
-                        -(req["cur_len"] + req["ahead"]) // self._page))
+                        -(req["cur_len"] + (k + 1) * req["ahead"])
+                        // self._page))
                 if finished[slot]:
                     self._done.append(self._evict(slot, "eos"))
                 elif dec_count[slot] >= self.gen_cfg.max_dec_len:
@@ -2612,6 +2680,7 @@ class GenerationServer:
                 s[f"{prefix}_p50_ms"] = round(h.percentile(50), 3)
                 s[f"{prefix}_p99_ms"] = round(h.percentile(99), 3)
         if self.spec:
+            s["spec_method"] = self.gen_cfg.spec_method
             s["spec_tokens"] = self._spec_k
             s["spec_drafted"] = self._spec_drafted
             s["spec_accepted"] = self._spec_accepted

@@ -6,14 +6,25 @@ scores the window ``[t0, d_1..d_k]`` in one forward — see
 ``models/gpt/generation.py``). Drafts only affect throughput, never
 output: a wrong draft just wastes its window column.
 
-The shipped source is n-gram self-speculation ("prompt lookup"): match
-the request's trailing n-gram against its own earlier history and
-propose the continuation that followed last time. It needs no second
-model and pays off on the repetitive spans (code, lists, quoted
-context) where speculative decoding wins most. The :class:`DraftSource`
-protocol is deliberately minimal so a small draft-model source (its own
-params + cache, proposing via k greedy steps) can slot in behind the
-same ``GenerationConfig.spec_method`` switch later.
+Two kinds of source stand behind ``GenerationConfig.spec_method``
+(:func:`make_draft_source`):
+
+* a HOST source (:class:`DraftSource`, ``"ngram"``): an object the
+  server asks before every launch, with the request's committed
+  history. The shipped one is n-gram self-speculation ("prompt
+  lookup"): match the request's trailing n-gram against its own
+  earlier history and propose the continuation that followed last
+  time. It needs no second model and pays off on the repetitive spans
+  (code, lists, quoted context) where speculative decoding wins most.
+  It reads the newest committed tokens, so its server reads every
+  launch in the step that made it.
+* a source ON THE DEVICE (:class:`ModelDraftSource`, ``"mtp"``): the
+  model's own multi-token-prediction block drafts inside the tick
+  program (``models/exaone_moe``), from hidden states and a cache of
+  its own that never leave the chip. The server asks the MODEL, not
+  the host: it hands the tick no drafts, and since such a source needs
+  nothing from the tick in flight, its launches are read a step late
+  like a plain server's (``core/serving.py``, "Deferred harvest").
 """
 
 from __future__ import annotations
@@ -73,9 +84,31 @@ class NgramDraftSource:
         return [0] * k
 
 
-def make_draft_source(method: str, **kwargs) -> DraftSource:
-    """Factory behind ``GenerationConfig.spec_method``."""
+class ModelDraftSource:
+    """The model's own multi-token-prediction block as draft source:
+    nothing to ask on the host. ``tokens`` is how many drafts a tick
+    the model's blocks give. A model without such a block refuses."""
+
+    #: the tick program drafts; the host fills no draft array
+    on_device = True
+
+    def __init__(self, model):
+        self.tokens = int(getattr(
+            model.config, "num_nextn_predict_layers", 0))
+        if not self.tokens:
+            raise ValueError(
+                f"spec_method='mtp' needs a model with a multi-token-"
+                f"prediction block; {type(model).__name__} has none "
+                f"(num_nextn_predict_layers)")
+
+
+def make_draft_source(method: str, model=None, **kwargs):
+    """Factory behind ``GenerationConfig.spec_method``: a host
+    :class:`DraftSource`, or for ``"mtp"`` the ``model``'s own
+    :class:`ModelDraftSource`."""
     if method == "ngram":
         return NgramDraftSource(**kwargs)
+    if method == "mtp":
+        return ModelDraftSource(model)
     raise ValueError(
-        f"unknown spec_method {method!r} (supported: 'ngram')")
+        f"unknown spec_method {method!r} (supported: 'ngram', 'mtp')")

@@ -79,6 +79,9 @@ class GenerationConfig:
     #: tokens by suffix match (core/spec.py) and ONE verify forward
     #: scores the whole run (verify_step). The interface is a draft
     #: SOURCE, so a small draft-model method can slot in later.
+    #: "mtp" = the served model's own multi-token-prediction block
+    #: drafts inside the tick program (models/exaone_moe); the server
+    #: refuses it on a model without one.
     spec_method: Optional[str] = None
     #: drafted tokens per verify tick (k); each tick commits
     #: 1..k+1 tokens. Only read when spec_method is set.
@@ -86,10 +89,10 @@ class GenerationConfig:
 
     def __post_init__(self):
         if self.spec_method is not None:
-            if self.spec_method not in ("ngram",):
+            if self.spec_method not in ("ngram", "mtp"):
                 raise ValueError(
                     f"unknown spec_method {self.spec_method!r} "
-                    f"(supported: 'ngram')")
+                    f"(supported: 'ngram', 'mtp')")
             if self.spec_tokens < 1:
                 raise ValueError(
                     f"spec_tokens must be >= 1, got "
@@ -757,8 +760,10 @@ def _verify_tick_impl(model, params, cache, state: SlotState,
     """Trace-level body of one speculative verify tick — the SHARED
     step function of the standalone :func:`verify_step` jit and the
     fused :func:`verify_loop`; see :func:`verify_step` for the full
-    commit semantics."""
-    slots, k = drafts.shape
+    commit semantics. ``drafts`` None: the model drafts itself, from
+    the ``t0`` this tick samples (``draft=True`` of its apply
+    protocol, ``models/exaone_moe``)."""
+    slots = state.lengths.shape[0]
     vocab = model.config.vocab_size
     eos, pad = gen_cfg.eos_token_id, gen_cfg.pad_token_id
     arange_s = jnp.arange(slots)
@@ -799,6 +804,15 @@ def _verify_tick_impl(model, params, cache, state: SlotState,
             f"{gen_cfg.decode_strategy!r}")
     t0 = jnp.where(state.finished | ~state.active,
                    pad, t0).astype(jnp.int32)
+
+    if drafts is None:
+        drafts, mutated = model.apply(
+            {"params": params, "cache": cache}, t0[:, None],
+            use_cache=True, deterministic=True,
+            cache_lengths=state.lengths, page_table=page_table,
+            draft=True, mutable=["cache"])
+        cache = mutated["cache"]
+    k = drafts.shape[1]
 
     # -- one forward over the [slots, k+1] window ---------------------
     window = jnp.concatenate(
@@ -879,7 +893,8 @@ def verify_step(model, params, cache, state: SlotState,
     ``drafts [slots, k]`` are the host draft source's guesses for each
     request's NEXT k tokens AFTER the one this tick samples
     (``core/spec.py``; draft content only affects throughput, never
-    output). The tick:
+    output), or None where the model drafts itself between steps 1
+    and 2 (a source on the device). The tick:
 
     1. samples ``t0`` from ``last_logits`` through exactly
        :func:`decode_step`'s processor/sampling pipeline (same
@@ -1213,7 +1228,8 @@ def init_page_pool(model, params, num_slots: int):
          donate_argnames=("cache",))
 def prefill_chunk_paged(model, params, cache, input_chunk: jax.Array,
                         chunk_start: jax.Array, page_table: jax.Array,
-                        adapter_ids=None, chunk_valid=None):
+                        adapter_ids=None, chunk_valid=None,
+                        draft_inputs=None):
     """One page-aligned chunk of a chunked prefill.
 
     ``input_chunk`` is ``[n, chunk]`` token ids (the tail past the
@@ -1227,7 +1243,10 @@ def prefill_chunk_paged(model, params, cache, input_chunk: jax.Array,
     is keys and values ignores it; one with a recurrent state
     (``models/solar_open2``, ``models/granite_hybrid``) reads
     everything it is fed, and leaves its state where the last real
-    token put it. The chunk's KV scatters straight into its physical
+    token put it. ``draft_inputs`` (a draft source on the device only:
+    ``(next tokens [n, chunk], slots [n])``) has the model prefill its
+    multi-token-prediction block's cache beside its own
+    (``models/exaone_moe``). The chunk's KV scatters straight into its physical
     pages (model.py ``chunk_start`` branch) while the
     queries attend every earlier position through the page-table
     gather. Returns ``(cache, logits)`` with fp32 ``[n, chunk, V]``
@@ -1245,7 +1264,8 @@ def prefill_chunk_paged(model, params, cache, input_chunk: jax.Array,
         position_ids=pos, use_cache=True, deterministic=True,
         chunk_start=chunk_start, page_table=page_table,
         chunk_valid=chunk_valid, adapter_ids=adapter_ids,
-        mutable=["cache"])
+        mutable=["cache"],
+        **({} if draft_inputs is None else {"draft_inputs": draft_inputs}))
     return (_constrain_slot_cache(mutated["cache"]),
             logits.astype(jnp.float32))
 

@@ -36,6 +36,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ...observability import metrics
 from ...ops.attention import (
     dot_product_attention, kv_cache_write, paged_prefill_attention,
 )
@@ -104,12 +105,16 @@ class Attention(nn.Module):
     the model's scale of it: every path, dense, paged prefill and the
     decode kernel, then scores alike with no argument threaded through
     them; the config keeps the factor a power of two, which no float
-    dtype rounds."""
+    dtype rounds. With ``qk_norm`` (``models/exaone_moe``) each
+    head's channels of ``q`` and of ``k`` pass an RMSNorm of their own
+    weight vector (one a layer for the queries, one for the keys)
+    before the rotation, float32 statistics as every norm's."""
     config: SmallThinkerConfig
     rope: bool
     window: bool
     gate: bool = False
     query_scale: float = 1.0
+    qk_norm: bool = False
 
     @nn.compact
     def __call__(self, h, positions, use_cache=False, cache_lengths=None,
@@ -127,6 +132,10 @@ class Attention(nn.Module):
         q = dense((nh, d), "q_proj")(h)
         k = dense((g, d), "k_proj")(h)
         v = dense((g, d), "v_proj")(h)
+        if self.qk_norm:
+            metrics.inc("attention/qk_norm_layers")
+            q = RMSNorm(cfg, name="q_norm")(q)
+            k = RMSNorm(cfg, name="k_norm")(k)
         if self.rope:
             q = apply_rope(q, positions, cfg.rope_theta)
             k = apply_rope(k, positions, cfg.rope_theta)

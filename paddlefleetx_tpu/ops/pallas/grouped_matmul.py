@@ -284,13 +284,36 @@ def _ragged_dw_kernel(tg_ref, x_ref, dy_ref, dw_ref):
     dw_ref[0] += _dot(x_ref[...], dy_ref[...], trans_a=True)
 
 
+#: what the ragged kernel's double-buffered blocks may take of the 16 MB
+#: a v5e core scopes to one kernel; the compiler's own temporaries (the
+#: float32 product before its cast) need the rest
+RAGGED_VMEM_BUDGET = 14 * 2 ** 20
+
+
+def _ragged_block_n(block_m, k_dim, n_dim, block_n, itemsize):
+    """Columns of one step: ``block_n`` at most, halved while the
+    step's blocks (a row tile and a weight block over the WHOLE
+    contraction, the output tile, each double-buffered) outgrow
+    :data:`RAGGED_VMEM_BUDGET`. Every shape served before hidden 6144
+    keeps ``block_n``; a prefill chunk's 128-row tiles against
+    ``[6144, 512]`` weight blocks are 16.7 MB, which the chip's
+    compiler refuses."""
+    bn = _block(n_dim, block_n)
+    while bn > 128 and 2 * itemsize * (
+            block_m * k_dim + k_dim * bn + block_m * bn) \
+            > RAGGED_VMEM_BUDGET:
+        bn = _block(n_dim, bn // 2)
+    return bn
+
+
 def _ragged_forward(x, w, tile_group, tiles_used, block_m, block_n,
                     transpose_rhs):
     """``out[rows of g] = x[rows of g] @ w[g]`` (``w[g]^T`` with
     ``transpose_rhs``) over the occupied tiles."""
     m_rows, k_dim = x.shape
     n_dim = w.shape[1] if transpose_rhs else w.shape[2]
-    bn = _block(n_dim, block_n)
+    bn = _ragged_block_n(block_m, k_dim, n_dim, block_n,
+                         x.dtype.itemsize)
     if transpose_rhs:
         w_spec = pl.BlockSpec((1, bn, k_dim),
                               lambda ni, t, tg: (tg[t], ni, 0))

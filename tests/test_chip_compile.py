@@ -875,3 +875,67 @@ def test_granite_tick_keeps_both_cache_classes_in_place(one_chip, as_tpu):
     assert held == (1 + GH_SLOTS) * cfg.state_row_bytes \
         + 2 * 1665 * 8 * 64 * 128 * 2
     assert compiled.memory_analysis().alias_size_in_bytes >= held
+
+
+# -- one chip: the K-EXAONE serving cell's kernels -----------------------
+#
+# kexaone.serve-reasoning-mtp: 64 slots, every tick a verify tick of
+# two columns; 64 query heads over 8 pooled K/V heads of 128, windows of
+# 128 keys in rings of 6 pages; 16 held experts of 6144 x 2048 picked 8
+# of 128.
+
+KE_SLOTS, KE_H, KE_G, KE_D = 64, 64, 8, 128
+
+
+@pytest.mark.parametrize("reach,pool", [(None, 2305), (128, 1 + 64 * 6)])
+def test_flash_decode_paged_verifies_two_columns_at_eight_heads_a_group(
+        reach, pool, one_chip, as_tpu):
+    """The verify branch at ``W`` = 2 on grouped heads, a global layer's
+    pool and a window layer's ring (the walk starts at the window's
+    first block): by shape, what every layer of the cell's tick runs."""
+    from paddlefleetx_tpu.ops.pallas import flash_attention as fa
+    q = _sds((KE_SLOTS, 2, KE_H, KE_D), BF16, one_chip)
+    off = _sds((KE_SLOTS,), jnp.int32, one_chip)
+    table = _sds((KE_SLOTS, 128), jnp.int32, one_chip)
+    kv = [_sds((pool, KE_G, KE_D, 128), BF16, one_chip)] * 2
+    fn = functools.partial(fa.flash_decode_paged, reach=reach)
+    (ops, used), = _mosaic_calls(_compile(fn, q, *kv, off, table))
+    assert ops == {b"matmul", b"multi_reduction"}
+    counted = fa._paged_vmem_bytes(2, KE_H, KE_D, 128, 2, 2, False, KE_G)
+    assert used <= counted <= 2 * used, (used, counted)
+
+
+def test_kv_write_compiles_at_two_columns_of_eight_pooled_heads(
+        one_chip, as_tpu):
+    """A verify tick's two columns a row into a pool of 8 heads of 128,
+    K and V in one call, both leaves aliased."""
+    from paddlefleetx_tpu.ops.pallas import kv_write as kw
+    shape = (2305, KE_G, KE_D, 128)
+    leaves = [_sds(shape, BF16, one_chip)] * 2
+    news = [_sds((KE_SLOTS, 2, KE_G, KE_D), BF16, one_chip)] * 2
+    idx = _sds((KE_SLOTS, 2), jnp.int32, one_chip)
+    compiled = jax.jit(kw.kv_write, donate_argnums=0).lower(
+        leaves, idx, idx, news).compile()
+    assert _kv_write_calls(compiled) == 1
+    assert compiled.memory_analysis().alias_size_in_bytes >= \
+        2 * math.prod(shape) * 2
+
+
+@pytest.mark.parametrize("rows", [2 * KE_SLOTS, 512])
+def test_ragged_matmul_compiles_at_hidden_6144(rows, one_chip, as_tpu):
+    """The grouped products over 16 held experts at hidden 6144: a
+    verify tick's 128 rows (tiles of 64) and a chunk's 512 (tiles of
+    128, whose ``[6144, 512]`` weight blocks the chip's compiler refused
+    at 16.7 MB of scoped VMEM until the column block adapted)."""
+    from paddlefleetx_tpu.models.deepseek_v3.moe import block_rows
+    from paddlefleetx_tpu.ops.pallas.grouped_matmul import ragged_matmul
+    block = block_rows(rows * 8, 16)
+    tiles = -(-rows * 8 // block) + 16
+    for k, n in ((6144, 4096), (2048, 6144)):
+        x = _sds((tiles * block, k), BF16, one_chip)
+        w = _sds((16, k, n), BF16, one_chip)
+        table = _sds((tiles,), jnp.int32, one_chip)
+        used = _sds((), jnp.int32, one_chip)
+        fn = functools.partial(ragged_matmul, block_m=block)
+        assert "tpu_custom_call" in _compile(
+            fn, x, w, table, used).as_text()

@@ -2962,7 +2962,8 @@ def test_every_step_puts_its_account_after_its_root(
         (b, name), (e, same) = log[i + 1], log[i + 2]
         assert (b, e) == ("B", "E") and name == same
         assert _account_of(name) == {
-            "ticks": rec.ticks, "chunks": rec.chunks, "live": rec.live}
+            "ticks": rec.ticks, "chunks": rec.chunks, "live": rec.live,
+            "committed": rec.tokens}
         ms = rec.phases_ms()
         assert (rec.chunks > 0) == ("prefill_dispatch" in ms)
         assert sum(ms.values()) == pytest.approx(

@@ -611,7 +611,7 @@ def test_a_steps_account_with_no_profiler_session_stays_in_budget():
     n = 10_000
 
     def account():
-        point(STEP_ACCOUNT, 1, 0, 17)
+        point(STEP_ACCOUNT, 1, 0, 17, 17)
     metrics.set_enabled(False)
     cost = {f.__name__: min(timeit.timeit(f, number=n)
                             for _ in range(5)) / n
@@ -754,7 +754,10 @@ def test_server_phases_land_on_the_profilers_clock(tmp_path):
         assert pe - ps < 1e6                    # well under a millisecond
     accounts = [dict(f.split("=") for f in n.split(" ")[1:])
                 for _, _, n in points]
-    assert all(list(a) == ["ticks", "chunks", "live"] for a in accounts)
+    assert all(list(a) == ["ticks", "chunks", "live", "committed"]
+               for a in accounts)
+    assert sum(int(a["committed"]) for a in accounts) == \
+        summ["decode_tokens"]
     assert sum(int(a["ticks"]) for a in accounts) == summ["decode_ticks"]
     assert sum(int(a["chunks"]) for a in accounts) == 4
     assert max(int(a["live"]) for a in accounts) == 2
