@@ -365,18 +365,22 @@ class Engine(BasicEngine):
         mp = self.mesh.shape.get(MP_AXIS, 1)
         mcfg = getattr(getattr(self.module, "model", None), "config",
                        None)
-        if mp > 1 and hasattr(mcfg, "use_collective_matmul"):
-            rings = bool(mcfg.use_collective_matmul and
-                         mcfg.sequence_parallel)
+        if mp > 1 and hasattr(mcfg, "sequence_parallel"):
+            # what the four mp linears of a layer dispatch to
+            # (models/gpt/model.py::_CollectiveDense); a site whose
+            # shapes the rings cannot divide still falls back and
+            # counts mp_linear/gspmd_fallback when it is traced
+            rings = bool(mcfg.sequence_parallel)
             obs_metrics.inc("mp_linear/config/"
                             + ("rings" if rings else "gspmd"))
             logger.info(
                 "tensor-parallel linears (mp=%d): %s", mp,
-                "decomposed collective-matmul rings (overlapped)"
+                "decomposed collective-matmul rings (overlapped) "
+                "wherever the shapes divide over mp"
                 if rings
-                else "plain GSPMD collectives (set "
-                     "use_collective_matmul + sequence_parallel to "
-                     "overlap them; docs/tensor_parallel.md)")
+                else "plain GSPMD collectives (the layer is not "
+                     "sequence-parallel, so there is no seq shard to "
+                     "stream; docs/tensor_parallel.md)")
         if getattr(mcfg, "moe_num_experts", 0):
             mode = mcfg.moe_dispatch
             obs_metrics.inc("moe/config/" + mode)
@@ -1149,8 +1153,7 @@ class Engine(BasicEngine):
         from ..ops.collective_matmul import (
             all_gather_matmul, matmul_reduce_scatter, mp_ring_viable,
         )
-        use_rings = (getattr(mcfg, "use_collective_matmul", False)
-                     and getattr(mcfg, "sequence_parallel", False)
+        use_rings = (getattr(mcfg, "sequence_parallel", False)
                      and mp_ring_viable(mesh, b, seq, (ffn,)))
         seq_s = NamedSharding(mesh, P(DATA_AXES, MP_AXIS, None))
         col_s = NamedSharding(mesh, P(DATA_AXES, None, MP_AXIS))

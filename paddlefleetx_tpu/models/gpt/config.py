@@ -32,16 +32,14 @@ class GPTConfig:
     recompute_granularity: str = "full"
     fused_linear: bool = False            # no-op on TPU: XLA fuses bias
     fuse_attn_qkv: bool = True
+    #: Megatron-SP: activations between the mp linears flow
+    #: sequence-sharded over mp. With mp > 1 on a live mesh the four mp
+    #: linears then run as the decomposed bidirectional-ring kernels
+    #: (ops/collective_matmul.py), whose hops overlap the per-shard
+    #: matmul chunks; a site whose shapes the rings cannot divide takes
+    #: the plain GSPMD lowering — the dispatch matrix is
+    #: docs/tensor_parallel.md.
     sequence_parallel: bool = False
-    #: mp>1: replace the GSPMD all-gather+matmul / matmul+reduce-scatter
-    #: lowering of the column/row-parallel linears with the decomposed
-    #: bidirectional-ring kernels (ops/collective_matmul.py) so the mp
-    #: collectives overlap the per-shard matmul chunks. Requires
-    #: sequence_parallel (the rings stream seq shards); falls back to
-    #: the plain with_logical_constraint path per-site when shapes are
-    #: ring-indivisible, mp == 1, or there is no mesh — the dispatch
-    #: matrix is docs/tensor_parallel.md.
-    use_collective_matmul: bool = False
     virtual_pp_degree: int = 1
     #: pipeline schedule when pp_degree > 1. "1F1B" (reference default,
     #: bounded activation memory via the explicit fwd/bwd-interleaved
@@ -135,7 +133,7 @@ class GPTConfig:
     quant_execution: str = "off"
     #: Multi-tenant LoRA (docs/lora.md). 0 = off — the param tree is
     #: byte-identical to the base model (the ``_CollectiveDense``
-    #: knob-off convention). > 0: every qkv/out-proj/fc1/fc2 site
+    #: convention). > 0: every qkv/out-proj/fc1/fc2 site
     #: grows a stacked adapter pair ``lora_a [A, K, r]`` /
     #: ``lora_b [A, r, N]`` (A = ``lora_num_adapters`` resident bank
     #: rows) and the forward adds ``(alpha/r)·B[id](A[id](x))`` per
@@ -244,21 +242,6 @@ class GPTConfig:
                         "[b, h, s, s] scores will not fit and the "
                         "training module refuses to start."
                         if self.max_position_embeddings >= 4096 else "")
-        # Same no-silent-degradation stance for the overlapped mp
-        # rings: they stream sequence shards, so without Megatron-SP
-        # there is nothing sharded to stream and every site falls back
-        # to the plain GSPMD path. Warn instead of raising — the knob
-        # is a pure perf optimization and the fallback is numerically
-        # identical.
-        if self.use_collective_matmul and not self.sequence_parallel:
-            from ...utils.log import logger
-            logger.warning(
-                "use_collective_matmul=True without sequence_parallel: "
-                "the decomposed collective-matmul rings stream sequence "
-                "shards over mp and are inert without Megatron-SP — "
-                "every linear falls back to the plain GSPMD constraint "
-                "path. Set sequence_parallel: True to enable the "
-                "overlap (docs/tensor_parallel.md).")
         if self.moe_num_experts:
             if not 1 <= self.moe_top_k <= self.moe_num_experts:
                 raise ValueError(
@@ -355,16 +338,6 @@ class GPTConfig:
                     "moe_num_experts > 0: the MoE block replaces the "
                     "fc1/fc2 sites the adapter pair rides on "
                     "(docs/lora.md)")
-        if self.quant_execution != "off" and self.use_collective_matmul:
-            from ...utils.log import logger
-            logger.warning(
-                "quant_execution=%r with use_collective_matmul=True: "
-                "the overlapped mp rings stream fp weight chunks and "
-                "cannot consume the frozen int8 kernels, so quantized "
-                "sites take the int8 GEMM (or its XLA dequant "
-                "fallback) under the plain GSPMD constraint path — "
-                "quantization wins over the rings at shared sites "
-                "(docs/quantization.md).", self.quant_execution)
 
     @property
     def head_dim(self) -> int:

@@ -48,15 +48,20 @@ def _dense_init(cfg: GPTConfig):
 
 
 class _CollectiveDense(nn.Module):
-    """``nn.DenseGeneral`` twin that dispatches its matmul to the
-    overlapped mp rings (``ops/collective_matmul.py``) when viable.
+    """The four mp linears of a decoder layer (qkv, out-proj, fc1,
+    fc2): ``nn.DenseGeneral``'s parameters and product, with the
+    product dispatched to the overlapped mp rings
+    (``ops/collective_matmul.py``) wherever they are viable. Nothing
+    asks for the rings: a sequence-parallel layer on a live mesh with
+    mp >= 2 takes them at every site whose shapes
+    :func:`mp_ring_viable` admits.
 
-    Parameters are created exactly as the DenseGeneral call sites
-    create them — same names ("kernel"/"bias"), shapes, logical axes
-    and init streams — so checkpoints and the abstract-init parameter
-    tree are identical whether the knob is on or off and whether a
-    given call falls back (the engine's batch-1 abstract-init sample
-    always does). Only the compute dispatches:
+    Parameters are created exactly as ``nn.DenseGeneral`` creates
+    them — same names ("kernel"/"bias"), shapes, logical axes and
+    init streams — so checkpoints and the abstract-init parameter
+    tree do not depend on whether a given call takes the rings (the
+    engine's batch-1 abstract-init sample never does). Only the
+    compute dispatches:
 
     - ``mode="column"`` ("embed" contraction, qkv / fc1):
       :func:`all_gather_matmul` — x arrives sequence-sharded
@@ -94,11 +99,10 @@ class _CollectiveDense(nn.Module):
         x, kernel, bias = promote_dtype(x, kernel, bias,
                                         dtype=jnp.dtype(cfg.dtype))
 
-        mesh = None
-        if cfg.use_collective_matmul and cfg.sequence_parallel:
-            from ...parallel.mesh import get_mesh
-            mesh = get_mesh()
-        if mesh is not None:
+        from ...parallel.mesh import MP_AXIS, get_mesh
+        mesh = get_mesh() if cfg.sequence_parallel else None
+        y = None
+        if mesh is not None and mesh.shape.get(MP_AXIS, 1) >= 2:
             from ...ops.collective_matmul import (
                 all_gather_matmul, matmul_reduce_scatter, mp_ring_viable,
             )
@@ -112,28 +116,29 @@ class _CollectiveDense(nn.Module):
                         and mp_ring_viable(
                             mesh, x.shape[0], x.shape[1],
                             (self.features[shard_idx],)):
-                    metrics.inc("mp_linear/rings")
                     y = all_gather_matmul(x, kernel, mesh,
                                           w_shard_dim=shard_idx)
-                    return y + bias
-            else:
-                if self.kernel_axes[0] in MP_WEIGHT_AXES \
-                        and x.ndim == 2 + cn and mp_ring_viable(
-                            mesh, x.shape[0], x.shape[1], (kshape[0],)):
-                    metrics.inc("mp_linear/rings")
-                    y = matmul_reduce_scatter(x, kernel, mesh,
-                                              contract_ndim=cn)
-                    return y + bias
-            # the knob was on but this call site fell off the ring
-            # conditions (docs/tensor_parallel.md) — count it so a
-            # "rings enabled but silently all-GSPMD" run is visible
-            metrics.inc("mp_linear/gspmd_fallback")
-
-        y = jax.lax.dot_general(
-            x, kernel,
-            ((tuple(range(x.ndim - cn, x.ndim)), tuple(range(cn))),
-             ((), ())))
-        return y + bias
+            elif self.kernel_axes[0] in MP_WEIGHT_AXES \
+                    and x.ndim == 2 + cn and mp_ring_viable(
+                        mesh, x.shape[0], x.shape[1], (kshape[0],)):
+                y = matmul_reduce_scatter(x, kernel, mesh,
+                                          contract_ndim=cn)
+            # a sequence-parallel mp site that fell off the ring
+            # conditions (docs/tensor_parallel.md) is counted, so a
+            # "rings expected but silently all-GSPMD" run is visible
+            # (not the batch-1 init sample, whose product never runs)
+            if y is not None:
+                metrics.inc("mp_linear/rings")
+            elif not self.is_initializing():
+                metrics.inc("mp_linear/gspmd_fallback")
+        if y is None:
+            y = jax.lax.dot_general(
+                x, kernel,
+                ((tuple(range(x.ndim - cn, x.ndim)), tuple(range(cn))),
+                 ((), ())))
+        # nn.DenseGeneral's own bias add, op for op
+        return y + jnp.reshape(
+            bias, (1,) * (y.ndim - len(self.features)) + bias.shape)
 
 
 class _QuantDense(nn.Module):
@@ -156,11 +161,10 @@ class _QuantDense(nn.Module):
     weight-only GEMM (``quant/matmul``), fall back PER SITE to the
     XLA dequantize-then-dot (``quant/fallback/kernel_rejected``) —
     the same per-site contract as the attention/moe/mp_linear
-    families. When ``use_collective_matmul`` is also on, this module
-    replaces ``_CollectiveDense`` at the shared sites: the rings
-    stream fp weight chunks and cannot consume frozen int8 kernels,
-    so quantization wins (warned at config construction; dispatch
-    matrix in docs/quantization.md).
+    families. This module replaces ``_CollectiveDense`` at the shared
+    sites: the rings stream fp weight chunks and cannot consume
+    frozen int8 kernels, so quantization wins (dispatch matrix in
+    docs/quantization.md).
     """
     config: GPTConfig
     features: Tuple[int, ...]
@@ -216,7 +220,7 @@ class _LoRADelta(nn.Module):
     (``lora_rank > 0``, docs/lora.md).
 
     Parameter contract — the additive twin of the ``_CollectiveDense``
-    knob-off convention: the base site's ``kernel``/``bias`` (and the
+    convention: the base site's ``kernel``/``bias`` (and the
     int8 ``kernel_scale``) are created by the base modules exactly as
     ever, so knob-off is param-tree-identical; this module adds ONLY
     the sibling pair ``lora_a [A, K, r]`` (normal init) / ``lora_b
@@ -369,19 +373,16 @@ class MultiHeadAttention(nn.Module):
         if cfg.fuse_attn_qkv:
             if quant:
                 # quantization wins over the rings at shared sites
-                # (config.py warns; docs/quantization.md matrix)
+                # (docs/quantization.md matrix)
                 qkv = _QuantDense(
                     cfg, features=(3, nh, hd),
                     kernel_axes=("embed", None, "heads", "kv"),
                     name="qkv_proj")(x)
-            elif cfg.use_collective_matmul:
+            else:
                 qkv = _CollectiveDense(
                     cfg, features=(3, nh, hd),
                     kernel_axes=("embed", None, "heads", "kv"),
                     mode="column", name="qkv_proj")(x)
-            else:
-                qkv = dense((3, nh, hd), "qkv_proj",
-                            (None, "heads", "kv"))(x)
             if cfg.lora_rank:
                 qkv = qkv + _LoRADelta(
                     cfg, features=(3, nh, hd),
@@ -665,19 +666,11 @@ class MultiHeadAttention(nn.Module):
                 cfg, features=(h,),
                 kernel_axes=("heads", "kv", "embed"),
                 contract_ndim=2, name="out_proj")(out)
-        elif cfg.use_collective_matmul:
+        else:
             out = _CollectiveDense(
                 cfg, features=(h,),
                 kernel_axes=("heads", "kv", "embed"),
                 mode="row", contract_ndim=2, name="out_proj")(out)
-        else:
-            out = nn.DenseGeneral(
-                h, axis=(-2, -1), name="out_proj", dtype=dtype,
-                param_dtype=jnp.dtype(cfg.param_dtype),
-                kernel_init=nn.with_logical_partitioning(
-                    _dense_init(cfg), ("heads", "kv", "embed")),
-                bias_init=nn.with_logical_partitioning(
-                    nn.initializers.zeros_init(), ("embed",)))(out)
         if cfg.lora_rank:
             out = out + _LoRADelta(
                 cfg, features=(h,), contract_ndim=2,
@@ -733,19 +726,11 @@ class TransformerDecoderLayer(nn.Module):
                 y = _QuantDense(cfg, features=(cfg.ffn_hidden_size,),
                                 kernel_axes=("embed", "mlp"),
                                 name="linear1")(y)
-            elif cfg.use_collective_matmul:
+            else:
                 y = _CollectiveDense(
                     cfg, features=(cfg.ffn_hidden_size,),
                     kernel_axes=("embed", "mlp"), mode="column",
                     name="linear1")(y)
-            else:
-                y = nn.DenseGeneral(
-                    cfg.ffn_hidden_size, name="linear1", dtype=dtype,
-                    param_dtype=pdtype,
-                    kernel_init=nn.with_logical_partitioning(
-                        _dense_init(cfg), ("embed", "mlp")),
-                    bias_init=nn.with_logical_partitioning(
-                        nn.initializers.zeros_init(), ("mlp",)))(y)
             if cfg.lora_rank:
                 y = y + _LoRADelta(
                     cfg, features=(cfg.ffn_hidden_size,),
@@ -758,19 +743,11 @@ class TransformerDecoderLayer(nn.Module):
                 y = _QuantDense(cfg, features=(cfg.hidden_size,),
                                 kernel_axes=("mlp", "embed"),
                                 name="linear2")(y)
-            elif cfg.use_collective_matmul:
+            else:
                 y = _CollectiveDense(
                     cfg, features=(cfg.hidden_size,),
                     kernel_axes=("mlp", "embed"), mode="row",
                     name="linear2")(y)
-            else:
-                y = nn.DenseGeneral(
-                    cfg.hidden_size, name="linear2", dtype=dtype,
-                    param_dtype=pdtype,
-                    kernel_init=nn.with_logical_partitioning(
-                        _dense_init(cfg), ("mlp", "embed")),
-                    bias_init=nn.with_logical_partitioning(
-                        nn.initializers.zeros_init(), ("embed",)))(y)
             if cfg.lora_rank:
                 y = y + _LoRADelta(
                     cfg, features=(cfg.hidden_size,),

@@ -19,17 +19,30 @@ overlap the per-shard matmul chunks:
 - :func:`matmul_reduce_scatter` (row-parallel, out-proj / fc2): the
   dual — partial products accumulate into two counter-rotating
   accumulators that arrive fully reduced at their destination shard.
+  An accumulator travels in the operands' dtype (bf16 in training, as
+  the all-reduce it replaces) and is added in float32 on arrival; the
+  product a device adds to it is kept out of that add's fusion
+  (:func:`_matmul_rs_ring`), so it runs while the hop is in flight.
 
 Both carry a custom VJP so the backward pass overlaps too: the
 transpose of an all-gather-matmul is a matmul-reduce-scatter and vice
 versa, and the weight gradient streams through the same ring
-(:func:`_ring_visit`). The ring/ppermute idiom and jax-version shims
-follow ``ops/ring_attention.py``.
+(:func:`_ring_visit`); where input and weight gradient circulate the
+same operand (the row-parallel backward) one ring folds both. The
+ring/ppermute idiom follows ``ops/ring_attention.py``.
 
-Dispatch lives in the model (`models/gpt/model.py::_CollectiveDense`
-behind ``use_collective_matmul``); :func:`mp_ring_viable` is the
-single shape gate, pinned by ``tests/test_collective_matmul.py``. The
-matrix is documented in ``docs/tensor_parallel.md``.
+At mp = 2 the ring is one hop between two neighbours: the two
+``ppermute`` directions are the same pair of transfers, 0 -> 1 and
+1 -> 0, which already load both directions of the one link, so there is
+no second direction to split a shard over (v5e 2x2, PR 40: one 16 MB
+hop 0.419 ms, two of 8 MB 0.416, four of 4 MB 0.412).
+
+Dispatch lives in the model (`models/gpt/model.py::_CollectiveDense`):
+a sequence-parallel layer on a live mesh with mp >= 2 takes the rings
+at every site :func:`mp_ring_viable` admits, without being asked. That
+function is the single shape gate, pinned by
+``tests/test_collective_matmul.py``. The matrix is documented in
+``docs/tensor_parallel.md``.
 """
 
 from __future__ import annotations
@@ -55,32 +68,47 @@ def _smap(fn, mesh, in_specs, out_specs):
 
 def _ring_visit(shard, axis_name, fold, init):
     """Bidirectionally circulate ``shard`` over the ring; call
-    ``fold(acc, shard_from_src, src)`` exactly once per ring position
-    — the local shard first, then one hop each way per step, so both
-    ICI directions carry traffic while the previous chunks compute.
+    ``fold(acc, shard_from_src, src, offset)`` exactly once per ring
+    position — the local shard first, then one hop each way per step,
+    so both ICI directions carry traffic while the previous chunks
+    compute. ``src`` is the (traced) ring position the shard came
+    from, ``offset = (src - idx) % n`` the same as a python int.
     """
     n = _axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
-    acc = fold(init, shard, idx)
+    acc = fold(init, shard, idx, 0)
     perm_fwd = [(i, (i + 1) % n) for i in range(n)]
     perm_bwd = [(i, (i - 1) % n) for i in range(n)]
     hops_fwd, hops_bwd = n // 2, (n - 1) // 2
     fwd = bwd = shard
     for i in range(1, hops_fwd + 1):
         fwd = jax.lax.ppermute(fwd, axis_name, perm_fwd)
-        acc = fold(acc, fwd, (idx - i) % n)
+        acc = fold(acc, fwd, (idx - i) % n, n - i)
         if i <= hops_bwd:
             bwd = jax.lax.ppermute(bwd, axis_name, perm_bwd)
-            acc = fold(acc, bwd, (idx + i) % n)
+            acc = fold(acc, bwd, (idx + i) % n, i)
     return acc
 
 
-def _zero_like_varying(shape, dtype, ref):
-    """A zeros array carrying ``ref``'s device-varying type (the
-    ring_attention accumulator trick — required if a future jax build
-    re-enables vma tracking for these rings)."""
-    z = jnp.sum(ref.astype(jnp.float32)) * 0.0
-    return jnp.zeros(shape, dtype) + z.astype(dtype)
+def _in_sequence(chunks, axis_name):
+    """``{offset: [b, s/n, f]}`` (the chunk of ring position
+    ``(idx + offset) % n``) -> ``[b, s, f]`` in sequence order.
+
+    Where a chunk belongs depends on ``axis_index``. Written into a
+    zeroed buffer with ``dynamic_update_slice`` each chunk cost a
+    stand-alone copy, 0.52-0.55 ms a site at the 1.3B cell's widths
+    and 48 ms of its 418 ms step; a dynamic ``jnp.roll`` of the
+    concatenation 0.22 ms. Selected by position it is elementwise and
+    its consumer's fusion (the bias add, gelu's derivative, the head
+    transposes) reads the chunks through it: 0.005-0.009 ms a site
+    over a concatenation at a known place (chip, PR 40).
+    """
+    n = _axis_size(axis_name)
+    idx = jax.lax.axis_index(axis_name)
+    by_offset = [chunks[o] for o in range(n)]
+    return jnp.concatenate(
+        [jax.lax.select_n((p - idx) % n, *by_offset) for p in range(n)],
+        axis=1)
 
 
 # -- per-shard kernels (call under shard_map) ---------------------------
@@ -88,18 +116,11 @@ def _zero_like_varying(shape, dtype, ref):
 def _ag_matmul_ring(x, w, axis_name):
     """Per-shard all-gather-matmul: ``x [b, s/n, k]`` (one seq shard),
     ``w [k, n_l]`` (one output-column shard) -> ``y [b, s, n_l]``."""
-    n = _axis_size(axis_name)
-    b, s_l, _ = x.shape
-    n_l = w.shape[-1]
 
-    def fold(buf, blk, src):
-        chunk = jnp.einsum("bsk,kn->bsn", blk, w)
-        return jax.lax.dynamic_update_slice(buf, chunk,
-                                            (0, src * s_l, 0))
+    def fold(chunks, blk, src, offset):
+        return {**chunks, offset: jnp.einsum("bsk,kn->bsn", blk, w)}
 
-    return _ring_visit(
-        x, axis_name, fold,
-        _zero_like_varying((b, n * s_l, n_l), x.dtype, x))
+    return _in_sequence(_ring_visit(x, axis_name, fold, {}), axis_name)
 
 
 def _matmul_rs_ring(x, w, axis_name):
@@ -107,37 +128,53 @@ def _matmul_rs_ring(x, w, axis_name):
     one contraction shard), ``w [k_l, n]`` -> ``y [b, s/n, n]`` fully
     reduced for this device's seq shard.
 
-    Two counter-rotating fp32 accumulators: the forward one starts
+    Two counter-rotating accumulators: the forward one starts
     ``n//2`` ring positions before its destination and collects a
     partial product at every hop; the backward one covers the
     remaining ``(n-1)//2`` positions from the other side. Each arrives
     at its destination having visited a disjoint device set, so their
     sum is the exact psum — in half the hops of a one-way ring.
+
+    An accumulator and a product have the operands' dtype (a product
+    accumulates in float32 on the MXU and is rounded once, like each
+    operand of the all-reduce this replaces); their add is float32.
+    The product a device adds to an arriving accumulator does not
+    depend on it, and has to stay so in the compiled program: fused
+    into the add (XLA's convolution+add output fusion) the matmul
+    would wait for the wire. The barrier keeps the two apart, so the
+    hop runs under the product.
     """
     n = _axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, s, k_l = x.shape
     s_l = s // n
-    n_out = w.shape[-1]
     perm_fwd = [(i, (i + 1) % n) for i in range(n)]
     perm_bwd = [(i, (i - 1) % n) for i in range(n)]
     hops_fwd, hops_bwd = n // 2, (n - 1) // 2
 
     def partial_for(dst):
         xc = jax.lax.dynamic_slice(x, (0, dst * s_l, 0), (b, s_l, k_l))
-        return jnp.einsum("bsk,kn->bsn", xc, w,
-                          preferred_element_type=jnp.float32)
+        return jnp.einsum("bsk,kn->bsn", xc, w)
 
-    acc_f = _zero_like_varying((b, s_l, n_out), jnp.float32, x)
-    acc_b = _zero_like_varying((b, s_l, n_out), jnp.float32, x)
-    for t in range(hops_fwd + 1):
-        acc_f = acc_f + partial_for((idx + hops_fwd - t) % n)
-        if t < hops_fwd:
-            acc_f = jax.lax.ppermute(acc_f, axis_name, perm_fwd)
+    def add(u, v):
+        return (u.astype(jnp.float32)
+                + v.astype(jnp.float32)).astype(x.dtype)
+
+    def hop(acc, perm, dst):
+        return add(*jax.lax.optimization_barrier(
+            (jax.lax.ppermute(acc, axis_name, perm), partial_for(dst))))
+
+    acc_f = partial_for((idx + hops_fwd) % n)
+    acc_b = partial_for((idx - hops_bwd) % n) if hops_bwd else None
+    for t in range(1, hops_fwd + 1):
+        acc_f = hop(acc_f, perm_fwd, (idx + hops_fwd - t) % n)
         if t < hops_bwd:
-            acc_b = acc_b + partial_for((idx - hops_bwd + t) % n)
-            acc_b = jax.lax.ppermute(acc_b, axis_name, perm_bwd)
-    return (acc_f + acc_b).astype(x.dtype)
+            acc_b = hop(acc_b, perm_bwd, (idx - hops_bwd + t) % n)
+    if hops_bwd:
+        # the last backward hop lands on the destination, whose own
+        # product the forward accumulator already holds
+        acc_f = add(acc_f, jax.lax.ppermute(acc_b, axis_name, perm_bwd))
+    return acc_f
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(2,))
@@ -155,22 +192,21 @@ def _ag_matmul_bwd(axis_name, res, dy):
     # seq-sharded reduction is exactly the matmul-reduce-scatter ring
     # (the transpose duality the module docstring states).
     x, w = res
-    dx = _matmul_rs_ring(dy, w.T, axis_name).astype(x.dtype)
+    dx = _matmul_rs_ring(dy, w.T, axis_name)
 
     # dw [k, n_l] = AG(x)^T @ dy: stream the x shards through the same
     # bidirectional ring, contracting each against its dy rows
     b, s_l, k = x.shape
     n_l = dy.shape[-1]
 
-    def fold(acc, x_blk, src):
+    def fold(acc, x_blk, src, offset):
         dyc = jax.lax.dynamic_slice(dy, (0, src * s_l, 0),
                                     (b, s_l, n_l))
         return acc + jnp.einsum("bsk,bsn->kn", x_blk, dyc,
                                 preferred_element_type=jnp.float32)
 
-    dw = _ring_visit(
-        x, axis_name, fold,
-        _zero_like_varying((k, n_l), jnp.float32, x))
+    dw = _ring_visit(x, axis_name, fold,
+                     jnp.zeros((k, n_l), jnp.float32))
     return dx, dw.astype(w.dtype)
 
 
@@ -188,26 +224,25 @@ def _matmul_rs_fwd(x, w, axis_name):
 
 def _matmul_rs_bwd(axis_name, res, dy):
     # dy [b, s/n, n]: seq-sharded cotangent. dx needs the full seq of
-    # dy against w^T -> the all-gather-matmul ring (dual of fwd).
+    # dy against w^T (the all-gather-matmul ring, dual of fwd) and
+    # dw [k_l, n] = x^T @ AG(dy) the same shards against the matching
+    # seq rows of the resident x: one circulation of dy folds both
     x, w = res
     n = _axis_size(axis_name)
-    dx = _ag_matmul_ring(dy, w.T, axis_name).astype(x.dtype)
-
-    # dw [k_l, n] = x^T @ AG(dy): circulate the dy shards, contract
-    # each against the matching seq rows of the resident x
     b, s, k_l = x.shape
     s_l = s // n
-    n_out = dy.shape[-1]
 
-    def fold(acc, dy_blk, src):
+    def fold(carry, dy_blk, src, offset):
+        dx, dw = carry
         xc = jax.lax.dynamic_slice(x, (0, src * s_l, 0), (b, s_l, k_l))
-        return acc + jnp.einsum("bsk,bsn->kn", xc, dy_blk,
-                                preferred_element_type=jnp.float32)
+        return ({**dx, offset: jnp.einsum("bsn,kn->bsk", dy_blk, w)},
+                dw + jnp.einsum("bsk,bsn->kn", xc, dy_blk,
+                                preferred_element_type=jnp.float32))
 
-    dw = _ring_visit(
+    dx, dw = _ring_visit(
         dy, axis_name, fold,
-        _zero_like_varying((k_l, n_out), jnp.float32, dy))
-    return dx, dw.astype(w.dtype)
+        ({}, jnp.zeros((k_l, dy.shape[-1]), jnp.float32)))
+    return _in_sequence(dx, axis_name), dw.astype(w.dtype)
 
 
 _matmul_rs.defvjp(_matmul_rs_fwd, _matmul_rs_bwd)

@@ -37,6 +37,20 @@ def _restore_global_telemetry():
     metrics.set_enabled(prior)
 
 
+@pytest.fixture
+def plain_gspmd(monkeypatch):
+    """Call the returned function and every mp linear traced after it
+    takes the plain GSPMD lowering: the twin the mp rings
+    (``ops/collective_matmul.py``), which a sequence-parallel layer
+    takes unasked, are held to."""
+    from paddlefleetx_tpu.ops import collective_matmul
+
+    def close():
+        monkeypatch.setattr(collective_matmul, "mp_ring_viable",
+                            lambda *a, **k: False)
+    return close
+
+
 # -- quick tier --------------------------------------------------------
 # `pytest -m "not slow"` is the fast feedback loop (<10 min); the full
 # suite runs everything. Centralized here (not as scattered decorators)

@@ -652,6 +652,100 @@ def test_grouped_matmul_compiles_on_2x2_mesh(mesh4, as_tpu):
     assert txt.count("tpu_custom_call") >= 5
 
 
+# -- the 2x2 mesh: the mp rings of the 1.3B cell ------------------------
+#
+# gpt1p3b.pretrain-mp2-fsdp2: global b16 x s1024 over fsdp2, hidden
+# 2048, 16 heads of 128, ffn 8192, mp 2. What the chip's scheduler
+# made of a ring is the point: a hop that no product covers is a chip
+# waiting on a wire.
+
+def _schedule(compiled):
+    """``[(opcode-or-"matmul", name)]`` of the entry computation in
+    scheduled order: collective starts and dones, and every fusion
+    whose body holds a convolution."""
+    txt = compiled.as_text()
+    comps = {m.group(1): m.start() for m in re.finditer(
+        r"\n(?:ENTRY )?%?([\w.\-]+) \([^\n]*\) -> [^\n]*\{\n", txt)}
+    names = sorted(comps, key=comps.get)
+    body = {n: txt[comps[n]:comps[names[i + 1]] if i + 1 < len(names)
+                   else len(txt)] for i, n in enumerate(names)}
+    matmul = {n for n in names if "convolution(" in body[n]}
+    entry = body[re.search(r"\nENTRY %?([\w.\-]+) ", txt).group(1)]
+    out = []
+    for line in entry.split("\n"):
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = .*? ([a-z\-]+)\(",
+                     line)
+        if not m:
+            continue
+        name, op = m.groups()
+        calls = re.search(r"calls=%([\w.\-]+)", line)
+        if op == "fusion" and calls and calls.group(1) in matmul:
+            out.append(("matmul", name))
+        elif op.startswith(("collective-permute", "all-reduce",
+                            "all-gather", "reduce-scatter")):
+            out.append((op, name))
+    return out
+
+
+def _ring_site(site, mesh4):
+    from paddlefleetx_tpu.ops.collective_matmul import (
+        all_gather_matmul, matmul_reduce_scatter,
+    )
+    rows = ("dp", "fsdp")
+    seq = NamedSharding(mesh4, P(rows, "mp", None))
+    if site == "fc1":
+        return (lambda x, w: all_gather_matmul(x, w, mesh4),
+                _sds((16, 1024, 2048), BF16, seq),
+                _sds((2048, 8192), BF16,
+                     NamedSharding(mesh4, P(None, "mp"))))
+    if site == "fc2":
+        return (lambda x, w: matmul_reduce_scatter(x, w, mesh4),
+                _sds((16, 1024, 8192), BF16,
+                     NamedSharding(mesh4, P(rows, None, "mp"))),
+                _sds((8192, 2048), BF16,
+                     NamedSharding(mesh4, P("mp", None))))
+    return (lambda x, w: matmul_reduce_scatter(x, w, mesh4,
+                                               contract_ndim=2),
+            _sds((16, 1024, 16, 128), BF16,
+                 NamedSharding(mesh4, P(rows, None, "mp", None))),
+            _sds((16, 128, 2048), BF16,
+                 NamedSharding(mesh4, P("mp", None, None))))
+
+
+@pytest.mark.parametrize("backward", [False, True],
+                         ids=["forward", "backward"])
+@pytest.mark.parametrize("site", ["fc1", "fc2", "out_proj"])
+def test_mp_ring_hops_run_under_a_product(site, backward, mesh4,
+                                          as_tpu):
+    """Every hop of a ring at the cell's widths has a product between
+    its ``collective-permute-start`` and its ``-done`` in the chip's
+    schedule, bf16 on the wire (16 MB a hop: the float32 accumulator
+    PR 2 circulated was the 32 MB of the all-reduce it replaced), and
+    no activation all-reduce is left. The reduce-scatter ring fails
+    this without its ``optimization_barrier``: XLA fuses the own-shard
+    product into the add of the arriving accumulator and the matmul
+    waits for the wire."""
+    fn, x, w = _ring_site(site, mesh4)
+    if backward:
+        fn = _grad_sum(fn, (0, 1))
+    with mesh4:
+        compiled = _compile(fn, x, w)
+    sched = _schedule(compiled)
+    ops = [op for op, _ in sched]
+    # the one all-reduce left is the weight gradient's over the rows
+    assert "all-gather" not in ops
+    assert ops.count("all-reduce") == (1 if backward else 0)
+    starts = [i for i, op in enumerate(ops)
+              if op == "collective-permute-start"]
+    assert len(starts) == (2 if backward and site == "fc1" else 1)
+    for i in starts:
+        done = ops.index("collective-permute-done", i)
+        assert "matmul" in ops[i:done], sched
+    wire = re.findall(r"= \((\w+)\[8,512,\d+\]\S* ?, [^\n]*? "
+                      r"collective-permute-start\(", compiled.as_text())
+    assert wire and set(wire) == {"bf16"}, wire
+
+
 # -- one chip: the SmallThinker serving cell's kernels ------------------
 #
 # smallthinker.serve-mixed-len: 48 slots, 28 query heads over 4 pooled

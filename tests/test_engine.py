@@ -515,28 +515,32 @@ def test_profiler_summary_printed(tmp_path, monkeypatch):
     assert any("tokens/s" in l for l in lines)
 
 
-@pytest.mark.parametrize("knob", [False, True],
+@pytest.mark.parametrize("sp", [False, True],
                          ids=["gspmd", "rings"])
 def test_profiler_summary_mp_collective_line(tmp_path, monkeypatch,
-                                             knob):
+                                             sp):
     """mp>1 summaries carry a measured mp-collective line naming the
-    dispatched path (ISSUE 2: recorded alongside 'h2d input wait')."""
+    dispatched path (ISSUE 2: recorded alongside 'h2d input wait'):
+    the rings for a sequence-parallel layer, which asks for nothing
+    else, and the start-up line names no knob."""
     from paddlefleetx_tpu.utils.log import logger as pfx_logger
     lines = []
     monkeypatch.setattr(
         pfx_logger, "info",
         lambda msg, *a, **k: lines.append(msg % a if a else str(msg)))
     overrides = {"Engine.max_steps": 2, "Engine.logging_freq": 1}
-    if knob:
-        overrides.update({"Model.sequence_parallel": True,
-                          "Model.use_collective_matmul": True})
+    if sp:
+        overrides["Model.sequence_parallel"] = True
     cfg, engine, loader = _build(tmp_path, **overrides)
+    start = [l for l in lines if "tensor-parallel linears" in l]
+    assert len(start) == 1 and "use_collective_matmul" not in start[0]
+    assert ("rings" in start[0]) == sp
     engine._step_costs = [0.1, 0.1]
     engine._prof_dir = str(tmp_path / "prof")
     engine._print_summary()
     mp_lines = [l for l in lines if "mp collective" in l]
     assert mp_lines, lines
-    want = "decomposed overlapped rings" if knob \
+    want = "decomposed overlapped rings" if sp \
         else "plain GSPMD all-gather/reduce-scatter"
     assert want in mp_lines[0]
 

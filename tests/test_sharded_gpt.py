@@ -60,11 +60,17 @@ def golden():
     ({"dp_degree": 2, "mp_degree": 2, "sharding_degree": 2,
       "sharding_stage": 3}, {}),
     ({"mp_degree": 4, "dp_degree": 2},
-     {"sequence_parallel": True, "use_collective_matmul": True}),
+     {"sequence_parallel": True, "rings": False}),
 ], ids=["tp4xdp2", "tp4xdp2-sp", "zero3x4xdp2", "dp2xtp2xfsdp2",
-        "tp4xdp2-sp-cm"])
-def test_sharded_matches_single_device(golden, topo_kw, cfg_kw):
+        "tp4xdp2-sp-plain"])
+def test_sharded_matches_single_device(golden, topo_kw, cfg_kw,
+                                       plain_gspmd):
     variables, ids, labels, mask, ref_loss, ref_grads = golden
+    cfg_kw = dict(cfg_kw)
+    if not cfg_kw.pop("rings", True):
+        # a sequence-parallel layer takes the mp rings unasked; with
+        # the gate closed it is the plain GSPMD lowering
+        plain_gspmd()
     topo = TopologyConfig(**topo_kw,
                           sequence_parallel=cfg_kw.get(
                               "sequence_parallel", False))
