@@ -568,8 +568,8 @@ class CallGraph:
 
 
 def modname_for(relpath: str) -> str:
-    """Repo-relative path -> dotted module name (``bench.py`` ->
-    ``bench``; package ``__init__.py`` -> the package)."""
+    """Repo-relative path -> dotted module name (``chip_smoke.py`` ->
+    ``chip_smoke``; package ``__init__.py`` -> the package)."""
     p = relpath[:-3] if relpath.endswith(".py") else relpath
     parts = [seg for seg in p.replace("\\", "/").split("/") if seg]
     if parts and parts[-1] == "__init__":

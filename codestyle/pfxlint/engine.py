@@ -36,7 +36,7 @@ from . import threadgraph
 EXCLUDE_DIRS = {
     ".git", "__pycache__", ".github", ".claude", ".pytest_cache",
     "tests",            # the tier-1 suite lints itself via pytest
-    "output", "bench_log", "profiler_log", "node_modules",
+    "output", "profiler_log", "node_modules",
     # git-ignored copies of the tree a builder unpacks for chip runs
     "_archive", "_parent", "chiprun_out", ".xla_cache",
 }

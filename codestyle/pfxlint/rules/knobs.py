@@ -1,7 +1,7 @@
 """PFX203/PFX204 — every ``PFX_*`` environment knob is documented.
 
-Knobs are the repo's operational API: a bench driver, an SRE, or the
-next session discovers ``PFX_BENCH_MAX_HUNG_PROBES`` only if a doc
+Knobs are the repo's operational API: a driver, an SRE, or the
+next session discovers ``PFX_WATCHDOG_ACTION`` only if a doc
 says it exists. The contract is bidirectional:
 
 - **PFX203** — a ``PFX_*`` name appears as a string literal in code
@@ -14,7 +14,7 @@ Code side: any string constant that IS a knob name (full match) in
 any scanned file — reads through loops like
 ``for var in ("PFX_CACHE_HOME", ...): os.environ.get(var)`` count,
 docstrings never match (a docstring is one big string). Docs side:
-exact tokens only — ``PFX_BENCH_SERVING_*`` style globs are prose
+exact tokens only — ``PFX_WATCHDOG_*`` style globs are prose
 shorthand and satisfy NEITHER direction, so each knob needs its own
 documented line (deleting one line always trips PFX203).
 """

@@ -1,10 +1,9 @@
 """Model-FLOPs and peak-FLOPs accounting — the single source of truth.
 
-The Megatron fwd+bwd formula and the per-chip bf16 peaks used to live
-in ``bench.py`` with a forward-only copy in ``scripts/profile_mfu.py``;
-both now import from here and the engine's in-band MFU
-(``core/engine.py::_print_summary``) uses the same numbers, so the
-banked headline metric and the summary's figure can never drift.
+The Megatron fwd+bwd formula and the per-chip bf16 peaks: the engine's
+in-band MFU (``core/engine.py::_print_summary``) and the tuning scripts
+read them here. The benchmark keeps a count of its own
+(``chipbench/flops*.py``), independent of the program on purpose.
 """
 
 from __future__ import annotations

@@ -61,7 +61,7 @@ _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
 def setup_compilation_cache(cache_dir: Optional[str] = None) -> str:
     """Turn on JAX's persistent compilation cache and return its
     directory. Every entry point that compiles calls this (Engine,
-    the serving/inference tasks, bench.py, chip_smoke.py): a cold
+    the serving/inference tasks, chipbench/run.py, chip_smoke.py): a cold
     compile of the unrolled 24-layer step is minutes, and both
     preempted-and-restarted jobs and every chip-tool call start cold.
 

@@ -1,10 +1,10 @@
 """One-shot flash-attention tuning sweep for the real chip.
 
-Times the Pallas kernel at the bench operating points across block
+Times the Pallas kernel at the training operating points across block
 sizes, against dense XLA attention, fwd and fwd+bwd — one run prints
-the whole decision table, so a returning/scarce TPU allocation yields
-the full tuning picture in a single session (VERDICT r3 #4: the d=64
-exp path is the named single-chip MFU floor).
+the whole decision table, so a scarce TPU allocation yields the full
+tuning picture in a single session (the d=64 exp path is the named
+single-chip MFU floor).
 
 Usage (TPU): ``python scripts/tune_flash.py [--points 345m,longctx,67b]``
 """
@@ -21,7 +21,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 POINTS = {
-    # (batch, heads, seq, head_dim) per microbatch at the bench points
+    # (batch, heads, seq, head_dim) per microbatch
     "345m": (8, 16, 1024, 64),
     "longctx": (1, 16, 8192, 64),
     "67b": (2, 32, 2048, 128),
@@ -81,7 +81,9 @@ def sweep(point: str, b: int, h: int, s: int, d: int):
         return jnp.sum(o.astype(jnp.float32) ** 2)
 
     try:
-        from bench import causal_attn_flops, peak_flops
+        from paddlefleetx_tpu.observability.flops import (
+            causal_attn_flops, peak_flops,
+        )
         floor_ms = causal_attn_flops(b, h, s, d) / peak_flops() * 1e3
     except Exception as e:
         floor_ms = None

@@ -1,6 +1,7 @@
 """Flash-attention kernel semantics, validated on CPU via the Pallas
-interpreter (the real-TPU path is exercised by bench.py and the
-on-device verification runs)."""
+interpreter (the real-TPU path is exercised by the benchmark's cells,
+``chipbench/run.py``, and compiled for the described chip in
+``tests/test_chip_compile.py``)."""
 
 import os
 
