@@ -106,21 +106,17 @@ _SLOW_PATTERNS = (
     "test_engine.py::test_sigterm_during_eval_breaks_out_and_saves",
     "test_engine.py::test_profiler_summary_printed",
     "test_moe.py::test_moe_generation_decodes",
-    # r5: full offline executions of the decode/MoE bench paths
-    "test_bench_harness.py::test_bench_generation_runs_offline",
-    "test_bench_harness.py::test_bench_moe_runs_offline",
     # r6 (measured quick-tier durations): the heaviest remaining
     # round-trips, each still represented in the quick tier by a
     # lighter sibling or enforced by a named CI job — imagen keeps
-    # its cascade/sampling tests, MoE its engine train step, the
-    # measure_train harness its bf16-accum twin, kill-resume
-    # determinism runs full-fidelity in the chaos-smoke CI job, and
+    # its cascade/sampling tests, MoE its engine train step,
+    # kill-resume determinism runs full-fidelity in the chaos-smoke
+    # CI job, and
     # the real-tree lint gate stays via test_real_tree_is_clean /
     # test_real_tree_clean_under_new_rules (the CLI/stats duplicates
     # re-lint the whole repo two more times)
     "test_imagen.py::test_imagen_trains_fsdp_sharded",
     "test_moe.py::test_all_tokens_dropped_is_pure_residual",
-    "test_bench_harness.py::test_measure_train_dropout_rng_threading",
     "test_resilience.py::test_resume_determinism_after_injected_kill",
     "test_pfxlint.py::test_real_tree_suppression_counts_pinned",
     "test_pfxlint.py::test_cli_list_rules_and_clean_exit",

@@ -760,3 +760,63 @@ def test_fit_under_a_profiler_session_annotates_each_step(tmp_path):
     assert inside("engine/log_sync", "train")
     for child in ("h2d/loader_next", "h2d/pretreat", "h2d/device_put"):
         assert len(by[child]) >= 5 and inside(child, "h2d"), child
+
+
+# -- the step shapes a training run can take, through the real step -------
+
+_DROPOUT = {"Model.hidden_dropout_prob": 0.1,
+            "Model.attention_probs_dropout_prob": 0.1,
+            "Model.use_flash_attention": False}
+
+_STEP_SHAPES = {
+    # dropout 0.1 through dense attention: a key folded per microbatch
+    # inside the accumulation scan (4 microbatches), and the one key of
+    # a step without a scan
+    "dropout-accumulate-4": dict(_DROPOUT, **{"Global.micro_batch_size": 2}),
+    "dropout-accumulate-1": dict(_DROPOUT, **{"Global.micro_batch_size": 8}),
+    # the chunked cross-entropy's own forward under dropout
+    "dropout-chunked-ce": dict(_DROPOUT, **{"Model.loss_chunks": 4}),
+    # the capacity MoE layer: router losses under a non-deterministic
+    # apply, summed over the scan's microbatches
+    "dropout-capacity-moe": dict(_DROPOUT, **{
+        "Model.moe_num_experts": 4, "Model.moe_top_k": 2,
+        "Global.micro_batch_size": 4}),
+    # bf16 compute over fp32 master weights: bf16 gradients summed into
+    # the float32 carry of the scan
+    "bf16-accumulate-4": dict(_DROPOUT, **{
+        "Engine.mix_precision.use_pure_fp16": True,
+        "Global.micro_batch_size": 2}),
+    # and through the chunked loss, whose logits never materialise
+    "bf16-chunked-ce": dict(_DROPOUT, **{
+        "Engine.mix_precision.use_pure_fp16": True,
+        "Model.loss_chunks": 4}),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(_STEP_SHAPES))
+def test_train_step_shapes_run_two_steps(tmp_path, shape):
+    """Two optimizer steps of the Engine's own jitted step in each of
+    the shapes a GPT run takes (the dropout key's threading through
+    the accumulation scan, the chunked loss, the MoE objective, mixed
+    precision): the loss is finite at both and the parameters moved."""
+    import flax.linen as nn
+    import jax
+    cfg, engine, loader = _build(tmp_path, **{"Engine.max_steps": 2},
+                                 **_STEP_SHAPES[shape])
+    want_acc = 8 // cfg.Global.micro_batch_size
+    assert engine.accumulate_steps == want_acc
+    batches = iter(loader)
+    before = jax.tree.map(np.asarray, engine.state["params"])
+    state, losses = engine.state, []
+    with engine.mesh, nn.logical_axis_rules(engine.rules):
+        for _ in range(2):
+            state, metrics = engine._train_step(
+                state, engine._put_batch(next(batches)))
+            losses.append(float(metrics["loss"]))
+    assert np.all(np.isfinite(losses)), losses
+    assert int(state["step"]) == 2
+    moved = jax.tree.map(
+        lambda a, b: float(np.max(np.abs(np.asarray(b, np.float32)
+                                         - np.asarray(a, np.float32)))),
+        before, state["params"])
+    assert max(jax.tree.leaves(moved)) > 0.0

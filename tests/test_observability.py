@@ -130,18 +130,19 @@ def test_recorder_unwritable_path_is_silent(tmp_path):
 # -- flops single source ----------------------------------------------
 
 
-def test_model_flops_345m_against_a_hand_count():
-    """The Engine's MFU line at the 345M shape (L 24, h 1024, V 50304,
-    s 1024), counted by hand: a layer's matmuls are 24 h^2 FLOPs a
-    token forward (QKV 6, out 2, the two MLP products 16), its
-    attention scores and values 4 s h, the head 2 h V; backward is
-    twice forward."""
-    L, h, V, s = 24, 1024, 50304, 1024
+@pytest.mark.parametrize("L,h,V,s,gflops", [
+    (24, 1024, 50304, 1024, 2.4230), (24, 2048, 50304, 1024, 8.4699)],
+    ids=["gpt-345m", "gpt-1.3b"])
+def test_model_flops_against_a_hand_count(L, h, V, s, gflops):
+    """The Engine's MFU line at the shapes of the two GPT cells,
+    counted by hand: a layer's matmuls are 24 h^2 FLOPs a token forward
+    (QKV 6, out 2, the two MLP products 16), its attention scores and
+    values 4 s h, the head 2 h V; backward is twice forward. The
+    GFLOPs a token are the figures PERF.md 2 quotes."""
     forward = L * (24 * h * h + 4 * s * h) + 2 * h * V
     assert obs_flops.model_flops_per_token(L, h, V, s) == \
         pytest.approx(3 * forward, rel=1e-12)
-    # the figure PERF.md 2 quotes for the 345M cells
-    assert 3 * forward == pytest.approx(2.4228e9, rel=1e-4)
+    assert 3 * forward == pytest.approx(gflops * 1e9, rel=1e-4)
 
 
 def test_disabled_registry_overhead_under_one_percent_of_step():
