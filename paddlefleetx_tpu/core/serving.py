@@ -144,6 +144,30 @@ has read. The scheduler's contract under it:
   ``Completion.ttft_ms``) grows by at most one step.
   ``prefill_harvest`` stays a synchronous read, once a prompt.
 
+What a launch passes (docs/inference.md "What a launch passes"): a
+jitted call handles its arguments leaf by leaf, whatever their size,
+and on a small model that handling, not the tick, is the step. So the
+server keeps its parameters as the arrays its launches pass
+(``generation.LaunchParams``, built once by ``pack_launch_params``
+and again by every assignment to :attr:`GenerationServer.params`):
+leaves that agree in shape, dtype and sharding ride STACKED in one
+``[n, ...]`` array — every layer's norm scales, biases, router and
+state rows; a model that arrives scan-stacked keeps such stacks as
+they came — and each slot primitive slices the per-layer tree out of
+them inside the program (``generation.launch_tree``), where the slice
+is fused into what reads it. NOT stacked: a leaf over
+``generation.STACK_LEAF_BYTES`` (1 MiB: every matrix. A stacked
+matrix is still read in place, but is no longer a buffer of its own
+that XLA prefetches ahead of its product: the 345M tick ran 1.34 ms
+for 1.15, PERF.md 6, PR 45), a leaf alone in its group, a leaf spread
+over more than one device; the page pool and the slot cache (the
+write and decode kernels alias each leaf in place: a stacked pool is
+the scan's carry again, ~40% of a step); the slot state. The rule
+reads the leaf and nothing else: there is no option.
+``server.params`` still reads and assigns the per-layer tree;
+``serving/launch_leaves/{decode,prefill}`` count the array leaves of
+every launch.
+
 Graceful degradation (docs/robustness.md): per-request deadlines/TTL
 (``submit(deadline_s=...)`` or a server-wide ``request_ttl_s``) evict
 expired requests with a ``deadline_exceeded`` result; a bounded queue
@@ -168,6 +192,8 @@ gauge, the ``serving/device_ticks`` counter and per-reason
 ``serving/loop_exit/{finished,admission,budget,drain}`` counters of
 the fused loop, the ``serving/d2h_reads`` counter (arrays pulled to
 the host inside ``step()``: one a decoding step), the
+``serving/launch_leaves/{decode,prefill}`` counters (array leaves the
+launches passed), the
 ``serving/harvest_deferred`` / ``serving/harvest_flushed/<why>`` /
 ``serving/harvest_rows_void`` counters of the deferred harvest, the
 ``serving/slow_steps`` / ``serving/slow_step/<phase>`` /
@@ -226,11 +252,11 @@ import numpy as np
 
 from ..models.gpt.generation import (
     LOOP_EXIT_BUDGET, LOOP_EXIT_FINISHED, LOOP_EXIT_NONE,
-    GenerationConfig, _unrolled_twin, activate_slot, copy_kv_pages,
+    GenerationConfig, _compute_params, activate_slot, copy_kv_pages,
     decode_loop, decode_step, gather_kv_pages, init_page_pool,
-    init_slot_cache, init_slot_state, prefill_chunk_paged,
-    prefill_into_slots, scatter_kv_pages, unpack_harvest, verify_loop,
-    verify_step,
+    init_slot_cache, init_slot_state, pack_launch_params,
+    prefill_chunk_paged, prefill_into_slots, scatter_kv_pages,
+    unpack_harvest, verify_loop, verify_step,
 )
 from ..observability import metrics
 from ..observability import server as obs_server
@@ -443,7 +469,13 @@ class GenerationServer:
         # round-trip (docs/inference.md "Device-resident decode")
         self._loop_ticks = int(device_loop_ticks)
         self._roundtrips = 0
-        model, params = _unrolled_twin(model, params)
+        # the same one-time cast as generate()'s
+        params = _compute_params(params, jnp.dtype(model.config.dtype))
+        # the layer loop unrolled, and the parameters as a launch
+        # passes them: the small leaves stacked (module docstring,
+        # "What a launch passes")
+        model, self._launch_params = pack_launch_params(model, params)
+        del params
         cfg = model.config
         # paged mode: explicit kwargs win, else the config's own
         # kv_page_size/kv_pool_pages turn it on; either way the model
@@ -573,20 +605,7 @@ class GenerationServer:
             raise ValueError(
                 "host_pool_bytes requires paged mode (page_size/"
                 "pool_pages): the spill tier holds KV pages")
-        compute_dtype = jnp.dtype(cfg.dtype)
-        if compute_dtype != jnp.float32:
-            # same one-time cast as generate(): halve the per-token
-            # parameter bandwidth of the decode tick; int8 kernels and
-            # their fp32 "kernel_scale" dequant grids pass through
-            # (quant_execution, docs/quantization.md)
-            def _cast(path, p):
-                name = getattr(path[-1], "key", "")
-                if name == "kernel_scale" or not jnp.issubdtype(
-                        p.dtype, jnp.floating):
-                    return p
-                return p.astype(compute_dtype)
-            params = jax.tree_util.tree_map_with_path(_cast, params)
-        self.model, self.params = model, params
+        self.model = model
         self.gen_cfg = gen_cfg
         self.num_slots = num_slots
         # speculative decoding: the draft source proposes (a host
@@ -629,8 +648,9 @@ class GenerationServer:
             buckets = buckets + (self._max_prompt,)
         self._buckets = buckets
         self._rng = rng if rng is not None else jax.random.PRNGKey(0)
-        self._cache = init_page_pool(model, params, num_slots) \
-            if self.paged else init_slot_cache(model, params, num_slots)
+        self._cache = init_page_pool(
+            model, self._launch_params, num_slots) if self.paged else \
+            init_slot_cache(model, self._launch_params, num_slots)
         self._state = init_slot_state(num_slots, cfg.vocab_size)
         self._queue: deque = deque()
         self._slots: List[Optional[dict]] = [None] * num_slots
@@ -657,6 +677,21 @@ class GenerationServer:
             self._aid_np = np.zeros((num_slots,), np.int32)
             self._aid_dev = jnp.asarray(self._aid_np)
             self._aid_dirty = False
+        # array leaves a launch passes beside the parameters' arrays
+        # (serving/launch_leaves/*): the cache's; for a tick the slot
+        # state, the key, the page table, a host source's drafts and
+        # the fused loop's flag; for a chunk its tokens, start, table
+        # row, valid count and a device source's (next tokens, slots);
+        # for an admission into contiguous rows the state, slots,
+        # tokens, lengths and nonces
+        held = len(jax.tree.leaves(self._cache)) + \
+            (self._adapters is not None)
+        state = len(jax.tree.leaves(self._state))
+        self._decode_extra = held + state + 1 + self.paged + \
+            (self.spec and not self._device_draft) + \
+            (self._loop_ticks > 1)
+        self._prefill_extra = held + (
+            4 + 2 * self._device_draft if self.paged else state + 4)
         #: completions the next step() hands out: admission-time
         #: request failures (e.g. unknown adapter id), and what a read
         #: outside step() found finished (_flush)
@@ -766,6 +801,22 @@ class GenerationServer:
                 cfg.max_position_embeddings)
 
     # -- host bookkeeping ---------------------------------------------
+
+    @property
+    def params(self):
+        """The per-layer parameter tree, as the model's ``apply`` takes
+        it. Reading it slices the launch's stacks apart (set-up and
+        tests do; a step never does); assigning it packs the tree
+        again, keeping every stack none of whose leaves is another
+        array than the last read handed out, so ``srv.params =
+        f(srv.params)`` costs what ``f`` changed."""
+        with self._surface_lock:
+            return self._launch_params.tree()
+
+    @params.setter
+    def params(self, tree) -> None:
+        with self._surface_lock:
+            self._launch_params = self._launch_params.assign(tree)
 
     def _emit(self, event: str, **fields) -> None:
         if self._recorder is not None:
@@ -1173,7 +1224,8 @@ class GenerationServer:
                 req["nonce"] = self._nonce
                 self._nonce += 1
             self._cache, self._state = prefill_into_slots(
-                self.model, self.params, self._cache, self._state,
+                self.model, self._launch_params, self._cache,
+                self._state,
                 jnp.asarray([slot], jnp.int32), jnp.asarray(row),
                 jnp.asarray([len(seq)], jnp.int32),
                 jnp.asarray([req["nonce"]], jnp.int32),
@@ -1183,6 +1235,9 @@ class GenerationServer:
                 self._state = self._state._replace(
                     dec_count=self._state.dec_count.at[slot].set(
                         len(req["tokens"])))
+            metrics.inc("serving/launch_leaves/prefill",
+                        len(self._launch_params.arrays)
+                        + self._prefill_extra)
             req["active"], req["ahead"] = True, 0
             self._slots[slot] = req
             self._counts["admitted"] += 1
@@ -1450,7 +1505,8 @@ class GenerationServer:
             self._sync_pt()
         with annotate("serving/step/prefill_dispatch", ph):
             self._cache, logits = prefill_chunk_paged(
-                self.model, self.params, self._cache, jnp.asarray(row),
+                self.model, self._launch_params, self._cache,
+                jnp.asarray(row),
                 jnp.asarray([c0], jnp.int32),
                 self._pt_dev[slot:slot + 1],
                 jnp.asarray([int(self._aid_np[slot])], jnp.int32)
@@ -1473,6 +1529,9 @@ class GenerationServer:
             self._prefill_chunk_count += 1
             rec.chunks += 1
             metrics.inc("serving/prefill_chunks")
+            metrics.inc("serving/launch_leaves/prefill",
+                        len(self._launch_params.arrays)
+                        + self._prefill_extra)
             self._emit("serving_prefill_chunk", request=req["id"],
                        slot=slot, start=c0, tokens=real,
                        trace=self._trace_id(req))
@@ -2243,25 +2302,31 @@ class GenerationServer:
         pt = self._pt_dev_dec if self.paged else None
         if T == 1 and self.spec:
             self._cache, self._state, harvest = verify_step(
-                self.model, self.params, self._cache, self._state,
+                self.model, self._launch_params, self._cache,
+                self._state,
                 None if drafts is None else jnp.asarray(drafts[:, 0]),
                 self._rng, self.gen_cfg, pt, self._aid_arg())
         elif T == 1:
             self._cache, self._state, harvest = decode_step(
-                self.model, self.params, self._cache, self._state,
+                self.model, self._launch_params, self._cache,
+                self._state,
                 self._rng, self.gen_cfg, pt, self._aid_arg())
         elif self.spec:
             self._cache, self._state, harvest = verify_loop(
-                self.model, self.params, self._cache, self._state,
+                self.model, self._launch_params, self._cache,
+                self._state,
                 jnp.asarray(drafts), self._rng, self.gen_cfg,
                 jnp.int32(host_flag), pt, self._aid_arg(),
                 loop_ticks=T)
         else:
             self._cache, self._state, harvest = decode_loop(
-                self.model, self.params, self._cache, self._state,
+                self.model, self._launch_params, self._cache,
+                self._state,
                 self._rng, self.gen_cfg, jnp.int32(host_flag), pt,
                 self._aid_arg(), loop_ticks=T)
         harvest.copy_to_host_async()
+        metrics.inc("serving/launch_leaves/decode",
+                    len(self._launch_params.arrays) + self._decode_extra)
         return harvest
 
     def _read_now(self) -> Optional[str]:

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import weakref
 from functools import partial
 from collections.abc import Mapping
 from typing import NamedTuple, Optional
@@ -183,13 +184,279 @@ def _unrolled_twin(model, params):
     materializes those as full-buffer copies — measured ~40% of decode
     step time at 345M/bs8 (projects/gpt/docs/inference analysis).
     Unrolled, each layer owns a plain cache buffer that XLA updates in
-    place. One up-front unstack of the scanned params replaces the
-    per-step stacked-cache traffic."""
+    place.
+
+    It is the CACHE that is unrolled. The parameters are only
+    re-indexed: ``decoder_i``'s leaf is row ``i`` of the stack the
+    scan came with, a static slice that feeds the layer's product.
+    :func:`generate` takes the rows at trace time; a server never
+    takes them apart on the host (:func:`pack_launch_params` hands
+    this function marks for arrays, keeps the stacks as the launch's
+    leaves and slices inside the program), because a launch pays for
+    every leaf it is handed, not for what is in it."""
     cfg = model.config
     if not cfg.scan_layers or not _has_decoder_stack(params):
         return model, params
     twin = type(model)(dataclasses.replace(cfg, scan_layers=False))
     return twin, _unstack_layer_params(params, cfg.num_layers)
+
+
+# -- what a launch passes ---------------------------------------------
+#
+# A jitted call handles its arguments leaf by leaf (a hold and an event
+# a buffer, whatever its size: ~1.2 us a read-only leaf on the chip's
+# host, PERF.md 6, PR 45), so a server keeps its parameters as FEWER
+# arrays: leaves that agree in shape, dtype and sharding ride stacked
+# in one ``[n, ...]`` array, and the per-layer tree the model wants is
+# put together again by static slices INSIDE the program
+# (:func:`launch_tree`, the first line of every slot primitive), where
+# XLA fuses each slice into what reads it. What is NOT stacked: a leaf
+# over ``STACK_LEAF_BYTES`` (every matrix), a leaf alone in its group,
+# a leaf spread over more than one device; and nothing of the cache
+# (the Pallas writes and decode kernels alias each pool leaf in place:
+# a stacked pool is the scan's carry again) nor of the slot state.
+
+#: the largest leaf that joins a stack. Slicing a stack is free where
+#: XLA fuses the slice into the product that reads it, and it does;
+#: but a weight that is a row of a stack is no longer a buffer of its
+#: own that XLA prefetches into fast memory ahead of its product, and
+#: on the chip GPT-345M's tick ran 1.34 ms for 1.15 with its 2-8 MiB
+#: matrices stacked (PERF.md 6, PR 45). Norm scales, biases, router
+#: and state rows lie under this; no matrix of a served model does
+STACK_LEAF_BYTES = 1 << 20
+
+
+class _Arrived:
+    """Stands for one array of the tree a server was given while
+    :func:`_unrolled_twin` lays the per-layer tree out: indexing it
+    gives a mark of the row, not a slice."""
+
+    __slots__ = ("array",)
+
+    def __init__(self, array):
+        self.array = array
+
+    def __getitem__(self, row: int):
+        return _Row(self, row)
+
+
+class _Row:
+    """Row ``row`` of the array ``stack`` stands for."""
+
+    __slots__ = ("stack", "row")
+
+    def __init__(self, stack: "_Arrived", row: int):
+        self.stack, self.row = stack, row
+
+
+class _LaunchPlan:
+    """The static half of :class:`LaunchParams`: the per-layer tree's
+    structure and, leaf by leaf, which array holds it and in which row
+    (None: the array IS the leaf). Hashed by identity and made through
+    :meth:`of`, which hands equal layouts the same plan: servers of
+    one model share their compiled programs."""
+
+    __slots__ = ("treedef", "where", "rows")
+    _made: dict = {}
+
+    @classmethod
+    def of(cls, treedef, where) -> "_LaunchPlan":
+        """THE plan of this layout (``where``: ``(array, row)`` a
+        leaf of ``treedef``)."""
+        where = tuple(where)
+        plan = cls._made.get((treedef, where))
+        if plan is None:
+            plan = cls._made[treedef, where] = cls()
+            plan.treedef, plan.where = treedef, where
+            #: array -> positions of the leaves it holds, in row order
+            plan.rows = [[] for _ in range(
+                1 + max((a for a, _ in where), default=-1))]
+            for row, pos, a in sorted(
+                    (row, pos, a) for pos, (a, row) in enumerate(where)
+                    if row is not None):
+                plan.rows[a].append(pos)
+        return plan
+
+
+def _stack_key(array, stacked: bool = False):
+    """What leaves must share to ride in one stack, or None for a
+    leaf that stays its own: observed from the leaf alone (from one
+    row of it, where ``array`` is a stack already)."""
+    sharding = getattr(array, "sharding", None)
+    if sharding is None or len(sharding.device_set) > 1:
+        return None
+    shape = array.shape[1:] if stacked else array.shape
+    dtype = jnp.dtype(array.dtype)
+    if math.prod(shape) * dtype.itemsize > STACK_LEAF_BYTES:
+        return None
+    return shape, dtype, sharding
+
+
+@jax.jit
+def _stack_groups(groups):
+    """Every group of leaves as one ``[n, ...]`` array: one program
+    for all of a packing's groups, not one a group."""
+    return tuple(jnp.stack(g) for g in groups)
+
+
+@jax.jit
+def _unstack_groups(stacks):
+    return tuple(tuple(s[i] for i in range(s.shape[0])) for s in stacks)
+
+
+@jax.tree_util.register_pytree_node_class
+class LaunchParams:
+    """A server's parameters as its launches pass them: ``arrays``
+    (the pytree's children) under a static plan (its auxiliary data).
+    :meth:`tree` and :meth:`assign` are the per-layer tree's way out
+    and back in, for set-up and tests; a step uses neither."""
+
+    def __init__(self, plan: _LaunchPlan, arrays):
+        self.plan = plan
+        self.arrays = tuple(arrays)
+        #: weak references to the stacked leaves :meth:`tree` last
+        #: handed out, by leaf position
+        self._read = {}
+
+    def tree_flatten(self):
+        return self.arrays, self.plan
+
+    @classmethod
+    def tree_unflatten(cls, plan, arrays):
+        return cls(plan, arrays)
+
+    def tree(self):
+        """The per-layer tree, the stacks sliced apart again in one
+        jitted call (a copy of every stacked leaf while the caller
+        holds it)."""
+        stacked = [a for a, rows in enumerate(self.plan.rows) if rows]
+        parts = dict(zip(stacked, _unstack_groups(
+            tuple(self.arrays[a] for a in stacked)))) if stacked else {}
+        leaves = [self.arrays[a] if row is None else parts[a][row]
+                  for a, row in self.plan.where]
+        self._read = {pos: weakref.ref(leaves[pos])
+                      for a in stacked for pos in self.plan.rows[a]}
+        return self.plan.treedef.unflatten(leaves)
+
+    def assign(self, tree) -> "LaunchParams":
+        """``tree`` packed under this plan. A stack none of whose
+        leaves is another array than :meth:`tree` last handed out is
+        kept as it is; the others are stacked again, in one jitted
+        call. A tree of another structure, or a leaf that no longer
+        fits its stack, is packed afresh (and its programs compile
+        again)."""
+        leaves, treedef = jax.tree_util.tree_flatten(tree)
+        if treedef != self.plan.treedef:
+            return pack_launch_params(None, tree)[1]
+        arrays, again = list(self.arrays), []
+        for a, rows in enumerate(self.plan.rows):
+            if not rows:
+                continue
+            if all(pos in self._read and self._read[pos]() is leaves[pos]
+                   for pos in rows):
+                continue
+            key = _stack_key(self.arrays[a], stacked=True)
+            if any(_stack_key(leaves[pos]) != key for pos in rows):
+                return pack_launch_params(None, tree)[1]
+            again.append(a)
+        for pos, (a, row) in enumerate(self.plan.where):
+            if row is None:
+                arrays[a] = leaves[pos]
+        if again:
+            for a, new in zip(again, _stack_groups(tuple(
+                    tuple(leaves[pos] for pos in self.plan.rows[a])
+                    for a in again))):
+                arrays[a] = new
+        return LaunchParams(self.plan, arrays)
+
+
+def pack_launch_params(model, params):
+    """``(model's unrolled twin, params as a server launches them)``.
+
+    The per-layer tree is laid out over marks (:func:`_unrolled_twin`
+    with an :class:`_Arrived` for every array), so a model that arrives
+    scan-stacked keeps the stacks it came with: a stack whose rows may
+    be stacked is an array of the launch as it is, the rows of one that
+    may not are sliced out here, once. Every other leaf joins the
+    leaves of its shape, dtype and sharding in one ``jnp.stack``, all
+    groups in one jitted call; a leaf with no such peer, or over
+    ``STACK_LEAF_BYTES``, or on more than one device, passes through
+    as it is. ``model`` None: ``params`` is a per-layer tree already."""
+    marked = jax.tree.map(_Arrived, params)
+    if model is not None:
+        model, marked = _unrolled_twin(model, marked)
+    marks, treedef = jax.tree_util.tree_flatten(marked)
+    arrays, where, groups = [], [None] * len(marks), {}
+
+    def own(pos, array):
+        key = _stack_key(array)
+        if key is None:
+            where[pos] = (len(arrays), None)
+            arrays.append(array)
+        else:
+            groups.setdefault(key, []).append((pos, array))
+
+    came = {}
+    for pos, m in enumerate(marks):
+        if isinstance(m, _Row):
+            came.setdefault(id(m.stack), (m.stack.array, []))[1].append(
+                (m.row, pos))
+        else:
+            own(pos, m.array)
+    for stack, rows in came.values():
+        if len(rows) == stack.shape[0] and \
+                _stack_key(stack, stacked=True) is not None:
+            for row, pos in rows:
+                where[pos] = (len(arrays), row)
+            arrays.append(stack)
+        else:
+            for row, pos in rows:
+                own(pos, stack[row])
+    alone = [g[0] for g in groups.values() if len(g) == 1]
+    groups = [g for g in groups.values() if len(g) > 1]
+    for pos, array in alone:
+        where[pos] = (len(arrays), None)
+        arrays.append(array)
+    stacks = _stack_groups(tuple(
+        tuple(array for _, array in g) for g in groups)) if groups else ()
+    for g, stack in zip(groups, stacks):
+        for row, (pos, _) in enumerate(g):
+            where[pos] = (len(arrays), row)
+        arrays.append(stack)
+    return model, LaunchParams(_LaunchPlan.of(treedef, where), arrays)
+
+
+def launch_tree(params):
+    """The per-layer tree of a :class:`LaunchParams`, by static slices
+    (inside a traced function: the first line of every slot
+    primitive); any other tree as it is."""
+    if not isinstance(params, LaunchParams):
+        return params
+    return params.plan.treedef.unflatten(
+        [params.arrays[a] if row is None else
+         jax.lax.index_in_dim(params.arrays[a], row, 0, keepdims=False)
+         for a, row in params.plan.where])
+
+
+def _compute_params(params, compute_dtype):
+    """``params`` cast once to the decode path's compute dtype. flax
+    casts fp32 params inside every op, so a decode loop would stream
+    fp32 bytes each token; one up-front cast is numerically identical
+    and halves the per-token parameter bandwidth (the decode
+    bottleneck). int8 kernels (non-floating) and their fp32
+    ``kernel_scale`` dequant grids (quant_execution,
+    docs/quantization.md) pass through: the scale grid is part of the
+    PTQ artifact's numerics."""
+    if compute_dtype == jnp.float32:
+        return params
+
+    def _cast(path, p):
+        name = getattr(path[-1], "key", "")
+        if name == "kernel_scale" or not jnp.issubdtype(
+                p.dtype, jnp.floating):
+            return p
+        return p.astype(compute_dtype)
+    return jax.tree_util.tree_map_with_path(_cast, params)
 
 
 @partial(jax.jit, static_argnames=("model", "gen_cfg"))
@@ -224,22 +491,7 @@ def generate(model, params, input_ids: jax.Array,
     # cover every allocated slot, while the LENGTH bound below stays
     # at max_position_embeddings (the position-embedding table size)
     capacity = cfg.cache_capacity
-    compute_dtype = jnp.dtype(cfg.dtype)
-    if compute_dtype != jnp.float32:
-        # flax casts fp32 params to the compute dtype inside every op,
-        # so the decode loop would stream fp32 bytes each token; one
-        # up-front cast is numerically identical and halves the
-        # per-token parameter bandwidth (the decode bottleneck).
-        # int8 kernels (non-floating) and their fp32 dequant scales
-        # (quant_execution, docs/quantization.md) pass through — the
-        # scale grid is part of the PTQ artifact's numerics.
-        def _cast(path, p):
-            name = getattr(path[-1], "key", "")
-            if name == "kernel_scale" or not jnp.issubdtype(
-                    p.dtype, jnp.floating):
-                return p
-            return p.astype(compute_dtype)
-        params = jax.tree_util.tree_map_with_path(_cast, params)
+    params = _compute_params(params, jnp.dtype(cfg.dtype))
     if prompt_len + gen_cfg.max_dec_len > cfg.max_position_embeddings:
         raise ValueError(
             f"prompt ({prompt_len}) + max_dec_len "
@@ -563,7 +815,7 @@ def init_slot_cache(model, params, num_slots: int):
     ``jax.eval_shape`` over a cached apply (no compile, no FLOPs)."""
     shapes = jax.eval_shape(
         lambda p: model.apply(
-            {"params": p}, jnp.zeros((num_slots, 1), jnp.int32),
+            {"params": launch_tree(p)}, jnp.zeros((num_slots, 1), jnp.int32),
             use_cache=True, deterministic=True,
             mutable=["cache"])[1]["cache"],
         params)
@@ -622,6 +874,7 @@ def prefill_into_slots(model, params, cache, state: SlotState,
     ``SlotState`` at ``slot_ids``. One compiled shape per
     ``(n, bucket)`` pair.
     """
+    params = launch_tree(params)
     n, bucket = input_ids.shape
     pos = jnp.broadcast_to(
         jnp.arange(bucket, dtype=jnp.int32)[None, :], (n, bucket))
@@ -739,6 +992,7 @@ def decode_step(model, params, cache, state: SlotState,
     ``T = 1, k = 0``: its ``window[:, 0, 0]`` is what each slot
     emitted this tick (pad for finished/inactive slots).
     """
+    params = launch_tree(params)
     cache, state, token = _decode_tick_impl(
         model, params, cache, state, rng, gen_cfg, page_table,
         adapter_ids)
@@ -930,6 +1184,7 @@ def verify_step(model, params, cache, state: SlotState,
     committed (1..k+1; the host appends
     ``window[slot, 0, :counts[slot, 0]]``).
     """
+    params = launch_tree(params)
     cache, state, window, counts = _verify_tick_impl(
         model, params, cache, state, drafts, rng, gen_cfg, page_table,
         adapter_ids)
@@ -1104,6 +1359,7 @@ def decode_loop(model, params, cache, state: SlotState,
     how many ticks executed (1..loop_ticks), and ``exit_code`` one of
     the ``LOOP_EXIT_*`` codes.
     """
+    params = launch_tree(params)
     if loop_ticks < 1:
         raise ValueError(f"loop_ticks must be >= 1, got {loop_ticks}")
     slots = state.lengths.shape[0]
@@ -1157,6 +1413,7 @@ def verify_loop(model, params, cache, state: SlotState,
     ``window[:, j] [slots, k+1]`` of which ``counts[:, j]`` committed
     per slot (0 beyond ``ticks_run``).
     """
+    params = launch_tree(params)
     if loop_ticks < 1:
         raise ValueError(f"loop_ticks must be >= 1, got {loop_ticks}")
     slots, t_axis, k = drafts.shape
@@ -1214,7 +1471,7 @@ def init_page_pool(model, params, num_slots: int):
     cfg = model.config
     shapes = jax.eval_shape(
         lambda p: model.apply(
-            {"params": p}, jnp.zeros((num_slots, 1), jnp.int32),
+            {"params": launch_tree(p)}, jnp.zeros((num_slots, 1), jnp.int32),
             use_cache=True, deterministic=True,
             cache_lengths=jnp.zeros((num_slots,), jnp.int32),
             page_table=jnp.zeros((num_slots, cfg.max_kv_pages),
@@ -1254,6 +1511,7 @@ def prefill_chunk_paged(model, params, cache, input_chunk: jax.Array,
     the final chunk as the first sampling distribution. One compiled
     shape per ``(n, chunk)``.
     """
+    params = launch_tree(params)
     n, c = input_chunk.shape
     mpe = model.config.max_position_embeddings
     pos = jnp.clip(

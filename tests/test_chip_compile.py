@@ -454,6 +454,59 @@ def test_prefill_chunk_scatters_pages_in_place(donated, one_chip,
         assert _pool_copies(compiled) == 2
 
 
+def _weight_sized(compiled):
+    """``(op, dims)`` of every instruction of the ENTRY computation
+    whose result holds a weight matrix's elements or more (2 MiB in
+    bfloat16: the smallest of a 345M layer's four), sorted: a slice
+    that was NOT fused into its product would show up here, and so
+    do XLA's prefetches of a weight into the fast memory space
+    (``slice-done`` / ``copy-done`` and the ``ConcatBitcast`` that
+    joins their pieces), which a weight must not lose."""
+    entry = compiled.as_text().split("\nENTRY ", 1)[1]
+    return sorted(
+        (op, dims) for dims, op in re.findall(
+            r"\n  (?:ROOT )?%\S+ = \w+\[([\d,]+)\]\S* ([\w-]+)\(", entry)
+        if math.prod(map(int, dims.split(","))) >= H * D * H * D
+        and op not in ("parameter", "get-tuple-element", "bitcast"))
+
+
+@pytest.mark.parametrize("program", ["tick", "chunk"])
+def test_stacked_parameters_leave_the_programs_as_they_were(
+        program, one_chip, as_tpu, monkeypatch):
+    """What a launch passes: two layers' 28 leaves go down as 13
+    arrays (``pack_launch_params``: the vectors stacked, every matrix
+    over ``STACK_LEAF_BYTES`` its own) and ``launch_tree`` slices them
+    apart inside the program. The compiled tick and chunk hold the
+    weight-sized instructions of the plain tree's, prefetches
+    included, and no other; the tick needs no temporary more."""
+    from paddlefleetx_tpu.models.gpt import generation as g
+    model, params, pool, state, rng, table, gen_cfg = \
+        _serving_program(one_chip, layers=2)
+    monkeypatch.setattr(g, "_stack_groups", lambda groups: tuple(
+        _sds((len(x),) + x[0].shape, x[0].dtype, one_chip)
+        for x in groups))
+    _, packed = g.pack_launch_params(None, params)
+    assert len(jax.tree.leaves(params)) == 28
+    assert len(packed.arrays) == 13
+
+    def compiled(p):
+        if program == "tick":
+            return g.decode_step.lower(
+                model, p, pool, state, rng, gen_cfg,
+                page_table=table).compile()
+        return g.prefill_chunk_paged.lower(
+            model, p, pool, _sds((1, 2 * PAGE), jnp.int32, one_chip),
+            _sds((1,), jnp.int32, one_chip),
+            _sds((1, model.config.max_kv_pages), jnp.int32,
+                 one_chip)).compile()
+    plain, stacked = compiled(params), compiled(packed)
+    assert _weight_sized(stacked) == _weight_sized(plain)
+    if program == "tick":
+        assert stacked.memory_analysis().temp_size_in_bytes <= \
+            plain.memory_analysis().temp_size_in_bytes
+    assert _pool_copies(stacked) == 0
+
+
 # -- one chip: grouped / quantized GEMMs, grouped LoRA ----------------
 
 @pytest.mark.parametrize("backward", [False, True])
