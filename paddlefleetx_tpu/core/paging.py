@@ -300,6 +300,17 @@ class PageAllocator:
         for pid in pages:
             self._page_prompt_keys.setdefault(pid, set()).add(key)
 
+    def replace_prompt_payload(self, key: str, old, new) -> None:
+        """Give ``key``'s entry the payload ``new`` where it still
+        holds ``old`` (the same object): the server registers a
+        prompt with its logits row still on the device and hands the
+        host's copy after once it is home. An entry that has gone
+        with its pages, or that another prefill of the same tokens
+        wrote first, is left alone."""
+        entry = self._prompt.get(key)
+        if entry is not None and entry[1] is old:
+            self._prompt[key] = (entry[0], new)
+
     # -- host spill tier ----------------------------------------------
 
     @property

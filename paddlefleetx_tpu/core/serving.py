@@ -141,8 +141,11 @@ has read. The scheduler's contract under it:
 - **Times.** A token's stamp is when the host received it, one step
   after its tick, for every token alike: ``tpot`` keeps its meaning,
   ``ttft`` (``serving/ttft_ms``, the ``first_token`` point,
-  ``Completion.ttft_ms``) grows by at most one step.
-  ``prefill_harvest`` stays a synchronous read, once a prompt.
+  ``Completion.ttft_ms``) grows by at most one step. The prefill
+  path reads nothing: a prompt's last logits row stays on the device
+  from the chunk that made it to the slot state that samples from it
+  (``generation.activate_slot`` picks it), and the prompt registry's
+  copy comes home behind the step (``_land_rows``).
 
 What a launch passes (docs/inference.md "What a launch passes"): a
 jitted call handles its arguments leaf by leaf, whatever their size,
@@ -191,7 +194,10 @@ spec decode 1 tick != 1 token), the tiered ``serving/spill`` /
 gauge, the ``serving/device_ticks`` counter and per-reason
 ``serving/loop_exit/{finished,admission,budget,drain}`` counters of
 the fused loop, the ``serving/d2h_reads`` counter (arrays pulled to
-the host inside ``step()``: one a decoding step), the
+the host inside ``step()``: one a launch, so on a plain server it
+equals ``serving/device_ticks``), the
+``serving/activations/{device_row,host_row}`` counters (where the
+first logits of an activated slot came from), the
 ``serving/launch_leaves/{decode,prefill}`` counters (array leaves the
 launches passed), the
 ``serving/harvest_deferred`` / ``serving/harvest_flushed/<why>`` /
@@ -699,6 +705,10 @@ class GenerationServer:
         #: the decode launch whose harvest has not been read (module
         #: docstring, "Deferred harvest"); never more than one
         self._inflight: Optional[_Launch] = None
+        #: ``(prompt key, row)`` of every registered prompt whose last
+        #: logits row the registry still holds on the device
+        #: (:meth:`_land_rows`)
+        self._rows_out: deque = deque()
         self._ticks = 0
         # graceful degradation (docs/robustness.md)
         self.request_ttl_s = request_ttl_s
@@ -1301,24 +1311,34 @@ class GenerationServer:
         self._observe_queue_wait(req)
         self._phase(req, "serving/prefill", slot=slot)
 
-    def _activate(self, slot: int, last_logits_row) -> None:
+    def _activate(self, slot: int, logits, row: int = 0) -> jax.Array:
         """Flip a placed slot live: per-slot SlotState from the host's
         view of the request (seq = prompt + already-emitted tokens, so
-        resumes re-enter mid-request)."""
+        resumes re-enter mid-request). The first sampling logits are
+        row ``row`` of ``logits``: a last chunk's output as the chunk
+        left it on the device, or a registered prompt's row wherever
+        the registry holds it. The row is picked inside the one jitted
+        call and read by no one here; it is returned, a device array,
+        for the registry."""
         req = self._slots[slot]
         seq = req["prompt"] + req["tokens"]
         appeared = np.zeros((self.model.config.vocab_size,), bool)
         appeared[np.asarray(seq, np.int64)] = True
-        self._state = activate_slot(
-            self._state, jnp.int32(slot), jnp.int32(len(seq)),
-            jnp.int32(len(req["tokens"])), jnp.int32(req["nonce"]),
-            jnp.asarray(appeared),
-            jnp.asarray(last_logits_row, jnp.float32),
-            jnp.int32(req.pop("spec_rejected", -1)))
+        # at 0 too, so that a reader tells "never" from "no such
+        # counter": a host row is uploaded, a device row goes nowhere
+        on_device = isinstance(logits, jax.Array)
+        metrics.inc("serving/activations/device_row", int(on_device))
+        metrics.inc("serving/activations/host_row", int(not on_device))
+        self._state, last = activate_slot(
+            self._state, np.int32(slot), np.int32(len(seq)),
+            np.int32(len(req["tokens"])), np.int32(req["nonce"]),
+            appeared, logits, np.int32(row),
+            np.int32(req.pop("spec_rejected", -1)))
         req["active"], req["ahead"] = True, 0
         req["cur_len"] = len(seq)
         self._pt_dirty = True   # decode view must unhide this row
         self._phase(req, "serving/decode", slot=slot)
+        return last
 
     def _admit_paged(self) -> None:
         """Paged admission: whole-prompt registry hit -> share every
@@ -1477,9 +1497,12 @@ class GenerationServer:
         table's upload, counters, and after a prompt's last chunk the
         slot's activation and the registries); ``prefill_dispatch`` is
         the chunk's jitted call with the upload of its operands, there
-        if and only if the step launched a chunk; ``prefill_harvest``
-        is the read of a last chunk's last logits row, a device
-        sync."""
+        if and only if the step launched a chunk. Nothing here reads
+        the device: a prompt's last logits row goes from the chunk's
+        output to the slot's state inside the activation's program
+        (:meth:`_activate`), so the step that ends a prompt launches
+        its tick, the new slot in it, without waiting for the
+        chunk."""
         ph = rec.phases
         with annotate("serving/step/prefill_pump", ph):
             if not self._prefilling:
@@ -1544,12 +1567,9 @@ class GenerationServer:
             # those pages straight back to the pool instead of pinning
             # them (and the registries below) until evict
             self._trim_pages(slot, req, -(-L // self._page))
-        with annotate("serving/step/prefill_harvest", ph):
-            # the last real token sits at chunk row L - 1 - c0
-            last = np.asarray(logits[0, L - 1 - c0])
-            metrics.inc("serving/d2h_reads")
-        with annotate("serving/step/prefill_pump", ph):
-            self._activate(slot, last)
+            # the last real token sits at chunk row L - 1 - c0; the
+            # row goes from the chunk to the slot's state on the device
+            last = self._activate(slot, logits, L - 1 - c0)
             # adapter-tinted KV must never enter the shared registries
             # (_admit_paged's share rule — base-only content
             # addressing)
@@ -1558,10 +1578,30 @@ class GenerationServer:
                 for j, kk in enumerate(keys):
                     self._alloc.register_prefix(
                         kk, int(self._pt[slot, j]))
+                key = prompt_key(seq)
                 self._alloc.register_prompt(
-                    prompt_key(seq),
+                    key,
                     [int(p) for p in self._pt[slot, :req["num_pages"]]],
                     last)
+                # the registry keeps a HOST row (a registered prompt
+                # costs no HBM): the copy starts when the chunk ends
+                # and is taken where it is home (_land_rows)
+                last.copy_to_host_async()
+                self._rows_out.append((key, last))
+
+    def _land_rows(self, wait: bool = False) -> None:
+        """Hand the prompt registry the host's copy of every logits
+        row that is home, oldest first, and let the device's go. What
+        a step's commit and ``prefill_step`` end with: a row is home
+        once the launch queued behind its chunk has been read (the
+        step after the one that made it, on a decoding server), and a
+        row whose chunk is still running is left for the next call:
+        nothing here waits for the device, unless ``wait`` says so
+        (outside ``step()``: what serialises the registry)."""
+        while self._rows_out and (
+                wait or self._rows_out[0][1].is_ready()):
+            key, row = self._rows_out.popleft()
+            self._alloc.replace_prompt_payload(key, row, np.asarray(row))
 
     def _trim_pages(self, slot: int, req: dict, used: int) -> None:
         """Hand the slot's pages past its first ``used`` back to the
@@ -1878,6 +1918,8 @@ class GenerationServer:
                     rec.queued = len(self._queue)
                 if self.paged:
                     self._prefill_pump(rec)
+                    with annotate("serving/step/commit", rec.phases):
+                        self._land_rows()
                     metrics.get_registry().set_gauge(
                         "serving/pages_in_use",
                         self._alloc.pages_in_use)
@@ -1902,9 +1944,12 @@ class GenerationServer:
         """Pin a finished prefill for handoff: look ``tokens`` up in
         the prompt registry and RETAIN every page so the KV survives
         the source request's eviction while the transfer is in
-        flight. Returns ``(pages, last_logits)`` or None on a miss;
-        the caller must :meth:`kv_export_release` the pages once the
-        peer holds a copy (or on any failure path)."""
+        flight. Returns ``(pages, last_logits)`` or None on a miss
+        (the row as the registry holds it: still a device array for a
+        prompt that ended a step ago, numpy from then on;
+        :meth:`kv_import` takes either); the caller must
+        :meth:`kv_export_release` the pages once the peer holds a copy
+        (or on any failure path)."""
         with self._surface_lock:
             if not self.paged:
                 return None
@@ -2012,6 +2057,7 @@ class GenerationServer:
         if self._tier is None:
             return None
         with self._surface_lock:
+            self._land_rows(wait=True)
             self._tier.collect(self._ticks, self._roundtrips)
         self._tier.ship()
         self._tier.await_writer()
@@ -2406,6 +2452,7 @@ class GenerationServer:
             else:
                 self._read_launch(launch, rec, why)
         with annotate("serving/step/commit", ph):
+            self._land_rows()
             metrics.get_registry().set_gauge(
                 "serving/slot_occupancy", self.occupancy)
             self._refresh_health()

@@ -1636,8 +1636,8 @@ def scatter_kv_pages(cache, page_data, pids: jax.Array):
 def activate_slot(state: SlotState, slot: jax.Array,
                   length: jax.Array, dec_count: jax.Array,
                   nonce: jax.Array, appeared_row: jax.Array,
-                  last_logits_row: jax.Array,
-                  rejected: jax.Array) -> SlotState:
+                  logits: jax.Array, row: jax.Array,
+                  rejected: jax.Array) -> tuple[SlotState, jax.Array]:
     """Flip one slot live from host-computed state — the paged
     admission paths (chunked-prefill completion, whole-prompt registry
     hit, preempted-request resume) activate through here instead of
@@ -1645,8 +1645,20 @@ def activate_slot(state: SlotState, slot: jax.Array,
     for resumes, so a requeued request's min-length processing and
     sampling stream continue exactly where they stopped; ``rejected``
     (-1 outside resumes of a speculative sampling server) likewise
-    restores a pending rejection-residual exclusion (verify_step)."""
+    restores a pending rejection-residual exclusion (verify_step).
+
+    The slot's first sampling logits are row ``row`` of ``logits``
+    taken as ``[rows, vocabulary]``: a last chunk's ``[1, chunk, V]``
+    output as it left ``prefill_chunk_paged`` with the prompt's last
+    position in the chunk, or a lone ``[V]`` row with 0. ``row`` is
+    traced, so no prompt length compiles anything and the row is
+    picked where it is consumed; it comes back beside the state, a
+    device array, for whoever keeps it (the server's prompt
+    registry)."""
     slot = jnp.asarray(slot, jnp.int32)
+    last = jax.lax.dynamic_index_in_dim(
+        logits.reshape(-1, logits.shape[-1]),
+        jnp.asarray(row, jnp.int32), keepdims=False).astype(jnp.float32)
     return SlotState(
         lengths=state.lengths.at[slot].set(
             jnp.asarray(length, jnp.int32)),
@@ -1656,9 +1668,9 @@ def activate_slot(state: SlotState, slot: jax.Array,
         appeared=state.appeared.at[slot].set(appeared_row),
         finished=state.finished.at[slot].set(False),
         active=state.active.at[slot].set(True),
-        last_logits=state.last_logits.at[slot].set(last_logits_row),
+        last_logits=state.last_logits.at[slot].set(last),
         rejected=state.rejected.at[slot].set(
-            jnp.asarray(rejected, jnp.int32)))
+            jnp.asarray(rejected, jnp.int32))), last
 
 
 def left_pad_batch(sequences, pad_id: int):

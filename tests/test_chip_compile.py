@@ -454,6 +454,30 @@ def test_prefill_chunk_scatters_pages_in_place(donated, one_chip,
         assert _pool_copies(compiled) == 2
 
 
+@pytest.mark.parametrize("logits", [(1, 512, 100352), (100352,)])
+def test_the_activation_picks_its_row_in_place(logits, one_chip, as_tpu):
+    """``activate_slot`` at Granite's sizes (64 slots, 100,352 rows of
+    vocabulary, a 512-token chunk's float32 output of 205 MB, or a
+    registry's lone row): the row a traced index names is read where
+    the slot's state is written, so the program holds no temporary
+    and nothing the size of the chunk's output but its argument."""
+    from paddlefleetx_tpu.models.gpt import generation as g
+    slots, vocab = 64, logits[-1]
+    state = jax.tree.map(
+        lambda x: _sds(x.shape, x.dtype, one_chip),
+        jax.eval_shape(lambda: g.init_slot_state(slots, vocab)))
+    i32 = _sds((), jnp.int32, one_chip)
+    compiled = g.activate_slot.lower(
+        state, i32, i32, i32, i32, _sds((vocab,), jnp.bool_, one_chip),
+        _sds(logits, jnp.float32, one_chip), i32, i32).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes == 0
+    row = 4 * vocab
+    state_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                      for x in jax.tree.leaves(state))
+    assert mem.output_size_in_bytes <= state_bytes + row + 4096
+
+
 def _weight_sized(compiled):
     """``(op, dims)`` of every instruction of the ENTRY computation
     whose result holds a weight matrix's elements or more (2 MiB in

@@ -26,6 +26,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
+from _served_rows import ends_a_prompt_without_a_read  # noqa: E402
 from paddlefleetx_tpu.core.serving import GenerationServer  # noqa: E402
 from paddlefleetx_tpu.core.spec import make_draft_source  # noqa: E402
 from paddlefleetx_tpu.models.exaone_moe import (  # noqa: E402
@@ -473,3 +474,26 @@ def test_ragged_columns_fit_the_scoped_vmem(block_m, k, n, want):
     assert bn == want and n % bn == 0
     assert bn == 128 or 4 * (block_m * k + k * bn + block_m * bn) \
         <= RAGGED_VMEM_BUDGET
+
+
+def test_the_step_that_ends_a_prompt_reads_nothing(params, prompts):
+    """A source on the device: the chunk that ends a prompt hands its
+    last logits row to the slot's state and its hidden rows to the
+    block without the host reading either, and the first verify tick
+    goes down behind it."""
+    prior = metrics.get_registry().enabled
+    metrics.set_enabled(True)
+    eos = CFG.vocab_size - 1
+    srv = GenerationServer(
+        ExaoneMoeForCausalLM(CFG), params, GenerationConfig(
+            max_dec_len=DEC, decode_strategy="greedy_search",
+            eos_token_id=eos, pad_token_id=eos, spec_method="mtp",
+            spec_tokens=1),
+        num_slots=2, page_size=PAGE, prefill_chunk_pages=2,
+        pool_pages=POOL)
+    try:
+        assert srv._read_now() is None
+        ends_a_prompt_without_a_read(srv, prompts[2])   # two chunks
+    finally:
+        srv.close()
+        metrics.set_enabled(prior)

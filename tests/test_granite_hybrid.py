@@ -29,7 +29,9 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
-from _served_rows import ServedRows  # noqa: E402
+from _served_rows import (  # noqa: E402
+    ServedRows, ends_a_prompt_without_a_read,
+)
 from paddlefleetx_tpu.core.paging import pool_bytes  # noqa: E402
 from paddlefleetx_tpu.core.serving import GenerationServer  # noqa: E402
 from paddlefleetx_tpu.models.gpt.generation import (  # noqa: E402
@@ -581,3 +583,19 @@ def test_bursty_gaps_are_gamma_with_a_coefficient_of_variation_of_two():
     assert max(max(p) for _, p in one[:50]) < 100352 - 1
     # a burst: some second of the window holds three times the rate
     assert np.histogram(inside, bins=40, range=(0, 40))[0].max() >= 16
+
+
+def test_the_step_that_ends_a_prompt_reads_nothing(params):
+    """A recurrent-state model's prompt, three chunks long: the last
+    logits row goes from the chunk to the slot's state on the device,
+    and the first tick is launched behind the chunk unread."""
+    prior = metrics.get_registry().enabled
+    metrics.set_enabled(True)
+    srv = _server(params, _greedy(), num_slots=2, prefill_chunk_pages=2,
+                  pool_pages=20)
+    try:
+        prompt = np.random.default_rng(3).integers(0, 500, 600).tolist()
+        ends_a_prompt_without_a_read(srv, prompt)
+    finally:
+        srv.close()
+        metrics.set_enabled(prior)

@@ -2509,9 +2509,9 @@ def _steady_server(paged512_model_and_params, max_dec=40):
 def test_a_decoding_step_reads_the_device_once(paged512_model_and_params):
     """``serving/d2h_reads`` beside ``serving/device_ticks``: a paged
     server in steady decode at T = 1 pulls ONE array to the host a
-    step (the harvest of the launch before its own), and one more on
-    the step that ends a prompt's last chunk (``prefill_harvest``'s
-    logits row)."""
+    step (the harvest of the launch before its own), and the step
+    that ends a prompt's last chunk reads what a plain one reads: the
+    logits row stays on the device."""
     metrics.set_enabled(True)
     reg = metrics.get_registry()
     reg.reset()
@@ -2526,20 +2526,235 @@ def test_a_decoding_step_reads_the_device_once(paged512_model_and_params):
                 srv.last_step.chunks)
     try:
         srv.submit([5, 9, 2])
-        # its only chunk is its last: the logits row, then the first
-        # launch, which the NEXT step reads
-        assert step() == (1, 0, 1)
+        # its only chunk is its last: nothing is read, and the first
+        # launch is read by the NEXT step
+        assert step() == (0, 0, 1)
         assert [step() for _ in range(21)] == [(1, 1, 0)] * 21
-        assert reg.counter("serving/d2h_reads") - 1 == \
+        assert reg.counter("serving/d2h_reads") == \
             reg.counter("serving/device_ticks") == 21
         # a second prompt, two chunks long: the first chunk's step
-        # reads the harvest alone, the last chunk's one more
+        # reads the harvest alone, and so does the last chunk's
         srv.submit(list(range(1, 90)) * 2)
         assert step() == (1, 1, 1)
-        assert step() == (2, 1, 1)
+        assert step() == (1, 1, 1)
         assert step() == (1, 1, 0)
+        assert reg.counter("serving/d2h_reads") == \
+            reg.counter("serving/device_ticks")
     finally:
         srv.close()
+        metrics.set_enabled(False)
+        reg.reset()
+
+
+def test_the_step_that_ends_a_prompt_reads_nothing(
+        paged512_model_and_params):
+    """No ``jax.Array`` still running is brought home by the steps
+    that carry a prompt's chunks, the last one included: its logits
+    row goes from the chunk's output to the slot's state inside the
+    activation's program, and the first tick goes down behind it."""
+    from _served_rows import ends_a_prompt_without_a_read
+    metrics.set_enabled(True)
+    reg = metrics.get_registry()
+    reg.reset()
+    srv = _steady_server(paged512_model_and_params)
+    try:
+        ends_a_prompt_without_a_read(srv, list(range(1, 90)) * 2)
+    finally:
+        srv.close()
+        metrics.set_enabled(False)
+        reg.reset()
+
+
+def test_prompt_lengths_compile_one_activation(paged512_model_and_params):
+    """The row a prompt ends on is a traced scalar of the activation's
+    one jitted call: ten prompts of ten lengths (one and two chunks)
+    through one server compile it once, and every one of them decodes
+    to its lockstep row."""
+    from paddlefleetx_tpu.models.gpt.generation import activate_slot
+    model, params = paged512_model_and_params
+    gen_cfg = _greedy_cfg(4)
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(1, 90, n).tolist()
+               for n in (1, 2, 5, 17, 64, 127, 128, 129, 200, 250)]
+    ref = _lockstep(model, params, prompts, gen_cfg)
+    # five slots: a state no other test's server has compiled for
+    srv = GenerationServer(model, params, gen_cfg, num_slots=5,
+                           page_size=128, prefill_chunk_pages=1,
+                           prefix_sharing=False)
+    try:
+        before = activate_slot._cache_size()
+        out = srv.run(prompts)
+        assert activate_slot._cache_size() == before + 1
+    finally:
+        srv.close()
+    assert [c.tokens for c in out] == ref
+
+
+def _hold_rows(srv):
+    """Keep the registry's rows where the chunk left them until
+    something outside ``step()`` asks for them (``wait``)."""
+    land = srv._land_rows
+    srv._land_rows = lambda wait=False: land(True) if wait else None
+    return lambda: srv.__dict__.pop("_land_rows")
+
+
+def test_the_registrys_row_comes_home_behind_the_step(
+        paged512_model_and_params):
+    """A registered prompt's row is the chunk's, on the device, until
+    the step after the one that made it, and the host's copy from
+    then on: the same float32 either way, so an identical prompt
+    admitted BEFORE the swap and one admitted AFTER it both decode to
+    the producer's tokens; ``kv_export`` hands out whichever the
+    registry holds, and ``np.asarray`` takes either. The two
+    activation counters say which kind each admission was handed."""
+    from paddlefleetx_tpu.core.paging import prompt_key
+    metrics.set_enabled(True)
+    reg = metrics.get_registry()
+    reg.reset()
+    model, params = paged512_model_and_params
+    gen_cfg = _greedy_cfg(max_dec=6)
+    base = np.random.default_rng(4).integers(0, EOS, 140).tolist()
+    ref = _lockstep(model, params, [base], gen_cfg)[0]
+    srv = GenerationServer(model, params, gen_cfg, num_slots=3,
+                           page_size=128, pool_pages=12,
+                           prefill_chunk_pages=1)
+    release = _hold_rows(srv)
+    done = {}
+
+    def step():
+        for c in srv.step():
+            done[c.request_id] = c
+
+    def row():
+        return srv._alloc.lookup_prompt(prompt_key(base))[1]
+    try:
+        ids = [srv.submit(base)]
+        step(), step()                  # two chunks, then the launch
+        assert isinstance(row(), jax.Array) and len(srv._rows_out) == 1
+        pages, exported = srv.kv_export(base)
+        srv.kv_export_release(pages)
+        assert exported is row()
+        want = np.asarray(exported)     # what a read of the device gives
+        assert want.dtype == np.float32 and want.shape == (PCFG512.vocab_size,)
+        ids.append(srv.submit(base))    # a hit on the device's row
+        step()
+        assert srv._alloc.stats["prompt_hits"] == 1
+        assert (reg.counter("serving/activations/device_row"),
+                reg.counter("serving/activations/host_row")) == (2, 0)
+        release()
+        step()                          # its commit lands the row
+        assert not srv._rows_out
+        home = row()
+        assert isinstance(home, np.ndarray) and home.dtype == np.float32
+        np.testing.assert_array_equal(home, want)
+        assert srv.kv_export(base)[1] is home
+        srv.kv_export_release(pages)
+        ids.append(srv.submit(base))    # a hit on the host's row
+        _drain(srv, done)
+        assert srv._alloc.stats["prompt_hits"] == 2
+        assert (reg.counter("serving/activations/device_row"),
+                reg.counter("serving/activations/host_row")) == (2, 1)
+        assert reg.counter("serving/d2h_reads") == \
+            reg.counter("serving/device_ticks")
+    finally:
+        srv.close()
+        metrics.set_enabled(False)
+        reg.reset()
+    assert [done[i].tokens for i in ids] == [ref] * 3
+    srv._alloc.check()
+    assert srv._alloc.pages_in_use == 0
+
+
+def test_a_prefix_store_is_exported_with_host_rows(
+        paged512_model_and_params):
+    """``export_prefix_store`` is where a registry is serialised, so
+    it waits for the rows still out (outside ``step()``): every prompt
+    entry of the store carries a numpy row, whenever the server last
+    stepped, and a server that adopts the store serves the same
+    tokens."""
+    model, params = paged512_model_and_params
+    gen_cfg = _greedy_cfg(max_dec=4)
+    waves = _conv_trace(seed=13)
+    kw = dict(num_slots=2, rng=jax.random.key(5), page_size=128,
+              pool_pages=5, prefill_chunk_pages=1, prefix_sharing=True,
+              host_pool_bytes=1 << 20)
+    srv = GenerationServer(model, params, gen_cfg, **kw)
+    _hold_rows(srv)
+    try:
+        ref = [[c.tokens for c in srv.run(w)] for w in waves]
+        assert srv._rows_out            # held: no step landed one
+        store = srv.export_prefix_store()
+        assert not srv._rows_out
+    finally:
+        srv.close()
+    assert store["prompts"]
+    for pages, payload in store["prompts"].values():
+        assert isinstance(payload, np.ndarray)
+        assert payload.dtype == np.float32
+    warm = GenerationServer(model, params, gen_cfg, **kw)
+    try:
+        assert warm.import_prefix_store(store) > 0
+        assert [[c.tokens for c in warm.run(w)] for w in waves] == ref
+        assert warm.summary()["rehydrates"] > 0
+    finally:
+        warm.close()
+
+
+def test_activation_counters_tell_the_rows_apart(
+        paged512_model_and_params):
+    """One of ``serving/activations/{device_row,host_row}`` a call of
+    ``_activate``, both there from the first (a reader tells "never"
+    from "no such counter"): chunked completions and a resume after a
+    preemption are handed the chunk's row on the device; a peer's
+    prefill adopted through ``kv_import`` is a host row."""
+    metrics.set_enabled(True)
+    reg = metrics.get_registry()
+    reg.reset()
+    model, params = paged512_model_and_params
+    gen_cfg = dataclasses.replace(_greedy_cfg(8), min_dec_len=8)
+    prompts = [[5, 9, 2], list(range(1, 90)) * 2]
+    ref = _lockstep(model, params, prompts, gen_cfg)
+
+    def kinds():
+        c = reg.snapshot()["counters"]
+        return (c["serving/activations/device_row"],
+                c["serving/activations/host_row"])
+    a = GenerationServer(model, params, gen_cfg, num_slots=2,
+                         page_size=128, prefill_chunk_pages=1)
+    b = GenerationServer(model, params, gen_cfg, num_slots=2,
+                         page_size=128, prefill_chunk_pages=1)
+    done = {}
+    try:
+        ids = [a.submit(p) for p in prompts]
+        while not a.prompt_ready(prompts[1]):
+            a.step()
+        assert kinds() == (2, 0)
+        # the second request leaves mid-answer and comes back: its
+        # prompt and tokens are prefilled again, to a device row
+        part = a.preempt(ids[1])
+        assert part is not None and part.finish_reason == "preempted"
+        back = a.submit(prompts[1], resume_tokens=part.tokens)
+        _drain(a, done)
+        assert kinds() == (3, 0)
+        assert done[ids[0]].tokens == ref[0]
+        assert done[back].tokens == ref[1]
+        # a peer adopts a finished prefill: the row crosses as numpy
+        rid = a.submit(prompts[1])
+        while not a.prompt_ready(prompts[1]):
+            a.step()
+        pages, last = a.kv_export(prompts[1])
+        assert b.kv_import(prompts[1], a.kv_page_data(pages), last,
+                           len(pages))
+        a.kv_export_release(pages)
+        bid = b.submit(prompts[1])
+        got = _drain(b, {})
+        assert got[bid].tokens == ref[1]
+        assert kinds() == (4, 1)
+        b.kv_import_release(prompts[1])
+        assert _drain(a, {})[rid].tokens == ref[1]
+    finally:
+        a.close()
+        b.close()
         metrics.set_enabled(False)
         reg.reset()
 
@@ -2884,21 +3099,21 @@ def test_a_step_is_judged_against_steps_of_its_own_kind(
     try:
         for _ in range(SLOW_STEP_HISTORY):
             assert step(13, 0, "decode_harvest") == 0
-        assert step(70, 1, "prefill_harvest") == 0      # no history yet
+        assert step(70, 1, "prefill_dispatch") == 0      # no history yet
         for _ in range(8):
             assert step(45, 1, "decode_harvest") == 0
-        assert step(70, 1, "prefill_harvest") == 0      # under 5 x 45
+        assert step(70, 1, "prefill_dispatch") == 0      # under 5 x 45
         assert len(srv._recent_steps[True]) == 10
         assert len(srv._recent_steps[False]) == SLOW_STEP_HISTORY
         assert step(70, 0, "decode_harvest") == 1       # over 5 x 13
-        assert step(240, 1, "prefill_harvest") == 2     # over 5 x 45
+        assert step(240, 1, "prefill_dispatch") == 2     # over 5 x 45
         counters = reg.snapshot()["counters"]
     finally:
         srv.close()
         metrics.set_enabled(False)
         reg.reset()
     assert counters["serving/slow_step/decode_harvest"] == 1
-    assert counters["serving/slow_step/prefill_harvest"] == 1
+    assert counters["serving/slow_step/prefill_dispatch"] == 1
     assert sum(v for k, v in counters.items()
                if k.startswith("serving/slow_step_cause/")) == 2
 
@@ -3294,8 +3509,8 @@ def test_pool_exhaustion_reads_the_launch_in_flight_before_it_preempts(
 
 def test_the_deferred_harvests_counters(paged512_model_and_params):
     """What the mechanism counts: nearly every decoding step's read
-    came after the next launch; the device is still read once a
-    launch and once a prompt; a void row at most once a request that
+    came after the next launch; the device is read once a launch
+    and never for a prompt; a void row at most once a request that
     ended, and never for one that ended on its budget (its row is not
     launched past it: the device's ``dec_count`` never passes
     ``max_dec_len``)."""
@@ -3323,7 +3538,7 @@ def test_the_deferred_harvests_counters(paged512_model_and_params):
         assert c("serving/harvest_deferred") >= \
             steps - (len(prompts) + flushed)
         assert 1 <= flushed <= len(prompts)
-        assert c("serving/d2h_reads") == steps + len(prompts)
+        assert c("serving/d2h_reads") == steps
         assert c("serving/harvest_rows_void") == 0
         assert c("serving/decode_tokens") == 10 * len(prompts)
         # answers that end on EOS: a void row for some, one at most
@@ -3338,8 +3553,7 @@ def test_the_deferred_harvests_counters(paged512_model_and_params):
         ended = sum(x.finish_reason == "eos" for x in out)
         assert ended >= 1
         assert 1 <= c("serving/harvest_rows_void") <= ended
-        assert c("serving/d2h_reads") == \
-            c("serving/device_ticks") + len(prompts)
+        assert c("serving/d2h_reads") == c("serving/device_ticks")
     finally:
         metrics.set_enabled(False)
         reg.reset()

@@ -726,9 +726,8 @@ def test_server_phases_land_on_the_profilers_clock(tmp_path):
     # what a paged, non-speculative, untiered run exercises
     assert set(phases) == {
         "expire", "spill_drain", "admit", "prefill_pump",
-        "prefill_dispatch", "prefill_harvest", "page_maintenance",
-        "table_sync", "decode_dispatch", "decode_harvest", "commit",
-        "ship_spills"}
+        "prefill_dispatch", "page_maintenance", "table_sync",
+        "decode_dispatch", "decode_harvest", "commit", "ship_spills"}
     for name, spans in phases.items():
         for s, e in spans:
             assert any(rs <= s and e <= re_ for rs, re_ in roots), name
